@@ -21,9 +21,6 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _env import ensure_backend
-ensure_backend()
 
 import numpy as np
 

@@ -8,9 +8,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _env import ensure_backend
-ensure_backend()
 
 import numpy as np
 

@@ -10,7 +10,6 @@ __version__ = "0.1.0"
 
 from . import flags  # noqa: F401  (flag registry first: ops read flags)
 from .flags import get_flags, set_flags  # noqa: F401
-from . import jax_compat  # noqa: F401  (installs jax.shard_map on old jax)
 
 from .core.dtype import (  # noqa: F401
     bfloat16, bool_ as bool8, complex64, complex128, DType,

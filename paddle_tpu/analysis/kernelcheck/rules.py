@@ -72,8 +72,9 @@ KERNEL_RULES: Dict[str, str] = {
 # templates name symbolically (tile_geometry.fused_decode_env keys)
 _SPELLINGS: Dict[str, str] = {
     "_LANES": "LANES",
-    "nh * d": "qw",
-    "nkv * d": "kvw",
+    "nh": "heads",
+    "nkv": "kv_heads",
+    "nh + 2 * nkv": "qkv_heads",
 }
 
 # fused-decode entry -> the scratch template its site must match

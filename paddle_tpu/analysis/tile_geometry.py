@@ -90,8 +90,8 @@ FUSED_DECODE_ACTIVATION_IO: Tuple[Tuple[str, ...], ...] = (
     ("b_pad", "hidden"),        # out
     ("b_pad", "d"),             # sin
     ("b_pad", "d"),             # cos
-    ("b_pad", "kvw"),           # k_new
-    ("b_pad", "kvw"),           # v_new
+    ("kv_heads", "b_pad", "d"),  # k_new
+    ("kv_heads", "b_pad", "d"),  # v_new
 )
 # per-layer K/V page blocks (2 operands per grouped layer — the only
 # term that scales with the fused-layer count N)
@@ -104,31 +104,31 @@ FUSED_DECODE_KV_BLOCK: Tuple[Tuple[str, ...], ...] = (
 FUSED_DECODE_SCRATCH: Tuple[Tuple[str, ...], ...] = (
     ("b_pad", "hidden"),        # x carry
     ("b_pad", "hidden"),        # h (normed)
-    ("b_pad", "wq_cols"),       # merged qkv
-    ("b_pad", "qw"),            # attn out
+    ("qkv_heads", "b_pad", "d"),  # merged q|k|v, head-major
+    ("heads", "b_pad", "d"),    # attn out, head-major
     ("b_pad", "hidden"),        # x2 (residual)
     ("b_pad", "inter"),         # silu(g)*u
     ("b_pad", "tc_max"),        # acc a
     ("b_pad", "tc_max"),        # acc b
-    ("rep_pad", "d"),           # attn acc
-    ("rep_pad", "LANES"),       # attn m
-    ("rep_pad", "LANES"),       # attn l
+    ("rep_rows", "d"),          # attn acc
+    ("rep_rows", "LANES"),      # attn m
+    ("rep_rows", "LANES"),      # attn l
 )
 # the single-layer kernel's scratch (``fused_block_decode_pallas``):
 # same carries plus split q/k/v projections instead of the merged one
 FUSED_DECODE_SINGLE_SCRATCH: Tuple[Tuple[str, ...], ...] = (
     ("b_pad", "hidden"),        # h (normed)
-    ("b_pad", "qw"),            # q
-    ("b_pad", "kvw"),           # k_new
-    ("b_pad", "kvw"),           # v_new
-    ("b_pad", "qw"),            # attn out
+    ("heads", "b_pad", "d"),    # q, head-major
+    ("kv_heads", "b_pad", "d"),  # k_new
+    ("kv_heads", "b_pad", "d"),  # v_new
+    ("heads", "b_pad", "d"),    # attn out
     ("b_pad", "hidden"),        # x2 (residual)
     ("b_pad", "inter"),         # silu(g)*u
     ("b_pad", "tc_max"),        # acc a
     ("b_pad", "tc_max"),        # acc b
-    ("rep_pad", "d"),           # attn acc
-    ("rep_pad", "LANES"),       # attn m
-    ("rep_pad", "LANES"),       # attn l
+    ("rep_rows", "d"),          # attn acc
+    ("rep_rows", "LANES"),      # attn m
+    ("rep_rows", "LANES"),      # attn l
 )
 
 
@@ -145,8 +145,12 @@ def fused_decode_env(*, hidden: int, intermediate: int, heads: int,
     return {
         "hidden": int(hidden), "inter": int(intermediate), "d": d,
         "qw": qw, "kvw": kvw, "wq_cols": wq_cols,
+        "heads": int(heads), "kv_heads": int(kv_heads),
+        "qkv_heads": int(heads) + 2 * int(kv_heads),
         "b_pad": -(-int(batch) // 8) * 8,
-        "rep_pad": -(-rep // 8) * 8,
+        # phase A's softmax state: rep query heads x the slot's 8-row
+        # sublane window
+        "rep_rows": rep * 8,
         "tr_h": tile(int(hidden), 512),
         "tr_o": tile(qw, 512),
         "tr_i": tile(int(intermediate), 512),

@@ -3,7 +3,7 @@
 TPU-native analogue of the reference's PADDLE_ENFORCE macro family
 (paddle/fluid/platform/enforce.h, paddle/phi/core/enforce.h): typed error
 classes with readable messages. Python stack traces replace the reference's
-demangled C++ stacks; the error taxonomy mirrors paddle's error types so
+demangled C++ stacks; the error hierarchy mirrors paddle's error types so
 user code catching them ports over.
 """
 

@@ -212,11 +212,7 @@ def build_zbh1_loss_and_grads(
 
     def _vary(x):
         """Promote x to varying over the engine's manual axes (idempotent
-        per axis) — cond branches and the scan carry must agree on vma.
-        jax versions without vma tracking (< 0.6) have no varying types
-        to reconcile, so x passes through."""
-        if not hasattr(jax, "typeof") or not hasattr(jax.lax, "pcast"):
-            return x
+        per axis) — cond branches and the scan carry must agree on vma."""
         missing = tuple(a for a in vary_axes
                         if a not in jax.typeof(x).vma)
         return jax.lax.pcast(x, missing, to="varying") if missing else x

@@ -57,7 +57,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from .. import observability as obs
+from ..core.tensor import _val
 from ..testing import faults
+from .program_cache import ProgramBuildError
 from .serving import OK, Request, ServingEngine
 
 __all__ = ["FleetRouter"]
@@ -168,9 +170,7 @@ class FleetRouter:
         callbacks fire with the FLEET rid (they survive re-routing: the
         wrapper closes over it, not over any replica-local id).
         ``replica`` pins placement explicitly (tests, drains)."""
-        prompt = np.asarray(
-            prompt._value if hasattr(prompt, "_value") else prompt,
-            np.int32).reshape(-1)
+        prompt = np.asarray(_val(prompt), np.int32).reshape(-1)
         rid = self._next_rid
         self._next_rid += 1
         if replica is not None:
@@ -217,6 +217,9 @@ class FleetRouter:
             except Exception as exc:
                 if getattr(exc, "_fleet_callback", False):
                     raise       # a client callback bug, not a loss
+                if isinstance(exc, ProgramBuildError):
+                    raise       # deterministic: a rebuilt replica
+                                # would hit the same compiler refusal
                 if self._fleet_completed() > self._completed_at_loss:
                     self._consec_losses = 0     # real progress since
                 if self._consec_losses >= self.max_losses:

@@ -34,15 +34,25 @@ executes only when jax actually (re)traces. ``trace_count(key)`` is the
 retrace regression test surface (the acceptance criterion "zero retraces
 across repeated step() calls" asserts it stays at 1).
 
-Telemetry (``FLAGS_telemetry``): hits/misses/traces mirror onto the
-process metrics registry, and every dispatch that (re)traced is charged
-its full wall clock to a per-kind compile-time histogram — a retrace
-regression shows up with a COST attached, not just a count. The timing
-wrapper exists only when telemetry is on; off, ``get`` returns the bare
-compiled callable (zero added work per decode step).
+Build and run are separate steps. ``jax.jit`` traces, compiles and runs
+a new argument signature inside one call, where a compiler refusal and a
+device fault on the first run cannot be told apart; so ``get`` hands out
+a :class:`_Program` that builds each new signature itself
+(``jitted.lower(*args).compile()``) and then runs the executable. Only
+the build step raises :class:`ProgramBuildError` (the tracer's or
+compiler's own exception chained as its cause): that failure is
+deterministic, so the serving recovery seams let it through instead of
+replaying it. Whatever the executable raises when it runs — the first
+time included — is a dispatch fault and propagates unchanged. Steady
+state is one dict lookup in front of the executable's own dispatch.
 
-Memwatch (``FLAGS_memwatch``, riding the telemetry gate): the same
-wrapper banks every (re)traced program's ``CompiledMemoryStats`` into
+Telemetry (``FLAGS_telemetry``): hits/misses/traces mirror onto the
+process metrics registry, and every build is charged its wall clock to a
+per-kind compile-time histogram — a retrace regression shows up with a
+COST attached, not just a count.
+
+Memwatch (``FLAGS_memwatch``, riding the telemetry gate): every build
+banks the executable's ``CompiledMemoryStats`` into
 ``program_memory_bytes{kind,bucket,extra,section}`` — each cached
 program carries a memory signature next to its compile-time counter
 (see ``paddle_tpu/observability/memory.py``).
@@ -56,8 +66,9 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
-__all__ = ["DecodeKey", "DecodeProgramCache", "decode_program_cache",
-           "clear_decode_program_cache", "model_signature"]
+__all__ = ["DecodeKey", "DecodeProgramCache", "ProgramBuildError",
+           "decode_program_cache", "clear_decode_program_cache",
+           "model_signature"]
 
 
 class DecodeKey(NamedTuple):
@@ -72,6 +83,18 @@ class DecodeKey(NamedTuple):
     dtype: str
     flags: Tuple              # flags.snapshot(...).as_tuple()
     extra: Tuple = ()         # kind-specific, e.g. (chunk_len,)
+
+
+class ProgramBuildError(RuntimeError):
+    """A cached program failed while it was built — in its traced python
+    body, its lowering or its compile (a Mosaic refusal, VMEM or HBM
+    over budget). ``__cause__`` carries the original exception."""
+
+    def __init__(self, key: DecodeKey):
+        super().__init__(
+            f"{key.kind} program (bucket {key.batch_bucket}, extra "
+            f"{key.extra}) failed to trace/compile; see the chained cause")
+        self.key = key
 
 
 # default object.__repr__ embeds a memory address: "<X object at 0x7f..>"
@@ -111,6 +134,35 @@ def _key_tp(key: DecodeKey) -> str:
     return "1"
 
 
+class _Program:
+    """One key's jitted step, as ``get`` hands it out: called like the
+    jitted function, it builds an executable for each new argument
+    signature and runs that.
+
+    The key fixes every container argument (weights and pools ride
+    ``model_sig`` / ``page_budget`` / ``dtype``), so the executable is
+    chosen by the shapes of the top-level array arguments alone — a
+    monolithic prefill's prompt length is the one thing that varies
+    under a key. An executable checks its own avals: anything else that
+    differs is refused with a TypeError, never silently retraced."""
+
+    __slots__ = ("_cache", "key", "_jitted", "_exes")
+
+    def __init__(self, cache: "DecodeProgramCache", key: DecodeKey, jitted):
+        self._cache = cache
+        self.key = key
+        self._jitted = jitted
+        self._exes: Dict[Tuple, Any] = {}
+
+    def __call__(self, *args):
+        sig = tuple([a.shape for a in args if hasattr(a, "shape")])
+        exe = self._exes.get(sig)
+        if exe is None:
+            exe = self._exes[sig] = self._cache._build(
+                self.key, self._jitted, args)
+        return exe(*args)
+
+
 class DecodeProgramCache:
     """Thread-safe keyed cache of compiled decode steps with per-key
     trace counting."""
@@ -124,19 +176,16 @@ class DecodeProgramCache:
         # clear_decode_program_cache() to re-arm after a flag change
         self._f_build = faults.site("program_build")
         self._lock = threading.Lock()
-        self._programs: Dict[DecodeKey, Any] = {}
+        self._programs: Dict[DecodeKey, _Program] = {}
         self._trace_counts: Dict[DecodeKey, int] = {}
-        # per-key mutable trace cell [count]: the dispatch timing wrapper
-        # reads it lock-free to detect "this call (re)traced"
-        self._trace_cells: Dict[DecodeKey, List[int]] = {}
         self._compile_seconds: Dict[DecodeKey, float] = {}
+        # key -> jax.stages.Lowered of its newest build
+        self._lowered: Dict[DecodeKey, Any] = {}
         self.hits = 0
         self.misses = 0
         self._telemetry = obs.enabled()
-        # memwatch (FLAGS_memwatch, riding the telemetry gate): a
-        # dispatch that (re)traced additionally banks the program's
-        # CompiledMemoryStats — one duplicate lower+compile at exactly
-        # the moment the compile-seconds histogram already charges
+        # memwatch (FLAGS_memwatch, riding the telemetry gate): every
+        # build additionally banks the executable's CompiledMemoryStats
         self._memwatch = self._telemetry and obs.memory.enabled()
         if self._telemetry:
             r = obs.registry()
@@ -156,7 +205,7 @@ class DecodeProgramCache:
                 labels=("kind", "model", "tp"))
             self._m_compile = r.histogram(
                 "program_cache_compile_seconds",
-                "wall clock of dispatches that (re)traced — trace + "
+                "wall clock of program builds — trace + lower + "
                 "compile cost per program kind, model and tp degree",
                 labels=("kind", "model", "tp"))
         else:
@@ -165,10 +214,11 @@ class DecodeProgramCache:
 
     def get(self, key: DecodeKey,
             builder: Callable[[Callable[[], None]], Any]):
-        """Return the compiled step for ``key``, building it on first
-        use. ``builder(note_trace)`` must return the (jitted) callable
-        and arrange for ``note_trace()`` to run inside the traced body —
-        it then fires exactly once per (re)trace."""
+        """Return the step for ``key``, admitting it on first use.
+        ``builder(note_trace)`` must return the jitted callable and
+        arrange for ``note_trace()`` to run inside the traced body — it
+        then fires exactly once per (re)trace. Nothing compiles here:
+        the returned :class:`_Program` builds when it is first called."""
         with self._lock:
             fn = self._programs.get(key)
             if fn is not None:
@@ -176,9 +226,8 @@ class DecodeProgramCache:
                 self._m_hits.inc()
                 return fn
         self._f_build.check(kind=key.kind)   # injected build failure
-        fn = builder(self._tracer(key))      # may be slow: build unlocked
-        if self._telemetry:
-            fn = self._timed_dispatch(key, fn)
+        # may be slow: build unlocked
+        fn = _Program(self, key, builder(self._tracer(key)))
         with self._lock:
             cur = self._programs.setdefault(key, fn)
             if cur is fn:
@@ -190,14 +239,10 @@ class DecodeProgramCache:
             return cur
 
     def _tracer(self, key: DecodeKey) -> Callable[[], None]:
-        with self._lock:
-            cell = self._trace_cells.setdefault(key, [0])
-
         def note_trace():
             # runs INSIDE the traced python body, so it fires exactly
             # once per (re)trace — a host-side trace-TIME write, which
             # is the deliberate exception to "no telemetry under trace"
-            cell[0] += 1
             with self._lock:
                 self._trace_counts[key] = self._trace_counts.get(key, 0) + 1
             self._m_traces.labels(kind=key.kind,
@@ -205,48 +250,50 @@ class DecodeProgramCache:
                                   tp=_key_tp(key)).inc()
         return note_trace
 
-    def _timed_dispatch(self, key: DecodeKey, fn):
-        """Wrap a compiled step so any dispatch that (re)traced is
-        charged its wall clock to the compile histogram — and, with
-        memwatch on, banks the program's CompiledMemoryStats (an AOT
-        lower+compile over the SAME avals: donation only invalidates
-        buffers, avals survive, so this is safe post-dispatch and each
-        retrace re-captures with the args that caused it). Steady-state
-        cost: one list read + two perf_counter calls per step (~100 ns
-        against a ~ms decode step)."""
+    def _build(self, key: DecodeKey, jitted, args):
+        """Trace, lower and compile ``jitted`` at ``args`` (donation is
+        declared, nothing is consumed until the executable runs). A
+        failure here is the one thing that is a
+        :class:`ProgramBuildError`; with telemetry on the build is
+        charged to the compile histogram and, with memwatch on, its
+        CompiledMemoryStats are banked."""
         from .. import observability as obs
 
+        t0 = time.perf_counter()
+        try:
+            lowered = jitted.lower(*args)
+            compiled = lowered.compile()
+        except Exception as exc:
+            raise ProgramBuildError(key) from exc
+        dt = time.perf_counter() - t0
         with self._lock:
-            cell = self._trace_cells.setdefault(key, [0])
-        hist = self._m_compile.labels(kind=key.kind,
-                                      model=key.model_sig[:8],
-                                      tp=_key_tp(key))
+            self._lowered[key] = lowered
+            if self._telemetry:
+                self._compile_seconds[key] = (
+                    self._compile_seconds.get(key, 0.0) + dt)
+        model = key.model_sig[:8]
+        self._m_compile.labels(kind=key.kind, model=model,
+                               tp=_key_tp(key)).observe(dt)
+        if self._memwatch:
+            obs.memory.capture_compiled(key.kind, key.batch_bucket,
+                                        key.extra, compiled, model=model)
+        return compiled
 
-        def dispatch(*args, **kwargs):
-            before = cell[0]
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            if cell[0] != before:
-                dt = time.perf_counter() - t0
-                hist.observe(dt)
-                with self._lock:
-                    self._compile_seconds[key] = (
-                        self._compile_seconds.get(key, 0.0) + dt)
-                if self._memwatch:
-                    obs.memory.capture_program(
-                        key.kind, key.batch_bucket, key.extra,
-                        fn, args, kwargs, model=key.model_sig[:8])
-            return out
-
-        return dispatch
+    def lowered(self, key: DecodeKey):
+        """``jax.stages.Lowered`` of ``key``'s newest build — the probe
+        that shows WHICH path a compiled program took (``.as_text()``
+        names a Pallas kernel as ``tpu_custom_call``; ``.compile()``
+        gives memory and collectives)."""
+        with self._lock:
+            return self._lowered[key]
 
     def trace_count(self, key: DecodeKey) -> int:
         with self._lock:
             return self._trace_counts.get(key, 0)
 
     def compile_seconds(self, key: DecodeKey) -> float:
-        """Accumulated trace+compile wall clock banked for ``key``
-        (0.0 with telemetry off — the timing wrapper is not installed)."""
+        """Accumulated build wall clock banked for ``key`` (0.0 with
+        telemetry off)."""
         with self._lock:
             return self._compile_seconds.get(key, 0.0)
 
@@ -267,8 +314,8 @@ class DecodeProgramCache:
         with self._lock:
             self._programs.clear()
             self._trace_counts.clear()
-            self._trace_cells.clear()
             self._compile_seconds.clear()
+            self._lowered.clear()
             self.hits = self.misses = 0
 
 

@@ -33,8 +33,13 @@ import jax.numpy as jnp
 
 from .. import observability as obs
 from ..analysis import key_vocab
+# unwraps a paddle Tensor and ONLY that: duck-typing on ``._value`` also
+# matches jax.Array, whose ``_value`` property is the array copied to the
+# host — on the chip that pulled the whole KV pool back every dispatch
+from ..core.tensor import _val
 from ..kernels.paged_attention import PagedDecodeState, PagedKVCache
 from ..testing import faults
+from .program_cache import ProgramBuildError
 
 __all__ = ["ServingEngine", "Request"]
 
@@ -1093,9 +1098,7 @@ class ServingEngine:
                 "temperature > 0 requires a speculative engine "
                 "(ServingEngine(..., draft_model=...)): the spec verify "
                 "program is the engine's sampler")
-        prompt = np.asarray(
-            prompt._value if hasattr(prompt, "_value") else prompt,
-            np.int32).reshape(-1)
+        prompt = np.asarray(_val(prompt), np.int32).reshape(-1)
         if len(prompt) + max_new_tokens > self.max_seq_len:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens "
@@ -1991,12 +1994,18 @@ class ServingEngine:
         failed dispatch does NOT propagate — replay recovery (fresh
         pools, re-queue of all in-flight requests, bounded retries with
         exponential backoff) runs instead, and requests only ever end
-        in a terminal OK/FAILED/TIMEOUT status. Streaming callbacks
-        drain LAST, outside the recovery boundary: a raising callback
-        surfaces to the caller, never as a fake dispatch failure."""
+        in a terminal OK/FAILED/TIMEOUT status. A program that fails to
+        trace or compile DOES propagate: the refusal is deterministic,
+        so replaying it would only end every request FAILED a few
+        back-offs later with the compiler's words buried in a status.
+        Streaming callbacks drain LAST, outside the recovery boundary:
+        a raising callback surfaces to the caller, never as a fake
+        dispatch failure."""
         try:
             self._step_inner()
             self._consec_failures = 0
+        except ProgramBuildError:
+            raise
         except Exception as exc:
             self._recover_dispatch(exc)
         finally:
@@ -3046,10 +3055,6 @@ class ServingEngine:
             self._m.bucket.set(self.bucket)
             if migrated:
                 self._m.migrations.inc()
-
-
-def _val(x):
-    return x._value if hasattr(x, "_value") else x
 
 
 # ------------------------------------------------------ program builders

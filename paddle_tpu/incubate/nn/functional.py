@@ -317,9 +317,10 @@ def fused_multi_transformer(x, ln_scales, ln_biases, qkv_weights, qkv_biases,
                                                  decode_program_cache)
         # O(1)-per-call key: layer count + exemplar shapes + bias/extra
         # presence. Per-layer shape heterogeneity the key misses is
-        # guarded by jit's own shape keying inside the cached program —
-        # hashing every weight leaf per TOKEN is exactly the per-call
-        # host overhead this fast path exists to remove.
+        # refused by the cached executable's own aval check (a
+        # TypeError, never a wrong answer) — hashing every weight leaf
+        # per TOKEN is exactly the per-call host overhead this fast path
+        # exists to remove.
         sig = (f"L{len(w['qkv_weights'])}:{xv.shape}:{xv.dtype}:"
                f"{caches[0].shape}:{caches[0].dtype}:"
                f"{w['qkv_weights'][0].shape}:{w['ffn1_weights'][0].shape}:"

@@ -30,9 +30,11 @@ wrapper is ``flash_attention_bshd``.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -139,9 +141,8 @@ def _rep(x):
     bwd pallas_calls (~128 MB each at BH=256, S=4096). The fix — compact
     (BH, S) stats loaded as (1, block_q) lane rows and transposed
     in-kernel, plus a scratch-stat forward — is implemented behind
-    FLAGS_flash_compact_stats (parity-tested in interpret mode); it stays
-    off by default until tools/chip_sprint.py validates the changed
-    Mosaic layouts compile on a real chip."""
+    FLAGS_flash_compact_stats (parity-tested in interpret mode, compiled
+    for the chip by tests/test_chip_compile.py)."""
     return jnp.broadcast_to(x[..., None], (*x.shape, _LANES))
 
 
@@ -153,22 +154,17 @@ def _interpret() -> bool:
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying ``like``'s varying-manual-axes: inside a
     check_vma=True shard_map (e.g. the ring-attention sep region) pallas
-    outputs must declare their vma explicitly. On jax versions without
-    ``jax.typeof``/vma tracking (< 0.6) there is nothing to declare."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None:
-        vma = getattr(typeof(like), "vma", ())
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    outputs must declare their vma explicitly."""
+    vma = jax.typeof(like).vma
+    if vma:
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _compact(snap=None) -> bool:
     """FLAGS_flash_compact_stats: keep softmax stats compact (BH, S) at
     the kernel boundary — no 128x lane-replicated HBM transients. Numerics
-    are identical (parity-tested); only Mosaic layouts differ, so the
-    default stays off until tools/chip_sprint.py validates on-chip
-    compilation."""
+    are identical (parity-tested); only Mosaic layouts differ."""
     if snap is None:
         snap = _flash_snapshot()
     return bool(snap.flash_compact_stats)
@@ -811,6 +807,75 @@ def flash_attention(q, k, v, segment_ids: Optional[jax.Array] = None,
                             n_heads, n_kv_heads, _compact(snap))
 
 
+class FlashPartitionError(ValueError):
+    """The declared activation layout does not let the kernel be split
+    over the context mesh (a busy axis nobody declared, or a dim an axis
+    does not divide). Not a NotImplementedError: callers fall back to
+    the dense path on those, and this must be seen."""
+
+
+# (batch axes, head axis) of the (B, S, H, D) activations traced inside
+# ``activation_layout`` — None outside one
+_LAYOUT: contextvars.ContextVar = contextvars.ContextVar(
+    "flash_activation_layout", default=None)
+
+
+@contextlib.contextmanager
+def activation_layout(mesh, batch_axes: Tuple[str, ...] = (),
+                      head_axis: Optional[str] = None):
+    """Trace the body with ``mesh`` as the context mesh and (B, S, H, D)
+    activations declared split by batch row over ``batch_axes`` and by
+    head over ``head_axis``.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so inside this context
+    ``flash_attention_bshd`` splits the call by hand — attention is
+    independent per batch row and per head. The names come from the
+    layer that laid the activations out (``hapi.TrainStep``: its data
+    axes, and the axis its parameters are split over); this module knows
+    none. Outside a declared layout the kernel runs unsplit."""
+    token = _LAYOUT.set((tuple(batch_axes), head_axis))
+    try:
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            yield
+    finally:
+        _LAYOUT.reset(token)
+
+
+def _mesh_split(b: int, h: int, hkv: int):
+    """``(batch axes, head axis, manual axes)`` to run the kernel per
+    shard of the declared layout, or None where there is nothing to
+    split: no declared layout, one device, or a region another engine is
+    already manual over (ring attention's sep region, a pipeline stage).
+    Mosaic wants the region manual over EVERY mesh axis, unit ones too."""
+    layout = _LAYOUT.get()
+    mesh = jax.sharding.get_abstract_mesh()
+    if layout is None or mesh.empty or mesh.manual_axes:
+        return None
+    busy = {a for a, n in mesh.shape.items() if n > 1}
+    if not busy:
+        return None
+    batch = tuple(a for a in layout[0] if a in busy)
+    head = layout[1] if layout[1] in busy else None
+    loose = busy - {*batch, head}
+    if loose:
+        raise FlashPartitionError(
+            f"flash attention under mesh {dict(mesh.shape)}: axes "
+            f"{sorted(loose)} hold more than one device but the declared "
+            f"layout (batch over {layout[0]}, heads over {layout[1]!r}) "
+            f"does not say how activations lie on them")
+    nb = math.prod(mesh.shape[a] for a in batch)
+    if b % nb:
+        raise FlashPartitionError(
+            f"flash attention: batch {b} is not divisible by the "
+            f"{nb} shards of its axes {batch}")
+    if head and (h % mesh.shape[head] or hkv % mesh.shape[head]):
+        raise FlashPartitionError(
+            f"flash attention: {h} heads / {hkv} kv heads are not "
+            f"divisible by the {mesh.shape[head]} shards of {head!r}")
+    return batch or None, head, frozenset(mesh.axis_names)
+
+
 def flash_attention_bshd(q, k, v, segment_ids=None, kv_segment_ids=None,
                          causal: bool = True,
                          sm_scale: Optional[float] = None,
@@ -821,26 +886,41 @@ def flash_attention_bshd(q, k, v, segment_ids=None, kv_segment_ids=None,
     python/paddle/nn/functional/flash_attention.py uses [batch, seq, heads,
     dim]). ``segment_ids``: (B, S_q); ``kv_segment_ids``: (B, S_kv),
     defaulting to ``segment_ids`` when the lengths match. GQA: k/v may
-    carry fewer heads (Hkv | H) — never expanded in HBM."""
-    b, s, h, d = q.shape
-    skv = k.shape[1]
-    hkv = k.shape[2]
+    carry fewer heads (Hkv | H) — never expanded in HBM. Traced inside
+    :func:`activation_layout` (``hapi.TrainStep(mesh=...)`` declares
+    one) the kernel runs per (batch, head) shard of the declared axes."""
+    if segment_ids is not None and kv_segment_ids is None:
+        if q.shape[1] != k.shape[1]:
+            raise ValueError(
+                "kv_segment_ids required when q and kv lengths differ")
+        kv_segment_ids = segment_ids
 
-    def to_bhsd(t, sl, nh):
-        return jnp.swapaxes(t, 1, 2).reshape(b * nh, sl, d)
+    def local(q, k, v, segment_ids, kv_segment_ids):
+        b, s, h, d = q.shape
+        skv = k.shape[1]
+        hkv = k.shape[2]
 
-    qf = to_bhsd(q, s, h)
-    kf, vf = to_bhsd(k, skv, hkv), to_bhsd(v, skv, hkv)
-    seg_q = seg_kv = None
-    if segment_ids is not None:
-        if kv_segment_ids is None:
-            if s != skv:
-                raise ValueError(
-                    "kv_segment_ids required when q and kv lengths differ")
-            kv_segment_ids = segment_ids
-        seg_q = jnp.repeat(segment_ids, h, axis=0)
-        seg_kv = jnp.repeat(kv_segment_ids, hkv, axis=0)
-    out = flash_attention(qf, kf, vf, seg_q, seg_kv, causal, sm_scale,
-                          block_q, block_k, n_heads=h, n_kv_heads=hkv,
-                          snap=snap)
-    return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
+        def to_bhsd(t, sl, nh):
+            return jnp.swapaxes(t, 1, 2).reshape(b * nh, sl, d)
+
+        seg_q = seg_kv = None
+        if segment_ids is not None:
+            seg_q = jnp.repeat(segment_ids, h, axis=0)
+            seg_kv = jnp.repeat(kv_segment_ids, hkv, axis=0)
+        out = flash_attention(to_bhsd(q, s, h), to_bhsd(k, skv, hkv),
+                              to_bhsd(v, skv, hkv), seg_q, seg_kv, causal,
+                              sm_scale, block_q, block_k, n_heads=h,
+                              n_kv_heads=hkv, snap=snap)
+        return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
+
+    split = _mesh_split(q.shape[0], q.shape[2], k.shape[2])
+    if split is None:
+        return local(q, k, v, segment_ids, kv_segment_ids)
+    batch, head, manual = split
+    from jax.sharding import PartitionSpec as P
+    bshd, bs = P(batch, None, head, None), P(batch, None)
+    seg_spec = None if segment_ids is None else bs
+    return jax.shard_map(
+        local, in_specs=(bshd, bshd, bshd, seg_spec, seg_spec),
+        out_specs=bshd, axis_names=manual, check_vma=False)(
+            q, k, v, segment_ids, kv_segment_ids)

@@ -49,10 +49,10 @@ for one written column).
 A pure-jnp reference (``fused_block_decode_ref``) is bit-compatible with
 the UNFUSED op chain the models execute (same primitive composition and
 dtypes) — it is the CPU-CI path and the parity oracle for the kernel.
-Mosaic-layout caveat: the kernel's in-VMEM (1, rep*d) <-> (rep, d)
-head-group reshapes follow the flash compact-stats precedent — interpret
-mode proves numerics every round; on-chip compile validation banks
-through tools/chip_sprint.py like every kernel before it.
+Interpret mode proves numerics; that Mosaic compiles both kernels at
+Llama-2-7B and GQA 32/8 widths across the batch ladder is pinned by
+tests/test_chip_compile.py (see "Mosaic-provable indexing" below for the
+layout rule that makes it so).
 """
 
 from __future__ import annotations
@@ -73,6 +73,7 @@ from .paged_attention import (QuantizedPages, paged_attention_xla,
                               write_paged_kv)
 
 _NEG_INF = -1e30
+_SUB = 8    # f32 sublane tile: the batch-row window phase A works on
 
 __all__ = ["BlockDecodeWeights", "Int4Tiles", "MultiBlockDecodeWeights",
            "fused_block_decode", "fused_block_decode_pallas",
@@ -262,6 +263,161 @@ def _int4_plan(hidden: int, qw: int, kvw: int, inter: int) -> dict:
     return plan
 
 
+# ------------------------------------------------ Mosaic-provable indexing
+# Mosaic refuses a dynamic index on a TILED dim (sublane or lane) unless
+# it can prove the index tile-aligned; a dynamic index on a LEADING dim
+# is always fine. So per-head tensors live HEAD-MAJOR in VMEM —
+# (heads, b_pad, d): the head picked at run time is a leading index —
+# the batch row is reached through its aligned 8-row sublane window plus
+# a row mask, and lane windows of the 2-D carries are declared aligned.
+
+
+def _check_head_tiles(d: int, **tiles: int) -> None:
+    """Matmul tiles over a head axis scatter to / gather from the
+    head-major scratch, so each must hold whole heads (true whenever
+    ``head_dim`` divides the 128-lane tile or the tile itself)."""
+    bad = {k: v for k, v in tiles.items() if v % d}
+    if bad:
+        raise ValueError(
+            f"fused block decode: head_dim {d} does not divide the "
+            f"head-axis tiles {bad}; this shape needs the unfused path")
+
+
+def _cols(idx, size: int):
+    """The ``idx``-th ``size``-wide lane window of a 2-D carry. Phase
+    tiles are lane-tile multiples wherever the dim allows
+    (``tile_geometry.tile``), which Mosaic has to be told."""
+    start = idx * size
+    if size % _LANES == 0:
+        start = pl.multiple_of(start, size)
+    return pl.ds(start, size)
+
+
+def _scatter_heads(ref, c, acc, d: int):
+    """Emit matmul column tile ``c`` (b_pad, k*d) into the head-major
+    scratch ``ref`` (heads, b_pad, d), one static lane slice per head."""
+    per = acc.shape[1] // d
+    for i in range(per):
+        ref[c * per + i] = acc[:, i * d:(i + 1) * d]
+
+
+def _gather_heads(ref, r, per: int):
+    """Contraction tile ``r`` of a head-major scratch as (b_pad, per*d)."""
+    return jnp.concatenate([ref[r * per + i] for i in range(per)], axis=1)
+
+
+def _rope_heads_inplace(ref, n_heads: int, sin, cos):
+    """Neox rotate-half on ``ref[head]`` (b_pad, d), heads [0, n_heads)."""
+    half = sin.shape[1] // 2
+    for head in range(n_heads):
+        u = ref[head]
+        rot = jnp.concatenate([-u[:, half:], u[:, :half]], axis=1)
+        ref[head] = u * cos + rot * sin
+
+
+def _online_softmax(s, pv, am_ref, mm_ref, ll_ref):
+    """One online-softmax update of the (rows, d) accumulator with scores
+    ``s`` (rows, n); ``pv(p)`` is the weighted-value term for ``p``."""
+    m_prev = mm_ref[:, 0:1]
+    l_prev = ll_ref[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    ll_ref[...] = jnp.broadcast_to(
+        alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), ll_ref.shape)
+    mm_ref[...] = jnp.broadcast_to(m_new, mm_ref.shape)
+    am_ref[...] = alpha * am_ref[...] + pv(p)
+
+
+def _attn_phase(D, local_a, in_a, sl_ref, q_ref, kn_ref, vn_ref, q0: int,
+                kn0: int, vn0: int, gates, pools, ao_ref, am_ref, mm_ref,
+                ll_ref, new_dtype):
+    """Phase A of both fused kernels: paged attention of one (slot,
+    kv-head) over one pool page per grid step, this step's own token
+    folded from VMEM at the slot's last valid page, the result emitted
+    into the head-major ``ao_ref``.
+
+    ``q_ref[q0 + head]``, ``kn_ref[kn0 + kv_head]`` and
+    ``vn_ref[vn0 + kv_head]`` are (b_pad, d). The softmax state covers
+    the slot's whole 8-row sublane window x ``rep`` query heads (row
+    ``r*8 + i`` = head ``r``, window row ``i``); rows of the other slots
+    in the window are computed and discarded — the MXU pads to 8 rows
+    regardless, and every index stays provably aligned. ``pools[m]`` is
+    layer ``m``'s operand group, read while ``gates[m]`` holds."""
+    nkv, d, rep = D["nkv"], D["d"], D["rep"]
+    page, mp, scale = D["page"], D["mp"], D["scale"]
+    j = local_a % mp
+    bh = local_a // mp
+    h_i = bh % nkv
+    b_i = bh // nkv
+    win = pl.ds(pl.multiple_of((b_i // _SUB) * _SUB, _SUB), _SUB)
+    seq = sl_ref[b_i]
+    n_pages = jnp.maximum((seq + page - 1) // page, 1)
+    rows = rep * _SUB
+
+    @pl.when(in_a & (j == 0))
+    def _attn_init():
+        am_ref[...] = jnp.zeros_like(am_ref)
+        mm_ref[...] = jnp.full_like(mm_ref, _NEG_INF)
+        ll_ref[...] = jnp.zeros_like(ll_ref)
+
+    def _page(kp_ref, vp_ref, kps_ref=None, vps_ref=None):
+        q = q_ref[pl.ds(q0 + h_i * rep, rep), win, :].reshape(rows, d)
+        k = kp_ref[0, 0].astype(jnp.float32)           # (page, d)
+        v = vp_ref[0, 0].astype(jnp.float32)
+        if kps_ref is not None:
+            k = k * kps_ref[0, 0]                      # (page, d)*(page, 1)
+            v = v * vps_ref[0, 0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1)
+        _online_softmax(
+            jnp.where(pos < seq, s, _NEG_INF),
+            lambda p: jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32),
+            am_ref, mm_ref, ll_ref)
+
+        # the token computed THIS step attends too: fold its k/v straight
+        # from VMEM at the row's last valid page — the pool append happens
+        # after the kernel, off the critical path
+        @pl.when(j == n_pages - 1)
+        def _attn_new_token():
+            kn = kn_ref[kn0 + h_i, win, :]                  # (8, d)
+            vn = vn_ref[vn0 + h_i, win, :]
+            if kps_ref is not None:
+                # match the post-kernel quantized pool write: round-trip
+                # through the emit dtype (what write_paged_kv will see),
+                # then fake-quantize to the value a re-read dequantizes to
+                kn = _fake_quant_rows(
+                    kn.astype(new_dtype).astype(jnp.float32))
+                vn = _fake_quant_rows(
+                    vn.astype(new_dtype).astype(jnp.float32))
+            # row r*8+i meets window row i's own new token
+            kn = jnp.concatenate([kn] * rep, axis=0)
+            vn = jnp.concatenate([vn] * rep, axis=0)
+            s_new = jnp.sum(q * kn, axis=1, keepdims=True) * scale
+            _online_softmax(s_new, lambda p: p * vn,
+                            am_ref, mm_ref, ll_ref)
+
+    for gate, refs in zip(gates, pools):
+        @pl.when(in_a & gate & (j < n_pages))
+        def _attn_page(refs=refs):
+            _page(*refs)
+
+    @pl.when(in_a & (j == mp - 1))
+    def _attn_emit():
+        l = ll_ref[:, 0:1]
+        o = am_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        mine = jax.lax.broadcasted_iota(
+            jnp.int32, (_SUB, 1), 0) == b_i % _SUB
+        for r in range(rep):
+            head = h_i * rep + r
+            ao_ref[head, win, :] = jnp.where(
+                mine, o[r * _SUB:(r + 1) * _SUB], ao_ref[head, win, :])
+
+
 def _fused_block_kernel(
         bt_ref, sl_ref,                                   # scalar prefetch
         x_ref, ln1_ref, ln2_ref, wq_ref, wk_ref, wv_ref, sin_ref, cos_ref,
@@ -270,19 +426,13 @@ def _fused_block_kernel(
     D = dims
     # quantized pools ride as (payload, payload, scale, scale) operands;
     # everything after is (out, knew, vnew) then the 12 scratch refs
-    if D["kv_quant"]:
-        kp_ref, vp_ref, kps_ref, vps_ref = rest[:4]
-        rest = rest[4:]
-    else:
-        kp_ref, vp_ref = rest[:2]
-        kps_ref = vps_ref = None
-        rest = rest[2:]
+    n_pool = 4 if D["kv_quant"] else 2
+    pool_refs, rest = rest[:n_pool], rest[n_pool:]
     out_ref, knew_ref, vnew_ref = rest[:3]
-    (h_ref, qs_ref, ks_ref, vs_ref, ao_ref, x2_ref, fs_ref,
+    (h_ref, q_ref, k_ref, v_ref, ao_ref, x2_ref, fs_ref,
      acc_a, acc_b, am_ref, mm_ref, ll_ref) = rest[3:]
-    nh, nkv, d, rep = D["nh"], D["nkv"], D["d"], D["rep"]
-    page, mp = D["page"], D["mp"]
-    eps, scale = D["eps"], D["scale"]
+    nh, nkv, d = D["nh"], D["nkv"], D["d"]
+    eps = D["eps"]
     t = pl.program_id(0)
 
     # ---------------------------------------------- t == 0: pre-attn norm
@@ -292,10 +442,10 @@ def _fused_block_kernel(
         var = jnp.mean(xv * xv, axis=-1, keepdims=True)
         h_ref[:] = (xv * jax.lax.rsqrt(var + eps)
                     * ln1_ref[:].astype(jnp.float32))
-        ao_ref[:] = jnp.zeros_like(ao_ref)
+        ao_ref[...] = jnp.zeros_like(ao_ref)
 
     # ------------------------------------------------ shared matmul phase
-    def _mm(local, n_r, tr, tc, src_ref, w_ref, emit):
+    def _mm(local, n_r, tc, src, w_ref, emit):
         c = local // n_r
         r = local % n_r
 
@@ -303,135 +453,56 @@ def _fused_block_kernel(
         def _zero():
             acc_a[:, :tc] = jnp.zeros_like(acc_a[:, :tc])
 
-        src = src_ref[:, pl.ds(r * tr, tr)]
-        acc_a[:, :tc] += _f32_dot(src, w_ref[:])
+        acc_a[:, :tc] += _f32_dot(src(r), w_ref[:])
 
         @pl.when(r == n_r - 1)
         def _emit():
             emit(c, acc_a[:, :tc])
 
+    def _h_tile(r):
+        return h_ref[:, _cols(r, D["tr_h"])]
+
     # Q / K / V projections out of the VMEM-resident normed activation
     @pl.when((t >= D["off_q"]) & (t < D["off_k"]))
     def _q():
-        _mm(t - D["off_q"], D["nr_h"], D["tr_h"], D["tc_q"], h_ref, wq_ref,
-            lambda c, acc: qs_ref.__setitem__(
-                (slice(None), pl.ds(c * D["tc_q"], D["tc_q"])), acc))
+        _mm(t - D["off_q"], D["nr_h"], D["tc_q"], _h_tile, wq_ref,
+            lambda c, acc: _scatter_heads(q_ref, c, acc, d))
 
     @pl.when((t >= D["off_k"]) & (t < D["off_v"]))
     def _k():
-        _mm(t - D["off_k"], D["nr_h"], D["tr_h"], D["tc_kv"], h_ref, wk_ref,
-            lambda c, acc: ks_ref.__setitem__(
-                (slice(None), pl.ds(c * D["tc_kv"], D["tc_kv"])), acc))
+        _mm(t - D["off_k"], D["nr_h"], D["tc_kv"], _h_tile, wk_ref,
+            lambda c, acc: _scatter_heads(k_ref, c, acc, d))
 
     @pl.when((t >= D["off_v"]) & (t < D["off_r"]))
     def _v():
-        _mm(t - D["off_v"], D["nr_h"], D["tr_h"], D["tc_kv"], h_ref, wv_ref,
-            lambda c, acc: vs_ref.__setitem__(
-                (slice(None), pl.ds(c * D["tc_kv"], D["tc_kv"])), acc))
+        _mm(t - D["off_v"], D["nr_h"], D["tc_kv"], _h_tile, wv_ref,
+            lambda c, acc: _scatter_heads(v_ref, c, acc, d))
 
     # ------------------------------------- R: in-VMEM rope + k/v emission
     @pl.when(t == D["off_r"])
     def _rope():
         sin = sin_ref[:]
         cos = cos_ref[:]
-        half = d // 2
-
-        def rot(u):
-            return jnp.concatenate([-u[:, half:], u[:, :half]], axis=1)
-
-        for head in range(nh):
-            c0 = head * d
-            u = qs_ref[:, c0:c0 + d]
-            qs_ref[:, c0:c0 + d] = u * cos + rot(u) * sin
-        for head in range(nkv):
-            c0 = head * d
-            u = ks_ref[:, c0:c0 + d]
-            ks_ref[:, c0:c0 + d] = u * cos + rot(u) * sin
-        knew_ref[:] = ks_ref[:].astype(knew_ref.dtype)
-        vnew_ref[:] = vs_ref[:].astype(vnew_ref.dtype)
+        _rope_heads_inplace(q_ref, nh, sin, cos)
+        _rope_heads_inplace(k_ref, nkv, sin, cos)
+        knew_ref[...] = k_ref[...].astype(knew_ref.dtype)
+        vnew_ref[...] = v_ref[...].astype(vnew_ref.dtype)
 
     # --------------------------------------- A: paged attention, by page
-    local_a = jnp.clip(t - D["off_a"], 0, D["steps_a"] - 1)
-    j = local_a % mp
-    bh = local_a // mp
-    h_i = bh % nkv
-    b_i = bh // nkv
-    in_a = (t >= D["off_a"]) & (t < D["off_o"])
-
-    def _online(s, vblk):
-        m_prev = mm_ref[0:rep, 0:1]
-        l_prev = ll_ref[0:rep, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        ll_ref[0:rep, :] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-            (rep, ll_ref.shape[1]))
-        mm_ref[0:rep, :] = jnp.broadcast_to(m_new, (rep, mm_ref.shape[1]))
-        am_ref[0:rep, :] = alpha * am_ref[0:rep, :] + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(in_a & (j == 0))
-    def _attn_init():
-        am_ref[...] = jnp.zeros_like(am_ref)
-        mm_ref[...] = jnp.full_like(mm_ref, _NEG_INF)
-        ll_ref[...] = jnp.zeros_like(ll_ref)
-
-    seq = sl_ref[b_i]
-    n_pages = jnp.maximum((seq + page - 1) // page, 1)
-
-    @pl.when(in_a & (j < n_pages))
-    def _attn_page():
-        q = qs_ref[pl.ds(b_i, 1), pl.ds(h_i * rep * d, rep * d)]
-        q = q.reshape(rep, d)
-        k = kp_ref[0, 0].astype(jnp.float32)           # (page, d)
-        v = vp_ref[0, 0].astype(jnp.float32)
-        if D["kv_quant"]:
-            k = k * kps_ref[0, 0]                      # (page, d)*(page, 1)
-            v = v * vps_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (rep, page), 1)
-        _online(jnp.where(pos < seq, s, _NEG_INF), v)
-
-        # the token computed THIS step attends too: fold its k/v straight
-        # from VMEM at the row's last valid page — the pool append happens
-        # after the kernel, off the critical path
-        @pl.when(j == n_pages - 1)
-        def _attn_new_token():
-            kn = ks_ref[pl.ds(b_i, 1), pl.ds(h_i * d, d)]   # (1, d)
-            vn = vs_ref[pl.ds(b_i, 1), pl.ds(h_i * d, d)]
-            if D["kv_quant"]:
-                # match the post-kernel quantized pool write: round-trip
-                # through the emit dtype (what write_paged_kv will see),
-                # then fake-quantize to the value a re-read dequantizes to
-                kn = _fake_quant_rows(
-                    kn.astype(knew_ref.dtype).astype(jnp.float32))
-                vn = _fake_quant_rows(
-                    vn.astype(vnew_ref.dtype).astype(jnp.float32))
-            s_new = jax.lax.dot_general(
-                q, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (rep, 1)
-            _online(s_new, vn)
-
-    @pl.when(in_a & (j == mp - 1))
-    def _attn_emit():
-        l = ll_ref[0:rep, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o = am_ref[0:rep, :] / l_safe
-        ao_ref[pl.ds(b_i, 1), pl.ds(h_i * rep * d, rep * d)] = \
-            o.reshape(1, rep * d)
+    _attn_phase(D, jnp.clip(t - D["off_a"], 0, D["steps_a"] - 1),
+                (t >= D["off_a"]) & (t < D["off_o"]), sl_ref,
+                q_ref, k_ref, v_ref, 0, 0, 0, [True], [pool_refs],
+                ao_ref, am_ref, mm_ref, ll_ref, knew_ref.dtype)
 
     # ------------------------------- O: out-projection + first residual
     @pl.when((t >= D["off_o"]) & (t < D["off_f"]))
     def _o():
         def emit(c, acc):
-            cols = pl.ds(c * D["tc_o"], D["tc_o"])
+            cols = _cols(c, D["tc_o"])
             x2_ref[:, cols] = x_ref[:, cols].astype(jnp.float32) + acc
 
-        _mm(t - D["off_o"], D["nr_o"], D["tr_o"], D["tc_o"], ao_ref,
+        _mm(t - D["off_o"], D["nr_o"], D["tc_o"],
+            lambda r: _gather_heads(ao_ref, r, D["tr_o"] // d),
             wo_ref, emit)
 
     # ------------------------------------- F: ffn norm + SwiGLU gate/up
@@ -456,24 +527,24 @@ def _fused_block_kernel(
             acc_a[:, :tc] = jnp.zeros_like(acc_a[:, :tc])
             acc_b[:, :tc] = jnp.zeros_like(acc_b[:, :tc])
 
-        src = h_ref[:, pl.ds(r * D["tr_h"], D["tr_h"])]
+        src = _h_tile(r)
         acc_a[:, :tc] += _f32_dot(src, wg_ref[:])
         acc_b[:, :tc] += _f32_dot(src, wu_ref[:])
 
         @pl.when(r == D["nr_h"] - 1)
         def _emit():
             g = acc_a[:, :tc]
-            fs_ref[:, pl.ds(c * tc, tc)] = jax.nn.silu(g) * acc_b[:, :tc]
+            fs_ref[:, _cols(c, tc)] = jax.nn.silu(g) * acc_b[:, :tc]
 
     # ---------------------------- D: down-projection + second residual
     @pl.when(t >= D["off_d"])
     def _d():
         def emit(c, acc):
-            x2 = x2_ref[:, pl.ds(c * D["tc_d"], D["tc_d"])]
+            x2 = x2_ref[:, _cols(c, D["tc_d"])]
             out_ref[:, :] = (x2 + acc).astype(out_ref.dtype)
 
-        _mm(t - D["off_d"], D["nr_i"], D["tr_i"], D["tc_d"], fs_ref,
-            wd_ref, emit)
+        _mm(t - D["off_d"], D["nr_i"], D["tc_d"],
+            lambda r: fs_ref[:, _cols(r, D["tr_i"])], wd_ref, emit)
 
 
 def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
@@ -507,8 +578,8 @@ def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
 
     bt = jnp.asarray(block_tables, jnp.int32)
     sl = jnp.asarray(seq_lens, jnp.int32)
-    b_pad = -(-b // 8) * 8
-    rep_pad = -(-rep // 8) * 8
+    b_pad = -(-b // _SUB) * _SUB
+    rep_rows = rep * _SUB
 
     sin, cos = _rope_tables(sl, d, rope_theta)
     if b_pad != b:
@@ -528,6 +599,7 @@ def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
     tr_i = _tile(inter, 512)        # FFN contraction rows (D)
     tc_q = _tile(nh * d, 256)
     tc_kv = _tile(nkv * d, 256)
+    _check_head_tiles(d, tr_o=tr_o, tc_q=tc_q, tc_kv=tc_kv)
     tc_o = _tile(hidden, 256)
     tc_f = _tile(inter, 256)
     tc_d = _tile(hidden, 256)
@@ -565,6 +637,9 @@ def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
 
     def _const(*_args):
         return (0, 0)
+
+    def _const3(*_args):
+        return (0, 0, 0)
 
     def _phase_map(off, steps, n_r):
         def index(t, bt_ref, sl_ref):
@@ -618,22 +693,22 @@ def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
         ] if kv_quant else []),
         out_specs=[
             pl.BlockSpec((b_pad, tc_d), _out_map),                  # out
-            pl.BlockSpec((b_pad, nkv * d), _const),                 # k_new
-            pl.BlockSpec((b_pad, nkv * d), _const),                 # v_new
+            pl.BlockSpec((nkv, b_pad, d), _const3),                 # k_new
+            pl.BlockSpec((nkv, b_pad, d), _const3),                 # v_new
         ],
         scratch_shapes=[
             pltpu.VMEM((b_pad, hidden), jnp.float32),     # h (normed)
-            pltpu.VMEM((b_pad, nh * d), jnp.float32),     # q
-            pltpu.VMEM((b_pad, nkv * d), jnp.float32),    # k_new
-            pltpu.VMEM((b_pad, nkv * d), jnp.float32),    # v_new
-            pltpu.VMEM((b_pad, nh * d), jnp.float32),     # attn out
+            pltpu.VMEM((nh, b_pad, d), jnp.float32),      # q
+            pltpu.VMEM((nkv, b_pad, d), jnp.float32),     # k_new
+            pltpu.VMEM((nkv, b_pad, d), jnp.float32),     # v_new
+            pltpu.VMEM((nh, b_pad, d), jnp.float32),      # attn out
             pltpu.VMEM((b_pad, hidden), jnp.float32),     # x2 (residual)
             pltpu.VMEM((b_pad, inter), jnp.float32),      # silu(g)*u
             pltpu.VMEM((b_pad, tc_max), jnp.float32),     # acc a
             pltpu.VMEM((b_pad, tc_max), jnp.float32),     # acc b
-            pltpu.VMEM((rep_pad, d), jnp.float32),        # attn acc
-            pltpu.VMEM((rep_pad, _LANES), jnp.float32),   # attn m
-            pltpu.VMEM((rep_pad, _LANES), jnp.float32),   # attn l
+            pltpu.VMEM((rep_rows, d), jnp.float32),       # attn acc
+            pltpu.VMEM((rep_rows, _LANES), jnp.float32),  # attn m
+            pltpu.VMEM((rep_rows, _LANES), jnp.float32),  # attn l
         ],
     )
 
@@ -644,8 +719,8 @@ def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, hidden), x.dtype),
-            jax.ShapeDtypeStruct((b_pad, nkv * d), x.dtype),
-            jax.ShapeDtypeStruct((b_pad, nkv * d), x.dtype),
+            jax.ShapeDtypeStruct((nkv, b_pad, d), x.dtype),
+            jax.ShapeDtypeStruct((nkv, b_pad, d), x.dtype),
         ],
         interpret=interpret,
     )(bt_p, sl_p, x_p, weights.ln1.reshape(1, hidden),
@@ -654,8 +729,8 @@ def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
       *pool_ops)
 
     k_pages, v_pages = write_paged_kv(
-        k_pages, v_pages, k_new[:b].reshape(b, nkv, d),
-        v_new[:b].reshape(b, nkv, d), bt, sl)
+        k_pages, v_pages, k_new[:, :b].swapaxes(0, 1),
+        v_new[:, :b].swapaxes(0, 1), bt, sl)
     return out[:b], k_pages, v_pages
 
 
@@ -946,11 +1021,8 @@ def _fused_multi_block_kernel(bt_ref, sl_ref,                 # scalar prefetch
         full = jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
         return full * w_sc[0, 0, 0]
 
-    nh, nkv, d, rep = D["nh"], D["nkv"], D["d"], D["rep"]
-    page, mp = D["page"], D["mp"]
-    eps, scale = D["eps"], D["scale"]
-    qw = nh * d
-    kvw = nkv * d
+    nh, nkv, d = D["nh"], D["nkv"], D["d"]
+    eps = D["eps"]
     per = D["per_layer"]
     t = pl.program_id(0)
     layer = t // per
@@ -967,11 +1039,11 @@ def _fused_multi_block_kernel(bt_ref, sl_ref,                 # scalar prefetch
         xv = xc_ref[:]
         var = jnp.mean(xv * xv, axis=-1, keepdims=True)
         h_ref[:] = (xv * jax.lax.rsqrt(var + eps)
-                    * ln1_ref[:].astype(jnp.float32))
-        ao_ref[:] = jnp.zeros_like(ao_ref)
+                    * ln1_ref[0].astype(jnp.float32))
+        ao_ref[...] = jnp.zeros_like(ao_ref)
 
     # ------------------------------------------------ shared matmul phase
-    def _mm(local, n_r, tr, tc, src_ref, w_ref, emit, w_sc=None):
+    def _mm(local, n_r, tc, src, w_ref, emit, w_sc=None):
         c = local // n_r
         r = local % n_r
 
@@ -979,132 +1051,52 @@ def _fused_multi_block_kernel(bt_ref, sl_ref,                 # scalar prefetch
         def _zero():
             acc_a[:, :tc] = jnp.zeros_like(acc_a[:, :tc])
 
-        src = src_ref[:, pl.ds(r * tr, tr)]
-        acc_a[:, :tc] += _f32_dot(src, _load(w_ref, w_sc))
+        acc_a[:, :tc] += _f32_dot(src(r), _load(w_ref, w_sc))
 
         @pl.when(r == n_r - 1)
         def _emit():
             emit(c, acc_a[:, :tc])
 
-    # ------------------------ QKV: ONE merged matmul into the qkv scratch
+    def _h_tile(r):
+        return h_ref[:, _cols(r, D["tr_h"])]
+
+    # ------- QKV: ONE merged matmul into the head-major q|k|v scratch
+    # (heads [0, nh) are q, [nh, nh+nkv) are k, the rest v)
     @pl.when((lt >= D["off_qkv"]) & (lt < D["off_r"]))
     def _qkv():
-        _mm(lt - D["off_qkv"], D["nr_h"], D["tr_h"], D["tc_qkv"], h_ref,
-            wqkv_ref,
-            lambda c, acc: qkv_ref.__setitem__(
-                (slice(None), pl.ds(c * D["tc_qkv"], D["tc_qkv"])), acc),
+        _mm(lt - D["off_qkv"], D["nr_h"], D["tc_qkv"], _h_tile, wqkv_ref,
+            lambda c, acc: _scatter_heads(qkv_ref, c, acc, d),
             w_sc=wqkv_sc)
 
     # ------------------------------------- R: in-VMEM rope + k/v emission
     @pl.when(lt == D["off_r"])
     def _rope():
-        sin = sin_ref[:]
-        cos = cos_ref[:]
-        half = d // 2
-
-        def rot(u):
-            return jnp.concatenate([-u[:, half:], u[:, :half]], axis=1)
-
-        for head in range(nh):
-            c0 = head * d
-            u = qkv_ref[:, c0:c0 + d]
-            qkv_ref[:, c0:c0 + d] = u * cos + rot(u) * sin
-        for head in range(nkv):
-            c0 = qw + head * d
-            u = qkv_ref[:, c0:c0 + d]
-            qkv_ref[:, c0:c0 + d] = u * cos + rot(u) * sin
-        knew_ref[0] = qkv_ref[:, qw:qw + kvw].astype(knew_ref.dtype)
-        vnew_ref[0] = qkv_ref[:, qw + kvw:qw + 2 * kvw].astype(
-            vnew_ref.dtype)
+        _rope_heads_inplace(qkv_ref, nh + nkv, sin_ref[:], cos_ref[:])
+        knew_ref[0] = qkv_ref[nh:nh + nkv].astype(knew_ref.dtype)
+        vnew_ref[0] = qkv_ref[nh + nkv:].astype(vnew_ref.dtype)
 
     # --------------------------------------- A: paged attention, by page
-    local_a = jnp.clip(lt - D["off_a"], 0, D["steps_a"] - 1)
-    j = local_a % mp
-    bh = local_a // mp
-    h_i = bh % nkv
-    b_i = bh // nkv
-    in_a = (lt >= D["off_a"]) & (lt < D["off_o"])
-
-    def _online(s, vblk):
-        m_prev = mm_ref[0:rep, 0:1]
-        l_prev = ll_ref[0:rep, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        ll_ref[0:rep, :] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-            (rep, ll_ref.shape[1]))
-        mm_ref[0:rep, :] = jnp.broadcast_to(m_new, (rep, mm_ref.shape[1]))
-        am_ref[0:rep, :] = alpha * am_ref[0:rep, :] + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(in_a & (j == 0))
-    def _attn_init():
-        am_ref[...] = jnp.zeros_like(am_ref)
-        mm_ref[...] = jnp.full_like(mm_ref, _NEG_INF)
-        ll_ref[...] = jnp.zeros_like(ll_ref)
-
-    seq = sl_ref[b_i]
-    n_pages = jnp.maximum((seq + page - 1) // page, 1)
-
-    def _attn_page(kp_ref, vp_ref, kps_ref=None, vps_ref=None):
-        q = qkv_ref[pl.ds(b_i, 1), pl.ds(h_i * rep * d, rep * d)]
-        q = q.reshape(rep, d)
-        k = kp_ref[0, 0].astype(jnp.float32)           # (page, d)
-        v = vp_ref[0, 0].astype(jnp.float32)
-        if kps_ref is not None:
-            k = k * kps_ref[0, 0]                      # (page, d)*(page, 1)
-            v = v * vps_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (rep, page), 1)
-        _online(jnp.where(pos < seq, s, _NEG_INF), v)
-
-        # this step's own token attends too: fold its k/v from VMEM at
-        # the row's last valid page (the pool append happens post-kernel)
-        @pl.when(j == n_pages - 1)
-        def _attn_new_token():
-            kn = qkv_ref[pl.ds(b_i, 1), pl.ds(qw + h_i * d, d)]
-            vn = qkv_ref[pl.ds(b_i, 1), pl.ds(qw + kvw + h_i * d, d)]
-            if D["kv_quant"]:
-                # match the post-kernel quantized pool write (see the
-                # single-layer kernel's fold for the contract)
-                kn = _fake_quant_rows(
-                    kn.astype(knew_ref.dtype).astype(jnp.float32))
-                vn = _fake_quant_rows(
-                    vn.astype(vnew_ref.dtype).astype(jnp.float32))
-            s_new = jax.lax.dot_general(
-                q, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (rep, 1)
-            _online(s_new, vn)
-
     # each layer reads ITS pool operand group: the layer gate is unrolled
     # over the static group size so the body indexes a python list, and
     # the operands' index maps freeze inactive layers at page 0 (no
     # spurious refetch mid-phase)
-    for m in range(n_layers):
-        @pl.when(in_a & (layer == m) & (j < n_pages))
-        def _attn_m(m=m):
-            _attn_page(*pool_refs[stride * m:stride * (m + 1)])
-
-    @pl.when(in_a & (j == mp - 1))
-    def _attn_emit():
-        l = ll_ref[0:rep, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o = am_ref[0:rep, :] / l_safe
-        ao_ref[pl.ds(b_i, 1), pl.ds(h_i * rep * d, rep * d)] = \
-            o.reshape(1, rep * d)
+    _attn_phase(D, jnp.clip(lt - D["off_a"], 0, D["steps_a"] - 1),
+                (lt >= D["off_a"]) & (lt < D["off_o"]), sl_ref,
+                qkv_ref, qkv_ref, qkv_ref, 0, nh, nh + nkv,
+                [layer == m for m in range(n_layers)],
+                [pool_refs[stride * m:stride * (m + 1)]
+                 for m in range(n_layers)],
+                ao_ref, am_ref, mm_ref, ll_ref, knew_ref.dtype)
 
     # ------------------------------- O: out-projection + first residual
     @pl.when((lt >= D["off_o"]) & (lt < D["off_f"]))
     def _o():
         def emit(c, acc):
-            cols = pl.ds(c * D["tc_o"], D["tc_o"])
+            cols = _cols(c, D["tc_o"])
             x2_ref[:, cols] = xc_ref[:, cols] + acc
 
-        _mm(lt - D["off_o"], D["nr_o"], D["tr_o"], D["tc_o"], ao_ref,
+        _mm(lt - D["off_o"], D["nr_o"], D["tc_o"],
+            lambda r: _gather_heads(ao_ref, r, D["tr_o"] // d),
             wo_ref, emit, w_sc=wo_sc)
 
     # --------------------- F: ffn norm + merged gate|up (two col-offset
@@ -1117,7 +1109,7 @@ def _fused_multi_block_kernel(bt_ref, sl_ref,                 # scalar prefetch
         xv = x2_ref[:]
         var = jnp.mean(xv * xv, axis=-1, keepdims=True)
         h_ref[:] = (xv * jax.lax.rsqrt(var + eps)
-                    * ln2_ref[:].astype(jnp.float32))
+                    * ln2_ref[0].astype(jnp.float32))
 
     @pl.when(in_f)
     def _f():
@@ -1130,14 +1122,14 @@ def _fused_multi_block_kernel(bt_ref, sl_ref,                 # scalar prefetch
             acc_a[:, :tc] = jnp.zeros_like(acc_a[:, :tc])
             acc_b[:, :tc] = jnp.zeros_like(acc_b[:, :tc])
 
-        src = h_ref[:, pl.ds(r * D["tr_h"], D["tr_h"])]
+        src = _h_tile(r)
         acc_a[:, :tc] += _f32_dot(src, _load(wg_ref, wg_sc))
         acc_b[:, :tc] += _f32_dot(src, _load(wu_ref, wu_sc))
 
         @pl.when(r == D["nr_h"] - 1)
         def _emit():
             g = acc_a[:, :tc]
-            fs_ref[:, pl.ds(c * tc, tc)] = jax.nn.silu(g) * acc_b[:, :tc]
+            fs_ref[:, _cols(c, tc)] = jax.nn.silu(g) * acc_b[:, :tc]
 
     # --------- D: down-projection + second residual. The next layer's
     # activation rounds through the activation dtype (matching the
@@ -1147,13 +1139,14 @@ def _fused_multi_block_kernel(bt_ref, sl_ref,                 # scalar prefetch
     @pl.when(lt >= D["off_d"])
     def _d():
         def emit(c, acc):
-            cols = pl.ds(c * D["tc_d"], D["tc_d"])
+            cols = _cols(c, D["tc_d"])
             nxt = (x2_ref[:, cols] + acc).astype(out_ref.dtype)
             out_ref[:, cols] = nxt
             xc_ref[:, cols] = nxt.astype(jnp.float32)
 
-        _mm(lt - D["off_d"], D["nr_i"], D["tr_i"], D["tc_d"], fs_ref,
-            wd_ref, emit, w_sc=wd_sc)
+        _mm(lt - D["off_d"], D["nr_i"], D["tc_d"],
+            lambda r: fs_ref[:, _cols(r, D["tr_i"])], wd_ref, emit,
+            w_sc=wd_sc)
 
 
 def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
@@ -1190,8 +1183,8 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
 
     bt = jnp.asarray(block_tables, jnp.int32)
     sl = jnp.asarray(seq_lens, jnp.int32)
-    b_pad = -(-b // 8) * 8
-    rep_pad = -(-rep // 8) * 8
+    b_pad = -(-b // _SUB) * _SUB
+    rep_rows = rep * _SUB
 
     sin, cos = _rope_tables(sl, d, rope_theta)
     if b_pad != b:
@@ -1207,6 +1200,7 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
     tr_o = _tile(qw, 512)
     tr_i = _tile(inter, 512)
     tc_qkv = _tile(wq_cols, 256)
+    _check_head_tiles(d, tr_o=tr_o, tc_qkv=tc_qkv)
     tc_o = _tile(hidden, 256)
     tc_f = _tile(inter, 256)
     tc_d = _tile(hidden, 256)
@@ -1245,7 +1239,7 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
         return (0, 0)
 
     def _ln_map(t, bt_ref, sl_ref):
-        return (t // per, 0)
+        return (t // per, 0, 0)
 
     def _phase_map(off, steps, n_r):
         def index(t, bt_ref, sl_ref):
@@ -1268,7 +1262,7 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
         return index
 
     def _kv_out_map(t, bt_ref, sl_ref):
-        return (t // per, 0, 0)
+        return (t // per, 0, 0, 0)
 
     # int4 weights stream HALF-row packed payload blocks, each chased by
     # its (1, 1, 1) per-tile scale under the SAME index map (block index
@@ -1277,21 +1271,28 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
     def _wrows(tr):
         return tr // 2 if wt_quant else tr
 
+    # the stacked (n, H) norm weights ride a unit middle axis: a
+    # (1, H) block of an (n, H) array breaks Mosaic's second-minor rule,
+    # a (1, 1, H) block of (n, 1, H) spans the tiled dims whole
     in_specs = [
         pl.BlockSpec((b_pad, hidden), _const),                      # x
-        pl.BlockSpec((1, hidden), _ln_map),                         # ln1
-        pl.BlockSpec((1, hidden), _ln_map),                         # ln2
+        pl.BlockSpec((1, 1, hidden), _ln_map),                      # ln1
+        pl.BlockSpec((1, 1, hidden), _ln_map),                      # ln2
     ]
-    operands = [bt_p, sl_p, x_p, weights.ln1, weights.ln2]
+    operands = [bt_p, sl_p, x_p, weights.ln1[:, None, :],
+                weights.ln2[:, None, :]]
 
     def _weight(w, spec, imap):
         in_specs.append(spec)
         if wt_quant:
             operands.append(w.q)
-            # int4 tile scale: one scalar per (row, col) weight tile
-            # by design  # kernelcheck: disable=KRN001
-            in_specs.append(pl.BlockSpec((1, 1, 1), imap))
-            operands.append(w.scale)
+            # int4 tile scale: one scalar per (row, col) weight tile by
+            # design, given unit tiled dims for the same block rule
+            # kernelcheck: disable=KRN001
+            sc_spec = pl.BlockSpec((1, 1, 1, 1, 1),
+                                   lambda *a: imap(*a) + (0, 0))
+            in_specs.append(sc_spec)
+            operands.append(w.scale[..., None, None])
         else:
             operands.append(w)
 
@@ -1333,21 +1334,21 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((b_pad, hidden), _const),                  # out
-            pl.BlockSpec((1, b_pad, kvw), _kv_out_map),             # k_new
-            pl.BlockSpec((1, b_pad, kvw), _kv_out_map),             # v_new
+            pl.BlockSpec((1, nkv, b_pad, d), _kv_out_map),          # k_new
+            pl.BlockSpec((1, nkv, b_pad, d), _kv_out_map),          # v_new
         ],
         scratch_shapes=[
             pltpu.VMEM((b_pad, hidden), jnp.float32),     # x carry
             pltpu.VMEM((b_pad, hidden), jnp.float32),     # h (normed)
-            pltpu.VMEM((b_pad, wq_cols), jnp.float32),    # merged qkv
-            pltpu.VMEM((b_pad, qw), jnp.float32),         # attn out
+            pltpu.VMEM((nh + 2 * nkv, b_pad, d), jnp.float32),  # q|k|v
+            pltpu.VMEM((nh, b_pad, d), jnp.float32),      # attn out
             pltpu.VMEM((b_pad, hidden), jnp.float32),     # x2 (residual)
             pltpu.VMEM((b_pad, inter), jnp.float32),      # silu(g)*u
             pltpu.VMEM((b_pad, tc_max), jnp.float32),     # acc a
             pltpu.VMEM((b_pad, tc_max), jnp.float32),     # acc b
-            pltpu.VMEM((rep_pad, d), jnp.float32),        # attn acc
-            pltpu.VMEM((rep_pad, _LANES), jnp.float32),   # attn m
-            pltpu.VMEM((rep_pad, _LANES), jnp.float32),   # attn l
+            pltpu.VMEM((rep_rows, d), jnp.float32),       # attn acc
+            pltpu.VMEM((rep_rows, _LANES), jnp.float32),  # attn m
+            pltpu.VMEM((rep_rows, _LANES), jnp.float32),  # attn l
         ],
     )
 
@@ -1356,8 +1357,8 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, hidden), x.dtype),
-            jax.ShapeDtypeStruct((n_layers, b_pad, kvw), x.dtype),
-            jax.ShapeDtypeStruct((n_layers, b_pad, kvw), x.dtype),
+            jax.ShapeDtypeStruct((n_layers, nkv, b_pad, d), x.dtype),
+            jax.ShapeDtypeStruct((n_layers, nkv, b_pad, d), x.dtype),
         ],
         interpret=interpret,
     )(*operands)
@@ -1365,8 +1366,8 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
     kps, vps = list(k_pages), list(v_pages)
     for i in range(n_layers):
         kps[i], vps[i] = write_paged_kv(
-            kps[i], vps[i], k_new[i, :b].reshape(b, nkv, d),
-            v_new[i, :b].reshape(b, nkv, d), bt, sl)
+            kps[i], vps[i], k_new[i, :, :b].swapaxes(0, 1),
+            v_new[i, :, :b].swapaxes(0, 1), bt, sl)
     return out[:b], kps, vps
 
 

@@ -749,9 +749,7 @@ def paged_scaled_dot_product_attention(query, key, value, state):
     # a pytree node, not a leaf) — unwrap to raw arrays for the kernels
     def _raw_pages(p):
         if isinstance(p, QuantizedPages):
-            return QuantizedPages(
-                p.q._value if hasattr(p.q, "_value") else p.q,
-                p.scale._value if hasattr(p.scale, "_value") else p.scale)
+            return QuantizedPages(_val(p.q), _val(p.scale))
         return p
 
     def fn(qv, kv, vv, kp, vp, bt, sl):

@@ -743,8 +743,7 @@ class FractionalMaxPool3D(Layer):
 def _fractional_pool(x, output_size, nd, return_mask=False):
     from ...core.tensor import apply_op as _ap
     import jax.numpy as _jnp
-    v = x._value if hasattr(x, "_value") else x
-    spatial = v.shape[-nd:]
+    spatial = x.shape[-nd:]
     outs = ((output_size,) * nd if isinstance(output_size, int)
             else tuple(output_size))
 

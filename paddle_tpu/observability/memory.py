@@ -9,13 +9,14 @@ go", with three instruments sharing one accounting vocabulary:
      ``CompiledMemoryStats`` (argument / output / temp / alias /
      generated-code bytes, plus the derived peak) into the registry as
      ``program_memory_bytes{kind,bucket,extra,section}`` gauges and a
-     host-side row table (:func:`program_table`). Capture costs ONE
-     duplicate ``lower().compile()`` per (re)trace — XLA's buffer
-     assignment is the only source of truth for temp/peak, and this
-     jaxlib exposes no handle to the executable the jit dispatch itself
-     built. The cost lands exactly where r09's compile-seconds histogram
-     already charges retraces; ``FLAGS_memwatch=0`` drops it while
-     keeping the rest of telemetry.
+     host-side row table (:func:`program_table`). XLA's buffer
+     assignment is the only source of truth for temp/peak. The program
+     cache builds its executables itself and hands them over
+     (:func:`capture_compiled`, no extra compile); a ``TrainStep``
+     dispatches through ``jax.jit``, which exposes no handle to the
+     executable it built, so its capture costs ONE duplicate
+     ``lower().compile()`` per (re)trace. ``FLAGS_memwatch=0`` drops
+     both while keeping the rest of telemetry.
   2. **Live pool ledger** — the serving engine publishes its
      :class:`~paddle_tpu.kernels.paged_attention.PagedKVCache` ledger
      (pages/bytes used, free, shared, pinned; free-list fragmentation)
@@ -34,7 +35,7 @@ go", with three instruments sharing one accounting vocabulary:
 Gating follows the r09 contract exactly: everything is host-side (the
 capture itself runs at trace time, never under trace), rides
 ``FLAGS_telemetry`` (off = the null-stub binding, zero residue), and
-``FLAGS_memwatch`` additionally gates the duplicate-compile capture.
+``FLAGS_memwatch`` additionally gates the compiled-program capture.
 Neither flag is in ``PROGRAM_FLAGS`` — toggling them never recompiles a
 serving or train program.
 """
@@ -49,7 +50,8 @@ import numpy as np
 
 __all__ = [
     "enabled", "stats_from_compiled", "capture_jitted", "capture_program",
-    "record_program", "program_table", "clear_program_table",
+    "capture_compiled", "record_program", "program_table",
+    "clear_program_table",
     "sample_device_memory", "section",
     "estimate_program", "estimate_decode_program", "estimate_prefill_program",
     "estimate_engine_memory", "fits", "sharded_param_bytes",
@@ -140,10 +142,26 @@ def capture_program(kind: str, bucket: int, extra: Any, fn,
                     args: Sequence[Any],
                     kwargs: Optional[Dict[str, Any]] = None,
                     model: str = "") -> bool:
-    """Capture + record one cached program (the program-cache /
-    TrainStep hook). Failures are counted, never raised — memory
-    accounting must not take down a dispatch that already succeeded."""
-    stats = capture_jitted(fn, args, kwargs)
+    """Capture + record one jitted program (the TrainStep hook).
+    Failures are counted, never raised — memory accounting must not
+    take down a dispatch that already succeeded."""
+    return _bank(kind, bucket, extra, capture_jitted(fn, args, kwargs),
+                 model)
+
+
+def capture_compiled(kind: str, bucket: int, extra: Any, compiled,
+                     model: str = "") -> bool:
+    """Record an executable the caller already holds (the program cache
+    builds its programs explicitly, so nothing compiles twice). Same
+    failure contract as :func:`capture_program`."""
+    try:
+        stats = stats_from_compiled(compiled)
+    except Exception:
+        stats = None
+    return _bank(kind, bucket, extra, stats, model)
+
+
+def _bank(kind, bucket, extra, stats, model) -> bool:
     if stats is None:
         from .metrics import registry
         registry().counter(

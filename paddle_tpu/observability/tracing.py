@@ -37,25 +37,6 @@ try:                                    # the annotation is optional:
 except Exception:                       # pragma: no cover - import guard
     _ANNOTATION = None
 
-try:
-    # jax 0.4.x internal: the live profiler session. Entering a
-    # TraceAnnotation costs ~10 µs per span on the decode hot path;
-    # outside a capture it annotates nothing, so spans skip it unless a
-    # session is actually recording. Private API — on any drift we fall
-    # back to always annotating (correct, just slower under load).
-    from jax._src.profiler import _profile_state as _JAX_PROFILE_STATE
-except Exception:                       # pragma: no cover - version drift
-    _JAX_PROFILE_STATE = None
-
-
-def _capture_active() -> bool:
-    if _JAX_PROFILE_STATE is None:
-        return True                     # can't tell: keep annotations
-    try:
-        return _JAX_PROFILE_STATE.profile_session is not None
-    except Exception:                   # pragma: no cover - state drift
-        return True
-
 
 class Span:
     """One timed scope. Context manager; also usable as a decorator
@@ -74,7 +55,7 @@ class Span:
         self._ann = None
 
     def __enter__(self) -> "Span":
-        if _ANNOTATION is not None and _capture_active():
+        if _ANNOTATION is not None:
             try:
                 self._ann = _ANNOTATION(self.name)
                 self._ann.__enter__()

@@ -112,11 +112,10 @@ def fc(x, size, num_flatten_dims=1, weight_attr=None, bias_attr=None,
     from ..nn.layers.common import Linear
     from ..ops import manipulation
     from .. import nn as _nn
-    v = x._value if hasattr(x, "_value") else x
     in_features = 1
-    for s in v.shape[num_flatten_dims:]:
+    for s in x.shape[num_flatten_dims:]:
         in_features *= int(s)
-    if tuple(v.shape[num_flatten_dims:]) != (in_features,):
+    if tuple(x.shape[num_flatten_dims:]) != (in_features,):
         x = manipulation.flatten(x, start_axis=num_flatten_dims)
     layer = Linear(in_features, size, weight_attr=weight_attr,
                    bias_attr=bias_attr)

@@ -41,11 +41,10 @@ def run_check() -> None:
 
     if len(devices) > 1:
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         mesh = Mesh(np.array(devices), axis_names=("x",))
-        f = shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
-                      in_specs=P("x"), out_specs=P())
+        f = jax.shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
+                          in_specs=P("x"), out_specs=P())
         out = f(jnp.ones((len(devices), 8)))
         assert float(out.ravel()[0]) == float(len(devices))
         print(f"paddle_tpu {len(devices)}-device collective (psum): OK")

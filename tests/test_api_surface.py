@@ -285,6 +285,38 @@ class TestGradientFlowThroughNewSurface:
         assert w.grad is not None
 
 
+class TestPlaceNamesARealDevice:
+    """A Place resolves to the device it names or raises — a tensor asked
+    onto the TPU never lands silently on whatever backend there is."""
+
+    def test_cpu_place_resolves_by_id(self):
+        t = paddle.to_tensor(np.ones(3, np.float32), place=paddle.CPUPlace(2))
+        assert [d.id for d in t._value.devices()] == [2]
+        assert t.place == paddle.CPUPlace(0) and t.place.is_cpu_place()
+
+    @pytest.mark.parametrize("place", [paddle.TPUPlace(0),
+                                       paddle.CUDAPlace(0)])
+    def test_absent_platform_raises(self, place):
+        from paddle_tpu.core.enforce import UnavailableError
+        with pytest.raises(UnavailableError, match="no 'tpu' backend"):
+            paddle.to_tensor(np.ones(3, np.float32), place=place)
+
+    def test_id_past_the_device_count_raises(self):
+        from paddle_tpu.core.enforce import OutOfRangeError
+        with pytest.raises(OutOfRangeError, match="only 8 cpu"):
+            paddle.CPUPlace(8).jax_device()
+
+    def test_traced_tensor_answers_the_current_place(self):
+        seen = []
+
+        @jax.jit
+        def f(v):
+            seen.append(paddle.Tensor(v).place)
+            return v
+        f(jnp.ones(2))
+        assert seen == [paddle.CPUPlace(0)]
+
+
 def test_tensor_method_aliases():
     t = paddle.to_tensor(np.ones((2, 3), np.float32))
     assert t.dim() == t.ndimension() == t.rank() == 2

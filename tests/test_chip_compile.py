@@ -1,0 +1,273 @@
+"""Compile every Pallas kernel entry point for a DESCRIBED TPU v5e.
+
+The TPU compiler ships with jaxlib/libtpu and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``), so these cases
+raise here, on the CPU, exactly what Mosaic would raise on the chip —
+what interpret mode cannot show (tile alignment the compiler cannot
+prove, VMEM over budget, block-shape rules). Nothing runs: a case that
+passes says the kernel COMPILES at that width, never that it is right
+(the interpret-mode parity tests say that) or fast (only a chip run does).
+
+Tier-1 keeps one case per kernel entry point at its widest shape; the
+full matrix the chip bring-up compiled before its first chip call rides
+``-m slow``. A kernel that stops compiling is a finding for the PR that
+broke it: fix the kernel, or mark the case ``xfail(strict=True)`` with
+the compiler's own words — never route around it with a fallback.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from paddle_tpu.kernels import fused_block_decode as fbd
+from paddle_tpu.kernels.decode_attention import flash_prefill
+from paddle_tpu.kernels.flash_attention import (activation_layout,
+                                                flash_attention,
+                                                flash_attention_bshd)
+from paddle_tpu.kernels.paged_attention import (QuantizedPages,
+                                                paged_attention,
+                                                paged_chunk_attention)
+from paddle_tpu.kernels.rms_norm import rms_norm_pallas
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def chips():
+    """The four described chips of a v5e 2x2; the whole file skips where
+    the topology cannot be described (no libtpu). The persistent compile
+    cache is off around the module: a described-device executable is
+    written to it but cannot be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — any failure means "no libtpu"
+        pytest.skip(f"cannot describe a v5e topology here: {exc!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _on_tpu(monkeypatch):
+    """The kernels pick interpret mode from ``is_tpu_backend()``, which
+    sees the CPU here: steer them to the real lowering from the test."""
+    monkeypatch.setattr("paddle_tpu.flags.is_tpu_backend", lambda: True)
+
+
+# ------------------------------------------------------------- the cases
+# Each builder returns (fn, abstract args); ``S(shape, dtype)`` is bound to
+# ONE described chip by the test (``S.chips`` is all four, for the case
+# that spans them).
+
+def _flash(bh, s, d, *, bkv=None, skv=None, causal=True, segments=False,
+           heads=1, kv_heads=None, grad=False):
+    skv = skv or s
+    bkv = bkv or bh
+
+    def build(S):
+        args = [S((bh, s, d), BF16), S((bkv, skv, d), BF16),
+                S((bkv, skv, d), BF16)]
+        if segments:
+            args.append(S((bh, s), I32))
+
+        def fwd(q, k, v, seg=None):
+            return flash_attention(q, k, v, segment_ids=seg, causal=causal,
+                                   n_heads=heads, n_kv_heads=kv_heads)
+
+        if not grad:
+            return fwd, args
+
+        def loss(q, k, v, seg=None):
+            return fwd(q, k, v, seg).astype(F32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2)), args
+    return build
+
+
+def _flash_dp2_mp2(b, s, h, hkv, d):
+    """Flash fwd+bwd inside a GSPMD program over a dp2 x mp2 mesh — what
+    ``hapi.TrainStep(mesh=...)`` compiles. GSPMD cannot partition a
+    Mosaic kernel; ``flash_attention_bshd`` splits it per shard of the
+    layout the step declares."""
+    def build(S):
+        mesh = Mesh(np.array(S.chips).reshape(2, 2), ("dp", "mp"))
+        on = NamedSharding(mesh, PartitionSpec("dp", None, "mp", None))
+        args = [jax.ShapeDtypeStruct((b, s, nh, d), BF16, sharding=on)
+                for nh in (h, hkv, hkv)]
+
+        def loss(q, k, v):
+            with activation_layout(mesh, ("dp",), "mp"):
+                return flash_attention_bshd(q, k, v).astype(F32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2)), args
+    return build
+
+
+def _prefill(b, s, h, hkv, d, t):
+    def build(S):
+        fn = lambda q, k, v, n: flash_prefill(q, k, v, n)  # noqa: E731
+        return fn, [S((b, s, h, d), BF16), S((b, t, hkv, d), BF16),
+                    S((b, t, hkv, d), BF16), S((), I32)]
+    return build
+
+
+def _rms(n, h, grad=False):
+    def build(S):
+        args = [S((n, h), BF16), S((h,), BF16)]
+        if not grad:
+            return rms_norm_pallas, args
+        return jax.grad(lambda x, w: rms_norm_pallas(x, w).astype(F32).sum(),
+                        argnums=(0, 1)), args
+    return build
+
+
+def _pool(S, hkv, d, page, n_pages, int8):
+    if int8:
+        return QuantizedPages(S((hkv, n_pages, page, d), I8),
+                              S((hkv, n_pages, page, 1), F32))
+    return S((hkv, n_pages, page, d), BF16)
+
+
+def _paged(h, hkv, d, *, int8=False, chunk=0, b=8, page=64, max_pages=16):
+    def build(S):
+        pool = _pool(S, hkv, d, page, 256, int8)
+        if chunk:
+            return paged_chunk_attention, [
+                S((b, chunk, h, d), BF16), pool, pool,
+                S((b, max_pages), I32), S((b,), I32)]
+        return paged_attention, [S((b, h, d), BF16), pool, pool,
+                                 S((b, max_pages), I32), S((b,), I32)]
+    return build
+
+
+def _fused(layers, b, *, h=4096, nh=32, nkv=32, d=128, inter=11008,
+           int8=False, int4=False, page=64, max_pages=16):
+    """``layers == 0``: the single-layer kernel; else the N-layer one."""
+    qw, kvw = nh * d, nkv * d
+
+    def build(S):
+        tail = [S((b, max_pages), I32), S((b,), I32)]
+        kw = dict(num_heads=nh, num_kv_heads=nkv, interpret=False)
+        if layers == 0:
+            w = fbd.BlockDecodeWeights(
+                S((h,), BF16), S((h, qw), BF16), S((h, kvw), BF16),
+                S((h, kvw), BF16), S((qw, h), BF16), S((h,), BF16),
+                S((h, inter), BF16), S((h, inter), BF16),
+                S((inter, h), BF16))
+            pool = _pool(S, nkv, d, page, 256, int8)
+
+            def fn(x, w, kp, vp, bt, sl):
+                return fbd.fused_block_decode_pallas(x, w, kp, vp, bt, sl,
+                                                     **kw)
+            return fn, [S((b, h), BF16), w, pool, pool] + tail
+
+        def mat(rows, cols, key):
+            if not int4:
+                return S((layers, rows, cols), BF16)
+            tr, tc = fbd._int4_plan(h, qw, kvw, inter)[key]
+            return fbd.Int4Tiles(S((layers, rows // 2, cols), jnp.uint8),
+                                 S((layers, rows // tr, cols // tc), F32))
+
+        w = fbd.MultiBlockDecodeWeights(
+            S((layers, h), BF16), mat(h, qw + 2 * kvw, "wqkv"),
+            mat(qw, h, "wo"), S((layers, h), BF16),
+            mat(h, 2 * inter, "wgu"), mat(inter, h, "wd"))
+        pools = [_pool(S, nkv, d, page, 256, int8) for _ in range(layers)]
+
+        def fn(x, w, kps, vps, bt, sl):
+            return fbd.fused_multi_block_decode_pallas(x, w, kps, vps, bt,
+                                                       sl, **kw)
+        return fn, [S((b, h), BF16), w, pools, list(pools)] + tail
+    return build
+
+
+_GQA = dict(nkv=8, inter=14336)           # H 4096, 32/8 heads, I 14336
+
+# one per kernel entry point, widest shape: the tier-1 guard
+_TIER1 = {
+    "flash_fwd-gqa32x8-d128-s2048": _flash(
+        8 * 32, 2048, 128, bkv=8 * 8, heads=32, kv_heads=8),
+    "flash_bwd-gqa32x8-d128-s2048": _flash(
+        8 * 32, 2048, 128, bkv=8 * 8, heads=32, kv_heads=8, grad=True),
+    "flash_bwd-dp2xmp2-gqa32x8-d128-s1024": _flash_dp2_mp2(4, 1024, 32, 8,
+                                                          128),
+    "flash_fwd-varlen-d64-s1024": _flash(8 * 16, 1024, 64, segments=True),
+    "flash_fwd-noncausal-d40-s4096": _flash(2 * 8, 4096, 40, causal=False),
+    "flash_prefill-d128": _prefill(1, 512, 32, 8, 128, 1024),
+    "rms_norm_fwd-8192x4096": _rms(8192, 4096),
+    "rms_norm_bwd-8192x4096": _rms(8192, 4096, grad=True),
+    "paged_attention-gqa32x8-d128": _paged(32, 8, 128),
+    "paged_attention-int8-gqa32x8-d128": _paged(32, 8, 128, int8=True),
+    "paged_chunk-gqa32x8-d128": _paged(32, 8, 128, chunk=256, b=1),
+    "paged_chunk-int8-gqa32x8-d128": _paged(32, 8, 128, int8=True,
+                                           chunk=256, b=1),
+    "fused_block-int8kv-gqa-b32": _fused(0, 32, int8=True, **_GQA),
+    "fused_nlayer2-int8kv-gqa-b32": _fused(2, 32, int8=True, **_GQA),
+    "fused_nlayer2-int4-7b-b8": _fused(2, 8, int4=True),
+}
+
+# the rest of the bring-up matrix (ISSUE 21 A): every listed width
+_MATRIX = {
+    "flash_fwd-d64-s1024": _flash(8 * 16, 1024, 64),
+    "flash_bwd-d64-s1024": _flash(8 * 16, 1024, 64, grad=True),
+    "flash_bwd-varlen-d64-s1024": _flash(8 * 16, 1024, 64, segments=True,
+                                         grad=True),
+    "flash_bwd-noncausal-d40-s4096": _flash(2 * 8, 4096, 40, causal=False,
+                                            grad=True),
+    "flash_fwd-noncausal-d80-s1024": _flash(2 * 8, 1024, 80, causal=False),
+    "flash_bwd-noncausal-d80-s1024": _flash(2 * 8, 1024, 80, causal=False,
+                                            grad=True),
+    "flash_prefill-d64": _prefill(1, 512, 16, 16, 64, 1024),
+    "rms_norm_fwd-8192x1024": _rms(8192, 1024),
+    "rms_norm_bwd-8192x1024": _rms(8192, 1024, grad=True),
+    "paged_attention-mha16-d64": _paged(16, 16, 64),
+    "paged_attention-int8-mha16-d64": _paged(16, 16, 64, int8=True),
+    "paged_chunk-mha16-d64": _paged(16, 16, 64, chunk=256, b=1),
+    "paged_chunk-int8-mha16-d64": _paged(16, 16, 64, int8=True, chunk=256,
+                                         b=1),
+    # the shape the old on-chip sprint checked: d 32 exercises the
+    # sub-lane-tile head path of the head-major scratch
+    "fused_block-small-d32": _fused(0, 8, h=256, nh=8, nkv=2, d=32,
+                                    inter=512, page=16, max_pages=4),
+    "fused_nlayer2-small-d32": _fused(2, 8, h=256, nh=8, nkv=2, d=32,
+                                      inter=512, page=16, max_pages=4),
+}
+for _name, _shape in (("7b", {}), ("gqa", _GQA)):
+    for _b in (4, 8, 16, 32):
+        for _int8 in (False, True):
+            for _layers in (0, 1, 2):
+                _kind = ("fused_block" if _layers == 0
+                         else f"fused_nlayer{_layers}")
+                _case = (f"{_kind}{'-int8kv' if _int8 else ''}"
+                         f"-{_name}-b{_b}")
+                if _case not in _TIER1:
+                    _MATRIX[_case] = _fused(_layers, _b, int8=_int8,
+                                            **_shape)
+
+_CASES = [pytest.param(build, id=name) for name, build in _TIER1.items()]
+_CASES += [pytest.param(build, id=name, marks=pytest.mark.slow)
+           for name, build in _MATRIX.items()]
+
+
+@pytest.mark.parametrize("build", _CASES)
+def test_compiles_for_v5e(chips, build):
+    one_chip = SingleDeviceSharding(chips[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    S.chips = chips
+    fn, args = build(S)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "compiled, but no Pallas kernel in the program: a fallback ran"

@@ -1,0 +1,97 @@
+"""chip_smoke.py, rehearsed: the same phases the chip runs, at tiny size on
+the CPU (Pallas kernels in interpret mode or their jnp twins), through the
+function the script exposes — the script itself has no CPU switch and
+refuses any platform but ``tpu``.
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import bench  # noqa: E402
+import chip_smoke  # noqa: E402
+
+TINY = dataclasses.replace(
+    chip_smoke.FULL, on_chip=False, train_model="gpt_tiny",
+    train_shape=(4, 64), train_steps=2, serve_batch=4, page_size=8,
+    max_seq_len=64, prompt_lens=(6, 6, 20), new_tokens=3,
+    prefill_chunk=8,
+    llama=dict(vocab_size=256, hidden_size=64, num_attention_heads=4,
+               intermediate_size=128, max_position_embeddings=128),
+    fused_layers=2, fused_prompt_lens=(5, 9), fused_new_tokens=4,
+    hybrid_layers=2, hybrid_shape=(4, 32), hybrid_steps=2)
+
+
+@pytest.fixture
+def cache_env(monkeypatch):
+    """The cache variables unset, and restored whatever a test does."""
+    for name in ("JAX_COMPILATION_CACHE_DIR",
+                 "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
+        monkeypatch.setenv(name, "restore-me")
+        monkeypatch.delenv(name)
+
+
+def test_one_chip_phases_at_tiny_size():
+    lines = chip_smoke.run_phases(TINY)
+    assert [ln["phase"] for ln in lines] == [
+        "device", "train", "serve", "fused_decode"]
+    device, train, serve, fused = lines
+    assert device["platform"] == "cpu" and device["peak_flops"] is None
+    assert train["traces"] == 1 and train["losses"][-1] < train["losses"][0]
+    # no Pallas custom call can exist on the CPU — and none is claimed
+    assert train["flash_custom_call"] is False
+    assert serve["chunk_dispatches"] > 0 and serve["near_ties"] == 0
+    assert set(serve["pallas_custom_calls"]) >= {"prefill_chunk",
+                                                 serve["decode_kind"]}
+    assert fused["decode_kind"] == "decode_fused"
+    assert fused["tokens_equal_unfused"] == "exact"     # f32 on the CPU
+
+
+def test_four_chip_phase_on_virtual_devices():
+    (line,) = chip_smoke.run_phases(TINY, chips=4)
+    assert line["phase"] == "hybrid_dp2_mp2"
+    assert line["mesh"] == {"dp": 2, "mp": 2}
+    assert line["losses_dp2_mp2"] == pytest.approx(line["losses_one_chip"],
+                                                   rel=1e-4)
+    assert line["device0_param_share"] == pytest.approx(0.5, abs=0.05)
+    assert line["batch_row_ranges"] == [[0, 2], [2, 4]]
+    assert "all-reduce" in line["collectives"]
+
+
+@pytest.mark.parametrize("argv", [[], ["--chips", "4"]])
+def test_main_refuses_the_cpu(argv, cache_env, capsys):
+    assert jax.devices()[0].platform == "cpu"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        chip_smoke.main(argv)
+    assert '"ok"' not in capsys.readouterr().out        # no result line
+
+
+def test_cache_dir_honours_the_environment(cache_env, monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert bench.use_compile_cache() == "/some/dir"
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/some/dir"
+
+
+def test_cache_dir_default_is_fixed_in_the_checkout(cache_env):
+    want = os.path.join(REPO, ".cache", "xla")
+    assert bench.use_compile_cache() == want
+    assert bench.use_compile_cache() == want    # no pid, time or temp name
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".cache/" in f.read().split()
+
+
+@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+def test_nothing_else_sets_a_cache_dir(script):
+    with open(os.path.join(REPO, script)) as f:
+        source = f.read()
+    assert "jax_compilation_cache_dir" not in source
+    assert "tempfile" not in source and "getpid" not in source
+    # one setdefault, in the one helper both scripts share
+    assert source.count('"JAX_COMPILATION_CACHE_DIR"') == (
+        2 if script == "bench.py" else 0)

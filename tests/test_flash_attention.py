@@ -453,6 +453,86 @@ class TestReferenceFlashAPI:
         assert out.shape == [6, 2, 16]        # eval dropout is a no-op
 
 
+class TestMeshPartitioned:
+    """GSPMD cannot partition a Mosaic kernel, so inside a declared
+    ``activation_layout`` the (B, S, H, D) entry runs the kernel per
+    (batch, head) shard (tests/test_chip_compile.py compiles that for
+    the chip; here: same numbers as the unsplit call, forward and
+    backward, GQA + segments). The axis names are the declarer's."""
+
+    def test_declared_layout_matches_unsplit(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from paddle_tpu.kernels.flash_attention import activation_layout
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("rows", "cols"))
+        rng = np.random.default_rng(5)
+        q = jnp.asarray(rng.standard_normal((4, 128, 4, 32)) * 0.5,
+                        jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal((4, 128, 2, 32)) * 0.5,
+                            jnp.float32) for _ in range(2))
+        seg = jnp.asarray(np.repeat([[0, 1]], 64, axis=1).repeat(4, 0),
+                          jnp.int32)
+
+        def loss(q, k, v):
+            out = flash_attention_bshd(q, k, v, segment_ids=seg,
+                                       block_q=64, block_k=64)
+            return (out * out).sum(), out
+
+        grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+        want, want_out = grad(q, k, v)
+        put = functools.partial(
+            jax.device_put,
+            device=NamedSharding(mesh, P("rows", None, "cols", None)))
+        with activation_layout(mesh, ("rows",), "cols"):
+            got, got_out = grad(put(q), put(k), put(v))
+        assert len(got_out.sharding.device_set) == 4
+        np.testing.assert_allclose(got_out, want_out, rtol=2e-5, atol=2e-5)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
+
+    def test_split_follows_the_declaration_or_says_why_not(self):
+        from jax.sharding import AbstractMesh
+        from paddle_tpu.kernels.flash_attention import (
+            FlashPartitionError, _mesh_split, activation_layout)
+
+        class mesh:     # what activation_layout needs of a Mesh
+            def __init__(self, sizes, names):
+                self.abstract_mesh = AbstractMesh(sizes, names)
+
+        assert _mesh_split(4, 4, 2) is None          # nothing declared
+        with jax.sharding.use_abstract_mesh(
+                AbstractMesh((2, 2), ("dp", "mp"))):
+            assert _mesh_split(4, 4, 2) is None      # a mesh, no layout
+        with activation_layout(mesh((2, 1, 2), ("x", "pp", "y")),
+                               ("x",), "y"):
+            assert _mesh_split(4, 4, 2) == (
+                ("x",), "y", frozenset({"x", "pp", "y"}))
+            with pytest.raises(FlashPartitionError, match="batch 3"):
+                _mesh_split(3, 4, 2)
+            with pytest.raises(FlashPartitionError, match="3 kv heads"):
+                _mesh_split(4, 6, 3)
+        with activation_layout(mesh((2, 2), ("dp", "sep")), ("dp",), "mp"):
+            with pytest.raises(FlashPartitionError, match=r"\['sep'\]"):
+                _mesh_split(4, 4, 2)
+        assert _mesh_split(4, 4, 2) is None          # context restored
+
+    def test_train_step_declares_data_and_tensor_parallel_axes(self):
+        """The layout comes from the layer that owns it: TrainStep's data
+        axes, and the one other axis its parameter specs name."""
+        import paddle_tpu as paddle
+        from jax.sharding import Mesh
+        from paddle_tpu.hapi import TrainStep
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.models.llama import annotate_llama_tp
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("data", "tensor"))
+        model = LlamaForCausalLM(LlamaConfig.tiny())
+        annotate_llama_tp(model, "tensor")
+        opt = paddle.optimizer.AdamW(1e-3, parameters=model.parameters())
+        step = TrainStep(model, opt, mesh=mesh, data_axes=("data",))
+        assert step._layout == (("data",), "tensor")
+
+
 class TestDispatchTable:
     """Per-shape dispatch (FLAGS_flash_dispatch_table): benched-slower
     shape buckets must resolve to the dense path, benched-faster ones to

@@ -204,25 +204,25 @@ KRN002_TEMPLATE_OK = HEADER + """
     def fused_block_decode_ref(x):
         return x
 
-    def fused_block_decode_pallas(x, b_pad, hidden, qw, kvw, inter,
-                                  tc_max, rep_pad, d):
+    def fused_block_decode_pallas(x, b_pad, hidden, nh, nkv, inter,
+                                  tc_max, rep_rows, d):
         return pl.pallas_call(
             _kern, grid=(2,),
             in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((b_pad, hidden), jnp.float32),
-                pltpu.VMEM((b_pad, qw), jnp.float32),
-                pltpu.VMEM((b_pad, kvw), jnp.float32),
-                pltpu.VMEM((b_pad, kvw), jnp.float32),
-                pltpu.VMEM((b_pad, qw), jnp.float32),
+                pltpu.VMEM((nh, b_pad, d), jnp.float32),
+                pltpu.VMEM((nkv, b_pad, d), jnp.float32),
+                pltpu.VMEM((nkv, b_pad, d), jnp.float32),
+                pltpu.VMEM((nh, b_pad, d), jnp.float32),
                 pltpu.VMEM((b_pad, hidden), jnp.float32),
                 pltpu.VMEM((b_pad, inter), jnp.float32),
                 pltpu.VMEM((b_pad, tc_max), jnp.float32),
                 pltpu.VMEM((b_pad, tc_max), jnp.float32),
-                pltpu.VMEM((rep_pad, d), jnp.float32),
-                pltpu.VMEM((rep_pad, LANES), jnp.float32),
-                pltpu.VMEM((rep_pad, LANES), jnp.float32),
+                pltpu.VMEM((rep_rows, d), jnp.float32),
+                pltpu.VMEM((rep_rows, LANES), jnp.float32),
+                pltpu.VMEM((rep_rows, LANES), jnp.float32),
             ],
             out_shape=x)(x)
 """

@@ -10,7 +10,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from paddle_tpu.jax_compat import abstract_mesh
+from jax.sharding import AbstractMesh
 
 import paddle_tpu as paddle
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLMPipe
@@ -29,7 +29,7 @@ def _build_70b_step(dp=2, pp=8, mp=8, microbatches=8):
     n_params = sum(int(np.prod(p.shape)) for p in pipe.parameters())
     assert n_params > 6.8e10, n_params          # ~68.98B
 
-    mesh = abstract_mesh((dp, pp, mp), ("dp", "pp", "mp"))
+    mesh = AbstractMesh((dp, pp, mp), ("dp", "pp", "mp"))
     opt = AdamW(learning_rate=1e-4, parameters=pipe.parameters(),
                 weight_decay=0.1, multi_precision=True)
     step = PipelineTrainStep(
@@ -58,10 +58,6 @@ class TestLlama70BNorthStar:
         assert by["total"] < 2 * perfect, (by, perfect)
 
     def test_lowers_for_tpu_with_full_mesh(self):
-        from paddle_tpu.jax_compat import abstract_mesh_can_lower
-        if not abstract_mesh_can_lower():
-            pytest.skip("jax<0.5 AbstractMesh cannot lower "
-                        "(_device_assignment unimplemented)")
         cfg, step, _ = _build_70b_step()
         b, s = 16, 4096
         x = jax.ShapeDtypeStruct((b, s), jnp.int32)

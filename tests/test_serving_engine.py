@@ -5,6 +5,7 @@ regardless of what else shared the batch, when it was admitted, or whose
 freed pages it recycled — the whole point of paged attention.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -147,6 +148,18 @@ class TestDonationDiscipline:
         out = eng.run()
         assert len(out[0]) == 4
 
+    def test_pools_stay_device_arrays_across_dispatches(self):
+        """The step's returned pools are installed as they come back —
+        device arrays. (Unwrapping them by duck-typing on ``._value``
+        matched jax.Array too and copied every pool to the host on every
+        dispatch: free on the CPU, seconds a step on the chip.)"""
+        eng, prompt = self._engine()
+        eng.submit(prompt, 3)
+        while eng.has_work():
+            eng.step()
+            assert all(isinstance(p, jax.Array)
+                       for p in eng.pool.k_pages + eng.pool.v_pages)
+
     def test_transient_dispatch_failure_recovers_with_parity(self):
         """r10 replay recovery: a dispatch that raises AFTER donation
         leaves the pool detached (r08) — recovery now allocates fresh
@@ -236,6 +249,107 @@ class TestDonationDiscipline:
         assert chaos.decode_key.kind == "decode_fused"
         assert [out[r] for r in rids] == refs
         assert all(chaos.status(r) == "OK" for r in rids)
+
+    def test_trace_time_failure_surfaces_without_replay(self, monkeypatch):
+        """A program that fails in the call that traces it (what a
+        compiler refusal looks like) propagates from step(): no replay,
+        no back-off, no FAILED status — the refusal is deterministic."""
+        from paddle_tpu.generation import serving
+        from paddle_tpu.generation.program_cache import (
+            ProgramBuildError, clear_decode_program_cache)
+
+        def refusing_builder(note_trace, model):
+            @jax.jit
+            def step(*args):
+                note_trace()
+                raise NotImplementedError("Mosaic says no")
+            return step
+
+        monkeypatch.setattr(serving, "_build_generic_decode",
+                            refusing_builder)
+        clear_decode_program_cache()    # no warmed program to re-serve
+        try:
+            eng, prompt = self._engine()
+            recovered = []
+            monkeypatch.setattr(
+                eng, "_recover_dispatch", recovered.append)
+            rid = eng.submit(prompt, 4)
+            with pytest.raises(ProgramBuildError) as err:
+                eng.run()
+        finally:
+            clear_decode_program_cache()
+        assert err.value.key.kind == "decode_generic"
+        assert isinstance(err.value.__cause__, NotImplementedError)
+        assert recovered == []
+        assert eng.status(rid) != "FAILED"
+
+    def test_first_run_fault_of_a_built_program_replays(self, monkeypatch):
+        """The window between the two: a program that BUILT, and whose
+        very first execution dies on the device. That is a dispatch
+        fault, not a refusal — it is replayed, the executable is kept
+        (no second build), and the request ends OK."""
+        from paddle_tpu.generation import serving
+        from paddle_tpu.generation.program_cache import (
+            ProgramBuildError, clear_decode_program_cache,
+            decode_program_cache)
+
+        real_build, ran = serving._build_generic_decode, []
+
+        def device_fault(tok):
+            if not ran:
+                ran.append(1)
+                raise RuntimeError("device fault on the first run")
+            return tok
+
+        def flaky_builder(note_trace, model):
+            step = real_build(note_trace, model)
+
+            def run(params, buffers, toks, pools, bt, sl):
+                tok, states = step(params, buffers, toks, pools, bt, sl)
+                return jax.pure_callback(
+                    device_fault, jax.ShapeDtypeStruct(tok.shape, tok.dtype),
+                    tok), states
+            return jax.jit(run, donate_argnums=(3,))
+
+        monkeypatch.setattr(serving, "_build_generic_decode", flaky_builder)
+        clear_decode_program_cache()
+        try:
+            eng, prompt = self._engine()
+            ref = solo(eng.model, prompt, 5)
+            recover, replayed = eng._recover_dispatch, []
+            monkeypatch.setattr(
+                eng, "_recover_dispatch",
+                lambda exc: (replayed.append(exc), recover(exc)))
+            rid = eng.submit(prompt, 5)
+            out = eng.run()
+            traces = decode_program_cache().trace_count(eng.decode_key)
+        finally:
+            clear_decode_program_cache()
+        assert ran and len(replayed) == 1
+        assert not isinstance(replayed[0], ProgramBuildError)
+        assert out[rid] == ref and eng.status(rid) == "OK"
+        assert traces == 1
+
+    def test_dispatch_fault_on_warmed_program_still_replays(
+            self, monkeypatch):
+        """The other side of the seam: once a program has run, a failed
+        dispatch of it is a fault, and replay recovery absorbs it."""
+        eng, prompt = self._engine()
+        ref = solo(eng.model, prompt, 5)
+        rid = eng.submit(prompt, 5)
+        assert eng.run()[rid] == ref            # warms prefill + decode
+        with fault_spec("decode_dispatch:every=2:times=2"):
+            chaos = ServingEngine(eng.model, max_batch=2, page_size=8,
+                                  max_seq_len=32)
+            recover, replayed = chaos._recover_dispatch, []
+            monkeypatch.setattr(
+                chaos, "_recover_dispatch",
+                lambda exc: (replayed.append(exc), recover(exc)))
+            rid = chaos.submit(prompt, 5)
+            out = chaos.run()
+        assert out[rid] == ref and chaos.status(rid) == "OK"
+        assert len(replayed) == 2
+        assert all(isinstance(e, faults.InjectedFault) for e in replayed)
 
     def test_deadline_eviction_at_step_boundary(self):
         """submit(deadline=...): an expired request — queued or in

@@ -8,7 +8,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.hapi import TrainStep
 from paddle_tpu.models import GPTConfig, GPTForCausalLM, LlamaConfig, LlamaForCausalLM
-from paddle_tpu.utils.metrics import SpeedMeter, train_flops_per_token
+from paddle_tpu.utils.metrics import (SpeedMeter, peak_flops,
+                                      train_flops_per_token)
 
 
 def make_batch(cfg, b=4, s=32):
@@ -215,15 +216,36 @@ class TestMetrics:
         f2 = train_flops_per_token(1000, n_layers=2, hidden=8, seq_len=10)
         assert f2 == 6000.0 + 12 * 2 * 8 * 10
 
-    def test_speed_meter(self):
+    @staticmethod
+    def _one_step(**kw):
         import time
-        meter = SpeedMeter(n_params=1000, n_chips=2, warmup=0)
+        meter = SpeedMeter(n_params=1000, n_chips=2, warmup=0, **kw)
         meter.start()
         time.sleep(0.01)
         meter.step(100)
+        return meter
+
+    def test_speed_meter(self):
+        s = self._one_step(peak_flops=197e12).summary()
+        assert s["tokens_per_sec_per_chip"] > 0
+        assert 0 <= s["mfu"] and s["peak_flops"] == 197e12
+
+    def test_speed_meter_on_cpu_has_throughput_and_no_mfu(self):
+        meter = self._one_step()
         s = meter.summary()
         assert s["tokens_per_sec_per_chip"] > 0
-        assert 0 <= s["mfu"]
+        assert "mfu" not in s and "peak_flops" not in s
+        with pytest.raises(ValueError, match="no peak"):
+            meter.mfu()
+
+    def test_peak_table_resolves_the_v5e_kind(self):
+        # the exact device_kind string jax reports for a v5e chip
+        assert peak_flops("TPU v5 lite") == 197e12
+
+    @pytest.mark.parametrize("kind", ["cpu", "v5e", "TPU v9", ""])
+    def test_peak_table_unknown_kind_raises(self, kind):
+        with pytest.raises(KeyError, match="no published peak"):
+            peak_flops(kind)
 
 
 class TestHapiModel:

@@ -25,9 +25,9 @@ schema ({"metric", "value", "unit", "vs_baseline", ...}):
     vs_baseline  = synced_step_ms / async_step_ms (the jitted A/B;
                    ~1.0 on CPU, the pipelining win on chip)
 
-``--bank PATH`` additionally writes the chip_sprint ledger payload
-({"step", "backend", "ts", "n_failed_checks", "results"}) so the
-artifact parses with bench.artifact_state like every other BENCH_*.json.
+``--bank PATH`` additionally writes the banked-artifact payload
+({"step", "backend", "ts", "n_failed_checks", "results"}) every
+BENCH_*.json in the repo root carries.
 
 Env knobs: LOOP_BENCH_STEPS (default 64), LOOP_BENCH_K (8),
 LOOP_BENCH_REPEATS (3), BENCH_BATCH (8), BENCH_SEQ (32).

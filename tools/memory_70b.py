@@ -16,7 +16,7 @@ def main():
     import jax
     import numpy as np
     import jax.numpy as jnp
-    from paddle_tpu.jax_compat import abstract_mesh
+    from jax.sharding import AbstractMesh
 
     import paddle_tpu as paddle
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLMPipe
@@ -29,7 +29,7 @@ def main():
     with paddle.LazyGuard():
         pipe = LlamaForCausalLMPipe(cfg, num_stages=pp, tensor_parallel=True)
     n_params = sum(int(np.prod(p.shape)) for p in pipe.parameters())
-    mesh = abstract_mesh((dp, pp, mp), ("dp", "pp", "mp"))
+    mesh = AbstractMesh((dp, pp, mp), ("dp", "pp", "mp"))
     opt = AdamW(1e-4, parameters=pipe.parameters(), weight_decay=0.1,
                 multi_precision=True)
     step = PipelineTrainStep(pipe, opt, mesh, num_microbatches=M,
@@ -41,16 +41,8 @@ def main():
     # accounting (observability/memory.sharded_param_bytes)
     by = step.per_device_state_bytes()
     b, s = 16, 4096
-    from paddle_tpu.jax_compat import abstract_mesh_can_lower
-    if abstract_mesh_can_lower():
-        lowered = step.lower(jax.ShapeDtypeStruct((b, s), jnp.int32),
-                             jax.ShapeDtypeStruct((b, s), jnp.int32))
-        text = lowered.as_text()
-    else:
-        # same version gate as tests/test_llama70b.py: this jax cannot
-        # lower an AbstractMesh program; the sharding-table accounting
-        # above is jax-version-independent and still banks
-        text = ""
+    text = step.lower(jax.ShapeDtypeStruct((b, s), jnp.int32),
+                      jax.ShapeDtypeStruct((b, s), jnp.int32)).as_text()
 
     rows = []
     for k in sorted(step.params):
@@ -85,24 +77,18 @@ def main():
                f"14 bytes/param state would be {14*n_params/128/1e9:.2f} "
                "GB/device.\n")
     out.append("## Lowering evidence\n")
-    if not text:
-        out.append("- SKIPPED on this jax: AbstractMesh lowering is "
-                   "version-gated (paddle_tpu.jax_compat."
-                   "abstract_mesh_can_lower() is False on 0.4.x) — "
-                   "re-run on jax >= 0.6 to regenerate this section.")
-    else:
-        n_cp = text.count("collective_permute")
-        out.append(f"- StableHLO module: {len(text):,} chars, "
-                   f"mesh `{'dp=2, pp=8, mp=8'}`, "
-                   f"`num_partitions = 128` present: "
-                   f"{'num_partitions = 128' in text}")
-        out.append(f"- sharding annotations: sdy={'sdy.sharding' in text}, "
-                   f"collective_permute sites: {n_cp} (0 is expected pre-"
-                   "partitioning: shardy lowers sharding as `sdy` "
-                   "annotations and XLA inserts the pp-ring collective-"
-                   "permutes during SPMD propagation at compile time)")
-        out.append(f"- while/scan loops: {text.count('stablehlo.while')}, "
-                   f"dots: {text.count('stablehlo.dot')}")
+    n_cp = text.count("collective_permute")
+    out.append(f"- StableHLO module: {len(text):,} chars, "
+               f"mesh `{'dp=2, pp=8, mp=8'}`, "
+               f"`num_partitions = 128` present: "
+               f"{'num_partitions = 128' in text}")
+    out.append(f"- sharding annotations: sdy={'sdy.sharding' in text}, "
+               f"collective_permute sites: {n_cp} (0 is expected pre-"
+               "partitioning: shardy lowers sharding as `sdy` "
+               "annotations and XLA inserts the pp-ring collective-"
+               "permutes during SPMD propagation at compile time)")
+    out.append(f"- while/scan loops: {text.count('stablehlo.while')}, "
+               f"dots: {text.count('stablehlo.dot')}")
     out.append("")
     out.append("## Sharding table (param -> (shape, dtype, param spec, "
                "opt-state spec))\n")

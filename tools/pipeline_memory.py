@@ -261,7 +261,7 @@ def main():
         "B/W lag (~S slots of per-stage activation residuals, the 1F1B "
         "in-flight bound, NOT M; the temp budget exists — zbh1's "
         "footprint is 2-4x below lockstep's above). Round-6 engine "
-        "change, final validation on-chip (TUNNEL_DIAGNOSIS.md).",
+        "change, final validation on-chip.",
         "",
     ]
     out = os.path.join(os.path.dirname(os.path.dirname(
