@@ -168,6 +168,25 @@ class _EngineTelemetry:
             "admissions that adopted cached prefix pages (prefill skipped)")
         self.decode_steps = c(
             "serving_decode_steps", "full-batch decode steps dispatched")
+        # ---- what the scheduler's batching decided, counted where the
+        # dispatch is made: rows over slots is the share of the paid-for
+        # batch that did work
+        self.decode_rows = c(
+            "serving_decode_rows",
+            "rows of the dispatched decode steps that held a decoding "
+            "request")
+        self.decode_slots = c(
+            "serving_decode_slots",
+            "rows the dispatched decode programs computed (each step's "
+            "ladder rung)")
+        self.decode_live_tokens = c(
+            "serving_decode_live_tokens",
+            "cached tokens of the decoding rows, summed over the decode "
+            "steps — the KV each step had to read")
+        self.prefill_tokens = c(
+            "serving_prefill_tokens",
+            "prompt tokens put through prefill compute (a monolithic "
+            "prompt whole, a chunk by its real tokens — pad excluded)")
         self.ttft = h(
             "serving_ttft_seconds",
             "time to first generated token, submit() to host-visible")
@@ -317,6 +336,8 @@ class _NullEngineTelemetry:
         self.event = obs.null_event
         self.submitted = self.finished = self.prefills = obs.NULL
         self.shared_admits = self.decode_steps = obs.NULL
+        self.decode_rows = self.decode_slots = obs.NULL
+        self.decode_live_tokens = self.prefill_tokens = obs.NULL
         self.ttft = self.itl = obs.NULL
         self.queue_depth = self.occupancy = obs.NULL
         self.kv_pages_in_use = self.prefix_pinned = obs.NULL
@@ -1007,6 +1028,13 @@ class ServingEngine:
         # during a step and drained AFTER dispatch/recovery, so a user
         # callback that raises never masquerades as a dispatch failure
         self._events: List[tuple] = []
+        # telemetry's view of the step: a running number every span and
+        # lifecycle record of the step carries, the root span's id, and
+        # rid -> when a first token became known on the host (closed as
+        # request.first_token once its callback has run)
+        self._step_no = 0
+        self._step_span = 0
+        self._first_known: Dict[int, float] = {}
         # streaming-callback registry: rid -> on_token. Engine-LOCAL by
         # design — callbacks never ride the Request objects the export/
         # harvest seams detach (a bound callable cannot serialize across
@@ -1703,7 +1731,7 @@ class ServingEngine:
         # queued phase closes at admission: submit() -> here (once per
         # REQUEST, not per token)  # tracecheck: disable=TRC007
         self._m.event("request.queued", req.t_submit, time.perf_counter(),
-                      rid=req.rid)
+                      rid=req.rid, step=self._step_no)
         replay = bool(req.tokens)
         if self._prefix is not None and not replay \
                 and self._hit_worth_taking(req):
@@ -1750,7 +1778,8 @@ class ServingEngine:
         self.pool.allocate(slot, p + remaining)
         bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
         # per-request prefill timeline span  # tracecheck: disable=TRC007
-        with self._m.span("request.prefill", rid=req.rid, prompt_len=p):
+        with self._m.span("request.prefill", rid=req.rid, prompt_len=p,
+                          step=self._step_no):
             pools = self.pool.take_pools()
             self._f_prefill.check()
             tok, states = fn(self._params, self._buffers,
@@ -1762,6 +1791,8 @@ class ServingEngine:
             tok = int(tok)              # the span owns the token pull
         # once per admitted request  # tracecheck: disable=TRC007
         self._m.prefills.inc()
+        # tracecheck: disable=TRC007
+        self._m.prefill_tokens.inc(p)
         if req.temperature > 0.0:
             # a sampled request never takes the prefill's greedy argmax:
             # park the cursor ONE position short with the last fed token
@@ -1789,6 +1820,8 @@ class ServingEngine:
             # TTFT closes on the prefill's token
             # tracecheck: disable=TRC007
             self._m.ttft.observe(tnow - req.t_submit)
+            if self._m.enabled:
+                self._first_known[req.rid] = tnow
         req.t_last = tnow
         req.tokens.append(tok)
         self._emit(req, tok)
@@ -1815,26 +1848,31 @@ class ServingEngine:
         ids[:end - pos] = feed[pos:end]
         fn = self._chunk_program()
         slot = req.slot
-        bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
-        sl = jnp.asarray(np.full((1,), pos, np.int32))
-        t0 = time.perf_counter() if self._m.enabled else 0.0
-        pools = self.pool.take_pools()
-        self._f_chunk.check()
-        tok, states = fn(self._params, self._buffers,
-                         jnp.asarray(ids[None]), pools, bt, sl,
-                         jnp.int32(end - pos - 1))
-        self._store(states)
-        self.pool.seq_lens[slot] = end
-        req.prefill_pos = end
-        self.chunk_dispatches += 1
-        if not last:
-            # the non-final argmax is garbage-padded and never pulled:
-            # the dispatch stays async  # tracecheck: disable=TRC007
-            self._observe_chunk(time.perf_counter() - t0)
-            return
-        tok = int(tok)      # designed sync: the first generated token
+        # uploads, dispatch and (last chunk only) the token pull: one
+        # span a chunk  # tracecheck: disable=TRC007
+        with self._m.span("engine.prefill_chunk", step=self._step_no,
+                          rid=req.rid, pos=pos, last=last):
+            bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
+            sl = jnp.asarray(np.full((1,), pos, np.int32))
+            t0 = time.perf_counter() if self._m.enabled else 0.0
+            pools = self.pool.take_pools()
+            self._f_chunk.check()
+            tok, states = fn(self._params, self._buffers,
+                             jnp.asarray(ids[None]), pools, bt, sl,
+                             jnp.int32(end - pos - 1))
+            self._store(states)
+            self.pool.seq_lens[slot] = end
+            req.prefill_pos = end
+            self.chunk_dispatches += 1
+            if last:
+                # designed sync: the first generated token. A non-final
+                # argmax is garbage-padded and never pulled, so that
+                # dispatch stays async
+                tok = int(tok)
         tnow = time.perf_counter()
-        self._observe_chunk(tnow - t0, final=True)
+        self._observe_chunk(tnow - t0, end - pos, final=last)
+        if not last:
+            return
         replay = bool(req.tokens)
         if req.temperature > 0.0:
             # sampled request: discard the final chunk's greedy argmax
@@ -1857,6 +1895,8 @@ class ServingEngine:
             # TTFT closes on the final chunk's token
             # tracecheck: disable=TRC007
             self._m.ttft.observe(tnow - req.t_submit)
+            if self._m.enabled:
+                self._first_known[req.rid] = tnow
         req.t_last = tnow
         req.tokens.append(tok)
         self._emit(req, tok)
@@ -1921,9 +1961,27 @@ class ServingEngine:
             self._events.append((cb, req.rid, tok, done))
 
     def _drain_events(self) -> None:
-        while self._events:
-            cb, rid, tok, done = self._events.pop(0)
-            cb(rid, tok, done)
+        first = self._first_known
+        # once per step  # tracecheck: disable=TRC007
+        with self._m.span("engine.callbacks", step=self._step_no,
+                          n=len(self._events)):
+            while self._events:
+                cb, rid, tok, done = self._events.pop(0)
+                cb(rid, tok, done)
+                if first and rid in first:
+                    self._observe_first_token(rid, first.pop(rid))
+            # no callback bound: the token is the caller's to read when
+            # step() returns, which is now
+            while first:
+                self._observe_first_token(*first.popitem())
+
+    def _observe_first_token(self, rid: int, t_known: float) -> None:
+        """How long a request's first token was held on the host: from
+        the moment it was known (the prefill's pull, the last chunk's,
+        or a shared admission's first decode pull) to the moment its
+        callback had run."""
+        self._m.event("request.first_token", t_known, time.perf_counter(),
+                      rid=rid, step=self._step_no)
 
     def _finalize(self, req: Request, status: str,
                   error: Optional[str] = None) -> None:
@@ -1956,7 +2014,7 @@ class ServingEngine:
                 # lifecycle close event  # tracecheck: disable=TRC007
                 self._m.event("request.complete", req.t_submit,
                               time.perf_counter(), rid=req.rid,
-                              tokens=len(req.tokens))
+                              tokens=len(req.tokens), step=self._step_no)
 
     def _sweep_deadlines(self) -> None:
         """Step-boundary deadline enforcement: terminate every queued or
@@ -2001,15 +2059,21 @@ class ServingEngine:
         Streaming callbacks drain LAST, outside the recovery boundary:
         a raising callback surfaces to the caller, never as a fake
         dispatch failure."""
-        try:
-            self._step_inner()
-            self._consec_failures = 0
-        except ProgramBuildError:
-            raise
-        except Exception as exc:
-            self._recover_dispatch(exc)
-        finally:
-            self._drain_events()
+        self._step_no += 1
+        # the step's root span: every phase below nests in it
+        # tracecheck: disable=TRC007
+        with self._m.span("engine.step", step=self._step_no,
+                          bucket=self.bucket) as root:
+            self._step_span = root.id
+            try:
+                self._step_inner()
+                self._consec_failures = 0
+            except ProgramBuildError:
+                raise
+            except Exception as exc:
+                self._recover_dispatch(exc)
+            finally:
+                self._drain_events()
 
     def _recover_dispatch(self, exc: Exception) -> None:
         """Replay recovery. The donated dispatch died, so the pool is
@@ -2278,34 +2342,37 @@ class ServingEngine:
         block-table row moves — KV pages never copy), growing just
         widens the next dispatch. Each rung's program compiles once and
         stays cached, so steady-state migration is retrace-free."""
-        self._f_migrate.check(phase="begin", frm=self.bucket, to=target)
-        if target < self.bucket:
-            dst = 0
-            for s in range(target, self.max_batch):
-                req = self._slots[s]
-                if req is None:
-                    continue
-                while self._slots[dst] is not None:
-                    dst += 1        # always < target: target covers active
-                self.pool.move_sequence(s, dst)
-                if req.spec_ready:
-                    # the draft pool mirrors the target's slot layout
-                    self._draft_pool.move_sequence(s, dst)
-                self._last_tok[dst] = self._last_tok[s]
-                self._slots[dst] = req
-                self._slots[s] = None
-                req.slot = dst
-                # deliberately MID-mutation: every=N drills must land
-                # between row moves, and recovery replays the whole
-                # batch from host state so no half-compacted table
-                # survives  # faultcheck: disable=FLT002
-                self._f_migrate.check(phase="move", rid=req.rid)
-        self.bucket = target
-        self.bucket_migrations += 1
-        # post-commit schedule point, same full-replay argument
-        # faultcheck: disable=FLT002
-        self._f_migrate.check(phase="commit")
-        self._observe_bucket(migrated=True)
+        with self._m.span("engine.migrate", step=self._step_no,
+                          **{"from": self.bucket, "to": target}):
+            self._f_migrate.check(phase="begin", frm=self.bucket,
+                                  to=target)
+            if target < self.bucket:
+                dst = 0
+                for s in range(target, self.max_batch):
+                    req = self._slots[s]
+                    if req is None:
+                        continue
+                    while self._slots[dst] is not None:
+                        dst += 1    # always < target: target covers active
+                    self.pool.move_sequence(s, dst)
+                    if req.spec_ready:
+                        # the draft pool mirrors the target's slot layout
+                        self._draft_pool.move_sequence(s, dst)
+                    self._last_tok[dst] = self._last_tok[s]
+                    self._slots[dst] = req
+                    self._slots[s] = None
+                    req.slot = dst
+                    # deliberately MID-mutation: every=N drills must land
+                    # between row moves, and recovery replays the whole
+                    # batch from host state so no half-compacted table
+                    # survives  # faultcheck: disable=FLT002
+                    self._f_migrate.check(phase="move", rid=req.rid)
+            self.bucket = target
+            self.bucket_migrations += 1
+            # post-commit schedule point, same full-replay argument
+            # faultcheck: disable=FLT002
+            self._f_migrate.check(phase="commit")
+            self._observe_bucket(migrated=True)
 
     # ------------------------------------------------ SLO preemption
     def _preempt_for(self, order: List[Request]) -> None:
@@ -2580,6 +2647,7 @@ class ServingEngine:
                 # TTFT closes on the round's first token
                 # tracecheck: disable=TRC007
                 self._m.ttft.observe(now - req.t_submit)
+                self._first_known[req.rid] = now
             else:
                 # ONE inter-token sample per round: a round delivers
                 # its tokens as a burst, so the host-visible gap is the
@@ -2716,20 +2784,24 @@ class ServingEngine:
             draft=False)
 
     def _step_inner(self) -> None:  # tracecheck: hotpath
-        self._sweep_deadlines()
-        self._probe_memo.clear()    # prefix probes are per-step
-        # decode-ready requests present BEFORE this step's scheduler +
-        # prefill work: the population that work below is stalling
-        waiting = any(r is not None and r.prefill_pos is None
-                      for r in self._slots)
-        t_sched = time.perf_counter()
-        # the step's admission order, sorted once and shared by the
-        # migration demand estimate and the slot-fill loop below
-        order = self._admission_order() if self._queue else []
-        self._maybe_migrate(order)
-        # SLO preemption runs BEFORE the slot fill: an unseated victim's
-        # slot admits the endangered head in this very step
-        self._preempt_for(order)
+        n = self._step_no
+        # one span a phase a step  # tracecheck: disable=TRC007
+        with self._m.span("engine.schedule", step=n,
+                          queued=len(self._queue)):
+            self._sweep_deadlines()
+            self._probe_memo.clear()    # prefix probes are per-step
+            # decode-ready requests present BEFORE this step's scheduler
+            # + prefill work: the population that work below is stalling
+            waiting = any(r is not None and r.prefill_pos is None
+                          for r in self._slots)
+            t_sched = time.perf_counter()
+            # the step's admission order, sorted once and shared by the
+            # migration demand estimate and the slot-fill loop below
+            order = self._admission_order() if self._queue else []
+            self._maybe_migrate(order)
+            # SLO preemption runs BEFORE the slot fill: an unseated
+            # victim's slot admits the endangered head in this very step
+            self._preempt_for(order)
         # the step's ONE prefill-compute unit alternates between new
         # monolithic admissions and in-flight chunks under contention:
         # admissions always winning would starve a mid-prefill long
@@ -2743,42 +2815,49 @@ class ServingEngine:
         if chunk_pending and self._chunk_turn:
             chunk_ran_first = self._chunk_step()
             did_prefill = chunk_ran_first
-        for slot in range(self.bucket):
-            if self._slots[slot] is not None or not order:
-                continue
-            req = self._next_admission(order)
-            if req is None:
-                break       # head page-blocked: wait, keep order
-            if did_prefill and self._needs_prefill_unit(req):
-                # the unit is spent: a monolithic-prefill head admits
-                # next step (head-of-line — nothing jumps it); cursor-
-                # only admissions behind a served head keep filling
-                break
-            order.remove(req)
-            self._queue.remove(req)
-            try:
-                did_prefill |= self._admit(req, slot)
-            except Exception as e:
-                if isinstance(e, RuntimeError) and \
-                        "page pool exhausted" in str(e):
-                    # allocate came up short mid-step (pinned pages
-                    # under-counted by the pre-check): back off to
-                    # the queue instead of killing the step
-                    self._rollback_admission(req, slot)
-                    self._queue.insert(0, req)
-                    self._observe_page_pressure(max(
-                        1, self._pages_needed(req)
-                        - self.pool.free_page_count()))
+        # request.prefill nests in this span, so its SELF time is the
+        # scheduler's  # tracecheck: disable=TRC007
+        with self._m.span("engine.admit", step=n) as fill:
+            admitted = 0
+            for slot in range(self.bucket):
+                if self._slots[slot] is not None or not order:
+                    continue
+                req = self._next_admission(order)
+                if req is None:
+                    break       # head page-blocked: wait, keep order
+                if did_prefill and self._needs_prefill_unit(req):
+                    # the unit is spent: a monolithic-prefill head admits
+                    # next step (head-of-line — nothing jumps it); cursor-
+                    # only admissions behind a served head keep filling
                     break
-                # dispatch failure: hand the request to recovery
-                # (it holds no slot state after the rollback)
-                self._rollback_admission(req, slot)
-                self._failed_admission = req
-                raise
-            if not self._head_blocked:
-                # a BYPASS admission must not clear the pressure the
-                # still-blocked head just published
-                self._observe_page_pressure(0)
+                order.remove(req)
+                self._queue.remove(req)
+                try:
+                    did_prefill |= self._admit(req, slot)
+                except Exception as e:
+                    if isinstance(e, RuntimeError) and \
+                            "page pool exhausted" in str(e):
+                        # allocate came up short mid-step (pinned pages
+                        # under-counted by the pre-check): back off to
+                        # the queue instead of killing the step
+                        self._rollback_admission(req, slot)
+                        self._queue.insert(0, req)
+                        self._observe_page_pressure(max(
+                            1, self._pages_needed(req)
+                            - self.pool.free_page_count()))
+                        break
+                    # dispatch failure: hand the request to recovery
+                    # (it holds no slot state after the rollback)
+                    self._rollback_admission(req, slot)
+                    self._failed_admission = req
+                    raise
+                admitted += 1
+                if not self._head_blocked:
+                    # a BYPASS admission must not clear the pressure the
+                    # still-blocked head just published
+                    self._observe_page_pressure(0)
+            if self._m.enabled:
+                fill.args["admitted"] = admitted
         # ONE prefill-compute unit per step (one monolithic prefill OR
         # one chunk — admitting several prefills back to back would
         # stack their stalls on every decoding request; the load bench
@@ -2808,82 +2887,92 @@ class ServingEngine:
 
         b = self.bucket
         fn = self._decode_program(b)
-        bt = jnp.asarray(self.pool.block_tables[:b])
-        sl = jnp.asarray(self.pool.seq_lens[:b])
-        t0 = time.perf_counter() if self._m.enabled else 0.0
-        pools = self.pool.take_pools()
-        self._f_decode.check()
-        if self._stacked is not None:
-            # N-layer program signature: the stacked per-group weight
-            # structs ride as traced args (never baked constants)
-            toks, states = fn(
-                self._params, self._buffers,
-                jnp.asarray(self._last_tok[:b, None]),
-                pools, bt, sl, self._stacked)
-        else:
-            toks, states = fn(
-                self._params, self._buffers,
-                jnp.asarray(self._last_tok[:b, None]),
-                pools, bt, sl)
-        self._store(states)
-        # the scheduler's designed sync point: admission/eviction need
-        # the concrete token ids  # tracecheck: disable=TRC002
-        toks = np.asarray(toks)
+        self._observe_decode(decode_rows)
+        # the decode dispatch, from its uploads to the token pull, and
+        # its three parts: real spans, so a device capture can say which
+        # device time ran under a decode dispatch and which part of the
+        # host's share is which  # tracecheck: disable=TRC007
+        with self._m.span("engine.decode_step", step=n,
+                          active=len(decode_rows), bucket=b):
+            # tracecheck: disable=TRC007
+            with self._m.span("engine.decode.stage"):
+                bt = jnp.asarray(self.pool.block_tables[:b])
+                sl = jnp.asarray(self.pool.seq_lens[:b])
+                last = jnp.asarray(self._last_tok[:b, None])
+                t0 = time.perf_counter() if self._m.enabled else 0.0
+                pools = self.pool.take_pools()
+                self._f_decode.check()
+            # tracecheck: disable=TRC007
+            with self._m.span("engine.decode.dispatch"):
+                if self._stacked is not None:
+                    # N-layer program signature: the stacked per-group
+                    # weight structs ride as traced args (never baked
+                    # constants)
+                    toks, states = fn(self._params, self._buffers, last,
+                                      pools, bt, sl, self._stacked)
+                else:
+                    toks, states = fn(self._params, self._buffers, last,
+                                      pools, bt, sl)
+                self._store(states)
+            # tracecheck: disable=TRC007
+            with self._m.span("engine.decode.pull"):
+                # the scheduler's designed sync point: admission/eviction
+                # need the concrete token ids  # tracecheck: disable=TRC002
+                toks = np.asarray(toks)
 
         now = time.perf_counter() if self._m.enabled else 0.0
-        # one retroactive timeline event per step (cheaper than a span
-        # object on the hot path; under a jax capture the compiled step
-        # shows up natively)  # tracecheck: disable=TRC007
-        self._m.event("engine.decode_step", t0, now,
-                      active=len(decode_rows))
         if self.tp_degree > 1:
             # sharded dispatch envelope: compute + the per-layer psum
             # pair, observed host-side OUTSIDE the shard_map body
             # (meshcheck MSH006 keeps telemetry off the traced path)
             self._observe_collective(now - t0)
-        for slot, req in enumerate(self._slots):
-            if req is None:
-                continue            # idle row wrote the null page; ignore
-            if req.prefill_pos is not None:
-                # mid-chunk-prefill slot: its decode row computed (and
-                # wrote) garbage at the cursor position — the next chunk
-                # overwrites that position and the cursor never advanced
-                continue
-            if req.temperature > 0.0 and not req.pending:
-                # a sampled request never takes a token from the greedy
-                # batch step — the spec verify program is its sampler.
-                # The row's KV write at the cursor was a correct (and
-                # repeatable) prefix write, but the cursor must NOT
-                # advance: the next speculation round re-feeds this
-                # position through its verify chunk
-                continue
-            self.pool.seq_lens[slot] += 1
-            if req.pending:
-                # still teacher-forcing the prompt suffix (prefix-cache
-                # admission): the model output is a prompt-position logit,
-                # not a generated token — feed the next suffix token
-                self._last_tok[slot] = req.pending.pop(0)
-                continue
-            tok = int(toks[slot])
-            if self._prefix is not None and not req.tokens:
-                # first generated token of a shared admission: the whole
-                # prompt's KV is now written — register the suffix's full
-                # pages so repeats of THIS prompt deepen the cache too
-                self._prefix.register(req.prompt,
-                                      self.pool.block_tables[slot])
-            if req.tokens:
-                # per-token host-side latency write, bench-gated <2%
-                # tracecheck: disable=TRC007
-                self._m.itl.observe(now - req.t_last)
-            else:
-                # first token of a shared admission: TTFT closes here
-                # tracecheck: disable=TRC007
-                self._m.ttft.observe(now - req.t_submit)
-            req.t_last = now
-            req.tokens.append(tok)
-            self._emit(req, tok)
-            self._last_tok[slot] = tok
-            self._finish_if_done(req)
+        # tracecheck: disable=TRC007
+        with self._m.span("engine.emit", step=n):
+            for slot, req in enumerate(self._slots):
+                if req is None:
+                    continue            # idle row wrote the null page; ignore
+                if req.prefill_pos is not None:
+                    # mid-chunk-prefill slot: its decode row computed (and
+                    # wrote) garbage at the cursor position — the next chunk
+                    # overwrites that position and the cursor never advanced
+                    continue
+                if req.temperature > 0.0 and not req.pending:
+                    # a sampled request never takes a token from the greedy
+                    # batch step — the spec verify program is its sampler.
+                    # The row's KV write at the cursor was a correct (and
+                    # repeatable) prefix write, but the cursor must NOT
+                    # advance: the next speculation round re-feeds this
+                    # position through its verify chunk
+                    continue
+                self.pool.seq_lens[slot] += 1
+                if req.pending:
+                    # still teacher-forcing the prompt suffix (prefix-cache
+                    # admission): the model output is a prompt-position logit,
+                    # not a generated token — feed the next suffix token
+                    self._last_tok[slot] = req.pending.pop(0)
+                    continue
+                tok = int(toks[slot])
+                if self._prefix is not None and not req.tokens:
+                    # first generated token of a shared admission: the whole
+                    # prompt's KV is now written — register the suffix's full
+                    # pages so repeats of THIS prompt deepen the cache too
+                    self._prefix.register(req.prompt,
+                                          self.pool.block_tables[slot])
+                if req.tokens:
+                    # per-token host-side latency write, bench-gated <2%
+                    # tracecheck: disable=TRC007
+                    self._m.itl.observe(now - req.t_last)
+                else:
+                    # first token of a shared admission: TTFT closes here
+                    # tracecheck: disable=TRC007
+                    self._m.ttft.observe(now - req.t_submit)
+                    if self._m.enabled:
+                        self._first_known[req.rid] = now
+                req.t_last = now
+                req.tokens.append(tok)
+                self._emit(req, tok)
+                self._last_tok[slot] = tok
+                self._finish_if_done(req)
         self._observe_step_end()
 
     # ------------------------------------------------- telemetry helpers
@@ -2908,11 +2997,25 @@ class ServingEngine:
         m = self._m
         if not m.enabled:
             return
-        m.queue_depth.set(len(self._queue))
-        m.occupancy.set(self.max_batch - self._slots.count(None))
-        if not self._queue:
-            m.page_pressure.set(0)      # an empty queue has no pressure
-        self._observe_pool_ledger()
+        # telemetry's own cost, under its own name
+        with m.span("engine.ledger", step=self._step_no):
+            m.queue_depth.set(len(self._queue))
+            m.occupancy.set(self.max_batch - self._slots.count(None))
+            if not self._queue:
+                m.page_pressure.set(0)  # an empty queue has no pressure
+            self._observe_pool_ledger()
+
+    def _observe_decode(self, rows: List[Request]) -> None:
+        """One batched decode dispatch is about to be made: the rows
+        that decode, the rows the rung's program computes, and the
+        cached tokens those rows must read."""
+        m = self._m
+        if not m.enabled:
+            return
+        m.decode_rows.inc(len(rows))
+        m.decode_slots.inc(self.bucket)
+        m.decode_live_tokens.inc(
+            int(sum(self.pool.seq_lens[r.slot] for r in rows)))
 
     def _observe_pool_ledger(self) -> None:
         """memwatch pool ledger (r13): the PagedKVCache ledger as
@@ -3018,15 +3121,19 @@ class ServingEngine:
         if gamma - accepted:
             m.spec_rejected.inc(gamma - accepted)
         m.spec_gamma.set(gamma)
-        m.event("engine.spec_round", t0, t1, gamma=gamma,
-                accepted=accepted)
+        # retroactive, but wholly inside the step: its root is the parent
+        m.event("engine.spec_round", t0, t1, parent=self._step_span,
+                step=self._step_no, gamma=gamma, accepted=accepted)
 
-    def _observe_chunk(self, dt: float, final: bool = False) -> None:
+    def _observe_chunk(self, dt: float, tokens: int,
+                       final: bool = False) -> None:
         """One chunked-prefill dispatch retired: bank its wall clock —
-        the unit a long-prompt arrival can stall decode by. The final
-        chunk also closes the per-request prefill counter."""
+        the unit a long-prompt arrival can stall decode by — and the
+        real tokens it computed. The final chunk also closes the
+        per-request prefill counter."""
         if self._m.enabled:
             self._m.prefill_chunk_s.observe(dt)
+            self._m.prefill_tokens.inc(tokens)
             if final:
                 self._m.prefills.inc()
 
@@ -3067,7 +3174,7 @@ class ServingEngine:
 def _build_prefill(note_trace, model):
     from ..jit import functional_call
 
-    def run(params, buffers, ids, pools, bt, sl):
+    def serving_prefill(params, buffers, ids, pools, bt, sl):
         note_trace()
         states = [PagedDecodeState(k, v, bt, sl) for k, v in pools]
         logits, states = functional_call(
@@ -3075,7 +3182,7 @@ def _build_prefill(note_trace, model):
             buffers=buffers, method="forward_with_cache")
         return (jnp.argmax(logits[0, -1].astype(jnp.float32)), states)
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_prefill, donate_argnums=(3,))
 
 
 def _build_chunk_prefill(note_trace, model):
@@ -3092,7 +3199,7 @@ def _build_chunk_prefill(note_trace, model):
     from ..jit import functional_call
     from ..kernels.paged_attention import PagedChunkState
 
-    def run(params, buffers, ids, pools, bt, sl, last_idx):
+    def serving_prefill_chunk(params, buffers, ids, pools, bt, sl, last_idx):
         note_trace()
         states = [PagedChunkState(k, v, bt, sl) for k, v in pools]
         logits, states = functional_call(
@@ -3101,7 +3208,7 @@ def _build_chunk_prefill(note_trace, model):
         return (jnp.argmax(logits[0, last_idx].astype(jnp.float32)),
                 states)
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_prefill_chunk, donate_argnums=(3,))
 
 
 def _build_generic_decode(note_trace, model):
@@ -3109,7 +3216,7 @@ def _build_generic_decode(note_trace, model):
     forward_with_cache (every layer an op chain XLA schedules)."""
     from ..jit import functional_call
 
-    def run(params, buffers, toks, pools, bt, sl):
+    def serving_decode_generic(params, buffers, toks, pools, bt, sl):
         note_trace()
         states = [PagedDecodeState(k, v, bt, sl) for k, v in pools]
         # offset=None -> per-slot positions from states.seq_lens
@@ -3119,7 +3226,7 @@ def _build_generic_decode(note_trace, model):
         return (jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1),
                 states)
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_decode_generic, donate_argnums=(3,))
 
 
 def _spec_filtered_probs(rows, temperature, top_k, top_p):
@@ -3161,7 +3268,7 @@ def _build_spec_draft(note_trace, model, gamma, sample, top_k,
         nh, nkv = fspec["num_heads"], fspec["num_kv_heads"]
         theta, eps = fspec["rope_theta"], fspec["epsilon"]
 
-    def run(params, buffers, tok, pools, bt, sl, *rest):
+    def serving_spec_draft(params, buffers, tok, pools, bt, sl, *rest):
         note_trace()
         if sample:
             key, temperature, top_p = rest
@@ -3219,7 +3326,7 @@ def _build_spec_draft(note_trace, model, gamma, sample, top_k,
             return props[:gamma], qrows[:gamma], states
         return outs[:gamma], states
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_spec_draft, donate_argnums=(3,))
 
 
 def _build_spec_verify(note_trace, model, sample, top_k):
@@ -3234,7 +3341,7 @@ def _build_spec_verify(note_trace, model, sample, top_k):
     from ..jit import functional_call
     from ..kernels.paged_attention import PagedChunkState
 
-    def run(params, buffers, ids, pools, bt, sl, *rest):
+    def serving_spec_verify(params, buffers, ids, pools, bt, sl, *rest):
         note_trace()
         states = [PagedChunkState(k, v, bt, sl) for k, v in pools]
         logits, states = functional_call(
@@ -3249,7 +3356,7 @@ def _build_spec_verify(note_trace, model, sample, top_k):
                 _spec_filtered_probs(rows, temperature, top_k, top_p),
                 states)
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_spec_verify, donate_argnums=(3,))
 
 
 def _build_fused_decode(note_trace, spec, snap):
@@ -3264,7 +3371,7 @@ def _build_fused_decode(note_trace, spec, snap):
     nh, nkv = spec["num_heads"], spec["num_kv_heads"]
     theta, eps = spec["rope_theta"], spec["epsilon"]
 
-    def run(params, buffers, toks, pools, bt, sl):
+    def serving_decode_fused(params, buffers, toks, pools, bt, sl):
         note_trace()
         allp = {**buffers, **params}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
@@ -3283,7 +3390,7 @@ def _build_fused_decode(note_trace, spec, snap):
             logits = x @ allp[spec["embed"]].T
         return jnp.argmax(logits.astype(jnp.float32), axis=-1), states
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_decode_fused, donate_argnums=(3,))
 
 
 def _build_fused_nlayer_decode(note_trace, spec, snap):
@@ -3303,7 +3410,7 @@ def _build_fused_nlayer_decode(note_trace, spec, snap):
     theta, eps = spec["rope_theta"], spec["epsilon"]
     groups = spec["layer_groups"]
 
-    def run(params, buffers, toks, pools, bt, sl, stacked):
+    def serving_decode_fused_nlayer(params, buffers, toks, pools, bt, sl, stacked):
         note_trace()
         allp = {**buffers, **params}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
@@ -3324,7 +3431,7 @@ def _build_fused_nlayer_decode(note_trace, spec, snap):
             logits = x @ allp[spec["embed"]].T
         return jnp.argmax(logits.astype(jnp.float32), axis=-1), states
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_decode_fused_nlayer, donate_argnums=(3,))
 
 
 def _build_fused_nlayer_decode_tp(note_trace, spec, snap, mesh, axis, tp):
@@ -3379,7 +3486,7 @@ def _build_fused_nlayer_decode_tp(note_trace, spec, snap, mesh, axis, tp):
         out_specs=(rep, pool_spec),
         check_vma=False)
 
-    def run(params, buffers, toks, pools, bt, sl, stacked):
+    def serving_decode_fused_tp(params, buffers, toks, pools, bt, sl, stacked):
         note_trace()
         allp = {**buffers, **params}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
@@ -3393,4 +3500,4 @@ def _build_fused_nlayer_decode_tp(note_trace, spec, snap, mesh, axis, tp):
             logits = x @ allp[spec["embed"]].T
         return jnp.argmax(logits.astype(jnp.float32), axis=-1), states
 
-    return jax.jit(run, donate_argnums=(3,))
+    return jax.jit(serving_decode_fused_tp, donate_argnums=(3,))

@@ -22,7 +22,6 @@ python/paddle/distributed/fleet/meta_parallel/sharding/.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -63,10 +62,6 @@ class _TrainTelemetry:
         self.staleness = r.gauge(
             "train_metrics_staleness",
             "steps between the displayed loss and the newest dispatch")
-        self.pull_seconds = r.histogram(
-            "train_pull_seconds",
-            "wall clock of host metric pulls (near-zero when the pulled "
-            "loss was dispatched >= k steps ago)")
 
 
 class _NullTrainTelemetry:
@@ -75,7 +70,7 @@ class _NullTrainTelemetry:
     def __init__(self):
         self.span = obs.null_span
         self.syncs = self.throttles = self.traces = obs.NULL
-        self.in_flight = self.staleness = self.pull_seconds = obs.NULL
+        self.in_flight = self.staleness = obs.NULL
 
 
 def _split_axes(spec) -> set:
@@ -409,13 +404,13 @@ class TrainStep:
                         for k, v in new_state["master"].items()}
             return new_params, new_state
 
-        def step(params, opt_state, lr, *batch):
+        def train_step(params, opt_state, lr, *batch):
             self._trace_count += 1   # python body runs only while tracing
             loss, grads = compute_loss_grads(params, batch)
             new_params, new_state = apply_update(params, opt_state, grads, lr)
             return loss, new_params, new_state
 
-        def step_merge(params, opt_state, merge, lr, *batch):
+        def train_step_merge(params, opt_state, merge, lr, *batch):
             self._trace_count += 1
             loss, grads = compute_loss_grads(params, batch)
             buf, count = merge
@@ -437,11 +432,11 @@ class TrainStep:
 
         donate_argnums = (0, 1, 2) if donate else ()
         if self._merge is not None:
-            self._jit_step = jax.jit(step_merge,
+            self._jit_step = jax.jit(train_step_merge,
                                      donate_argnums=donate_argnums)
         else:
             self._jit_step = jax.jit(
-                step, donate_argnums=(0, 1) if donate else ())
+                train_step, donate_argnums=(0, 1) if donate else ())
         self._step_count = 0
 
     def _build_localsgd_step(self, loss_of, donate):
@@ -492,7 +487,7 @@ class TrainStep:
             np_, ns = optimizer.functional_update(p, g, s, lr)
             return loss, np_, ns
 
-        def step(params, opt_state, count, lr, *batch):
+        def train_step_localsgd(params, opt_state, count, lr, *batch):
             self._trace_count += 1
             micro = tuple(jax.tree.map(
                 lambda b: b.reshape((dp, b.shape[0] // dp) + b.shape[1:]),
@@ -518,35 +513,37 @@ class TrainStep:
 
         self._merge = None
         self._jit_step = jax.jit(
-            step, donate_argnums=(0, 1, 2) if donate else ())
+            train_step_localsgd, donate_argnums=(0, 1, 2) if donate else ())
         self._step_count = 0
 
     def stage(self, *batch) -> StagedBatch:  # tracecheck: hotpath
         """Convert + place a batch on device (async dispatch, never
         blocks). ``__call__`` accepts the result directly, so a prefetching
         loader can stage batch N+1 while the device runs step N."""
-        vals = tuple(tree_to_values(b) for b in batch)
-        if self._data_sharding is not None:
-            if jax.process_count() > 1:
-                # multi-host: each process feeds its LOCAL batch shard
-                # (what its DataLoader/DistributedBatchSampler yields);
-                # the global array spans the mesh (reference analogue:
-                # per-trainer readers + NCCL data parallel). Per-leaf so
-                # pytree batch elements work like the single-process path
-                vals = tuple(jax.tree.map(
-                    lambda leaf: jax.make_array_from_process_local_data(
-                        self._data_sharding, np.asarray(leaf)), v)
-                    for v in vals)
+        # once per staged batch  # tracecheck: disable=TRC007
+        with self._m.span("train.stage"):
+            vals = tuple(tree_to_values(b) for b in batch)
+            if self._data_sharding is not None:
+                if jax.process_count() > 1:
+                    # multi-host: each process feeds its LOCAL batch shard
+                    # (what its DataLoader/DistributedBatchSampler yields);
+                    # the global array spans the mesh (reference analogue:
+                    # per-trainer readers + NCCL data parallel). Per-leaf so
+                    # pytree batch elements work like the single-process path
+                    vals = tuple(jax.tree.map(
+                        lambda leaf: jax.make_array_from_process_local_data(
+                            self._data_sharding, np.asarray(leaf)), v)
+                        for v in vals)
+                else:
+                    vals = tuple(jax.device_put(v, self._data_sharding)
+                                 for v in vals)
             else:
-                vals = tuple(jax.device_put(v, self._data_sharding)
-                             for v in vals)
-        else:
-            # unsharded: an explicit async H2D here (instead of letting
-            # the jit dispatch do it) is what overlaps input transfer
-            # with the previous step's compute
-            vals = tuple(jax.tree.map(
-                lambda leaf: leaf if isinstance(leaf, jax.core.Tracer)
-                else jax.device_put(leaf), v) for v in vals)
+                # unsharded: an explicit async H2D here (instead of letting
+                # the jit dispatch do it) is what overlaps input transfer
+                # with the previous step's compute
+                vals = tuple(jax.tree.map(
+                    lambda leaf: leaf if isinstance(leaf, jax.core.Tracer)
+                    else jax.device_put(leaf), v) for v in vals)
         return StagedBatch(vals)
 
     def __call__(self, *batch) -> Tensor:  # tracecheck: hotpath
@@ -555,22 +552,26 @@ class TrainStep:
         # donating call below never ran), so fit's recovery can sync to
         # last-good state and simply re-dispatch the same batch
         self._f_dispatch.check()
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         if len(batch) == 1 and isinstance(batch[0], StagedBatch):
             vals = batch[0].vals
         else:
             vals = self.stage(*batch).vals
-        if getattr(self, "_lsgd_count", None) is not None:
-            loss, self.params, self.opt_state, self._lsgd_count = \
-                self._jit_step(self.params, self.opt_state,
-                               self._lsgd_count, lr, *vals)
-        elif self._merge is not None:
-            loss, self.params, self.opt_state, self._merge = \
-                self._jit_step(self.params, self.opt_state, self._merge,
-                               lr, *vals)
-        else:
-            loss, self.params, self.opt_state = self._jit_step(
-                self.params, self.opt_state, lr, *vals)
+        # the host's share of a step: the lr upload and the call of the
+        # compiled program until it returns (it does not wait for the
+        # device)  # tracecheck: disable=TRC007
+        with self._m.span("train.dispatch", step=self._step_count):
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            if getattr(self, "_lsgd_count", None) is not None:
+                loss, self.params, self.opt_state, self._lsgd_count = \
+                    self._jit_step(self.params, self.opt_state,
+                                   self._lsgd_count, lr, *vals)
+            elif self._merge is not None:
+                loss, self.params, self.opt_state, self._merge = \
+                    self._jit_step(self.params, self.opt_state,
+                                   self._merge, lr, *vals)
+            else:
+                loss, self.params, self.opt_state = self._jit_step(
+                    self.params, self.opt_state, lr, *vals)
         if isinstance(self.optimizer._lr, LRScheduler):
             self.optimizer._lr.step()
         self._step_count += 1
@@ -588,9 +589,12 @@ class TrainStep:
             ready = getattr(old, "is_ready", None)
             if ready is not None and ready():
                 continue
-            # deliberate bounded sync — the documented HBM safety net
-            # tracecheck: disable=TRC002
-            np.asarray(old)
+            # deliberate bounded sync — the documented HBM safety net;
+            # a span, so the wait is not read as dispatch cost
+            # tracecheck: disable=TRC007
+            with self._m.span("train.throttle"):
+                # tracecheck: disable=TRC002
+                np.asarray(old)
             self.throttle_count += 1
             # throttles must be visible in exported snapshots (a nonzero
             # rate means the caller never pulls)
@@ -675,8 +679,8 @@ class TrainStep:
             return self.last_metrics
         idx, dev = picked
         # a host pull, not block_until_ready: the value is what the
-        # caller wants
-        t0 = time.perf_counter()
+        # caller wants. The span is the pull's wall clock: near zero
+        # when the loss was dispatched >= k steps ago
         # the k-step metrics cadence, not per-step
         # tracecheck: disable=TRC007
         with self._m.span("train.pull_metrics", step=idx):
@@ -688,8 +692,6 @@ class TrainStep:
         if self._m.enabled:
             # once per pull (every k steps)  # tracecheck: disable=TRC007
             self._m.syncs.inc()
-            # tracecheck: disable=TRC007
-            self._m.pull_seconds.observe(time.perf_counter() - t0)
             self._m.staleness.set(self.last_metrics["staleness"])
             self._m.in_flight.set(len(self._inflight))
         return self.last_metrics

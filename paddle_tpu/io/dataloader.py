@@ -599,18 +599,12 @@ class DevicePrefetcher:
         self._data = data
         self._stage = stage_fn if stage_fn is not None else _default_stage
         self.depth = max(1, int(depth))
-        self._telemetry = obs.enabled()
-        if self._telemetry:
-            r = obs.registry()
-            self._m_staged = r.counter(
-                "io_batches_staged",
-                "batches staged host->device by DevicePrefetcher")
-            self._m_stage_s = r.histogram(
-                "io_stage_seconds",
-                "host wall clock per staging dispatch (fetch + async "
-                "device_put; the H2D copy itself overlaps compute)")
-        else:
-            self._m_staged = self._m_stage_s = obs.NULL
+        # counts the staging dispatches; how long one takes is the
+        # stage function's to say (TrainStep.stage: ``train.stage``)
+        self._m_staged = (obs.registry().counter(
+            "io_batches_staged",
+            "batches staged host->device by DevicePrefetcher")
+            if obs.enabled() else obs.NULL)
 
     def __iter__(self):
         buf = collections.deque()
@@ -623,11 +617,8 @@ class DevicePrefetcher:
                 except StopIteration:
                     exhausted = True
                     continue
-                t0 = time.perf_counter() if self._telemetry else 0.0
                 buf.append(self._stage(nxt))
-                if self._telemetry:
-                    self._m_stage_s.observe(time.perf_counter() - t0)
-                    self._m_staged.inc()
+                self._m_staged.inc()
             if not buf:
                 return
             yield buf.popleft()

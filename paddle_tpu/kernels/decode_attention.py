@@ -277,6 +277,7 @@ def flash_prefill(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         interpret=_prefill_interpret(),
+        name="flash_prefill",
     )(offset, qf, kf, vf)
 
     if pad_q:

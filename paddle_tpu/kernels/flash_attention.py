@@ -375,6 +375,7 @@ def _fwd_compact(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*args)
     return out, lse[:, 0, :]
 
@@ -422,6 +423,7 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k,
             _sds((bh, sq, _LANES), jnp.float32, q),
         ],
         interpret=_interpret(),
+        name="flash_fwd_stats",
     )(*args)
 
     # reduce the lane-replicated stats to compact (BH, S) residuals —
@@ -603,6 +605,7 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=_sds((bh, sq, d), jnp.float32, q),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(*common)
     dq = (dq * sm_scale).astype(q.dtype)
 
@@ -650,6 +653,7 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
         out_shape=[_sds((bh_kv, skv, d), jnp.float32, q),
                    _sds((bh_kv, skv, d), jnp.float32, q)],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(*common)
     # dk already carries sm_scale via the scaled q used in ds
     return dq, dk.astype(k.dtype), dv.astype(v.dtype), None, None

@@ -723,6 +723,7 @@ def fused_block_decode_pallas(x, weights: BlockDecodeWeights, k_pages,
             jax.ShapeDtypeStruct((nkv, b_pad, d), x.dtype),
         ],
         interpret=interpret,
+        name="fused_block_decode",
     )(bt_p, sl_p, x_p, weights.ln1.reshape(1, hidden),
       weights.ln2.reshape(1, hidden), weights.wq, weights.wk, weights.wv,
       sin, cos, weights.wo, weights.wg, weights.wu, weights.wd,
@@ -1361,6 +1362,7 @@ def fused_multi_block_decode_pallas(x, weights: MultiBlockDecodeWeights,
             jax.ShapeDtypeStruct((n_layers, nkv, b_pad, d), x.dtype),
         ],
         interpret=interpret,
+        name="fused_block_decode_nlayer",
     )(*operands)
 
     kps, vps = list(k_pages), list(v_pages)

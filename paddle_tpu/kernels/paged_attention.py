@@ -276,6 +276,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
         interpret=_interpret(),
+        name="paged_attention",
     )(bt, sl, *operands)
     return out.reshape(b, h, d)
 
@@ -451,6 +452,7 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows_pad, d), q.dtype),
         interpret=_interpret(),
+        name="paged_chunk_attention",
     )(bt, st, *operands)
     out = out[:, :, :rows].reshape(b, hkv, rep, s, d)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)

@@ -61,7 +61,7 @@ def _bwd_kernel(x_ref, w_ref, g_ref, r_ref, dx_ref, *, h: int):
     dx_ref[:] = dx.astype(dx_ref.dtype)
 
 
-def _row_call(kernel, n, h, block, n_out, out_shapes, args):
+def _row_call(kernel, name, n, h, block, n_out, out_shapes, args):
     grid = (pl.cdiv(n, block),)
     in_specs = []
     for a in args:
@@ -85,13 +85,13 @@ def _row_call(kernel, n, h, block, n_out, out_shapes, args):
         out_specs, out_shapes = out_specs[0], out_shapes[0]
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shapes, interpret=_interpret())(*args)
+        out_shape=out_shapes, interpret=_interpret(), name=name)(*args)
 
 
 def _fwd(x2, w, eps, block):
     n, h = x2.shape
     return _row_call(
-        functools.partial(_fwd_kernel, eps=eps), n, h, block, 2,
+        functools.partial(_fwd_kernel, eps=eps), "rms_norm", n, h, block, 2,
         [jax.ShapeDtypeStruct((n, h), x2.dtype),
          jax.ShapeDtypeStruct((n, 1), jnp.float32)],
         [x2, w])
@@ -112,7 +112,7 @@ def _rms_bwd_rule(eps, block, res, g):
     x2, w, r = res
     n, h = x2.shape
     dx = _row_call(
-        functools.partial(_bwd_kernel, h=h), n, h, block, 1,
+        functools.partial(_bwd_kernel, h=h), "rms_norm_bwd", n, h, block, 1,
         [jax.ShapeDtypeStruct((n, h), x2.dtype)],
         [x2, w, g, r])
     dw = jnp.einsum("nh,nh->h", g.astype(jnp.float32),

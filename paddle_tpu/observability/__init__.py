@@ -7,10 +7,13 @@ text) and a :mod:`span tracer <paddle_tpu.observability.tracing>`
 (nested host-side timing events -> Chrome-trace JSON, mirrored into
 ``jax.profiler`` captures).
 
-Instrumented subsystems: ``generation.serving.ServingEngine`` (request
-lifecycle spans, TTFT/inter-token histograms, queue/occupancy/KV-pool
+Instrumented subsystems: ``generation.serving.ServingEngine`` (one span
+a phase of a step under the step's ``engine.step``, request lifecycle
+records sharing a ``rid``, rows/slots/live-token/prefill-token counters
+at the dispatch, TTFT/inter-token histograms, queue/occupancy/KV-pool
 gauges, prefix-cache counters), ``hapi.train_step.TrainStep`` (in-flight
-window depth, sync/throttle/retrace counters, pull/sync spans),
+window depth, sync/throttle/retrace counters, stage/dispatch/throttle/
+pull/sync spans),
 ``generation.program_cache`` (hit/miss counters, compile wall-time
 histograms) and ``io.DevicePrefetcher``. ``tools/telemetry_dump.py``
 renders snapshots; ``bench.py`` and the ``tools/*_bench.py`` drivers
@@ -44,16 +47,14 @@ from .metrics import (Counter, Gauge, Histogram, LATENCY_BUCKETS,
                       series_quantile)
 from .tracing import (NULL_SPAN, Span, SpanTracer, null_counter, null_event,
                       null_span, tracer)
-from .export import (chrome_trace, save_chrome_trace, save_snapshot,
-                     to_prometheus)
+from .export import to_prometheus
 from . import memory
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NULL",
     "LATENCY_BUCKETS", "exponential_buckets", "registry",
     "series_quantile", "Span", "SpanTracer", "NULL_SPAN", "tracer",
-    "null_span", "null_event", "null_counter", "chrome_trace",
-    "save_chrome_trace", "save_snapshot", "to_prometheus", "enabled",
+    "null_span", "null_event", "null_counter", "to_prometheus", "enabled",
     "span", "snapshot", "memory",
 ]
 

@@ -1,26 +1,18 @@
-"""Exporters over the registry snapshot / span ring.
-
-Two wire formats, both derived from the same JSON-able snapshot dict so
-an embedded ``BENCH_*.json`` telemetry blob and a live registry render
-identically:
-
-  - :func:`to_prometheus` — Prometheus text exposition format
-    (cumulative ``_bucket{le=...}`` histogram encoding);
-  - :func:`chrome_trace` / :func:`save_chrome_trace` — the span ring as
-    a Chrome-trace/Perfetto JSON object.
+"""Exporter over the registry snapshot: :func:`to_prometheus` renders
+the JSON-able snapshot dict (an embedded ``BENCH_*.json`` telemetry blob
+or the live registry, identically) as Prometheus text exposition format
+(cumulative ``_bucket{le=...}`` histogram encoding). The span ring
+exports itself: ``tracer().chrome_trace()`` / ``tracer().save(path)``.
 """
 
 from __future__ import annotations
 
-import json
 import math
-from typing import Any, Dict, Iterable, Optional
+from typing import Any, Dict, Optional
 
 from .metrics import registry
-from .tracing import tracer
 
-__all__ = ["to_prometheus", "chrome_trace", "save_chrome_trace",
-           "save_snapshot"]
+__all__ = ["to_prometheus"]
 
 
 def _fmt_labels(labels: Dict[str, str], extra=()) -> str:
@@ -77,26 +69,3 @@ def to_prometheus(snapshot: Optional[Dict[str, Any]] = None) -> str:
                 lines.append(
                     f"{name}{_fmt_labels(labels)} {_fmt_val(s['value'])}")
     return "\n".join(lines) + ("\n" if lines else "")
-
-
-def chrome_trace(events: Optional[Iterable[Dict[str, Any]]] = None
-                 ) -> Dict[str, Any]:
-    """Chrome-trace JSON object for ``events`` (default: the live span
-    ring)."""
-    if events is None:
-        return tracer().chrome_trace()
-    return {"traceEvents": list(events), "displayTimeUnit": "ms"}
-
-
-def save_chrome_trace(path: str,
-                      events: Optional[Iterable[Dict[str, Any]]] = None
-                      ) -> None:
-    with open(path, "w") as fh:
-        json.dump(chrome_trace(events), fh)
-
-
-def save_snapshot(path: str,
-                  snapshot: Optional[Dict[str, Any]] = None) -> None:
-    with open(path, "w") as fh:
-        json.dump(snapshot if snapshot is not None
-                  else registry().snapshot(), fh, indent=1)

@@ -2,10 +2,13 @@
 
 ``tracer().span("prefill", rid=3)`` is a context manager (and
 decorator) that records one complete event — name, wall-clock begin,
-duration, thread — into a bounded ring buffer. The export is the Chrome
+duration, thread, its own ``id`` and the ``parent`` id of the span that
+was open on the same thread when it began (0 = none) — into a bounded
+ring buffer. ``parent`` is what gives a layer its SELF time: a span's
+length less the part of it its children cover. The export is the Chrome
 ``traceEvents`` format (``chrome://tracing`` / Perfetto opens it
-directly), so a serving run under load produces a per-request timeline
-with zero external dependencies.
+directly; both ignore the two extra keys), so a serving run under load
+produces a per-request timeline with zero external dependencies.
 
 Interop with the profiler facade: every span also enters a
 ``jax.profiler.TraceAnnotation`` (the primitive behind
@@ -22,6 +25,7 @@ rule TRC007.
 from __future__ import annotations
 
 import functools
+import itertools
 import os
 import threading
 import time
@@ -38,13 +42,25 @@ except Exception:                       # pragma: no cover - import guard
     _ANNOTATION = None
 
 
+_IDS = itertools.count(1)       # span ids; next() is atomic under the GIL
+_OPEN = threading.local()       # .stack: ids of this thread's open spans
+
+
+def _open_stack() -> list:
+    try:
+        return _OPEN.stack
+    except AttributeError:
+        _OPEN.stack = []
+        return _OPEN.stack
+
+
 class Span:
     """One timed scope. Context manager; also usable as a decorator
     (``@tracer().span("load")`` — note the enabled/disabled decision is
     then frozen at decoration time; prefer the ``with`` form for code
     whose telemetry flag may toggle)."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_ann")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann", "id", "parent")
 
     def __init__(self, tr: "SpanTracer", name: str,
                  args: Optional[Dict[str, Any]] = None):
@@ -53,8 +69,13 @@ class Span:
         self.args = args or {}
         self._t0 = 0.0
         self._ann = None
+        self.id = self.parent = 0
 
     def __enter__(self) -> "Span":
+        stack = _open_stack()
+        self.id = next(_IDS)
+        self.parent = stack[-1] if stack else 0
+        stack.append(self.id)
         if _ANNOTATION is not None:
             try:
                 self._ann = _ANNOTATION(self.name)
@@ -69,7 +90,11 @@ class Span:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
-        self._tracer._append(self.name, self._t0, t1, self.args)
+        stack = _open_stack()
+        if stack and stack[-1] == self.id:
+            stack.pop()
+        self._tracer._append(self.name, self._t0, t1, self.args,
+                             self.id, self.parent)
         return False
 
     def __call__(self, fn):
@@ -84,6 +109,7 @@ class _NullSpan:
     """No-op stand-in bound when telemetry is off."""
 
     __slots__ = ()
+    id = parent = 0
 
     def __enter__(self):
         return self
@@ -102,7 +128,8 @@ def null_span(name: str, **args) -> _NullSpan:
     return NULL_SPAN
 
 
-def null_event(name: str, t0: float, t1: float, **args) -> None:
+def null_event(name: str, t0: float, t1: float, parent: int = 0,
+               **args) -> None:
     return None
 
 
@@ -126,11 +153,16 @@ class SpanTracer:
     def span(self, name: str, **args) -> Span:
         return Span(self, name, args)
 
-    def event(self, name: str, t0: float, t1: float, **args) -> None:
+    def event(self, name: str, t0: float, t1: float, parent: int = 0,
+              **args) -> None:
         """Retroactive complete event from explicit ``perf_counter``
         begin/end stamps (request lifecycle phases whose boundaries were
-        observed before the phase name was known)."""
-        self._append(name, t0, t1, args)
+        observed before the phase name was known). Ring-only: it never
+        reaches a ``jax.profiler`` capture. It nests under no span by
+        itself — a phase that began before the open span did is not its
+        child — so ``parent`` is 0 unless the caller names the span the
+        event lies inside."""
+        self._append(name, t0, t1, args, next(_IDS), parent)
 
     def counter(self, name: str, t: float, **values) -> None:
         """Perfetto counter sample (Chrome-trace ``"C"`` phase): each
@@ -144,12 +176,13 @@ class SpanTracer:
             "args": {k: float(v) for k, v in values.items()},
         })
 
-    def _append(self, name, t0, t1, args) -> None:
+    def _append(self, name, t0, t1, args, id, parent) -> None:
         self._events.append({
             "name": name, "ph": "X",
             "ts": t0 * 1e6,                       # Chrome wants µs
             "dur": max(0.0, (t1 - t0)) * 1e6,
             "pid": self._pid, "tid": threading.get_ident(),
+            "id": id, "parent": parent,
             "args": dict(args),
         })
 
@@ -168,6 +201,13 @@ class SpanTracer:
 
     def clear(self) -> None:
         self._events.clear()
+
+    @property
+    def capacity(self) -> int:
+        """Records the ring holds; a ring that is FULL may have dropped
+        its oldest, so a reader that needs every record of an interval
+        checks ``len(tracer) < tracer.capacity`` first."""
+        return self._events.maxlen
 
     def __len__(self) -> int:
         return len(self._events)

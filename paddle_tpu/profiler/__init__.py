@@ -196,8 +196,8 @@ class Profiler:
         """Write the observability span ring (engine/train/RecordEvent
         host spans) as Chrome-trace JSON — the host-side companion to
         the jax.profiler device capture in ``self._log_dir``."""
-        from ..observability import save_chrome_trace
-        save_chrome_trace(path)
+        from ..observability import tracer
+        tracer().save(path)
 
 
 def load_profiler_result(filename: str):

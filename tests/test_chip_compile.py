@@ -255,19 +255,82 @@ for _name, _shape in (("7b", {}), ("gqa", _GQA)):
                     _MATRIX[_case] = _fused(_layers, _b, int8=_int8,
                                             **_shape)
 
-_CASES = [pytest.param(build, id=name) for name, build in _TIER1.items()]
-_CASES += [pytest.param(build, id=name, marks=pytest.mark.slow)
+# the names the kernels give their ``pl.pallas_call``: what a device trace
+# shows in place of ``run`` / ``jvp__`` (the HLO instruction of a Mosaic
+# custom call is named after the innermost scope of JAX's name stack,
+# which ``name=`` opens)
+_NAMES = {
+    "flash_fwd": ("flash_fwd",),
+    "flash_bwd": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "flash_prefill": ("flash_prefill",),
+    "rms_norm_fwd": ("rms_norm",),
+    "rms_norm_bwd": ("rms_norm", "rms_norm_bwd"),
+    "paged_attention": ("paged_attention",),
+    "paged_chunk": ("paged_chunk_attention",),
+    "fused_block": ("fused_block_decode",),
+    "fused_nlayer": ("fused_block_decode_nlayer",),
+}
+
+
+def _names_of(case_id: str):
+    return _NAMES[case_id.split("-")[0].rstrip("0123456789")]
+
+
+_CASES = [pytest.param(name, build, id=name)
+          for name, build in _TIER1.items()]
+_CASES += [pytest.param(name, build, id=name, marks=pytest.mark.slow)
            for name, build in _MATRIX.items()]
 
 
-@pytest.mark.parametrize("build", _CASES)
-def test_compiles_for_v5e(chips, build):
+@pytest.mark.parametrize("case_id,build", _CASES)
+def test_compiles_for_v5e(chips, case_id, build):
     one_chip = SingleDeviceSharding(chips[0])
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     S.chips = chips
     fn, args = build(S)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), \
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, \
         "compiled, but no Pallas kernel in the program: a fallback ran"
+    # the kernels' instructions carry the given names; under autodiff
+    # JAX's name stack wraps them (``jvp_flash_fwd_``,
+    # ``transpose_jvp_flash_bwd_dq__``), so the name is looked for inside
+    called = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    for name in _names_of(case_id):
+        assert any(name in inst for inst in called), (name, called)
+
+
+def _abstract(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _stats_variant(S):
+    """The forward with lane-replicated stats (FLAGS_flash_compact_stats
+    off): the one ``pallas_call`` the default flags never reach."""
+    from paddle_tpu import flags
+    fn, args = _flash(8 * 16, 1024, 64)(S)
+
+    def fwd(*a):
+        was = flags.get_flag("flash_compact_stats")
+        flags.set_flags({"flash_compact_stats": False})
+        try:
+            return fn(*a)
+        finally:
+            flags.set_flags({"flash_compact_stats": was})
+    return fwd, args
+
+
+@pytest.mark.parametrize("case,names", [
+    pytest.param(build, _names_of(name), id=name)
+    for name, build in _TIER1.items() if "dp2xmp2" not in name
+] + [pytest.param(_stats_variant, ("flash_fwd_stats",),
+                  id="flash_fwd-stats-d64-s1024")])
+def test_pallas_call_carries_its_name(case, names):
+    """Every ``pl.pallas_call`` site passes ``name=``: traced here on the
+    CPU (nothing lowers, nothing runs), the call's equation holds it."""
+    fn, args = case(_abstract)
+    text = str(jax.make_jaxpr(fn)(*args))
+    for name in names:
+        assert f"name={name}\n" in text or f"name={name} " in text, name

@@ -157,11 +157,50 @@ class TestSpans:
         assert isinstance(doc["traceEvents"], list) and doc["traceEvents"]
         for e in doc["traceEvents"]:
             assert e["ph"] == "X"
-            assert {"name", "ts", "dur", "pid", "tid", "args"} <= set(e)
+            assert {"name", "ts", "dur", "pid", "tid", "args", "id",
+                    "parent"} <= set(e)
             assert e["dur"] >= 0
         retro = [e for e in doc["traceEvents"] if e["name"] == "retro"][0]
         assert retro["dur"] == pytest.approx(1e6)
         assert retro["args"]["rid"] == 4
+
+    def test_ids_and_parents(self):
+        """A span's parent is the span open on the same thread when it
+        began; a retroactive event nests under nothing unless told."""
+        import threading
+
+        tr = obs.tracer()
+        with tr.span("outer") as outer:
+            with tr.span("inner") as inner:
+                tr.event("retro", 1.0, 2.0)
+                tr.event("retro_inside", 1.0, 2.0, parent=inner.id)
+            other = threading.Thread(
+                target=lambda: tr.span("elsewhere").__enter__().__exit__())
+            other.start()
+            other.join(timeout=10)
+            with tr.span("sibling"):
+                pass
+        with tr.span("after"):
+            pass
+        ev = {e["name"]: e for e in tr.events()}
+        assert len({e["id"] for e in ev.values()}) == len(ev)   # unique
+        assert all(e["id"] > 0 for e in ev.values())
+        assert ev["outer"]["parent"] == 0 and ev["after"]["parent"] == 0
+        assert ev["inner"]["parent"] == ev["outer"]["id"] == outer.id
+        assert ev["sibling"]["parent"] == outer.id
+        assert ev["retro"]["parent"] == 0
+        assert ev["retro_inside"]["parent"] == inner.id
+        # the stack of open spans is the thread's own
+        assert ev["elsewhere"]["parent"] == 0
+
+    def test_capacity_tells_a_reader_when_the_ring_may_have_wrapped(self):
+        tr = obs.SpanTracer(capacity=3)
+        assert tr.capacity == 3
+        tr.event("a", 0.0, 0.1)
+        assert len(tr) < tr.capacity
+        for _ in range(5):
+            tr.event("b", 0.0, 0.1)
+        assert len(tr) == tr.capacity
 
     def test_decorator_form(self):
         calls = []
@@ -309,6 +348,284 @@ class TestServingTelemetry:
                       "program_cache_hits")["value"] >= 2
 
 
+# ------------------------------------------- the step's spans and counts
+RING_ONLY = {"request.queued", "request.first_token", "request.complete",
+             "engine.spec_round"}
+DECODE_PARTS = ["engine.decode.stage", "engine.decode.dispatch",
+                "engine.decode.pull"]
+
+
+def _spans():
+    return [e for e in obs.tracer().events() if e["ph"] == "X"]
+
+
+def _scripted_engine(**kw):
+    """GPT-tiny, four slots on a (2, 4) ladder, prompts over 8 tokens
+    chunked by 8, shrink after one step of lower demand."""
+    paddle.seed(90)
+    cfg = GPTConfig.tiny()
+    eng = ServingEngine(GPTForCausalLM(cfg), max_batch=4, page_size=8,
+                        max_seq_len=64, bucket_ladder=(2, 4),
+                        prefill_chunk=8, **kw)
+    eng.bucket_patience = 1
+    rng = np.random.default_rng(11)
+
+    def prompt(n):
+        return rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+    return eng, prompt
+
+
+def _scripted_run():
+    """Three requests whose every step can be counted by hand:
+
+    step 1  demand 3 -> rung 4; r0 (5 tokens) prefills whole, r1 (19) is
+            seated for chunking, r2 (6) waits for the prefill unit;
+            decode: r0 alone, 5 cached tokens, 4 slots
+    step 2  r2 prefills whole; decode: r0 (6 cached) and r2 (6), 4 slots;
+            both finish (r0 3 tokens, r2 2)
+    step 3  demand 1 -> rung 2; r1's chunk 0..8; nothing decodes
+    step 4  r1's chunk 8..16; nothing decodes
+    step 5  r1's last chunk 16..19, padded to 8; decode: r1, 19 cached
+            tokens, 2 slots; it finishes (2 tokens)
+    """
+    eng, prompt = _scripted_engine()
+    rids = [eng.submit(prompt(5), 3), eng.submit(prompt(19), 2),
+            eng.submit(prompt(6), 2)]
+    steps = 0
+    while eng.has_work():
+        eng.step()
+        steps += 1
+    return eng, rids, steps
+
+
+class TestStepSpans:
+    def test_counters_equal_a_hand_count(self):
+        eng, rids, steps = _scripted_run()
+        assert steps == 5
+        assert [len(eng.results()[r]) for r in rids] == [3, 2, 2]
+        snap = obs.registry().snapshot()
+        assert metric(snap, "serving_decode_steps")["value"] == 3
+        assert metric(snap, "serving_decode_rows")["value"] == 1 + 2 + 1
+        # the rung of each step that decoded: 4, 4, then 2
+        assert metric(snap, "serving_decode_slots")["value"] == 4 + 4 + 2
+        assert metric(snap, "serving_decode_live_tokens")["value"] == \
+            5 + (6 + 6) + 19
+        # the last chunk's five pad positions are not prompt tokens
+        assert metric(snap, "serving_prefill_tokens")["value"] == 5 + 6 + 19
+        assert metric(snap, "serving_prefills")["value"] == 3
+        assert metric(snap, "serving_bucket_migrations")["value"] == 2
+
+    def test_every_span_of_a_step_nests_in_its_engine_step(self):
+        _scripted_run()
+        spans = {e["id"]: e for e in _spans()}
+        roots = [e for e in spans.values() if e["name"] == "engine.step"]
+        assert [e["args"]["step"] for e in roots] == [1, 2, 3, 4, 5]
+        assert all(e["parent"] == 0 for e in roots)
+        seen = set()
+        for e in spans.values():
+            if e["name"] in RING_ONLY or e["name"] == "engine.step":
+                continue
+            seen.add(e["name"])
+            # up the parent chain to the root, each span inside its parent
+            cur = e
+            while cur["parent"]:
+                up = spans[cur["parent"]]
+                assert up["ts"] <= cur["ts"] + 1e-3
+                assert cur["ts"] + cur["dur"] <= up["ts"] + up["dur"] + 1e-3
+                cur = up
+            assert cur["name"] == "engine.step", e["name"]
+            if "step" in e["args"]:
+                assert e["args"]["step"] == cur["args"]["step"]
+        assert seen == {"engine.schedule", "engine.migrate", "engine.admit",
+                        "request.prefill", "engine.prefill_chunk",
+                        "engine.decode_step", *DECODE_PARTS, "engine.emit",
+                        "engine.callbacks", "engine.ledger"}
+        by_name = {}
+        for e in spans.values():
+            by_name.setdefault(e["name"], []).append(e)
+        # the prefill is the admission's child, the migration the
+        # scheduler's; one decode span (not also an event) a decode step
+        assert all(spans[e["parent"]]["name"] == "engine.admit"
+                   for e in by_name["request.prefill"])
+        assert all(spans[e["parent"]]["name"] == "engine.schedule"
+                   for e in by_name["engine.migrate"])
+        assert [(e["args"]["from"], e["args"]["to"])
+                for e in by_name["engine.migrate"]] == [(2, 4), (4, 2)]
+        assert [(e["args"]["active"], e["args"]["bucket"])
+                for e in by_name["engine.decode_step"]] == \
+            [(1, 4), (2, 4), (1, 2)]
+        assert [e["args"]["admitted"] for e in by_name["engine.admit"]] == \
+            [2, 1, 0, 0, 0]
+        assert [(e["args"]["pos"], e["args"]["last"])
+                for e in by_name["engine.prefill_chunk"]] == \
+            [(0, False), (8, False), (16, True)]
+
+    def test_decode_parts_tile_the_decode_step(self):
+        _scripted_run()
+        spans = _spans()
+        decodes = [e for e in spans if e["name"] == "engine.decode_step"]
+        assert len(decodes) == 3
+        for d in decodes:
+            parts = [e for e in spans if e["parent"] == d["id"]]
+            assert [e["name"] for e in parts] == DECODE_PARTS
+            # back to back, in order, and all but some microseconds of
+            # the parent between them
+            for a, b in zip(parts, parts[1:]):
+                assert a["ts"] + a["dur"] <= b["ts"] + 1e-3
+            covered = sum(e["dur"] for e in parts)
+            assert covered <= d["dur"] + 1e-3
+            assert d["dur"] - covered < max(2000.0, 0.05 * d["dur"])
+
+    @pytest.mark.parametrize("callback", [True, False],
+                             ids=["callback", "polled"])
+    @pytest.mark.parametrize("admission", ["monolithic", "chunked",
+                                           "shared_prefix"])
+    def test_request_life_in_order_with_one_first_token(self, admission,
+                                                        callback):
+        eng, prompt = _scripted_engine(
+            prefix_cache=admission == "shared_prefix")
+        seen = []
+        on_token = (lambda rid, tok, done: seen.append(rid)) \
+            if callback else None
+        p = prompt({"monolithic": 6, "chunked": 19, "shared_prefix": 17}[
+            admission])
+        if admission == "shared_prefix":
+            eng.submit(p.copy(), 3)       # leaves its two full pages cached
+            eng.run()
+        rid = eng.submit(p, 4, on_token=on_token)
+        eng.run()
+        mine = [e for e in _spans() if e["args"].get("rid") == rid]
+        want = {"monolithic": ["request.prefill"],
+                "chunked": ["engine.prefill_chunk"] * 3,
+                "shared_prefix": []}[admission]
+        assert [e["name"] for e in mine] == (
+            ["request.queued"] + want
+            + ["request.first_token", "request.complete"])
+        steps = [e["args"]["step"] for e in mine]
+        assert steps == sorted(steps) and steps[0] >= 1
+        first = mine[-2]
+        # known on the host before it was handed over, inside one step
+        assert first["dur"] > 0
+        if admission == "shared_prefix":
+            snap = obs.registry().snapshot()
+            assert metric(snap, "serving_shared_admissions")["value"] == 1
+        assert not eng._first_known      # nothing left waiting
+
+    def test_self_times_are_length_less_children(self):
+        """tools/telemetry_dump.py --spans: the operator's use of
+        ``parent``. The roots' self times and every child's add up to
+        the roots' lengths, and a parent's self time is what its
+        children leave."""
+        import importlib.util
+        import os
+
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "telemetry_dump.py")
+        spec = importlib.util.spec_from_file_location("_tdump", path)
+        tdump = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tdump)
+        _scripted_run()
+        events = obs.tracer().events()
+        table = tdump.self_times(events)
+        in_step = [n for n in table if n not in RING_ONLY]
+        assert table["engine.step"][0] == 5
+        assert sum(table[n][2] for n in in_step) == pytest.approx(
+            table["engine.step"][1], rel=1e-6)
+        decode = table["engine.decode_step"]
+        parts = sum(table[n][1] for n in DECODE_PARTS)
+        assert decode[2] == pytest.approx(decode[1] - parts, abs=1e-6)
+        assert "engine.decode.dispatch" in tdump.render_self_times(events)
+
+    def test_span_names_reach_a_profiler_trace(self, tmp_path):
+        """Every span the engine and TrainStep put in the ring during a
+        step is in a jax.profiler capture too, under its plain name —
+        the names benchmark/lib/trace.py attributes idle gaps to."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _scripted_run()
+            TestTrainTelemetry()._fit(steps=3, k=2)
+        finally:
+            jax.profiler.stop_trace()
+        in_ring = {e["name"] for e in _spans()} - RING_ONLY
+        assert {"engine.step", "engine.decode.dispatch", "train.dispatch",
+                "train.stage", "train.pull_metrics"} <= in_ring
+        path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))[0]
+        in_trace = {e.name for plane in ProfileData.from_file(path).planes
+                    for line in plane.lines for e in line.events}
+        assert in_ring <= in_trace, in_ring - in_trace
+        # ring-only records are no host activity: they stay out
+        assert not (RING_ONLY & in_trace)
+
+
+def _program_names():
+    from jax.sharding import Mesh
+
+    import jax
+    from paddle_tpu.generation import serving
+
+    model = object()
+    spec = dict(num_heads=4, num_kv_heads=4, rope_theta=1e4, epsilon=1e-6,
+                layer_groups=[[0]])
+
+    def mesh():
+        return Mesh(np.array(jax.devices()[:2]), ("mp",))
+    return {
+        "serving_prefill": lambda: serving._build_prefill(None, model),
+        "serving_prefill_chunk":
+            lambda: serving._build_chunk_prefill(None, model),
+        "serving_decode_generic":
+            lambda: serving._build_generic_decode(None, model),
+        "serving_spec_draft":
+            lambda: serving._build_spec_draft(None, model, 2, False, 0),
+        "serving_spec_verify":
+            lambda: serving._build_spec_verify(None, model, False, 0),
+        "serving_decode_fused":
+            lambda: serving._build_fused_decode(None, spec, None),
+        "serving_decode_fused_nlayer":
+            lambda: serving._build_fused_nlayer_decode(None, spec, None),
+        "serving_decode_fused_tp":
+            lambda: serving._build_fused_nlayer_decode_tp(
+                None, spec, None, mesh(), "mp", 2),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_program_names()))
+def test_serving_program_is_named_after_its_kind(name):
+    """The XLA module of a jitted program is ``jit_<function name>``:
+    what a device trace's ``XLA Modules`` line calls it."""
+    assert _program_names()[name]().__name__ == name
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("train_step", {}),
+    ("train_step_merge", {"gradient_merge_k": 2}),
+    ("train_step_localsgd", {"localsgd_k": 2, "mesh": "dp2"}),
+])
+def test_train_program_is_named_after_its_kind(name, kw):
+    import jax
+    from jax.sharding import Mesh
+
+    from paddle_tpu.hapi import TrainStep
+
+    paddle.seed(91)
+    cfg = GPTConfig.tiny()
+    model = GPTForCausalLM(cfg)
+    opt = paddle.optimizer.AdamW(1e-4, parameters=model.parameters())
+    if kw.get("mesh"):
+        kw = dict(kw, mesh=Mesh(np.array(jax.devices()[:2]), ("dp",)))
+    step = TrainStep(model, opt, loss_fn=lambda logits, y: logits.sum(), **kw)
+    assert step._jit_step.__name__ == name
+    if not kw:      # lower() takes the plain step only
+        ids = paddle.to_tensor(np.zeros((2, 8), np.int32))
+        assert "@jit_train_step" in step.lower(ids, ids).as_text()
+
+
 # ------------------------------------------------------------ training
 class TestTrainTelemetry:
     def _fit(self, steps=6, k=2):
@@ -342,10 +659,37 @@ class TestTrainTelemetry:
             step.trace_count == 1
         assert metric(snap, "train_throttles")["value"] == 0
         assert metric(snap, "train_in_flight")["value"] == 0  # post-sync
-        assert metric(snap, "train_pull_seconds")["count"] >= 1
         names = [e["name"] for e in obs.tracer().events()]
+        # one pull span a pull: the span IS the pull's wall clock
+        assert names.count("train.pull_metrics") + names.count(
+            "train.sync") == step.sync_count
         assert "train.pull_metrics" in names
         assert "train.sync" in names
+        # one dispatch span a step (the step's number on it); an unstaged
+        # batch is staged first, outside the dispatch
+        dispatch = [e for e in obs.tracer().events()
+                    if e["name"] == "train.dispatch"]
+        assert [e["args"]["step"] for e in dispatch] == list(range(6))
+        assert names.count("train.stage") == 6
+        assert "train.throttle" not in names        # nothing was throttled
+
+    def test_throttle_span_when_the_window_is_full(self):
+        from paddle_tpu.hapi import TrainStep
+
+        paddle.seed(86)
+        cfg = GPTConfig.tiny()
+        model = GPTForCausalLM(cfg)
+        opt = paddle.optimizer.AdamW(1e-4, parameters=model.parameters())
+        step = TrainStep(model, opt, loss_fn=lambda lg, y: lg.mean(),
+                         max_in_flight=1)
+        x = paddle.to_tensor(np.zeros((2, 8), np.int32))
+        for _ in range(4):
+            step(x, x)
+        step.sync()
+        # whether the oldest loss was still outstanding is the device's
+        # to say; the span is there exactly when the step had to wait
+        names = [e["name"] for e in obs.tracer().events()]
+        assert names.count("train.throttle") == step.throttle_count
 
     def test_fit_epoch_sync_span_nests_train_sync(self):
         from paddle_tpu.hapi import Model
@@ -399,6 +743,17 @@ class TestTelemetryOff:
         assert all(len(v) == 5 for v in out.values())
         assert obs.registry().snapshot()["metrics"] == {}
         assert len(obs.tracer()) == 0
+        # the scripted run reaches every span and counter of the step:
+        # chunks, a padded last chunk, both migrations, callbacks
+        eng, rids, steps = _scripted_run()
+        seen = []
+        rid = eng.submit(np.arange(19, dtype=np.int32), 2,
+                         on_token=lambda r, t, d: seen.append(t))
+        eng.run()
+        assert steps == 5 and len(seen) == 3 and eng._step_no > 5
+        assert obs.registry().snapshot()["metrics"] == {}
+        assert len(obs.tracer()) == 0
+        assert not eng._first_known
         # the cache skipped the timing wrapper entirely
         assert decode_program_cache().compile_seconds(eng.decode_key) == 0.0
         assert decode_program_cache().stats()["compile_seconds"] == {}

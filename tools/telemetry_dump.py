@@ -24,6 +24,15 @@ Three modes:
      FLAGS_telemetry on vs off — reporting the steady-state decode
      step-time delta (acceptance bar: < 2% on CPU).
 
+  4. **Span mode** (``--spans``): SELF time by span name — a span's
+     length less what its children (the records whose ``parent`` is its
+     ``id``) cover — from the live ring with ``--demo``, or from a
+     Chrome-trace JSON written by ``tracer().save``. Where a serving
+     step's host time goes, phase by phase.
+
+         python tools/telemetry_dump.py --demo --spans
+         python tools/telemetry_dump.py trace.json --spans
+
 No file argument and no --demo reads a snapshot JSON from stdin.
 """
 import argparse
@@ -198,8 +207,38 @@ def render_table(snap: dict) -> str:
     return "\n".join(lines)
 
 
+def self_times(events):
+    """{span name: [count, total ms, self ms]} of the ring's complete
+    events. A record's self time is its length less its children's; a
+    record without ``id`` (an older trace) has no children to find."""
+    spans = [e for e in events if e.get("ph") == "X"]
+    covered = {}
+    for e in spans:
+        if e.get("parent"):
+            covered[e["parent"]] = covered.get(e["parent"], 0.0) + e["dur"]
+    out = {}
+    for e in spans:
+        row = out.setdefault(e["name"], [0, 0.0, 0.0])
+        row[0] += 1
+        row[1] += e["dur"] / 1e3
+        row[2] += max(0.0, e["dur"] - covered.get(e.get("id"), 0.0)) / 1e3
+    return out
+
+
+def render_self_times(events) -> str:
+    lines = [f"{'span':28s} {'count':>7s} {'total ms':>11s} "
+             f"{'self ms':>11s} {'self ms each':>13s}"]
+    for name, (n, total, own) in sorted(self_times(events).items()):
+        lines.append(f"{name:28s} {n:7d} {total:11.3f} {own:11.3f} "
+                     f"{own / n:13.4f}")
+    lines.append("(request.queued, request.first_token and "
+                 "request.complete are a request's life, not a step's "
+                 "time: they overlap the engine's spans and have no parent)")
+    return "\n".join(lines)
+
+
 def run_demo(n_requests: int, tokens: int, trace_path, overhead: bool,
-             programs: bool = False):
+             programs: bool = False, spans: bool = False):
     import numpy as np
 
     import paddle_tpu as paddle
@@ -296,6 +335,8 @@ def run_demo(n_requests: int, tokens: int, trace_path, overhead: bool,
         # the census reads LIVE cache state, so render it before the
         # finally clears the cache (the snapshot survives, keys don't)
         prog_text = render_programs() if programs else None
+        if spans:
+            prog_text = render_self_times(obs.tracer().events())
     finally:
         flags.set_flags(dict(prior))
         clear_decode_program_cache()
@@ -318,6 +359,9 @@ def main() -> int:
                     "cached DecodeKey (kind/model/bucket/pages/dtype/"
                     "extra) with trace counts, compile seconds, and "
                     "memwatch peak bytes; pairs with --demo")
+    ap.add_argument("--spans", action="store_true",
+                    help="self time by span name (length less children) "
+                    "from the live ring (--demo) or a saved Chrome trace")
     ap.add_argument("--demo", action="store_true",
                     help="run a tiny in-process ServingEngine load and "
                     "dump ITS telemetry")
@@ -333,7 +377,8 @@ def main() -> int:
     prog_text = None
     if args.demo:
         snap, prog_text = run_demo(args.requests, args.tokens, args.trace,
-                                   args.overhead, programs=args.programs)
+                                   args.overhead, programs=args.programs,
+                                   spans=args.spans)
     else:
         if args.programs:
             # live cache of THIS process — no demo means nothing was
@@ -346,6 +391,9 @@ def main() -> int:
                 doc = json.load(fh)
         else:
             doc = json.load(sys.stdin)
+        if args.spans:
+            print(render_self_times(doc["traceEvents"]))
+            return 0
         snap = extract_snapshot(doc)
 
     if args.prom:
@@ -356,7 +404,7 @@ def main() -> int:
         sys.stdout.write("\n")
     elif args.memory:
         print(render_memory(snap, doc))
-    elif args.programs:
+    elif args.programs or args.spans:
         print(prog_text)
     else:
         print(render_table(snap))
