@@ -37,7 +37,8 @@ from ..analysis import key_vocab
 # matches jax.Array, whose ``_value`` property is the array copied to the
 # host — on the chip that pulled the whole KV pool back every dispatch
 from ..core.tensor import _val
-from ..kernels.paged_attention import PagedDecodeState, PagedKVCache
+from ..kernels.paged_attention import (PagedDecodeState, PagedKVCache,
+                                       padded_head_dim)
 from ..testing import faults
 from .program_cache import ProgramBuildError
 
@@ -912,7 +913,8 @@ class ServingEngine:
         # pools with the identical shape (same compiled programs apply)
         self._pool_geom = dict(
             num_layers=len(spec), num_pages=num_pages, page_size=page_size,
-            num_kv_heads=spec[0][0], head_dim=spec[0][1],
+            num_kv_heads=spec[0][0],
+            head_dim=_pool_head_dim(model, spec[0][1], self.kv_dtype),
             max_batch=max_batch, max_seq_len=max_seq_len, dtype=dtype,
             reserve_null_page=True, kv_dtype=self.kv_dtype)
         self.pool = PagedKVCache(**self._pool_geom)
@@ -969,7 +971,9 @@ class ServingEngine:
                 num_layers=len(dspec),
                 num_pages=1 + max_batch * (-(-max_seq_len // page_size)),
                 page_size=page_size,
-                num_kv_heads=dspec[0][0], head_dim=dspec[0][1],
+                num_kv_heads=dspec[0][0],
+                head_dim=_pool_head_dim(draft_model, dspec[0][1],
+                                        self.kv_dtype),
                 max_batch=max_batch, max_seq_len=max_seq_len,
                 dtype=jnp.result_type(next(iter(dparams.values()))),
                 reserve_null_page=True, kv_dtype=self.kv_dtype)
@@ -3164,12 +3168,35 @@ class ServingEngine:
                 self._m.migrations.inc()
 
 
+def _pool_head_dim(model, head_dim: int, kv_dtype: str) -> int:
+    """The row width of ``model``'s KV pool. A model that only ever runs
+    the generic path (``forward_with_cache``, which pads to the pool's
+    width) gets the lane-padded width that keeps a plain pool's default
+    layout row-major on the TPU (``padded_head_dim``); one that
+    publishes a fused block-decode layout keeps its head's own width,
+    which those kernels address the pool by, and so does an int8 pool
+    (written by the scatter either way)."""
+    if (kv_dtype == "native"
+            and getattr(model, "block_decode_spec", None) is None):
+        return padded_head_dim(head_dim)
+    return head_dim
+
+
 # ------------------------------------------------------ program builders
 # Module-level (not engine methods) so the decode program cache can hand
 # one compiled step to every engine over the same model. All three donate
 # ONLY the pools (each buffer appears once there; bt/sl are shared by
-# every layer's state and must not be donated): page writes then alias
-# the pool memory in place instead of copying every pool every token.
+# every layer's state and must not be donated), so a page write may
+# reuse the pool's memory. Donation alone did not make the write free:
+# XLA's scatter was done in place but in a layout of its own, and a pool
+# with a head dim under 128 crossed the jit boundary in a page-minor
+# layout, so every program transposed each pool two or three times. On
+# the TPU the write is an aliased Pallas page-write kernel and the pool
+# is allocated lane-padded (kernels/paged_attention.py, "pool
+# management", ``padded_head_dim``): parameter, kernels and result all
+# have the row-major layout, and the compiled programs hold no
+# pool-shaped copy
+# (tests/test_chip_compile.py::test_serving_program_copies_no_pool).
 
 def _build_prefill(note_trace, model):
     from ..jit import functional_call

@@ -41,10 +41,14 @@ scratch persists across phases):
               the kernel output.
 
 The ONLY HBM round-trip the step still makes for activations is the
-(B, Hkv, D) new-token k/v append, which is scattered into the pool by
-``write_paged_kv`` inside the same compiled program (a few KB; folding
-the scatter into the kernel would stream every visited page back out
-for one written column).
+(B, Hkv, D) new-token k/v append, which ``write_paged_kv`` puts into the
+pool inside the same compiled program: on the TPU an aliased page-write
+kernel that rewrites the ONE page a row's token lands on (B pages a
+layer; folding the write into this kernel would stream every visited
+page back out for one written row), elsewhere a scatter. The new token
+is a few KB, but the scatter was not: its layout made XLA copy the whole
+pool at least twice per array per program, which is why plain pools no
+longer take it on the chip.
 
 A pure-jnp reference (``fused_block_decode_ref``) is bit-compatible with
 the UNFUSED op chain the models execute (same primitive composition and

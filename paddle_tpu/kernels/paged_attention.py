@@ -528,10 +528,234 @@ def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, start,
 
 
 # ------------------------------------------------------- pool management
+# A pool is written in the layout its readers stream it in. The obvious
+# write, ``pool.at[:, page, off].set(...)``, IS done in place under
+# donation, but XLA's TPU layout assignment gives a scatter over dims 1
+# and 2 the layout {3,0,2,1} (scattered dims major), while the Pallas
+# readers are row-major {3,2,1,0}: every program that scattered into a
+# pool transposed the whole pool into the scatter's layout and on into
+# the readers' (and, where the parameter's own layout was a third one,
+# back into that: ``padded_head_dim``) — 83% of a gpt3-345m decode
+# step's device time. ``with_layout_constraint`` on the scatter's result
+# and a ``dynamic_update_slice`` loop both kept the copies. A Pallas
+# call is opaque to layout assignment, so on the TPU plain pools are
+# written by one aliased read-modify-write-by-page kernel; the scatter
+# (``*_xla``) stays as the reference everywhere else (CPU tier-1,
+# ``use_pallas`` off) and for ``QuantizedPages``.
+
+def _page_write_kernel(blk_ref, lo_ref, hi_ref, kn_ref, vn_ref, kin_ref,
+                       vin_ref, kout_ref, vout_ref, *, n_pg: int):
+    """One grid step = one pool page of one batch row: rows ``[lo, hi)``
+    of the page take the new tokens, the rest keep the pool's bits.
+
+    The pools alias input to output, and Pallas neither re-fetches an
+    input block nor writes an output block back while consecutive steps
+    stay on the same block — so a step that selected from the (stale)
+    input would undo the step before it. The output block is therefore
+    the accumulator: it is seeded from the input on the first step of a
+    run of equal blocks and only ever updated in place after that
+    (``lo >= hi`` updates nothing: how the wrapper parks a step that has
+    nothing to write on the block of the step before it)."""
+    i = pl.program_id(0) * n_pg + pl.program_id(1)
+    fresh = jnp.logical_or(
+        i == 0, blk_ref[i] != blk_ref[jnp.maximum(i - 1, 0)])
+    lo, hi = lo_ref[i], hi_ref[i]
+
+    def one_pool(new_ref, pin_ref, pout_ref):
+        @pl.when(fresh)
+        def _seed():
+            pout_ref[...] = pin_ref[...]
+
+        @pl.when(hi > lo)
+        def _write():
+            page = pout_ref[:, 0]                    # (Hkv, page_size, D)
+            row = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+            # decode hands one token (Hkv, 1, D), broadcast over the
+            # page's rows; a prompt hands the page's own rows
+            new = jnp.broadcast_to(new_ref[0], page.shape)
+            pout_ref[:, 0] = jnp.where((row >= lo) & (row < hi), new, page)
+
+    one_pool(kn_ref, kin_ref, kout_ref)
+    one_pool(vn_ref, vin_ref, vout_ref)
+
+
+def _page_write(k_pages, v_pages, k_new, v_new, pages, lo, hi, *, name,
+                interpret):
+    """Run :func:`_page_write_kernel` over a ``(B, n_pg)`` grid.
+    ``pages``/``lo``/``hi`` are (B, n_pg) int32: the pool page each step
+    works on and the half-open row range it writes there (``lo >= hi``:
+    nothing; such a step — and one whose page lies outside the pool —
+    is parked on the nearest earlier writing step's page, so it moves no
+    block and can never sit between two steps of a page that is written
+    for real). ``k_new``/``v_new``: (B, Hkv, 1, D) for one token a row,
+    else (B, Hkv, n_pg * page_size, D) with row ``r`` of step ``j`` at
+    ``j * page_size + r``."""
+    hkv, num_pages, page_size, d = k_pages.shape
+    b, n_pg = pages.shape
+    rows = k_new.shape[2] // n_pg
+    pages, lo, hi = (x.reshape(-1) for x in (pages, lo, hi))
+    # an out-of-pool page never reaches an index map (a DMA past the
+    # pool faults the chip where the scatter silently dropped the write)
+    writes = (hi > lo) & (pages >= 0) & (pages < num_pages)
+    step = jnp.arange(b * n_pg, dtype=jnp.int32)
+    prev = jax.lax.cummax(jnp.where(writes, step, -1))
+    # before the first writing step: ITS page (seeded there, untouched
+    # until it arrives); no writing step at all: page 0, copied onto itself
+    # (argmax of all-False is step 0, whose masked page is 0)
+    park = jnp.where(prev >= 0, prev, jnp.argmax(writes).astype(jnp.int32))
+    blk = jnp.where(writes, pages, 0)[park]
+    hi = jnp.where(writes, hi, lo)
+
+    new_spec = pl.BlockSpec((1, hkv, rows, d),
+                            lambda b_, j, *_: (b_, 0, j, 0))
+    pool_spec = pl.BlockSpec(
+        (hkv, 1, page_size, d),
+        lambda b_, j, blk_ref, *_: (0, blk_ref[b_ * n_pg + j], 0, 0))
+    pool_shape = jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype)
+    return pl.pallas_call(
+        functools.partial(_page_write_kernel, n_pg=n_pg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, n_pg),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+        ),
+        out_shape=[pool_shape, pool_shape],
+        # operands count the scalar-prefetch arguments: 5, 6 = the pools
+        input_output_aliases={5: 0, 6: 1},
+        interpret=interpret,
+        name=name,
+    )(blk, lo, hi, k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype),
+      k_pages, v_pages)
+
+
+def _table_pages(bt, page_idx):
+    """Pool page of each ``page_idx`` (B, n) through the block tables;
+    a position past the table's width gets the out-of-pool page -1."""
+    width = bt.shape[1]
+    pages = jnp.take_along_axis(bt, jnp.clip(page_idx, 0, width - 1), axis=1)
+    return jnp.where(page_idx < width, pages, -1)
+
+
+# The two Pallas writers are jitted: the layers of one program call them
+# with the same shapes, so they share ONE trace and ONE Mosaic lowering
+# where each layer paid its own (most of a second a program of set-up at
+# 24 layers). ``interpret`` is static because a trace is cached by its
+# arguments, not by the backend a test may have steered it to.
+
+def write_paged_kv_pallas(k_pages, v_pages, k_new, v_new, block_tables,
+                          positions):
+    """Pallas twin of :func:`write_paged_kv_xla` for plain pools:
+    grid over batch rows, each row rewrites the one page its token lands
+    on. Inactive rows (all-zero block tables) write the engine's null
+    page 0 one after another, which the kernel's accumulation makes as
+    well-defined as their scatter was."""
+    return _kv_write(k_pages, v_pages, k_new, v_new,
+                     jnp.asarray(block_tables, jnp.int32),
+                     jnp.asarray(positions, jnp.int32),
+                     interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_write(k_pages, v_pages, k_new, v_new, bt, pos, *, interpret):
+    page_size = k_pages.shape[2]
+    off = (pos % page_size)[:, None]
+    return _page_write(
+        k_pages, v_pages, k_new[:, :, None], v_new[:, :, None],
+        _table_pages(bt, (pos // page_size)[:, None]), off, off + 1,
+        name="paged_kv_write", interpret=interpret)
+
+
+def write_paged_prompt_at_pallas(k_pages, v_pages, k_new, v_new,
+                                 block_tables, start):
+    """Pallas twin of :func:`write_paged_prompt_at_xla` for plain pools:
+    grid over batch rows x the ``ceil((S - 1) / page) + 1`` pages S
+    tokens can touch from an arbitrary ``start``. The tokens are staged
+    page-aligned first (token ``t`` at row ``start % page + t`` — a small
+    XLA gather; in-kernel the shift would be an unaligned dynamic sublane
+    slice of a packed dtype), so every step selects whole aligned rows.
+    Pages past the block table's width are dropped, as the scatter's
+    ``mode="drop"`` drops them."""
+    return _prompt_write(k_pages, v_pages, k_new, v_new,
+                         jnp.asarray(block_tables, jnp.int32),
+                         jnp.asarray(start, jnp.int32),
+                         interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _prompt_write(k_pages, v_pages, k_new, v_new, bt, st, *, interpret):
+    b, s, hkv, d = k_new.shape
+    page_size = k_pages.shape[2]
+    n_pg = (s + page_size - 2) // page_size + 1
+    first = st // page_size                                   # (B,)
+    page_idx = first[:, None] + jnp.arange(n_pg, dtype=jnp.int32)[None, :]
+    base = page_idx * page_size                               # (B, n_pg)
+    lo = jnp.clip(st[:, None] - base, 0, page_size)
+    hi = jnp.clip(st[:, None] + s - base, 0, page_size)
+    # staged row i of batch row b holds token i - start_b % page
+    tok = (jnp.arange(n_pg * page_size, dtype=jnp.int32)[None, :]
+           - (st % page_size)[:, None])                       # (B, L)
+    tok = jnp.clip(tok, 0, s - 1)[:, None, :, None]
+
+    def staged(x):
+        return jnp.take_along_axis(jnp.swapaxes(x, 1, 2), tok, axis=2,
+                                   mode="promise_in_bounds")
+
+    return _page_write(k_pages, v_pages, staged(k_new), staged(v_new),
+                       _table_pages(bt, page_idx), lo, hi,
+                       name="paged_prompt_write", interpret=interpret)
+
+
+def padded_head_dim(head_dim: int) -> int:
+    """The row width to ALLOCATE a plain pool with for heads of
+    ``head_dim``: on the TPU a head narrower than the 128 lanes pads up
+    to them, everywhere else it is ``head_dim``.
+
+    Why: the layout an array crosses a ``jit`` boundary in is its
+    shape's default, and for ``(Hkv, pages, page_size, 64)`` the TPU
+    runtime's default is PAGE-MINOR (``major_to_minor=(0, 2, 3, 1)``,
+    1.25x the bytes; row-major would leave half of every 128-lane row
+    empty, 2x). One page is then one lane of every tile, no page-indexed
+    kernel can address it, and XLA transposed the whole pool into
+    row-major in front of the first kernel of every program and back
+    behind the last — whatever wrote the page. (Pinning the layout on
+    the ``jit`` instead works until the executable comes back from the
+    persistent compile cache: jax 0.9 loads it expecting the default.)
+    A 128-wide row has the row-major default, so a pool allocated that
+    wide is never copied; it costs the lane padding, resident, which the
+    row-major copies cost in passing. The caller pads q/k/v to the
+    pool's width and slices the output
+    (``nn.functional.paged_scaled_dot_product_attention``); code that
+    addresses the pool by the head's own width (the fused block-decode
+    kernels) must be given an unpadded pool."""
+    from ..flags import is_tpu_backend
+    return max(head_dim, _LANES) if is_tpu_backend() else head_dim
+
+
+def _write_kernel_applies(k_pages) -> bool:
+    """The page-write kernels run where the attention kernels run (a TPU
+    backend with ``FLAGS_use_pallas``) on plain pools; ``QuantizedPages``
+    (int8 payload + a 1-lane scale array) keep the scatter."""
+    from ..flags import is_tpu_backend, snapshot
+    return (not isinstance(k_pages, QuantizedPages)
+            and snapshot(("use_pallas",)).use_pallas and is_tpu_backend())
+
+
 def write_paged_kv(k_pages, v_pages, k_new, v_new, block_tables, positions):
     """Write one token per sequence into the pool at absolute sequence
-    ``positions`` ((B,) int32). k_new/v_new: (B, Hkv, D). Device-side
-    scatter via the block tables; returns the updated pools."""
+    ``positions`` ((B,) int32). k_new/v_new: (B, Hkv, D). Returns the
+    updated pools: the page-write kernel on the TPU (plain pools), the
+    scatter elsewhere."""
+    write = (write_paged_kv_pallas if _write_kernel_applies(k_pages)
+             else write_paged_kv_xla)
+    return write(k_pages, v_pages, k_new, v_new, block_tables, positions)
+
+
+def write_paged_kv_xla(k_pages, v_pages, k_new, v_new, block_tables,
+                       positions):
+    """:func:`write_paged_kv` as a device-side scatter via the block
+    tables: the reference (CPU tests, ``use_pallas`` off) and the write
+    of ``QuantizedPages`` pools."""
     bt = jnp.asarray(block_tables, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     b = pos.shape[0]
@@ -573,7 +797,19 @@ def write_paged_prompt_at(k_pages, v_pages, k_new, v_new, block_tables,
     the chunked-prefill cursor; :func:`write_paged_prompt` is the
     start=0 case). Positions past the block table's width are DROPPED
     (scatter mode="drop"): the final chunk of a prompt pads to the fixed
-    chunk length, and its pad tail must never clamp onto a live page."""
+    chunk length, and its pad tail must never clamp onto a live page.
+    The page-write kernel on the TPU (plain pools), the scatter
+    elsewhere."""
+    write = (write_paged_prompt_at_pallas if _write_kernel_applies(k_pages)
+             else write_paged_prompt_at_xla)
+    return write(k_pages, v_pages, k_new, v_new, block_tables, start)
+
+
+def write_paged_prompt_at_xla(k_pages, v_pages, k_new, v_new, block_tables,
+                              start):
+    """:func:`write_paged_prompt_at` as a ``mode="drop"`` scatter: the
+    reference (CPU tests, ``use_pallas`` off) and the write of
+    ``QuantizedPages`` pools."""
     bt = jnp.asarray(block_tables, jnp.int32)
     b, s, hkv, d = k_new.shape
     page_size = k_pages.shape[2]

@@ -754,12 +754,23 @@ def paged_scaled_dot_product_attention(query, key, value, state):
 
     def fn(qv, kv, vv, kp, vp, bt, sl):
         s = qv.shape[1]
+        d = qv.shape[-1]
+        if kp.shape[-1] != d:
+            # a lane-padded pool (``padded_head_dim``): the pool's rows are
+            # wider than the head. Zero lanes add nothing to q.k and carry
+            # zeros through p.v, so q/k/v pad up to the pool's width, the
+            # softmax scale stays the head's, and the output slices back
+            qp, kvp, vvp = (jnp.pad(x, [(0, 0)] * 3 + [(0, kp.shape[-1] - d)])
+                            for x in (qv, kv, vv))
+        else:
+            qp, kvp, vvp = qv, kv, vv
+        scale = 1.0 / math.sqrt(d)
         if s > 1 and chunked:
             if qv.shape[0] != 1:
                 raise NotImplementedError(
                     "chunked paged prefill is per-request (B = 1); got "
                     f"batch {qv.shape[0]}")
-            kp2, vp2 = write_paged_prompt_at(kp, vp, kv, vv, bt, sl)
+            kp2, vp2 = write_paged_prompt_at(kp, vp, kvp, vvp, bt, sl)
             # query rows sit at absolute positions sl .. sl+s-1; rows
             # past the real prompt tail (final-chunk padding) emit
             # garbage the caller discards, and their K is masked off
@@ -768,7 +779,7 @@ def paged_scaled_dot_product_attention(query, key, value, state):
             # view is ever materialized.
             attend = (paged_chunk_attention if use_pallas
                       else paged_chunk_attention_xla)
-            out = attend(qv, kp2, vp2, bt, sl)
+            out = attend(qp, kp2, vp2, bt, sl, sm_scale=scale)[..., :d]
             sl2 = sl + s
         elif s > 1:
             # whole-prompt prefill contract: the sequences must be
@@ -782,14 +793,15 @@ def paged_scaled_dot_product_attention(query, key, value, state):
                     "Use a PagedChunkState (chunked prefill) to extend "
                     "non-empty sequences, or decode one token at a "
                     "time after the prompt.")
-            kp2, vp2 = write_paged_prompt(kp, vp, kv, vv, bt)
+            kp2, vp2 = write_paged_prompt(kp, vp, kvp, vvp, bt)
             # the prompt is the whole valid cache: causal self-attention
             out = cached_attention(qv, kv, vv, s)
             sl2 = sl + s
         else:
-            kp2, vp2 = write_paged_kv(kp, vp, kv[:, 0], vv[:, 0], bt, sl)
+            kp2, vp2 = write_paged_kv(kp, vp, kvp[:, 0], vvp[:, 0], bt, sl)
             attend = paged_attention if use_pallas else paged_attention_xla
-            out = attend(qv[:, 0], kp2, vp2, bt, sl + 1)[:, None]
+            out = attend(qp[:, 0], kp2, vp2, bt, sl + 1,
+                         sm_scale=scale)[:, None, :, :d]
             sl2 = sl + 1
         return out, kp2, vp2, sl2
 

@@ -33,7 +33,9 @@ from paddle_tpu.kernels.flash_attention import (activation_layout,
                                                 flash_attention_bshd)
 from paddle_tpu.kernels.paged_attention import (QuantizedPages,
                                                 paged_attention,
-                                                paged_chunk_attention)
+                                                paged_chunk_attention,
+                                                write_paged_kv_pallas,
+                                                write_paged_prompt_at_pallas)
 from paddle_tpu.kernels.rms_norm import rms_norm_pallas
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
@@ -151,6 +153,22 @@ def _paged(h, hkv, d, *, int8=False, chunk=0, b=8, page=64, max_pages=16):
     return build
 
 
+def _page_write(hkv, d, *, chunk=0, b=32, page=64, max_pages=16):
+    """The aliased page-write kernels at a configuration's pool shape
+    (``max_batch`` 32 x 16 pages + the null page): one token a row, or a
+    ``chunk`` of one prompt."""
+    def build(S):
+        pool = _pool(S, hkv, d, page, b * max_pages + 1, False)
+        if chunk:
+            new = S((1, chunk, hkv, d), BF16)
+            return write_paged_prompt_at_pallas, [
+                pool, pool, new, new, S((1, max_pages), I32), S((1,), I32)]
+        new = S((b, hkv, d), BF16)
+        return write_paged_kv_pallas, [pool, pool, new, new,
+                                       S((b, max_pages), I32), S((b,), I32)]
+    return build
+
+
 def _fused(layers, b, *, h=4096, nh=32, nkv=32, d=128, inter=11008,
            int8=False, int4=False, page=64, max_pages=16):
     """``layers == 0``: the single-layer kernel; else the N-layer one."""
@@ -212,6 +230,13 @@ _TIER1 = {
     "paged_chunk-gqa32x8-d128": _paged(32, 8, 128, chunk=256, b=1),
     "paged_chunk-int8-gqa32x8-d128": _paged(32, 8, 128, int8=True,
                                            chunk=256, b=1),
+    # gpt3-345m's 16 KV heads on a head-width pool (what a fused-decode
+    # model of that width gets), and mistral-7b's; gpt3-345m's own pool is
+    # lane-padded to 128 (test_serving_program_copies_no_pool)
+    "paged_kv_write-mha16-d64-b32": _page_write(16, 64),
+    "paged_kv_write-gqa8-d128-b32": _page_write(8, 128),
+    "paged_prompt_write-mha16-d64-c256": _page_write(16, 64, chunk=256),
+    "paged_prompt_write-gqa8-d128-c256": _page_write(8, 128, chunk=256),
     "fused_block-int8kv-gqa-b32": _fused(0, 32, int8=True, **_GQA),
     "fused_nlayer2-int8kv-gqa-b32": _fused(2, 32, int8=True, **_GQA),
     "fused_nlayer2-int4-7b-b8": _fused(2, 8, int4=True),
@@ -267,6 +292,8 @@ _NAMES = {
     "rms_norm_bwd": ("rms_norm", "rms_norm_bwd"),
     "paged_attention": ("paged_attention",),
     "paged_chunk": ("paged_chunk_attention",),
+    "paged_kv_write": ("paged_kv_write",),
+    "paged_prompt_write": ("paged_prompt_write",),
     "fused_block": ("fused_block_decode",),
     "fused_nlayer": ("fused_block_decode_nlayer",),
 }
@@ -300,6 +327,90 @@ def test_compiles_for_v5e(chips, case_id, build):
               if 'custom_call_target="tpu_custom_call"' in ln]
     for name in _names_of(case_id):
         assert any(name in inst for inst in called), (name, called)
+
+
+# ------------------------------------------- no serving program copies a pool
+@pytest.fixture(scope="module")
+def gpt3_345m_serving(chips):
+    """gpt3-345m at its published widths and its cell's pool geometry
+    (16 KV heads of 64, 513 pages of 64 tokens), cut to two layers: every
+    layer writes its pools the same way. The pool has the row width the
+    engine allocates it with on the chip, which ``is_tpu_backend`` (the
+    autouse fixture) makes ``padded_head_dim`` give here. Layouts are
+    left open, so each array has its shape's default on the chip, as a
+    concrete array under ``jit`` has. Returns the model, its abstract
+    state, and a function giving the three program builders' abstract
+    arguments."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.generation import serving
+
+    layers, hkv, d, page, max_pages, max_batch = 2, 16, 64, 64, 16, 32
+    one_chip = SingleDeviceSharding(chips[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    cfg = models.GPTConfig(
+        vocab_size=50304, hidden_size=1024, num_hidden_layers=layers,
+        num_attention_heads=16, intermediate_size=4096,
+        max_position_embeddings=1024, layer_norm_epsilon=1e-5,
+        tie_word_embeddings=True)
+    with paddle.LazyGuard():
+        model = models.GPTForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    model.eval()
+    params, buffers = ({k: S(v.shape, v.dtype) for k, v in state.items()}
+                       for state in model.raw_state())
+
+    def programs():
+        # called from a test: ``_on_tpu`` is function-scoped
+        width = serving._pool_head_dim(model, d, "native")
+        pool = S((hkv, max_batch * max_pages + 1, page, width), BF16)
+        pools = [(pool, pool)] * layers
+
+        def tail(b):
+            return [pools, S((b, max_pages), I32), S((b,), I32)]
+        return pool.shape, {
+            "serving_decode_generic": (serving._build_generic_decode,
+                                       [S((16, 1), I32)] + tail(16)),
+            "serving_prefill_chunk": (serving._build_chunk_prefill,
+                                      [S((1, 256), I32)] + tail(1)
+                                      + [S((), I32)]),
+            "serving_prefill": (serving._build_prefill,
+                                [S((1, 128), I32)] + tail(1)),
+        }
+    return model, params, buffers, programs
+
+
+@pytest.mark.parametrize("program", ["serving_decode_generic",
+                                     "serving_prefill_chunk",
+                                     "serving_prefill"])
+def test_serving_program_copies_no_pool(chips, gpt3_345m_serving, program):
+    """The pool is written in the layout its readers read and it crosses
+    the jit boundary in: the program the chip's compiler emits holds NO
+    copy with a pool's shape (the scatter on a head-width pool cost up
+    to three per array: into its preferred {3,0,2,1}, on into the
+    kernels' row-major, back into the parameter's page-minor default),
+    aliases every pool input to output, and writes each layer's pair
+    with one page-write kernel."""
+    import re
+    model, params, buffers, programs = gpt3_345m_serving
+    pool_shape, programs = programs()
+    assert pool_shape[-1] == 128
+    build, args = programs[program]
+    text = build(lambda: None, model).lower(
+        params, buffers, *args).compile().as_text()
+    assert f"HloModule jit_{program}" in text
+    shape = ",".join(map(str, pool_shape))
+    copies = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(rf"= bf16\[{shape}\]\S* copy\(", ln)]
+    assert not copies, copies
+    n_pools = 2 * len(args[1])
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") \
+        == n_pools, header[:400]
+    writes = re.findall(r"%paged_(?:kv|prompt)_write\S* = ", text)
+    assert len(writes) == len(args[1]), writes
 
 
 def _abstract(shape, dtype):
