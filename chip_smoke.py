@@ -13,6 +13,11 @@ points a user calls, on ONE TPU chip, in this one process:
   fused_decode  a Llama-layout model at Llama-2-7B widths, 4 layers deep,
                 through the fused block-decode engine against the same
                 engine with FLAGS_fused_block_decode off
+  hybrid        granite-4.0-h-micro whole (36 Mamba-2 + 4 attention
+                layers, the benchmark's configuration and weights) through
+                ServingEngine: one prompt through a full and a padded
+                prefill chunk, then decoded, against the benchmark's plain
+                reference
 
 ``python chip_smoke.py --chips 4`` runs ONLY the Fleet hybrid path
 (dp2 x mp2 TrainStep at Llama-2-7B widths, 2 layers) and its one-chip twin.
@@ -63,6 +68,11 @@ class Sizes:
     hybrid_layers: int
     hybrid_shape: Tuple[int, int]
     hybrid_steps: int
+    granite_prompt_len: int = 416           # a full chunk, then a padded one
+    granite_new_tokens: int = 8
+    # a configuration in the benchmark's form; None: the benchmark's own
+    # file, benchmark/configs/granite-4.0-h-micro.json
+    granite: Optional[dict] = None
     prefill_chunk: Optional[int] = None     # None: FLAGS_serving_prefill_chunk
     seed: int = 0
 
@@ -380,6 +390,81 @@ def phase_fused_decode(s: Sizes) -> dict:
         steps_s=round(secs[1], 3))
 
 
+# ------------------------------------- hybrid: Mamba-2 beside attention
+def phase_granite_hybrid(s: Sizes) -> dict:
+    """The recurrent-state path end to end at the published widths: the
+    chunk program carries the state from a full chunk into a padded one
+    (whose pad must not move it), decode advances it in place through
+    ``ssm_decode_update``, and every token is held against the plain
+    reference's argmax by the benchmark's own near-tie rule. (The
+    benchmark's check seats prompts of one chunk or less: without this
+    phase nothing on the chip compares the carry or the pad mask.)"""
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib import hybrid
+    from paddle_tpu.generation.program_cache import decode_program_cache
+
+    config = s.granite
+    if config is None:
+        here = os.path.dirname(os.path.abspath(__file__))
+        with open(os.path.join(here, "benchmark", "configs",
+                               "granite-4.0-h-micro.json")) as fh:
+            config = json.load(fh)
+    sysm = hybrid.build_serve_hybrid(config, {}, s.seed + 3, 1)
+    eng, ref, model = sysm.engine, sysm.ref, config["model"]
+    n, new = s.granite_prompt_len, s.granite_new_tokens
+    assert eng.chunk < n < 2 * eng.chunk, (eng.chunk, n)
+
+    def refuse_recovery(exc):
+        raise AssertionError("a serving dispatch failed") from exc
+    eng._recover_dispatch = refuse_recovery
+    rng = np.random.default_rng(s.seed + 3)
+    prompt = rng.integers(0, sysm.vocab, (n,)).astype(np.int32)
+    t0 = time.perf_counter()
+    rid = eng.submit(prompt, new)
+    toks = eng.run()[rid]
+    serve_s = time.perf_counter() - t0
+    assert eng.status(rid) == "OK" and len(toks) == new, eng.statuses()
+    assert eng.chunk_dispatches == 2, eng.chunk_dispatches
+    has_kernel = "ssm_decode_update" in decode_program_cache().lowered(
+        eng.decode_key).as_text()
+    if s.on_chip:
+        assert has_kernel, "decode program without the state-update kernel"
+
+    ids = jnp.asarray(np.concatenate([prompt, np.asarray(toks, np.int32)]))
+    rows = np.asarray(jax.jit(
+        lambda w, ids: ref.logits(w, ids, model)[n - 1:n - 1 + new])(
+            sysm.weights, ids), np.float32)
+    ties = []
+    for t, (row, tok) in enumerate(zip(rows, toks)):
+        best = int(row.argmax())
+        if best == tok:
+            continue
+        tie = dict(position=t, engine=int(tok), reference=best,
+                   gap=float(row[best] - row[tok]), top=float(row[best]))
+        print(json.dumps({"near_tie": tie}), flush=True)
+        assert tie["gap"] <= ref.TIE_ATOL + ref.TIE_RTOL * abs(tie["top"]), \
+            f"a token differs from the reference beyond a near-tie: {tie}"
+        ties.append(tie)
+    second = np.sort(rows, axis=-1)
+    return _emit(
+        "hybrid", config=config["name"],
+        layers=len(model["layer_types"]),
+        n_params=int(sum(p.size for p in sysm.model.parameters())),
+        prompt_len=n, chunk=eng.chunk, chunk_dispatches=eng.chunk_dispatches,
+        new_tokens=new, tokens=[int(t) for t in toks], statuses="OK",
+        decode_kind=eng.decode_key.kind, ssm_update_custom_call=has_kernel,
+        state_bytes=eng._state.nbytes, near_ties=len(ties),
+        tie_atol=ref.TIE_ATOL,
+        # how decided the reference itself was: top logit less the second
+        reference_margins=[round(float(v), 5)
+                           for v in second[:, -1] - second[:, -2]],
+        serve_s=round(serve_s, 2))
+
+
 # ------------------------------------------------- four chips: dp2 x mp2
 def _hybrid_losses(s: Sizes, mesh, x, y):
     """Build the model from the seed, take ``hybrid_steps`` steps on
@@ -505,6 +590,9 @@ def run_phases(s: Sizes, chips: int = 1) -> list:
     del model
     gc.collect()
     lines.append(phase_fused_decode(s))
+    clear_decode_program_cache()
+    gc.collect()
+    lines.append(phase_granite_hybrid(s))
     return lines
 
 

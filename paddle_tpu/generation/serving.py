@@ -39,6 +39,9 @@ from ..analysis import key_vocab
 from ..core.tensor import _val
 from ..kernels.paged_attention import (PagedDecodeState, PagedKVCache,
                                        padded_head_dim)
+from ..kernels.recurrent_state import (RecurrentSpec, RecurrentState,
+                                       RecurrentStateCache,
+                                       is_recurrent_state, recurrent_layout)
 from ..testing import faults
 from .program_cache import ProgramBuildError
 
@@ -117,8 +120,9 @@ _POOL_STATES = ("used", "free", "shared", "pinned", "spilled")
 # schema version of the harvest_request/adopt_request handoff bundle:
 # bumped whenever the bundle's field set changes, and validated at
 # adopt — a disaggregated pair built from different revisions must
-# refuse loudly instead of mis-seating pages
-HANDOFF_SCHEMA_VERSION = 1
+# refuse loudly instead of mis-seating pages. 2: ``state``, the
+# recurrent layers' rows (None for a model that has none)
+HANDOFF_SCHEMA_VERSION = 2
 
 
 class _EngineTelemetry:
@@ -324,6 +328,30 @@ class _EngineTelemetry:
             "high-water mark of pages resident in the host-RAM KV "
             "tier — the tier watermark memwatch prices against host "
             "memory")
+        # ---- the recurrent-state store (a model with state-space
+        # layers): written only by an engine that has one
+        self.state_bytes = g(
+            "serving_state_bytes",
+            "device bytes of the recurrent-state store: every slot's "
+            "row of every recurrent layer (SSM state float32 + "
+            "convolution window), resident whether or not the slot is "
+            "taken")
+        self.state_slots_live = g(
+            "serving_state_slots_live",
+            "slots whose recurrent-state rows belong to a seated "
+            "request")
+        self.state_resets = c(
+            "serving_state_resets",
+            "recurrent-state rows zeroed at admission (one per "
+            "admission that runs prefill compute)")
+        self.state_moves = c(
+            "serving_state_moves",
+            "recurrent-state rows copied slot to slot on the device by "
+            "a bucket-ladder migration")
+        self.state_exports = c(
+            "serving_state_exports",
+            "recurrent-state rows snapshotted to the host for a "
+            "harvest_request handoff")
         self.counter_track = t.counter
 
 
@@ -355,6 +383,9 @@ class _NullEngineTelemetry:
         self.pool_pages = {s: obs.NULL for s in _POOL_STATES}
         self.pool_bytes = {s: obs.NULL for s in _POOL_STATES}
         self.pool_frag = self.host_tier_peak = obs.NULL
+        self.state_bytes = self.state_slots_live = obs.NULL
+        self.state_resets = self.state_moves = obs.NULL
+        self.state_exports = obs.NULL
         self.counter_track = obs.null_counter
 
 
@@ -796,7 +827,12 @@ class ServingEngine:
         self.replica = str(replica)
         self.max_batch = max_batch
         self.max_seq_len = max_seq_len
-        spec = model.cache_spec()
+        # a hybrid model's cache_spec says per layer which kind of state
+        # it keeps: (kv_heads, head_dim) = pages, RecurrentSpec = a row
+        # of the recurrent-state store. A plain list is "all pages"
+        full_spec = model.cache_spec()
+        state_specs = [e for e in full_spec if isinstance(e, RecurrentSpec)]
+        spec = [e for e in full_spec if not isinstance(e, RecurrentSpec)]
         if num_pages is None:
             # the pool budget decouples from the ladder's top rung:
             # FLAGS_serving_page_budget caps memory and lets admission
@@ -863,6 +899,29 @@ class ServingEngine:
         if self.tp_degree < 1:
             raise ValueError(
                 f"tp_degree must be >= 1, got {self.tp_degree}")
+        if state_specs or (
+                draft_model is not None
+                and recurrent_layout(draft_model.cache_spec()) is not None):
+            # each would need something the state store does not have;
+            # none falls back to a path that gives other tokens
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with a recurrent model: a shared "
+                    "prefix would need a snapshot of the recurrent state "
+                    "at the prefix boundary, which the store does not keep "
+                    "(pages are addressable by position, a recurrence is "
+                    "not)")
+            if draft_model is not None:
+                raise ValueError(
+                    "draft_model= with a recurrent model: a rejected "
+                    "draft token cannot be rolled back out of a "
+                    "recurrence (the KV cursor rolls back by length; the "
+                    "state has already absorbed the token)")
+            if self.tp_degree > 1:
+                raise ValueError(
+                    f"tp_degree={self.tp_degree} with a recurrent model: "
+                    "the recurrent-state store and the state-update kernel "
+                    "are not sharded over heads")
         self._tp_mesh = None
         self._tp_axis = "mp"
         self._pool_sharding = None
@@ -919,6 +978,12 @@ class ServingEngine:
             reserve_null_page=True, kv_dtype=self.kv_dtype)
         self.pool = PagedKVCache(**self._pool_geom)
         self._shard_pool(self.pool)
+        # ---- the recurrent-state store, beside the pool: one row a
+        # slot a recurrent layer, NOT addressed through the block table
+        self._state_geom = dict(specs=state_specs, max_batch=max_batch,
+                                dtype=dtype)
+        self._state: Optional[RecurrentStateCache] = (
+            RecurrentStateCache(**self._state_geom) if state_specs else None)
         maxpos = getattr(getattr(model, "config", None),
                          "max_position_embeddings", None)
         if maxpos is not None and max_seq_len > maxpos:
@@ -1340,6 +1405,12 @@ class ServingEngine:
         slot = req.slot
         seq_len = int(self.pool.seq_lens[slot])
         last_tok = int(self._last_tok[slot])
+        # the recurrent layers' rows leave with the pages: they are as
+        # much the sequence's written state, and as little recomputed
+        state = None
+        if self._state is not None:
+            state = self._state.export(slot)
+            self._m.state_exports.inc()
         n_pages = int(self.pool._pages_used[slot])
         pages = []
         for i in range(n_pages):
@@ -1357,7 +1428,7 @@ class ServingEngine:
         self._callbacks.pop(rid, None)
         return {"v": HANDOFF_SCHEMA_VERSION, "request": req,
                 "pages": pages, "seq_len": seq_len,
-                "last_token": last_tok}
+                "last_token": last_tok, "state": state}
 
     def adopt_request(self, bundle: dict,
                       on_token: Optional[Callable] = None) -> int:
@@ -1380,8 +1451,17 @@ class ServingEngine:
                 "a matching build instead of mis-seating pages)")
         req: Request = bundle["request"]
         pages = bundle["pages"]
+        state = bundle["state"]
         if not self.pool.k_pages or self.pool.k_pages[0] is None:
             raise RuntimeError("adopt_request: pool is detached")
+        if (state is None) != (self._state is None):
+            raise ValueError(
+                "adopt_request: the bundle "
+                + ("carries no recurrent state but this engine's model "
+                   "has recurrent layers" if state is None else
+                   "carries recurrent state but this engine's model has "
+                   "no recurrent layers")
+                + " (the disaggregated pair must serve the same model)")
         if pages and pages[0].nbytes != self.pool.bytes_per_page:
             raise ValueError(
                 f"adopt_request: page layout mismatch — bundle pages "
@@ -1407,6 +1487,12 @@ class ServingEngine:
                 f"the span only needs {int(self.pool._pages_used[slot])}")
         for i, hp in enumerate(pages):
             self.pool.adopt_page(hp, int(self.pool.block_tables[slot, i]))
+        if state is not None:
+            try:
+                self._state.import_(slot, state)
+            except ValueError:
+                self.pool.free_sequence(slot)
+                raise
         self.pool.seq_lens[slot] = int(bundle["seq_len"])
         req.rid = self._next_rid
         self._next_rid += 1
@@ -1655,10 +1741,37 @@ class ServingEngine:
                  jax.device_put(v, self._pool_sharding))
                 for k, v in pairs]
 
+    def take_caches(self):
+        """What a donating serving program is handed as its ``pools``:
+        the per-layer ``(k, v)`` pairs, and for a model with recurrent
+        layers ``(pairs, [(ssm, conv) a recurrent layer])``. Both
+        caches are detached until :meth:`_store`."""
+        pairs = self.pool.take_pools()
+        if self._state is None:
+            return pairs
+        return pairs, self._state.take_arrays()
+
+    def _slot_arg(self, slot: int) -> tuple:
+        """The extra argument of a b=1 program over a recurrent model:
+        the row of the state store it works on."""
+        return () if self._state is None else (jnp.int32(slot),)
+
     def _store(self, states) -> None:
+        if self._state is not None:
+            self._state.install_arrays(
+                [(_val(st.ssm), _val(st.conv)) for st in states
+                 if is_recurrent_state(st)])
+            states = [st for st in states if not is_recurrent_state(st)]
         self.pool.install_pools(self._canon_pairs(
             [(_val(st.k_pages), _val(st.v_pages)) for st in states],
             self.pool))
+
+    def _reset_state(self, slot: int) -> None:
+        """Admission: the slot's recurrent rows start from zero (they
+        hold what the slot's last request left)."""
+        if self._state is not None:
+            self._state.reset(slot)
+            self._m.state_resets.inc()
 
     def _admit_shared(self, req: Request, slot: int, pages: List[int],
                       n_cached: int) -> None:
@@ -1756,6 +1869,7 @@ class ServingEngine:
             # more than one chunk
             remaining = req.max_new_tokens - len(req.tokens)
             self.pool.allocate(slot, len(feed) + remaining)
+            self._reset_state(slot)
             req.feed = feed
             req.prefill_pos = 0
             req.slot = slot
@@ -1780,15 +1894,17 @@ class ServingEngine:
 
         remaining = req.max_new_tokens - len(req.tokens)
         self.pool.allocate(slot, p + remaining)
+        self._reset_state(slot)
         bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
         # per-request prefill timeline span  # tracecheck: disable=TRC007
         with self._m.span("request.prefill", rid=req.rid, prompt_len=p,
                           step=self._step_no):
-            pools = self.pool.take_pools()
+            pools = self.take_caches()
             self._f_prefill.check()
             tok, states = fn(self._params, self._buffers,
                              jnp.asarray(feed[None]),
-                             pools, bt, jnp.zeros((1,), jnp.int32))
+                             pools, bt, jnp.zeros((1,), jnp.int32),
+                             *self._slot_arg(slot))
             # b=1 prefill wrote THROUGH slot's block table into the
             # shared pool arrays; adopt them and the slot's bookkeeping
             self._store(states)
@@ -1859,11 +1975,12 @@ class ServingEngine:
             bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
             sl = jnp.asarray(np.full((1,), pos, np.int32))
             t0 = time.perf_counter() if self._m.enabled else 0.0
-            pools = self.pool.take_pools()
+            pools = self.take_caches()
             self._f_chunk.check()
             tok, states = fn(self._params, self._buffers,
                              jnp.asarray(ids[None]), pools, bt, sl,
-                             jnp.int32(end - pos - 1))
+                             jnp.int32(end - pos - 1),
+                             *self._slot_arg(slot))
             self._store(states)
             self.pool.seq_lens[slot] = end
             req.prefill_pos = end
@@ -2105,6 +2222,8 @@ class ServingEngine:
                 # after max_retries consecutive failures instead of
                 # spinning forever.
                 if (self.pool.k_pages and self.pool.k_pages[0] is None) \
+                        or (self._state is not None
+                            and self._state.detached) \
                         or (self._draft_pool is not None
                             and self._draft_pool.k_pages[0] is None):
                     self._rebuild_pool()    # a detached pool stays dead
@@ -2171,6 +2290,10 @@ class ServingEngine:
         the dead pool and restarts empty."""
         self.pool = PagedKVCache(**self._pool_geom)
         self._shard_pool(self.pool)
+        if self._state is not None:
+            # the donated state arrays died with the pools; every
+            # request replays from its prompt, so fresh zeros are right
+            self._state = RecurrentStateCache(**self._state_geom)
         if self._draft_pool is not None:
             # the draft pool dies with the target's (a spec fault leaves
             # one detached, and a rebuilt target invalidates the draft's
@@ -2342,10 +2465,12 @@ class ServingEngine:
 
     def _migrate(self, target: int) -> None:
         """Move the decode batch to rung ``target``: shrinking compacts
-        the active sequences into the low slots (pure host-side
-        block-table row moves — KV pages never copy), growing just
-        widens the next dispatch. Each rung's program compiles once and
-        stays cached, so steady-state migration is retrace-free."""
+        the active sequences into the low slots (host-side block-table
+        row moves — KV pages never copy; a recurrent model's state rows
+        are indexed by slot, so those DO move, one device row copy a
+        layer), growing just widens the next dispatch. Each rung's
+        program compiles once and stays cached, so steady-state
+        migration is retrace-free."""
         with self._m.span("engine.migrate", step=self._step_no,
                           **{"from": self.bucket, "to": target}):
             self._f_migrate.check(phase="begin", frm=self.bucket,
@@ -2359,6 +2484,11 @@ class ServingEngine:
                     while self._slots[dst] is not None:
                         dst += 1    # always < target: target covers active
                     self.pool.move_sequence(s, dst)
+                    if self._state is not None:
+                        self._state.move(s, dst)
+                        # once per moved request, not per token
+                        # tracecheck: disable=TRC007
+                        self._m.state_moves.inc()
                     if req.spec_ready:
                         # the draft pool mirrors the target's slot layout
                         self._draft_pool.move_sequence(s, dst)
@@ -2900,23 +3030,31 @@ class ServingEngine:
                           active=len(decode_rows), bucket=b):
             # tracecheck: disable=TRC007
             with self._m.span("engine.decode.stage"):
-                bt = jnp.asarray(self.pool.block_tables[:b])
-                sl = jnp.asarray(self.pool.seq_lens[:b])
-                last = jnp.asarray(self._last_tok[:b, None])
-                t0 = time.perf_counter() if self._m.enabled else 0.0
-                pools = self.pool.take_pools()
-                self._f_decode.check()
-            # tracecheck: disable=TRC007
-            with self._m.span("engine.decode.dispatch"):
+                host = [self.pool.block_tables[:b], self.pool.seq_lens[:b],
+                        self._last_tok[:b, None]]
+                if self._state is not None:
+                    # the rows whose recurrence advances: an idle or
+                    # mid-prefill row's pages take a garbage write that
+                    # is overwritten later, its STATE must not move
+                    live = np.zeros((b,), np.int32)
+                    live[[r.slot for r in decode_rows]] = 1
+                    host.append(live)
+                # ONE transfer call for the step's small inputs: each
+                # ``jnp.asarray`` is a dispatch of its own, a third of a
+                # millisecond of the host's share of every step
+                bt, sl, last, *extra = jax.device_put(host)
                 if self._stacked is not None:
                     # N-layer program signature: the stacked per-group
                     # weight structs ride as traced args (never baked
                     # constants)
-                    toks, states = fn(self._params, self._buffers, last,
-                                      pools, bt, sl, self._stacked)
-                else:
-                    toks, states = fn(self._params, self._buffers, last,
-                                      pools, bt, sl)
+                    extra = [self._stacked]
+                t0 = time.perf_counter() if self._m.enabled else 0.0
+                pools = self.take_caches()
+                self._f_decode.check()
+            # tracecheck: disable=TRC007
+            with self._m.span("engine.decode.dispatch"):
+                toks, states = fn(self._params, self._buffers, last,
+                                  pools, bt, sl, *extra)
                 self._store(states)
             # tracecheck: disable=TRC007
             with self._m.span("engine.decode.pull"):
@@ -3063,6 +3201,16 @@ class ServingEngine:
             bytes_in_use=led["bytes_in_use"],
             pages_shared=led["pages_shared"], pages_pinned=pinned,
             pages_spilled=led["pages_spilled"])
+        if self._state is not None:
+            # the state store is billed beside the pages: all of it is
+            # resident, the seated slots' share is what is in use
+            seated = self.max_batch - self._slots.count(None)
+            m.state_bytes.set(self._state.nbytes)
+            m.state_slots_live.set(seated)
+            m.counter_track(
+                "recurrent_state", time.perf_counter(),
+                bytes_resident=self._state.nbytes,
+                bytes_in_use=seated * self._state.bytes_per_slot)
 
     def _observe_page_pressure(self, short: int) -> None:
         """Admission is (or stopped being) page-blocked: publish how
@@ -3198,12 +3346,31 @@ def _pool_head_dim(model, head_dim: int, kv_dtype: str) -> int:
 # pool-shaped copy
 # (tests/test_chip_compile.py::test_serving_program_copies_no_pool).
 
+def _cache_entries(model, pools, paged_cls, bt, sl, **recurrent):
+    """The per-layer cache entries a program hands ``model``, by its
+    ``cache_spec()`` (read while tracing). No recurrent layer: every
+    layer is paged and ``pools`` is the list of ``(k, v)``. Else
+    ``pools`` is ``(pairs, rows)`` (``ServingEngine.take_caches``) and
+    the recurrent layers take a ``RecurrentState`` over their rows,
+    with the call's ``slot`` / ``n_valid`` / ``live``. ALL of ``pools``
+    is donated, so the state-update kernel and the row write-backs work
+    in place like the page writes."""
+    layout = recurrent_layout(model.cache_spec())
+    if layout is None:
+        return [paged_cls(k, v, bt, sl) for k, v in pools]
+    pairs, rows = (iter(p) for p in pools)
+    return [RecurrentState(*next(rows), **recurrent) if rec
+            else paged_cls(*next(pairs), bt, sl) for rec in layout]
+
+
 def _build_prefill(note_trace, model):
     from ..jit import functional_call
 
-    def serving_prefill(params, buffers, ids, pools, bt, sl):
+    def serving_prefill(params, buffers, ids, pools, bt, sl, *slot):
+        # ``slot``: only for a recurrent model, the state row to use
         note_trace()
-        states = [PagedDecodeState(k, v, bt, sl) for k, v in pools]
+        states = _cache_entries(model, pools, PagedDecodeState, bt, sl,
+                                **({"slot": slot[0]} if slot else {}))
         logits, states = functional_call(
             model, params, ids, states, jnp.int32(0),
             buffers=buffers, method="forward_with_cache")
@@ -3226,9 +3393,15 @@ def _build_chunk_prefill(note_trace, model):
     from ..jit import functional_call
     from ..kernels.paged_attention import PagedChunkState
 
-    def serving_prefill_chunk(params, buffers, ids, pools, bt, sl, last_idx):
+    def serving_prefill_chunk(params, buffers, ids, pools, bt, sl, last_idx,
+                              *slot):
+        # a recurrent layer starts from what the last chunk left in row
+        # ``slot`` and must not see the pad: pad rows are causally
+        # invisible to attention, but a recurrence would absorb them
         note_trace()
-        states = [PagedChunkState(k, v, bt, sl) for k, v in pools]
+        states = _cache_entries(
+            model, pools, PagedChunkState, bt, sl,
+            **({"slot": slot[0], "n_valid": last_idx + 1} if slot else {}))
         logits, states = functional_call(
             model, params, ids, states, sl[0],
             buffers=buffers, method="forward_with_cache")
@@ -3243,9 +3416,11 @@ def _build_generic_decode(note_trace, model):
     forward_with_cache (every layer an op chain XLA schedules)."""
     from ..jit import functional_call
 
-    def serving_decode_generic(params, buffers, toks, pools, bt, sl):
+    def serving_decode_generic(params, buffers, toks, pools, bt, sl, *live):
+        # ``live``: only for a recurrent model, the rows that advance
         note_trace()
-        states = [PagedDecodeState(k, v, bt, sl) for k, v in pools]
+        states = _cache_entries(model, pools, PagedDecodeState, bt, sl,
+                                **({"live": live[0]} if live else {}))
         # offset=None -> per-slot positions from states.seq_lens
         logits, states = functional_call(
             model, params, toks, states, None,

@@ -15,6 +15,9 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTForCausalLM, GPTForCausalLMPipe, GPTModel,
     GPTPretrainingCriterion,
 )
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig, GraniteHybridForCausalLM,
+)
 from .llama import (LlamaConfig, LlamaForCausalLM,  # noqa: F401
                     LlamaForCausalLMPipe, LlamaModel, annotate_llama_tp)
 from .moe_gpt import MoEGPTConfig, MoEGPTForCausalLM  # noqa: F401

@@ -589,7 +589,7 @@ def _reduce_loss(loss, reduction):
 
 # ---------------------------------------------------------------- attention
 def cached_scaled_dot_product_attention(query, key, value, k_cache, v_cache,
-                                        offset):
+                                        offset, scale=None):
     """Decode-phase attention (reference: the masked-MHA cache branch of
     paddle/fluid/operators/fused/fused_multi_transformer_op.cu): write the
     new key/value chunk (B, S, Hkv, D) into the static ring-buffer caches
@@ -598,13 +598,15 @@ def cached_scaled_dot_product_attention(query, key, value, k_cache, v_cache,
 
     Returns ``(out, k_cache, v_cache)`` — out (B, S, H, D), caches updated.
     ``offset`` may be a python int or a traced scalar; shapes stay static so
-    one compilation serves every decode step."""
+    one compilation serves every decode step. ``scale``: the softmax
+    scale, ``1 / sqrt(D)`` where None."""
     from ..kernels.decode_attention import cached_attention, update_kv_cache
 
     def fn(qv, knv, vnv, kcv, vcv, off):
         kcv, vcv = update_kv_cache(kcv, vcv, knv, vnv, off)
         out = cached_attention(qv, kcv, vcv,
-                               jnp.asarray(off, jnp.int32) + qv.shape[1])
+                               jnp.asarray(off, jnp.int32) + qv.shape[1],
+                               sm_scale=scale)
         return out, kcv, vcv
 
     return apply_op("cached_sdpa", fn, query, key, value, k_cache, v_cache,
@@ -707,7 +709,7 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
     return out, None
 
 
-def paged_scaled_dot_product_attention(query, key, value, state):
+def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
     """Paged (block-table) variant of the decode attention (reference:
     block_multihead_attention's two phases). ``state`` is a per-layer
     :class:`~paddle_tpu.kernels.paged_attention.PagedDecodeState` or —
@@ -728,7 +730,11 @@ def paged_scaled_dot_product_attention(query, key, value, state):
     owns the true lengths (see the PagedChunkState length contract).
     Decode (S == 1): the token writes at position ``seq_lens`` and
     attends against the pool through the Pallas block-table kernel (XLA
-    gather fallback when pallas is off). Returns ``(out, new_state)``."""
+    gather fallback when pallas is off). Returns ``(out, new_state)``.
+
+    ``scale``: the softmax scale handed to the kernels as ``sm_scale``
+    (q is never pre-scaled in its own dtype); None is ``1 / sqrt(D)`` of
+    the head's own width."""
     from .. import flags
     from ..kernels.decode_attention import cached_attention
     from ..kernels.paged_attention import (PagedChunkState, QuantizedPages,
@@ -764,7 +770,7 @@ def paged_scaled_dot_product_attention(query, key, value, state):
                             for x in (qv, kv, vv))
         else:
             qp, kvp, vvp = qv, kv, vv
-        scale = 1.0 / math.sqrt(d)
+        sm_scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
         if s > 1 and chunked:
             if qv.shape[0] != 1:
                 raise NotImplementedError(
@@ -779,7 +785,7 @@ def paged_scaled_dot_product_attention(query, key, value, state):
             # view is ever materialized.
             attend = (paged_chunk_attention if use_pallas
                       else paged_chunk_attention_xla)
-            out = attend(qp, kp2, vp2, bt, sl, sm_scale=scale)[..., :d]
+            out = attend(qp, kp2, vp2, bt, sl, sm_scale=sm_scale)[..., :d]
             sl2 = sl + s
         elif s > 1:
             # whole-prompt prefill contract: the sequences must be
@@ -795,13 +801,13 @@ def paged_scaled_dot_product_attention(query, key, value, state):
                     "time after the prompt.")
             kp2, vp2 = write_paged_prompt(kp, vp, kvp, vvp, bt)
             # the prompt is the whole valid cache: causal self-attention
-            out = cached_attention(qv, kv, vv, s)
+            out = cached_attention(qv, kv, vv, s, sm_scale=sm_scale)
             sl2 = sl + s
         else:
             kp2, vp2 = write_paged_kv(kp, vp, kvp[:, 0], vvp[:, 0], bt, sl)
             attend = paged_attention if use_pallas else paged_attention_xla
             out = attend(qp[:, 0], kp2, vp2, bt, sl + 1,
-                         sm_scale=scale)[:, None, :, :d]
+                         sm_scale=sm_scale)[:, None, :, :d]
             sl2 = sl + 1
         return out, kp2, vp2, sl2
 

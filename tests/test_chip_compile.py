@@ -37,6 +37,7 @@ from paddle_tpu.kernels.paged_attention import (QuantizedPages,
                                                 write_paged_kv_pallas,
                                                 write_paged_prompt_at_pallas)
 from paddle_tpu.kernels.rms_norm import rms_norm_pallas
+from paddle_tpu.kernels.ssm_update import ssm_decode_update_pallas
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
@@ -169,6 +170,20 @@ def _page_write(hkv, d, *, chunk=0, b=32, page=64, max_pages=16):
     return build
 
 
+def _ssm_update(b, *, slots=32, n_heads=64, d_head=64, d_state=128):
+    """The aliased Mamba-2 state-update kernel at granite-4.0-h-micro's
+    widths: the packed store of one layer (two heads of 64 to a 128-lane
+    row), the first ``b`` of its ``slots`` rows stepped."""
+    def build(S):
+        rows = n_heads * d_head // 128
+        return ssm_decode_update_pallas, [
+            S((slots, rows, d_state, 128), F32), S((b, n_heads, d_head), BF16),
+            S((b, n_heads), F32), S((n_heads,), F32),
+            S((b, 1, d_state), BF16), S((b, 1, d_state), BF16),
+            S((n_heads,), F32)]
+    return build
+
+
 def _fused(layers, b, *, h=4096, nh=32, nkv=32, d=128, inter=11008,
            int8=False, int4=False, page=64, max_pages=16):
     """``layers == 0``: the single-layer kernel; else the N-layer one."""
@@ -237,6 +252,7 @@ _TIER1 = {
     "paged_kv_write-gqa8-d128-b32": _page_write(8, 128),
     "paged_prompt_write-mha16-d64-c256": _page_write(16, 64, chunk=256),
     "paged_prompt_write-gqa8-d128-c256": _page_write(8, 128, chunk=256),
+    "ssm_decode_update-h64-d64-n128-b32": _ssm_update(32),
     "fused_block-int8kv-gqa-b32": _fused(0, 32, int8=True, **_GQA),
     "fused_nlayer2-int8kv-gqa-b32": _fused(2, 32, int8=True, **_GQA),
     "fused_nlayer2-int4-7b-b8": _fused(2, 8, int4=True),
@@ -263,6 +279,8 @@ _MATRIX = {
                                          b=1),
     # the shape the old on-chip sprint checked: d 32 exercises the
     # sub-lane-tile head path of the head-major scratch
+    "ssm_decode_update-h64-d64-n128-b1": _ssm_update(1),
+    "ssm_decode_update-h64-d64-n128-b16": _ssm_update(16),
     "fused_block-small-d32": _fused(0, 8, h=256, nh=8, nkv=2, d=32,
                                     inter=512, page=16, max_pages=4),
     "fused_nlayer2-small-d32": _fused(2, 8, h=256, nh=8, nkv=2, d=32,
@@ -294,6 +312,7 @@ _NAMES = {
     "paged_chunk": ("paged_chunk_attention",),
     "paged_kv_write": ("paged_kv_write",),
     "paged_prompt_write": ("paged_prompt_write",),
+    "ssm_decode_update": ("ssm_decode_update",),
     "fused_block": ("fused_block_decode",),
     "fused_nlayer": ("fused_block_decode_nlayer",),
 }
@@ -411,6 +430,101 @@ def test_serving_program_copies_no_pool(chips, gpt3_345m_serving, program):
         == n_pools, header[:400]
     writes = re.findall(r"%paged_(?:kv|prompt)_write\S* = ", text)
     assert len(writes) == len(args[1]), writes
+
+
+# ------------------------- nor does one copy a recurrent layer's state
+@pytest.fixture(scope="module")
+def granite_hybrid_serving(chips):
+    """granite-4.0-h-micro at its published widths and its cell's
+    geometry (32 slots; 641 pages of 64 tokens for the attention
+    layers), cut to one period's three kinds of neighbour: Mamba-2,
+    attention, Mamba-2. Returns the model, its abstract state, the
+    state's shapes and a function giving the three builders' abstract
+    arguments."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.generation import serving
+    from paddle_tpu.kernels.recurrent_state import RecurrentSpec
+
+    slots, page, max_pages = 32, 64, 20
+    one_chip = SingleDeviceSharding(chips[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    cfg = models.GraniteHybridConfig(
+        num_hidden_layers=3, layer_types=("mamba", "attention", "mamba"))
+    with paddle.LazyGuard():
+        model = models.GraniteHybridForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    model.eval()
+    params, buffers = ({k: S(v.shape, v.dtype) for k, v in state.items()}
+                       for state in model.raw_state())
+    spec = model.cache_spec()
+    rec = next(e for e in spec if isinstance(e, RecurrentSpec))
+    shapes = ((slots,) + rec.ssm_shape, (slots,) + rec.conv_shape)
+
+    def programs():
+        width = serving._pool_head_dim(model, 64, "native")
+        pool = S((8, slots * max_pages + 1, page, width), BF16)
+        pools = ([(pool, pool)], [(S(shapes[0], F32), S(shapes[1], BF16))] * 2)
+
+        def tail(b):
+            return [pools, S((b, max_pages), I32), S((b,), I32)]
+        return {
+            "serving_decode_generic": (serving._build_generic_decode,
+                                       [S((32, 1), I32)] + tail(32)
+                                       + [S((32,), I32)]),
+            "serving_prefill_chunk": (serving._build_chunk_prefill,
+                                      [S((1, 256), I32)] + tail(1)
+                                      + [S((), I32), S((), I32)]),
+            "serving_prefill": (serving._build_prefill,
+                                [S((1, 160), I32)] + tail(1) + [S((), I32)]),
+        }
+    return model, params, buffers, shapes, programs
+
+
+@pytest.mark.parametrize("program", ["serving_decode_generic",
+                                     "serving_prefill_chunk",
+                                     "serving_prefill"])
+def test_serving_program_copies_no_state(chips, granite_hybrid_serving,
+                                         program):
+    """The recurrent state is changed in place: the program the chip's
+    compiler emits holds NO copy with the SSM state's shape, aliases
+    every pool and state input to its output, and steps each Mamba-2
+    layer of the decode program with one ``ssm_decode_update`` call.
+    The convolution's window (0.8 MB a layer) may be staged into fast
+    memory, in the layout it has: a copy of it that changes the layout
+    is a finding."""
+    import re
+    model, params, buffers, (ssm_shape, conv_shape), programs = \
+        granite_hybrid_serving
+    build, args = programs()[program]
+    text = build(lambda: None, model).lower(
+        params, buffers, *args).compile().as_text()
+    assert f"HloModule jit_{program}" in text
+    ssm = ",".join(map(str, ssm_shape))
+    assert ssm == "32,32,128,128"
+    copies = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(rf"= f32\[{ssm}\]\S* copy\(", ln)]
+    assert not copies, copies
+    conv = ",".join(map(str, conv_shape))
+    for ln in text.splitlines():
+        m = re.search(rf"= bf16\[{conv}\](\{{[^}}]*\}}) copy\(", ln)
+        if m:
+            layout = re.sub(r"S\(\d+\)", "", m.group(1))
+            src = re.search(rf"%(\S+)\)", ln.split(" copy(", 1)[1]).group(1)
+            src_line = next(x for x in text.splitlines()
+                            if x.strip().startswith(f"%{src} = "))
+            assert re.sub(r"S\(\d+\)", "", re.search(
+                rf"bf16\[{conv}\](\{{[^}}]*\}})", src_line).group(1)) \
+                == layout, ln.strip()[:200]
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 2 + 4, \
+        header[:400]
+    updates = re.findall(r"%ssm_decode_update\S* = ", text)
+    assert len(updates) == (2 if program == "serving_decode_generic" else 0)
+    writes = re.findall(r"%paged_(?:kv|prompt)_write\S* = ", text)
+    assert len(writes) == 1, writes
 
 
 def _abstract(shape, dtype):

@@ -25,7 +25,24 @@ TINY = dataclasses.replace(
     llama=dict(vocab_size=256, hidden_size=64, num_attention_heads=4,
                intermediate_size=128, max_position_embeddings=128),
     fused_layers=2, fused_prompt_lens=(5, 9), fused_new_tokens=4,
-    hybrid_layers=2, hybrid_shape=(4, 32), hybrid_steps=2)
+    hybrid_layers=2, hybrid_shape=(4, 32), hybrid_steps=2,
+    granite_prompt_len=27, granite_new_tokens=4,
+    granite=dict(
+        name="granite-4.0-h-micro", arch="granite_hybrid", dtype="float32",
+        model=dict(vocab_size=256, hidden_size=64, num_hidden_layers=4,
+                   num_attention_heads=4, num_key_value_heads=2,
+                   intermediate_size=96, shared_intermediate_size=96,
+                   layer_types=["mamba", "attention", "mamba", "mamba"],
+                   attention_multiplier=0.0625, embedding_multiplier=1.0,
+                   logits_scaling=8.0, residual_multiplier=0.22,
+                   mamba_n_heads=4, mamba_d_head=32, mamba_d_state=16,
+                   mamba_n_groups=1, mamba_d_conv=4, mamba_expand=2,
+                   mamba_chunk_size=16, rms_norm_eps=1e-5,
+                   max_position_embeddings=512, tie_word_embeddings=True),
+        program=dict(config_class="GraniteHybridConfig",
+                     model_class="GraniteHybridForCausalLM"),
+        serve=dict(max_batch=2, page_size=8, max_seq_len=64,
+                   prefill_chunk=16)))
 
 
 @pytest.fixture
@@ -40,8 +57,8 @@ def cache_env(monkeypatch):
 def test_one_chip_phases_at_tiny_size():
     lines = chip_smoke.run_phases(TINY)
     assert [ln["phase"] for ln in lines] == [
-        "device", "train", "serve", "fused_decode"]
-    device, train, serve, fused = lines
+        "device", "train", "serve", "fused_decode", "hybrid"]
+    device, train, serve, fused, hybrid = lines
     assert device["platform"] == "cpu" and device["peak_flops"] is None
     assert train["traces"] == 1 and train["losses"][-1] < train["losses"][0]
     # no Pallas custom call can exist on the CPU — and none is claimed
@@ -51,6 +68,10 @@ def test_one_chip_phases_at_tiny_size():
                                                  serve["decode_kind"]}
     assert fused["decode_kind"] == "decode_fused"
     assert fused["tokens_equal_unfused"] == "exact"     # f32 on the CPU
+    # one full chunk, one padded chunk, then decode, against the reference
+    assert hybrid["chunk_dispatches"] == 2 and hybrid["near_ties"] == 0
+    assert len(hybrid["tokens"]) == 4 and hybrid["statuses"] == "OK"
+    assert hybrid["ssm_update_custom_call"] is False     # the jnp twin
 
 
 def test_four_chip_phase_on_virtual_devices():
