@@ -188,6 +188,14 @@ class _EngineTelemetry:
             "serving_decode_live_tokens",
             "cached tokens of the decoding rows, summed over the decode "
             "steps — the KV each step had to read")
+        self.decode_read_pages = c(
+            "serving_decode_read_pages",
+            "pool pages the decode steps' paged attention reads: over ALL "
+            "rows of the rung, the pages a row holds with the step's token")
+        self.decode_table_pages = c(
+            "serving_decode_table_pages",
+            "page slots of the dispatched decode steps' block tables "
+            "(rung x pages a row): what a grid over the table visits")
         self.prefill_tokens = c(
             "serving_prefill_tokens",
             "prompt tokens put through prefill compute (a monolithic "
@@ -367,6 +375,7 @@ class _NullEngineTelemetry:
         self.shared_admits = self.decode_steps = obs.NULL
         self.decode_rows = self.decode_slots = obs.NULL
         self.decode_live_tokens = self.prefill_tokens = obs.NULL
+        self.decode_read_pages = self.decode_table_pages = obs.NULL
         self.ttft = self.itl = obs.NULL
         self.queue_depth = self.occupancy = obs.NULL
         self.kv_pages_in_use = self.prefix_pinned = obs.NULL
@@ -3158,6 +3167,12 @@ class ServingEngine:
         m.decode_slots.inc(self.bucket)
         m.decode_live_tokens.inc(
             int(sum(self.pool.seq_lens[r.slot] for r in rows)))
+        # every row of the rung rides the kernel, decoding or not (an
+        # idle slot reads the null page, a mid-prefill row its cursor)
+        b, page = self.bucket, self.pool.page_size
+        held = -(-(self.pool.seq_lens[:b] + 1) // page)
+        m.decode_read_pages.inc(int(held.sum()))
+        m.decode_table_pages.inc(b * self.pool.block_tables.shape[1])
 
     def _observe_pool_ledger(self) -> None:
         """memwatch pool ledger (r13): the PagedKVCache ledger as

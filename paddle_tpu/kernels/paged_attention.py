@@ -10,12 +10,14 @@ across requests, and HBM holds exactly ceil(len/page) pages per sequence
 instead of a max-length ring buffer.
 
 TPU-native pieces:
-  - ``paged_attention`` — Pallas decode kernel: grid (batch, kv_head,
-    page); the page index map reads the SCALAR-PREFETCHED block table, so
-    each kernel step streams one page of the pool straight from HBM (no
-    gather materialization of a contiguous per-sequence view). Online
-    softmax accumulates across pages in VMEM; GQA reads the unexpanded
-    pool at Hkv bandwidth (q heads ride the block's sublane dim).
+  - ``paged_attention`` — Pallas decode kernel: a grid step is several
+    pages of one batch row with ALL its KV heads; the page index maps
+    read a SCALAR-PREFETCHED table made from the block table, so each
+    step streams its pages of the pool straight from HBM (no gather
+    materialization of a contiguous per-sequence view) and a page past
+    a row's length is neither fetched nor computed. Online softmax
+    accumulates across pages in VMEM; GQA reads the unexpanded pool at
+    Hkv bandwidth (q heads ride the products' sublane dim).
   - ``paged_attention_xla`` — gather-based reference (CPU tests, and the
     fallback wherever pallas is off). Materializes the gathered view —
     correct, but pays the copy the kernel avoids.
@@ -151,19 +153,87 @@ def _gathered_pool(pages, idx):
 
 
 # ------------------------------------------------------------ the kernel
-def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
-                  sm_scale: float, page_size: int, rep: int,
-                  quant: bool = False):
-    # quantized pools append per-page scale operands after the payloads:
-    # (..., k_ref, v_ref, ks_ref, vs_ref, out_ref, scratch...) — dequant
-    # happens on the VMEM-resident page block, never in HBM
+# One grid step is ``ppb`` pages of one batch row, all of its KV heads: the
+# pool rides in ``ppb`` times over, operand ``i`` fetching page
+# ``j * ppb + i`` of the row as a ``(Hkv, 1, page_size, D)`` block, and
+# heads are a batch dimension of the two products. A slot whose page lies
+# past the row's length is not computed (``pl.when``) and not fetched
+# either: its index map repeats the page the slot already holds
+# (``_slot_pages``), and Pallas moves no block whose index did not change.
+# So a call pays for the pages its rows hold, plus the pipeline's
+# bookkeeping of a slot (about 0.15 us on a v5e, live or dead).
+# (The pool left in ``pl.ANY`` with hand-made copies bounded by the row's
+# own page count — how ``jax.experimental.pallas.ops.tpu.paged_attention``
+# does it — would drop that too, but Mosaic slices no copy out of an array
+# whose minor dim is under the 128 lanes: a head-width pool of 64 and the
+# int8 pools' one-lane scale columns would need a second body.)
+
+# VMEM the page blocks may take (K and V, and a quantized pool's scale
+# columns; ``ppb`` of each, double-buffered by the pipeline). v5e has
+# 128 MiB of VMEM, of which Mosaic scopes a kernel to 16 MiB unless told
+# otherwise; the kernel asks for this plus the products' temporaries.
+_PAGE_BLOCK_VMEM_BYTES = 8 << 20
+_TEMP_VMEM_BYTES = 8 << 20
+# Keys one product spans: a step's pages are multiplied in groups of this
+# many keys (pages side by side as one operand), which costs a short row
+# at most the masked tail of its last group and saves the long ones a
+# product and a softmax update a page. On the chip 256 beat 128 and 64 at
+# every length tried (KERNEL_DECISIONS.md "Decode paged attention").
+_GROUP_KEYS = 256
+
+
+def _pages_per_step(hkv: int, page_size: int, d: int, max_pages: int,
+                    pool_itemsize: int, quant: bool) -> Tuple[int, int]:
+    """``(ppb, grp)`` from the call's own shapes: the pages one grid step
+    holds — the table's width split into the fewest equal steps whose
+    page blocks, K and V double-buffered, fit ``_PAGE_BLOCK_VMEM_BYTES``
+    — and the pages one product spans (``_GROUP_KEYS`` keys)."""
+    # in VMEM a row narrower than the 128 lanes pads up to them
+    page_bytes = 2 * hkv * page_size * max(d, _LANES) * pool_itemsize
     if quant:
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
+        # the (page_size, 1) float32 scale column pads to 128 lanes
+        page_bytes += 2 * hkv * page_size * _LANES * 4
+    fit = max(1, _PAGE_BLOCK_VMEM_BYTES // (2 * page_bytes))
+    n_blk = -(-max_pages // fit)
+    ppb = -(-max_pages // n_blk)
+    return ppb, max(1, min(_GROUP_KEYS // page_size, ppb))
+
+
+def _slot_pages(bt, n_pages, ppb: int):
+    """The pool page each slot of each grid step fetches, ``(B * n_blk *
+    ppb,)`` int32 in grid order. A live slot (page ``j * ppb + i`` of a
+    row that holds it) fetches its page; a dead one repeats what the
+    slot fetched at its last live step — of an earlier row, if need be —
+    so it moves nothing; before a slot's first live step it waits on
+    that step's page, so the step itself moves nothing either (the
+    parking rule of ``_page_write``)."""
+    b, max_pages = bt.shape
+    n_blk = -(-max_pages // ppb)
+    bt = jnp.pad(bt, ((0, 0), (0, n_blk * ppb - max_pages)))
+    live = (jnp.arange(n_blk * ppb, dtype=jnp.int32)[None, :]
+            < n_pages[:, None]).reshape(b * n_blk, ppb)
+    pages = jnp.where(live, bt.reshape(b * n_blk, ppb), 0)
+    step = jnp.arange(b * n_blk, dtype=jnp.int32)[:, None]
+    prev = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    first = jnp.argmax(live, axis=0).astype(jnp.int32)[None, :]
+    held = jnp.where(prev >= 0, prev, first)
+    return jnp.take_along_axis(pages, held, axis=0).reshape(-1)
+
+
+def _paged_decode_kernel(pg_ref, sl_ref, q_ref, *rest, sm_scale: float,
+                         page_size: int, ppb: int, max_pages: int,
+                         quant: bool, grp: int):
+    # per slot: a K and a V page block, then (quantized pools) their
+    # scale columns in the same order
+    k_refs, v_refs = rest[:ppb], rest[ppb:2 * ppb]
+    rest = rest[2 * ppb:]
+    if quant:
+        ks_refs, vs_refs = rest[:ppb], rest[ppb:2 * ppb]
+        rest = rest[2 * ppb:]
     out_ref, acc_ref, m_ref, l_ref = rest
 
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -172,43 +242,66 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     seq_len = sl_ref[b]
-    n_pages = jnp.maximum((seq_len + page_size - 1) // page_size, 1)
+    n_pages = jnp.clip((seq_len + page_size - 1) // page_size, 1, max_pages)
+    q = q_ref[0]                                       # (hkv, rep_pad, d)
 
-    @pl.when(j < n_pages)
-    def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)            # (rep, d)
-        k = k_ref[0, 0].astype(jnp.float32)            # (page, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quant:
-            k = k * ks_ref[0, 0]                       # (page, d)*(page, 1)
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # (rep, page)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rep, page_size), 1)
-        s = jnp.where(pos < seq_len, s, _NEG_INF)
+    for i in range(0, ppb, grp):
+        pg = j * ppb + i
 
-        # scratch rows are sublane-padded; compute on the first rep rows
-        m_prev = m_ref[0:rep, 0:1]
-        l_prev = l_ref[0:rep, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[0:rep, :] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-            (rep, l_ref.shape[1]))
-        m_ref[0:rep, :] = jnp.broadcast_to(m_new, (rep, m_ref.shape[1]))
-        acc_ref[0:rep, :] = alpha * acc_ref[0:rep, :] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        # a group is computed if the row holds its first page; pages of
+        # it past the row's length hold a page fetched earlier (finite,
+        # whatever it is) and are masked by position
+        @pl.when(pg < n_pages)
+        def _accumulate():
+            def group(refs):
+                pages = [ref[:, 0] for ref in refs[i:i + grp]]
+                return (pages[0] if len(pages) == 1
+                        else jnp.concatenate(pages, axis=1))
+            k, v = group(k_refs), group(v_refs)        # (hkv, keys, d)
+            if quant:
+                # dequantize in VMEM: (keys, d) * (keys, 1) per head
+                k = k.astype(jnp.float32) * group(ks_refs)
+                v = v.astype(jnp.float32) * group(vs_refs)
+            # operands as they are stored: bf16 x bf16 is exact in
+            # float32, so a bf16 pool under a bf16 query needs no upcast
+            # (Mosaic takes no float32-precision request on bf16
+            # operands: DEFAULT is pinned against a process-wide
+            # ``jax_default_matmul_precision``); otherwise float32, at
+            # the precision the process asks for, as before
+            if q.dtype == k.dtype == jnp.bfloat16:
+                qk, kk, precision = q, k, jax.lax.Precision.DEFAULT
+            else:
+                qk, kk, precision = (q.astype(jnp.float32),
+                                     k.astype(jnp.float32), None)
+            s = jax.lax.dot_general(
+                qk, kk, (((2,), (2,)), ((0,), (0,))), precision=precision,
+                preferred_element_type=jnp.float32) * sm_scale
+            pos = pg * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 2)                 # (hkv, rep_pad, keys)
+            s = jnp.where(pos < seq_len, s, _NEG_INF)
 
-    @pl.when(j == n_pages - 1)
+            # the running max and sum are lane-broadcast in their scratch
+            m_prev, l_prev = m_ref[:, :, 0:1], l_ref[:, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = jnp.broadcast_to(
+                alpha * l_prev + jnp.sum(p, axis=2, keepdims=True),
+                l_ref.shape)
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            # p stays float32 (three bf16 parts of it in one product
+            # were no faster on the chip: the step is not bound here)
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)    # (hkv, rep_pad, d)
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
-        l = l_ref[0:rep, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, 0] = (acc_ref[0:rep, :] / l_safe).astype(out_ref.dtype)
+        # an idle row (seq_len 0) masked every key: l is 0, emit zeros
+        l = l_ref[:, :, 0:1]
+        out_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                      ).astype(out_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -220,65 +313,81 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     k/v_pages:    (Hkv, num_pages, page_size, D) — the shared pool
     block_tables: (B, max_pages) int32 — page i of sequence b is pool page
                   ``block_tables[b, i]`` (entries past the used count are
-                  ignored; keep them 0)
+                  never fetched)
     seq_lens:     (B,) int32 — valid tokens per sequence
     Returns (B, H, D) in q's dtype.
     """
-    b, h, d = q.shape
-    hkv, _, page_size, _ = k_pages.shape
+    h, d = q.shape[1:]
+    hkv = k_pages.shape[0]
     if h % hkv:
         raise ValueError(f"query heads {h} not divisible by kv heads {hkv}")
-    rep = h // hkv
-    max_pages = block_tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    return _paged_decode(q, k_pages, v_pages,
+                         jnp.asarray(block_tables, jnp.int32),
+                         jnp.asarray(seq_lens, jnp.int32),
+                         sm_scale=float(sm_scale), interpret=_interpret())
 
-    qg = q.reshape(b, hkv, rep, d)
-    bt = jnp.asarray(block_tables, jnp.int32)
-    sl = jnp.asarray(seq_lens, jnp.int32)
 
-    def q_index(b_, h_, j, bt_ref, sl_ref):
-        return (b_, h_, 0, 0)
-
-    def kv_index(b_, h_, j, bt_ref, sl_ref):
-        return (h_, bt_ref[b_, j], 0, 0)
-
-    rep_pad = -(-rep // 8) * 8
-    grid = (b, hkv, max_pages)
+# jitted like the writers below: the layers of one program share ONE
+# trace and ONE Mosaic lowering of the kernel
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _paged_decode(q, k_pages, v_pages, bt, sl, *, sm_scale, interpret):
+    b, h, d = q.shape
+    hkv, _, page_size, _ = k_pages.shape
+    rep = h // hkv
+    max_pages = bt.shape[1]
     quant = isinstance(k_pages, QuantizedPages)
-    in_specs = [
-        pl.BlockSpec((1, 1, rep, d), q_index),
-        pl.BlockSpec((1, 1, page_size, d), kv_index),
-        pl.BlockSpec((1, 1, page_size, d), kv_index),
-    ]
-    operands = [qg, k_pages, v_pages]
+    ppb, grp = _pages_per_step(hkv, page_size, d, max_pages,
+                               jnp.dtype(k_pages.dtype).itemsize, quant)
+    n_blk = -(-max_pages // ppb)
+    n_pages = jnp.clip((sl + page_size - 1) // page_size, 1, max_pages)
+
+    # query heads ride the sublanes of their KV head's product; pad them
+    # to a whole tile so every block is aligned
+    rep_pad = -(-rep // 8) * 8
+    qg = q.reshape(b, hkv, rep, d)
+    if rep_pad != rep:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_pad - rep), (0, 0)))
+
+    def page_spec(i, width):
+        return pl.BlockSpec(
+            (hkv, 1, page_size, width),
+            lambda b_, j, pg_ref, sl_ref: (
+                0, pg_ref[(b_ * n_blk + j) * ppb + i], 0, 0))
+
+    q_spec = pl.BlockSpec((1, hkv, rep_pad, d),
+                          lambda b_, j, *_: (b_, 0, 0, 0))
+    pools = [k_pages, v_pages]
     if quant:
-        # per-token scale rows ride as their own operands, indexed by
-        # the SAME block-table map as the payload pages; the 1-wide
-        # lane is the int8-scale contract (one value per token row)
+        # per-token scale columns: their own operands, fetched by the
+        # same page; the 1-wide lane is the int8-scale contract
         # kernelcheck: disable=KRN001
-        in_specs += [pl.BlockSpec((1, 1, page_size, 1), kv_index)] * 2
-        operands = [qg, k_pages.q, v_pages.q,
-                    k_pages.scale, v_pages.scale]
+        pools = [k_pages.q, v_pages.q, k_pages.scale, v_pages.scale]
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, sm_scale=float(sm_scale),
-                          page_size=page_size, rep=rep, quant=quant),
+        functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
+                          page_size=page_size, ppb=ppb, max_pages=max_pages,
+                          quant=quant, grp=grp),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, rep, d), q_index),
+            grid=(b, n_blk),
+            in_specs=[q_spec] + [page_spec(i, x.shape[-1])
+                                 for x in pools for i in range(ppb)],
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((rep_pad, d), jnp.float32),       # acc
-                pltpu.VMEM((rep_pad, _LANES), jnp.float32),  # m
-                pltpu.VMEM((rep_pad, _LANES), jnp.float32),  # l
+                pltpu.VMEM((hkv, rep_pad, d), jnp.float32),      # acc
+                pltpu.VMEM((hkv, rep_pad, _LANES), jnp.float32),  # m
+                pltpu.VMEM((hkv, rep_pad, _LANES), jnp.float32),  # l
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PAGE_BLOCK_VMEM_BYTES + _TEMP_VMEM_BYTES),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rep_pad, d), q.dtype),
+        interpret=interpret,
         name="paged_attention",
-    )(bt, sl, *operands)
-    return out.reshape(b, h, d)
+    )(_slot_pages(bt, n_pages, ppb), sl, qg,
+      *(x for x in pools for _ in range(ppb)))
+    return out[:, :, :rep].reshape(b, h, d)
 
 
 def paged_attention_xla(q, k_pages, v_pages, block_tables, seq_lens,

@@ -242,6 +242,16 @@ _TIER1 = {
     "rms_norm_bwd-8192x4096": _rms(8192, 4096, grad=True),
     "paged_attention-gqa32x8-d128": _paged(32, 8, 128),
     "paged_attention-int8-gqa32x8-d128": _paged(32, 8, 128, int8=True),
+    # the serving cells' own decode calls: gpt3-345m's lane-padded pool on
+    # rungs 4 and 32 (16 pages a row), granite-4.0-h-micro's attention
+    # layers (20 pages a row: not a multiple of the pages a grid step holds)
+    "paged_attention-gpt3-b4": _paged(16, 16, 128, b=4),
+    "paged_attention-gpt3-b32": _paged(16, 16, 128, b=32),
+    "paged_attention-granite-b32": _paged(32, 8, 128, b=32, max_pages=20),
+    "paged_attention-int8-gpt3-b4": _paged(16, 16, 128, int8=True, b=4),
+    "paged_attention-int8-gpt3-b32": _paged(16, 16, 128, int8=True, b=32),
+    "paged_attention-int8-granite-b32": _paged(32, 8, 128, int8=True, b=32,
+                                               max_pages=20),
     "paged_chunk-gqa32x8-d128": _paged(32, 8, 128, chunk=256, b=1),
     "paged_chunk-int8-gqa32x8-d128": _paged(32, 8, 128, int8=True,
                                            chunk=256, b=1),

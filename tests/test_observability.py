@@ -410,6 +410,13 @@ class TestStepSpans:
         assert metric(snap, "serving_decode_slots")["value"] == 4 + 4 + 2
         assert metric(snap, "serving_decode_live_tokens")["value"] == \
             5 + (6 + 6) + 19
+        # pages the kernel reads, over every row of the rung (page 8;
+        # an idle or not-yet-chunked row reads one): 1+1+1+1, 1+1+1+1,
+        # then 3+1; the table is rung x 8 pages a row
+        assert metric(snap, "serving_decode_read_pages")["value"] == \
+            4 + 4 + 4
+        assert metric(snap, "serving_decode_table_pages")["value"] == \
+            (4 + 4 + 2) * 8
         # the last chunk's five pad positions are not prompt tokens
         assert metric(snap, "serving_prefill_tokens")["value"] == 5 + 6 + 19
         assert metric(snap, "serving_prefills")["value"] == 3

@@ -18,8 +18,10 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.kernels.paged_attention import (PagedKVCache,
+                                                QuantizedPages,
                                                 paged_attention,
                                                 paged_attention_xla,
+                                                quantize_kv_rows,
                                                 write_paged_kv,
                                                 write_paged_prompt)
 
@@ -101,6 +103,78 @@ class TestPagedKernelParity:
         ref = dense_ref(q, k_pages, v_pages, bt, sl)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), ref, rtol=3e-2, atol=3e-2)
+
+
+# The serving cells' decode shapes (page 64, a 128-lane pool row): gpt3-345m
+# (16 KV heads, one query head each, 16 pages a row, the default scale) and
+# granite-4.0-h-micro's attention layers (8 KV heads x 4, 20 pages a row —
+# not a multiple of the pages a grid step holds — and its own softmax scale).
+_CELL_SHAPES = {"gpt3": (16, 1, 16, None), "granite": (8, 4, 20, 1.0 / 64)}
+_POISON = 100.0
+
+
+def _cell_lengths(b, max_pages, page=64):
+    """Row lengths for a rung of ``b``: an idle slot (0), 1, the table's
+    full width and one that ends mid-page; a wider rung adds a page's
+    edge, one token short of the full width, and seeded others."""
+    full = max_pages * page
+    lens = [0, 1, full, 3 * page + 17, page, full - 1, 5 * page + 63]
+    more = np.random.default_rng(b).integers(1, full + 1, size=b)
+    return np.array((lens + list(more))[:b], np.int32)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("b", [4, 32])
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_kernel_at_the_cells_shapes(cell, b, kv_dtype):
+    """The decode kernel against the gather reference at the shapes the
+    benchmark's cells run it at. Everything a row does not hold is
+    poison: the keys past its length inside its last page, and the null
+    page 0 that every unused block-table entry names — a key that leaks
+    moves the output by far more than the tolerance."""
+    hkv, rep, max_pages, sm_scale = _CELL_SHAPES[cell]
+    page, d = 64, 128
+    rng = np.random.default_rng(100 * len(cell) + b)
+    sl = _cell_lengths(b, max_pages)
+    n_pages = -(-sl // page)
+    num_pages = int(n_pages.sum()) + 1
+    k = rng.standard_normal((hkv, num_pages, page, d)) * 0.5
+    v = rng.standard_normal((hkv, num_pages, page, d)) * 0.5
+    k[:, 0], v[:, 0] = 4.0, _POISON
+    bt = np.zeros((b, max_pages), np.int32)
+    perm = rng.permutation(np.arange(1, num_pages))
+    at = 0
+    for r in range(b):
+        bt[r, :n_pages[r]] = perm[at:at + n_pages[r]]
+        at += n_pages[r]
+        if sl[r] % page:
+            last = bt[r, n_pages[r] - 1]
+            k[:, last, sl[r] % page:] = 4.0
+            v[:, last, sl[r] % page:] = _POISON
+    q = jnp.asarray(rng.standard_normal((b, hkv * rep, d)) * 0.5)
+    if kv_dtype == "int8":
+        # float32 queries, as tests/test_kv_quant.py: the kernel's
+        # arithmetic alone, at its tolerance
+        q = q.astype(jnp.float32)
+        k_pages, v_pages = (QuantizedPages(*quantize_kv_rows(jnp.asarray(
+            x, jnp.float32))) for x in (k, v))
+        tol = dict(rtol=2e-5, atol=2e-5)
+    else:
+        # what the cells run: bf16 queries on a bf16 pool; against the
+        # reference's float32 result, half a bf16 ulp of rounding
+        q = q.astype(jnp.bfloat16)
+        k_pages, v_pages = (jnp.asarray(x, jnp.bfloat16) for x in (k, v))
+        tol = dict(rtol=2.0 ** -8, atol=1e-5)
+    out = np.asarray(paged_attention(q, k_pages, v_pages, bt, sl,
+                                     sm_scale=sm_scale), np.float32)
+    ref = np.asarray(paged_attention_xla(q.astype(jnp.float32), k_pages,
+                                         v_pages, bt, sl,
+                                         sm_scale=sm_scale))
+    assert np.isfinite(out).all()
+    live = sl > 0
+    np.testing.assert_allclose(out[live], ref[live], **tol)
+    # an idle slot (seq_len 0) emits zeros, not the null page's mean
+    assert not out[~live].any() and (~live).any()
 
 
 class TestWrites:
