@@ -65,6 +65,9 @@ class FunctionInfo:
     calls: List[ast.Call] = field(default_factory=list)
     # donor analysis results filled by DonorPass
     returns_donor: Optional[Tuple[int, ...]] = None
+    # rules._body_walk's memo: the six suites walk every body many times
+    body_nodes: Optional[Tuple[ast.AST, ...]] = field(
+        default=None, repr=False, compare=False)
 
     @property
     def name(self) -> str:

@@ -269,13 +269,6 @@ define_flag("fraction_of_gpu_memory_to_use", 0.92, "API parity; PJRT owns memory
 define_flag("log_level", 1, "Framework log verbosity (GLOG_v analogue).")
 define_flag("eager_delete_tensor_gb", 0.0, "API parity; JAX GC owns tensor lifetime.")
 define_flag("tpu_matmul_precision", "default", "jax matmul precision: default|high|highest.")
-define_flag("telemetry", True,
-            "Host-side runtime telemetry (paddle_tpu.observability): the "
-            "process-wide metrics registry and span tracer. Eager-only by "
-            "design — telemetry never executes under trace and is NOT part "
-            "of PROGRAM_FLAGS, so toggling it can never recompile a serving "
-            "or train program. Off = instrumented code binds no-op stubs at "
-            "construction time (zero registry lookups on hot paths).")
 define_flag("memwatch", True,
             "Compiled-program memory capture (observability.memory): "
             "every program admitted by the decode program cache and "
@@ -285,8 +278,7 @@ define_flag("memwatch", True,
             "A TrainStep capture costs ONE duplicate lower()+compile() "
             "per (re)trace (the program cache hands over the "
             "executable it built) and nothing per steady-state "
-            "step. Rides the FLAGS_telemetry gate (telemetry off = "
-            "memwatch off). Eager-only by design, NOT in PROGRAM_FLAGS: "
+            "step. Eager-only by design, NOT in PROGRAM_FLAGS: "
             "toggling never recompiles a serving or train program.")
 define_flag("telemetry_ring", 16384,
             "Span-tracer ring-buffer capacity in events; the oldest events "

@@ -66,8 +66,6 @@ __all__ = ["FleetRouter"]
 
 
 class _FleetTelemetry:
-    enabled = True
-
     def __init__(self):
         r = obs.registry()
         self.routed = r.counter(
@@ -87,15 +85,6 @@ class _FleetTelemetry:
             "out of its host-side state")
         self.replicas = r.gauge(
             "fleet_replicas", "engine replicas the router is driving")
-
-
-class _NullFleetTelemetry:
-    enabled = False
-
-    def __init__(self):
-        self.routed = obs.NULL
-        self.losses = obs.NULL
-        self.rerouted = self.replicas = obs.NULL
 
 
 class FleetRouter:
@@ -152,8 +141,7 @@ class FleetRouter:
         self.rerouted = 0
         self.placements: List[Tuple[int, int, str]] = []  # (rid, ri, why)
         self._f_router = faults.site("router_dispatch")
-        self._m = (_FleetTelemetry() if obs.enabled()
-                   else _NullFleetTelemetry())
+        self._m = _FleetTelemetry()
         self._observe_fleet()
 
     def _make_engine(self, idx: int) -> ServingEngine:
@@ -410,17 +398,14 @@ class FleetRouter:
 
     # ------------------------------------------------- telemetry helpers
     def _observe_fleet(self) -> None:
-        if self._m.enabled:
-            self._m.replicas.set(len(self.engines))
+        self._m.replicas.set(len(self.engines))
 
     def _observe_placement(self, ri: int, why: str) -> None:
-        if self._m.enabled:
-            self._m.routed.labels(replica=str(ri), reason=why).inc()
+        self._m.routed.labels(replica=str(ri), reason=why).inc()
 
     def _observe_loss(self, ri: int) -> None:
-        if self._m.enabled:
-            self._m.losses.labels(replica=str(ri)).inc()
+        self._m.losses.labels(replica=str(ri)).inc()
 
     def _observe_reroutes(self, n: int) -> None:
-        if self._m.enabled and n:
+        if n:
             self._m.rerouted.inc(n)

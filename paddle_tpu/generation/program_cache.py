@@ -46,13 +46,13 @@ replaying it. Whatever the executable raises when it runs — the first
 time included — is a dispatch fault and propagates unchanged. Steady
 state is one dict lookup in front of the executable's own dispatch.
 
-Telemetry (``FLAGS_telemetry``): hits/misses/traces mirror onto the
-process metrics registry, and every build is charged its wall clock to a
-per-kind compile-time histogram — a retrace regression shows up with a
-COST attached, not just a count.
+Telemetry: hits/misses/traces mirror onto the process metrics registry,
+and every build is charged its wall clock to a per-kind compile-time
+histogram — a retrace regression shows up with a COST attached, not
+just a count.
 
-Memwatch (``FLAGS_memwatch``, riding the telemetry gate): every build
-banks the executable's ``CompiledMemoryStats`` into
+Memwatch (``FLAGS_memwatch``): every build banks the executable's
+``CompiledMemoryStats`` into
 ``program_memory_bytes{kind,bucket,extra,section}`` — each cached
 program carries a memory signature next to its compile-time counter
 (see ``paddle_tpu/observability/memory.py``).
@@ -183,34 +183,29 @@ class DecodeProgramCache:
         self._lowered: Dict[DecodeKey, Any] = {}
         self.hits = 0
         self.misses = 0
-        self._telemetry = obs.enabled()
-        # memwatch (FLAGS_memwatch, riding the telemetry gate): every
-        # build additionally banks the executable's CompiledMemoryStats
-        self._memwatch = self._telemetry and obs.memory.enabled()
-        if self._telemetry:
-            r = obs.registry()
-            self._m_hits = r.counter(
-                "program_cache_hits",
-                "decode program cache admissions served from cache")
-            self._m_misses = r.counter(
-                "program_cache_misses",
-                "decode program cache admissions that built a program")
-            self._m_traces = r.counter(
-                "program_cache_traces",
-                "jax (re)traces of cached programs (steady state: one "
-                "per key); model = signature prefix, so two models' "
-                "programs — or a fleet serving several — never share "
-                "a series; tp = tensor-parallel degree from the key "
-                "(\"1\" unless the engine sharded the program)",
-                labels=("kind", "model", "tp"))
-            self._m_compile = r.histogram(
-                "program_cache_compile_seconds",
-                "wall clock of program builds — trace + lower + "
-                "compile cost per program kind, model and tp degree",
-                labels=("kind", "model", "tp"))
-        else:
-            self._m_hits = self._m_misses = obs.NULL
-            self._m_traces = self._m_compile = obs.NULL
+        # memwatch (FLAGS_memwatch): every build additionally banks
+        # the executable's CompiledMemoryStats
+        self._memwatch = obs.memory.enabled()
+        r = obs.registry()
+        self._m_hits = r.counter(
+            "program_cache_hits",
+            "decode program cache admissions served from cache")
+        self._m_misses = r.counter(
+            "program_cache_misses",
+            "decode program cache admissions that built a program")
+        self._m_traces = r.counter(
+            "program_cache_traces",
+            "jax (re)traces of cached programs (steady state: one "
+            "per key); model = signature prefix, so two models' "
+            "programs — or a fleet serving several — never share "
+            "a series; tp = tensor-parallel degree from the key "
+            "(\"1\" unless the engine sharded the program)",
+            labels=("kind", "model", "tp"))
+        self._m_compile = r.histogram(
+            "program_cache_compile_seconds",
+            "wall clock of program builds — trace + lower + "
+            "compile cost per program kind, model and tp degree",
+            labels=("kind", "model", "tp"))
 
     def get(self, key: DecodeKey,
             builder: Callable[[Callable[[], None]], Any]):
@@ -254,9 +249,9 @@ class DecodeProgramCache:
         """Trace, lower and compile ``jitted`` at ``args`` (donation is
         declared, nothing is consumed until the executable runs). A
         failure here is the one thing that is a
-        :class:`ProgramBuildError`; with telemetry on the build is
-        charged to the compile histogram and, with memwatch on, its
-        CompiledMemoryStats are banked."""
+        :class:`ProgramBuildError`; the build is charged to the compile
+        histogram and, with memwatch on, its CompiledMemoryStats are
+        banked."""
         from .. import observability as obs
 
         t0 = time.perf_counter()
@@ -268,9 +263,8 @@ class DecodeProgramCache:
         dt = time.perf_counter() - t0
         with self._lock:
             self._lowered[key] = lowered
-            if self._telemetry:
-                self._compile_seconds[key] = (
-                    self._compile_seconds.get(key, 0.0) + dt)
+            self._compile_seconds[key] = (
+                self._compile_seconds.get(key, 0.0) + dt)
         model = key.model_sig[:8]
         self._m_compile.labels(kind=key.kind, model=model,
                                tp=_key_tp(key)).observe(dt)
@@ -292,8 +286,7 @@ class DecodeProgramCache:
             return self._trace_counts.get(key, 0)
 
     def compile_seconds(self, key: DecodeKey) -> float:
-        """Accumulated build wall clock banked for ``key`` (0.0 with
-        telemetry off)."""
+        """Accumulated build wall clock banked for ``key``."""
         with self._lock:
             return self._compile_seconds.get(key, 0.0)
 
@@ -334,8 +327,8 @@ def decode_program_cache() -> DecodeProgramCache:
 
 def clear_decode_program_cache() -> None:
     """Drop every cached program AND the cache instance itself, so the
-    next :func:`decode_program_cache` call rebinds telemetry under the
-    current ``FLAGS_telemetry`` setting."""
+    next :func:`decode_program_cache` call rebinds its fault site and
+    memwatch gate under the current flags."""
     global _GLOBAL
     with _GLOBAL_LOCK:
         if _GLOBAL is not None:

@@ -37,12 +37,10 @@ from ..analysis import key_vocab
 # matches jax.Array, whose ``_value`` property is the array copied to the
 # host — on the chip that pulled the whole KV pool back every dispatch
 from ..core.tensor import _val
-from ..kernels.paged_attention import (PagedDecodeState, PagedKVCache,
-                                       padded_head_dim)
-from ..kernels.recurrent_state import (RecurrentSpec, RecurrentState,
-                                       RecurrentStateCache,
-                                       is_recurrent_state, recurrent_layout)
+from ..kernels.paged_attention import PagedDecodeState, PagedKVCache
 from ..testing import faults
+from .cache_manager import (CacheManager, cache_entries,
+                            has_recurrent_layers, kv_heads)
 from .program_cache import ProgramBuildError
 
 __all__ = ["ServingEngine", "Request"]
@@ -140,8 +138,6 @@ class _EngineTelemetry:
     degree, "1" for a solo engine): one FLT005-clean schema per family
     everywhere it is registered, so a tp=2 engine's series never merge
     with a solo replica's in a mixed fleet."""
-
-    enabled = True
 
     def __init__(self, replica: str = "0", tp: str = "1"):
         r = obs.registry()
@@ -363,44 +359,7 @@ class _EngineTelemetry:
         self.counter_track = t.counter
 
 
-class _NullEngineTelemetry:
-    """FLAGS_telemetry=0 binding: every write is a no-op method call."""
-
-    enabled = False
-
-    def __init__(self, replica: str = "0", tp: str = "1"):
-        self.span = obs.null_span
-        self.event = obs.null_event
-        self.submitted = self.finished = self.prefills = obs.NULL
-        self.shared_admits = self.decode_steps = obs.NULL
-        self.decode_rows = self.decode_slots = obs.NULL
-        self.decode_live_tokens = self.prefill_tokens = obs.NULL
-        self.decode_read_pages = self.decode_table_pages = obs.NULL
-        self.ttft = self.itl = obs.NULL
-        self.queue_depth = self.occupancy = obs.NULL
-        self.kv_pages_in_use = self.prefix_pinned = obs.NULL
-        self.evict_short = obs.NULL
-        self.retries = self.recoveries = obs.NULL
-        self.requests_failed = self.requests_timeout = obs.NULL
-        self.recovery_seconds = self.page_pressure = obs.NULL
-        self.prefill_chunk_s = self.decode_stall_s = obs.NULL
-        self.bucket = self.migrations = obs.NULL
-        self.preemptions = self.preempted_tokens = obs.NULL
-        self.spec_rounds_c = self.spec_accept = obs.NULL
-        self.spec_accepted = self.spec_rejected = obs.NULL
-        self.spec_gamma = self.collective_s = obs.NULL
-        self.pool_pages = {s: obs.NULL for s in _POOL_STATES}
-        self.pool_bytes = {s: obs.NULL for s in _POOL_STATES}
-        self.pool_frag = self.host_tier_peak = obs.NULL
-        self.state_bytes = self.state_slots_live = obs.NULL
-        self.state_resets = self.state_moves = obs.NULL
-        self.state_exports = obs.NULL
-        self.counter_track = obs.null_counter
-
-
 class _PrefixTelemetry:
-    enabled = True
-
     def __init__(self, replica: str = "0"):
         r = obs.registry()
         rl = ("replica",)
@@ -433,16 +392,6 @@ class _PrefixTelemetry:
             "prefix_cache_dropped_spilled_pages",
             "spilled pages evicted from the host tier entirely "
             "(host-tier budget pressure)")
-
-
-class _NullPrefixTelemetry:
-    enabled = False
-
-    def __init__(self, replica: str = "0"):
-        self.hits = self.misses = self.hit_pages = obs.NULL
-        self.registered_pages = self.evicted_pages = obs.NULL
-        self.spilled_pages = self.restored_pages = obs.NULL
-        self.dropped_spilled = obs.NULL
 
 
 class PrefixCache:
@@ -490,8 +439,7 @@ class PrefixCache:
         self._pinned_nodes = 0      # nodes with pins > 0 (O(1) gauge)
         self._spilled_nodes = 0     # nodes in the host tier (O(1))
         self._f_spill = faults.site("kv_spill")
-        self._m = (_PrefixTelemetry(replica) if obs.enabled()
-                   else _NullPrefixTelemetry(replica))
+        self._m = _PrefixTelemetry(replica)
 
     def _chunks(self, prompt: np.ndarray):
         key = self._ROOT
@@ -836,12 +784,6 @@ class ServingEngine:
         self.replica = str(replica)
         self.max_batch = max_batch
         self.max_seq_len = max_seq_len
-        # a hybrid model's cache_spec says per layer which kind of state
-        # it keeps: (kv_heads, head_dim) = pages, RecurrentSpec = a row
-        # of the recurrent-state store. A plain list is "all pages"
-        full_spec = model.cache_spec()
-        state_specs = [e for e in full_spec if isinstance(e, RecurrentSpec)]
-        spec = [e for e in full_spec if not isinstance(e, RecurrentSpec)]
         if num_pages is None:
             # the pool budget decouples from the ladder's top rung:
             # FLAGS_serving_page_budget caps memory and lets admission
@@ -878,7 +820,7 @@ class ServingEngine:
         self._shrink_wait = 0
         # prefill-unit fairness flip-flop (chunks' turn when True)
         self._chunk_turn = False
-        # host-side probes (test/bench surface, telemetry-independent)
+        # host-side probes (test/bench surface)
         self.bucket_migrations = 0
         self.chunk_dispatches = 0
         self.max_decode_stall = 0.0
@@ -908,9 +850,9 @@ class ServingEngine:
         if self.tp_degree < 1:
             raise ValueError(
                 f"tp_degree must be >= 1, got {self.tp_degree}")
-        if state_specs or (
+        if has_recurrent_layers(model) or (
                 draft_model is not None
-                and recurrent_layout(draft_model.cache_spec()) is not None):
+                and has_recurrent_layers(draft_model)):
             # each would need something the state store does not have;
             # none falls back to a path that gives other tokens
             if prefix_cache:
@@ -933,7 +875,7 @@ class ServingEngine:
                     "are not sharded over heads")
         self._tp_mesh = None
         self._tp_axis = "mp"
-        self._pool_sharding = None
+        pool_sharding = None
         if self.tp_degree > 1:
             if self.weight_dtype == "int4":
                 raise ValueError(
@@ -941,10 +883,11 @@ class ServingEngine:
                     "supported: Int4Tiles nibble packing does not commute "
                     "with the head-shard permutation (pack after sharding "
                     "is a chip-window follow-up)")
-            if spec[0][0] % self.tp_degree:
+            heads = kv_heads(model)
+            if heads % self.tp_degree:
                 raise ValueError(
                     f"tp_degree={self.tp_degree} must divide the model's "
-                    f"kv-head count ({spec[0][0]}) so the paged pool "
+                    f"kv-head count ({heads}) so the paged pool "
                     "partitions evenly over kv-heads")
             from jax.sharding import Mesh as _Mesh
             from jax.sharding import NamedSharding as _NS
@@ -975,24 +918,16 @@ class ServingEngine:
             # canonical partition of every per-layer pool leaf: kv-heads
             # lead on the payload AND the int8 scale band, so one spec
             # shards both together
-            self._pool_sharding = _NS(self._tp_mesh,
-                                      _P(self._tp_axis, None, None, None))
-        # pool geometry is kept so replay recovery can allocate FRESH
-        # pools with the identical shape (same compiled programs apply)
-        self._pool_geom = dict(
-            num_layers=len(spec), num_pages=num_pages, page_size=page_size,
-            num_kv_heads=spec[0][0],
-            head_dim=_pool_head_dim(model, spec[0][1], self.kv_dtype),
-            max_batch=max_batch, max_seq_len=max_seq_len, dtype=dtype,
-            reserve_null_page=True, kv_dtype=self.kv_dtype)
-        self.pool = PagedKVCache(**self._pool_geom)
-        self._shard_pool(self.pool)
-        # ---- the recurrent-state store, beside the pool: one row a
-        # slot a recurrent layer, NOT addressed through the block table
-        self._state_geom = dict(specs=state_specs, max_batch=max_batch,
-                                dtype=dtype)
-        self._state: Optional[RecurrentStateCache] = (
-            RecurrentStateCache(**self._state_geom) if state_specs else None)
+            pool_sharding = _NS(self._tp_mesh,
+                                _P(self._tp_axis, None, None, None))
+        # ---- what a request keeps, per layer kind (pages; for a model
+        # with recurrent layers also a row of the state store): one
+        # owner, generation/cache_manager.py
+        geom = dict(max_batch=max_batch, page_size=page_size,
+                    max_seq_len=max_seq_len, kv_dtype=self.kv_dtype,
+                    pool_sharding=pool_sharding, tp_degree=self.tp_degree)
+        self._caches = CacheManager(model, num_pages=num_pages, dtype=dtype,
+                                    **geom)
         maxpos = getattr(getattr(model, "config", None),
                          "max_position_embeddings", None)
         if maxpos is not None and max_seq_len > maxpos:
@@ -1013,7 +948,7 @@ class ServingEngine:
             _flags.get_flag("serving_preempt_margin"))
         self.preempt_horizon = float(
             _flags.get_flag("serving_preempt_horizon"))
-        self.preemptions = 0        # host probe (telemetry-independent)
+        self.preemptions = 0        # host probe
         self._host_tier_peak = 0
         # ---- speculative decoding (r16): a draft model turns decode
         # into propose-γ/verify-once rounds. The draft keeps its OWN
@@ -1023,9 +958,8 @@ class ServingEngine:
         # while speculation was priced out) is detected by comparing
         # the two cursors — no separate bookkeeping to drift
         self.draft_model = draft_model
-        self._draft_pool: Optional[PagedKVCache] = None
+        self._draft: Optional[CacheManager] = None
         if draft_model is not None:
-            dspec = draft_model.cache_spec()
             dparams, dbuffers = draft_model.raw_state()
             ensure_live(dparams, "call step.sync_to_model() first.")
             self._draft_params, self._draft_buffers = dparams, dbuffers
@@ -1041,18 +975,11 @@ class ServingEngine:
             # sync must then never fail an allocate of the same span.
             # Draft KV is a fraction of target KV, so the safety margin
             # is cheap where it matters
-            self._draft_geom = dict(
-                num_layers=len(dspec),
+            self._draft = CacheManager(
+                draft_model,
                 num_pages=1 + max_batch * (-(-max_seq_len // page_size)),
-                page_size=page_size,
-                num_kv_heads=dspec[0][0],
-                head_dim=_pool_head_dim(draft_model, dspec[0][1],
-                                        self.kv_dtype),
-                max_batch=max_batch, max_seq_len=max_seq_len,
                 dtype=jnp.result_type(next(iter(dparams.values()))),
-                reserve_null_page=True, kv_dtype=self.kv_dtype)
-            self._draft_pool = PagedKVCache(**self._draft_geom)
-            self._shard_pool(self._draft_pool)
+                **geom)
             raw = str(_flags.get_flag("serving_spec_rungs"))
             srungs = sorted({int(r) for r in raw.replace(";", ",").split(",")
                              if r.strip()})
@@ -1078,7 +1005,7 @@ class ServingEngine:
             self._spec_keys: Dict[tuple, object] = {}
             self.spec_draft_key = None      # test probes: last-used keys
             self.spec_verify_key = None
-            # host probes (bench/test surface, telemetry-independent)
+            # host probes (bench/test surface)
             self.spec_rounds = 0
             self.spec_tokens_accepted = 0
             self.spec_tokens_rejected = 0
@@ -1156,13 +1083,9 @@ class ServingEngine:
         self._model_sig = model_signature(model)
         self._draft_sig = (model_signature(draft_model)
                            if draft_model is not None else None)
-        # telemetry binding is per-engine and resolved once here (the
-        # no-op stubs cost one method call per write when disabled);
-        # the replica id labels every series so fleet engines coexist
-        self._m = (_EngineTelemetry(self.replica, str(self.tp_degree))
-                   if obs.enabled()
-                   else _NullEngineTelemetry(self.replica,
-                                             str(self.tp_degree)))
+        # telemetry binding is per-engine and resolved once here; the
+        # replica id labels every series so fleet engines coexist
+        self._m = _EngineTelemetry(self.replica, str(self.tp_degree))
         # pool-ledger fragmentation memo: recompute only when the pool's
         # free-list epoch moved (steady-state decode never moves it)
         self._pool_frag_epoch = -1
@@ -1332,11 +1255,11 @@ class ServingEngine:
         transportable): grab them with :meth:`take_callbacks` and
         re-bind each via ``inject_request(req, on_token=...)``."""
         live = [r for r in self._slots if r is not None]
-        pool_alive = self.pool.k_pages and self.pool.k_pages[0] is not None
+        pool_alive = not self._caches.detached
         out = sorted(live + self._queue, key=lambda r: r.rid)
         for req in live:
             if pool_alive and req.slot is not None:
-                self.pool.free_sequence(req.slot)
+                self._caches.free(req.slot)
         for req in out:
             self._to_replay_form(req)
         self._slots = [None] * self.max_batch
@@ -1409,26 +1332,10 @@ class ServingEngine:
                 "harvest_request: sampled requests park their KV cursor "
                 "in the spec verify program; only greedy requests hand "
                 "off with pages")
-        if not self.pool.k_pages or self.pool.k_pages[0] is None:
-            raise RuntimeError("harvest_request: pool is detached")
         slot = req.slot
-        seq_len = int(self.pool.seq_lens[slot])
+        pages, seq_len, state = self._caches.export_slot(slot)
+        self._m.state_exports.inc(self._caches.state_rows)
         last_tok = int(self._last_tok[slot])
-        # the recurrent layers' rows leave with the pages: they are as
-        # much the sequence's written state, and as little recomputed
-        state = None
-        if self._state is not None:
-            state = self._state.export(slot)
-            self._m.state_exports.inc()
-        n_pages = int(self.pool._pages_used[slot])
-        pages = []
-        for i in range(n_pages):
-            hp = self.pool.spill_page(int(self.pool.block_tables[slot, i]))
-            # the copy leaves with the request — it was never this
-            # pool's host-tier resident, so retire it from the census
-            self.pool.forget_spilled(hp)
-            pages.append(hp)
-        self.pool.free_sequence(slot)
         self._to_replay_form(req)
         self._slots[slot] = None
         self._last_tok[slot] = 0
@@ -1461,48 +1368,10 @@ class ServingEngine:
         req: Request = bundle["request"]
         pages = bundle["pages"]
         state = bundle["state"]
-        if not self.pool.k_pages or self.pool.k_pages[0] is None:
-            raise RuntimeError("adopt_request: pool is detached")
-        if (state is None) != (self._state is None):
-            raise ValueError(
-                "adopt_request: the bundle "
-                + ("carries no recurrent state but this engine's model "
-                   "has recurrent layers" if state is None else
-                   "carries recurrent state but this engine's model has "
-                   "no recurrent layers")
-                + " (the disaggregated pair must serve the same model)")
-        if pages and pages[0].nbytes != self.pool.bytes_per_page:
-            raise ValueError(
-                f"adopt_request: page layout mismatch — bundle pages "
-                f"are {pages[0].nbytes} bytes, this pool's are "
-                f"{self.pool.bytes_per_page} (layers/kv-heads/page_size/"
-                "kv_dtype must agree across the disaggregated pair)")
-        try:
-            slot = self._slots.index(None)
-        except ValueError:
-            raise RuntimeError(
-                "adopt_request: no free slot (drain or grow max_batch)")
-        try:
-            self.pool.allocate(slot,
-                               len(req.prompt) + int(req.max_new_tokens))
-        except RuntimeError:
-            # partial allocation is recorded in _pages_used — return it
-            self.pool.free_sequence(slot)
-            raise
-        if int(self.pool._pages_used[slot]) < len(pages):
-            self.pool.free_sequence(slot)
-            raise ValueError(
-                f"adopt_request: bundle carries {len(pages)} pages but "
-                f"the span only needs {int(self.pool._pages_used[slot])}")
-        for i, hp in enumerate(pages):
-            self.pool.adopt_page(hp, int(self.pool.block_tables[slot, i]))
-        if state is not None:
-            try:
-                self._state.import_(slot, state)
-            except ValueError:
-                self.pool.free_sequence(slot)
-                raise
-        self.pool.seq_lens[slot] = int(bundle["seq_len"])
+        slot = self._slots.index(None) if None in self._slots else None
+        self._caches.adopt_slot(
+            slot, len(req.prompt) + int(req.max_new_tokens), pages,
+            bundle["seq_len"], state)
         req.rid = self._next_rid
         self._next_rid += 1
         req.slot = slot
@@ -1710,77 +1579,30 @@ class ServingEngine:
         return fn
 
     # ----------------------------------------------------------- internals
-    # Donation discipline (tracecheck TRC003): the compiled programs
-    # donate their pools argument, so the dispatch sites pass
-    # ``self.pool.take_pools()`` — the cache's references are detached
-    # BEFORE the buffers are invalidated by donation, and ``_store``
-    # installs the step's returned pools.  A dispatch that raises leaves
-    # the pool explicitly empty (take_pools refuses a second detach)
-    # rather than silently aliasing deleted device buffers.
+    # What a request keeps per layer kind lives behind ``self._caches``
+    # (and, for the draft model, ``self._draft``): the dispatch sites
+    # pass ``take_caches()`` to the donating programs and hand their
+    # returned entries to ``install_caches`` (generation/cache_manager.py,
+    # "dispatch"). These three are read-only views for tests, tools and
+    # the benchmark.
 
-    def _shard_pool(self, pool) -> None:
-        """Commit every per-layer pool leaf onto the canonical kv-head
-        NamedSharding (the int8 payload and its per-token-row scale band
-        both lead with the kv-head axis, so one spec shards both). A
-        pool whose kv-head count does not divide tp stays replicated (a
-        narrow draft model); no-op at tp=1 or on a detached pool. All
-        host bookkeeping — ledger, spill/restore, replay recovery — is
-        kv-head-count-invariant, so it needs no per-shard twin."""
-        if (self._pool_sharding is None or pool is None
-                or not pool.k_pages or pool.k_pages[0] is None
-                or pool.num_kv_heads % self.tp_degree):
-            return
-        for i in range(len(pool.k_pages)):
-            pool.k_pages[i] = jax.device_put(pool.k_pages[i],
-                                             self._pool_sharding)
-            pool.v_pages[i] = jax.device_put(pool.v_pages[i],
-                                             self._pool_sharding)
+    @property
+    def pool(self) -> PagedKVCache:
+        return self._caches.pool
 
-    def _canon_pairs(self, pairs, pool):
-        """Re-pin returned pools to the canonical sharding before they
-        re-enter the cache: the sharded decode step already returns them
-        committed there (free), while prefill/chunk/spec outputs carry
-        whatever placement GSPMD inferred and reshard once here — so the
-        next decode dispatch always sees one stable input sharding and
-        never retraces."""
-        if (self._pool_sharding is None
-                or pool.num_kv_heads % self.tp_degree):
-            return pairs
-        return [(jax.device_put(k, self._pool_sharding),
-                 jax.device_put(v, self._pool_sharding))
-                for k, v in pairs]
+    @property
+    def _state(self):
+        return self._caches.state
 
-    def take_caches(self):
-        """What a donating serving program is handed as its ``pools``:
-        the per-layer ``(k, v)`` pairs, and for a model with recurrent
-        layers ``(pairs, [(ssm, conv) a recurrent layer])``. Both
-        caches are detached until :meth:`_store`."""
-        pairs = self.pool.take_pools()
-        if self._state is None:
-            return pairs
-        return pairs, self._state.take_arrays()
-
-    def _slot_arg(self, slot: int) -> tuple:
-        """The extra argument of a b=1 program over a recurrent model:
-        the row of the state store it works on."""
-        return () if self._state is None else (jnp.int32(slot),)
-
-    def _store(self, states) -> None:
-        if self._state is not None:
-            self._state.install_arrays(
-                [(_val(st.ssm), _val(st.conv)) for st in states
-                 if is_recurrent_state(st)])
-            states = [st for st in states if not is_recurrent_state(st)]
-        self.pool.install_pools(self._canon_pairs(
-            [(_val(st.k_pages), _val(st.v_pages)) for st in states],
-            self.pool))
+    @property
+    def _draft_pool(self) -> Optional[PagedKVCache]:
+        return None if self._draft is None else self._draft.pool
 
     def _reset_state(self, slot: int) -> None:
-        """Admission: the slot's recurrent rows start from zero (they
-        hold what the slot's last request left)."""
-        if self._state is not None:
-            self._state.reset(slot)
-            self._m.state_resets.inc()
+        """Admission that runs prefill compute: what the slot's last
+        request left in its fixed-size state goes."""
+        self._caches.reset(slot)
+        self._m.state_resets.inc(self._caches.state_rows)
 
     def _admit_shared(self, req: Request, slot: int, pages: List[int],
                       n_cached: int) -> None:
@@ -1802,7 +1624,7 @@ class ServingEngine:
             req.pinned = [int(p) for p in pages]
         self.pool.seq_lens[slot] = n_cached
         suffix = req.prompt[n_cached:]
-        self.pool.allocate(slot, len(suffix) + req.max_new_tokens)
+        self._caches.allocate(slot, len(suffix) + req.max_new_tokens)
         if self.chunk and len(suffix) > 2 * self.pool.page_size:
             req.feed = req.prompt
             req.prefill_pos = n_cached
@@ -1877,7 +1699,7 @@ class ServingEngine:
             # prefill one chunk per step() so decode never stalls for
             # more than one chunk
             remaining = req.max_new_tokens - len(req.tokens)
-            self.pool.allocate(slot, len(feed) + remaining)
+            self._caches.allocate(slot, len(feed) + remaining)
             self._reset_state(slot)
             req.feed = feed
             req.prefill_pos = 0
@@ -1902,21 +1724,21 @@ class ServingEngine:
         fn = self._prefill_program()
 
         remaining = req.max_new_tokens - len(req.tokens)
-        self.pool.allocate(slot, p + remaining)
+        self._caches.allocate(slot, p + remaining)
         self._reset_state(slot)
         bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
         # per-request prefill timeline span  # tracecheck: disable=TRC007
         with self._m.span("request.prefill", rid=req.rid, prompt_len=p,
                           step=self._step_no):
-            pools = self.take_caches()
+            pools = self._caches.take_caches()
             self._f_prefill.check()
             tok, states = fn(self._params, self._buffers,
                              jnp.asarray(feed[None]),
                              pools, bt, jnp.zeros((1,), jnp.int32),
-                             *self._slot_arg(slot))
+                             *self._caches.slot_args(slot))
             # b=1 prefill wrote THROUGH slot's block table into the
             # shared pool arrays; adopt them and the slot's bookkeeping
-            self._store(states)
+            self._caches.install_caches(states)
             tok = int(tok)              # the span owns the token pull
         # once per admitted request  # tracecheck: disable=TRC007
         self._m.prefills.inc()
@@ -1939,19 +1761,7 @@ class ServingEngine:
             return
         self.pool.seq_lens[slot] = p
         self._last_tok[slot] = tok
-        tnow = time.perf_counter()
-        if replay:
-            # the replayed prefill's token continues the sequence: its
-            # latency is inter-token, not a second TTFT
-            # tracecheck: disable=TRC007
-            self._m.itl.observe(tnow - req.t_last)
-        else:
-            # TTFT closes on the prefill's token
-            # tracecheck: disable=TRC007
-            self._m.ttft.observe(tnow - req.t_submit)
-            if self._m.enabled:
-                self._first_known[req.rid] = tnow
-        req.t_last = tnow
+        self._observe_token(req, time.perf_counter())
         req.tokens.append(tok)
         self._emit(req, tok)
         req.slot = slot
@@ -1983,14 +1793,14 @@ class ServingEngine:
                           rid=req.rid, pos=pos, last=last):
             bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
             sl = jnp.asarray(np.full((1,), pos, np.int32))
-            t0 = time.perf_counter() if self._m.enabled else 0.0
-            pools = self.take_caches()
+            t0 = time.perf_counter()
+            pools = self._caches.take_caches()
             self._f_chunk.check()
             tok, states = fn(self._params, self._buffers,
                              jnp.asarray(ids[None]), pools, bt, sl,
                              jnp.int32(end - pos - 1),
-                             *self._slot_arg(slot))
-            self._store(states)
+                             *self._caches.slot_args(slot))
+            self._caches.install_caches(states)
             self.pool.seq_lens[slot] = end
             req.prefill_pos = end
             self.chunk_dispatches += 1
@@ -2016,18 +1826,7 @@ class ServingEngine:
                 self._prefix.register(req.prompt,
                                       self.pool.block_tables[slot])
             return
-        if replay:
-            # a replayed prefill's token continues the sequence: its
-            # latency is inter-token, not a second TTFT
-            # tracecheck: disable=TRC007
-            self._m.itl.observe(tnow - req.t_last)
-        else:
-            # TTFT closes on the final chunk's token
-            # tracecheck: disable=TRC007
-            self._m.ttft.observe(tnow - req.t_submit)
-            if self._m.enabled:
-                self._first_known[req.rid] = tnow
-        req.t_last = tnow
+        self._observe_token(req, tnow)
         req.tokens.append(tok)
         self._emit(req, tok)
         self._last_tok[slot] = tok
@@ -2071,9 +1870,9 @@ class ServingEngine:
             # rebuilt or detached pool has nothing of ours to free);
             # gamma/spec_ema deliberately survive — the draft's observed
             # agreement is the request's property, not the admission's
-            if (req.slot is not None and self._draft_pool is not None
-                    and self._draft_pool.k_pages[0] is not None):
-                self._draft_pool.free_sequence(req.slot)
+            if (req.slot is not None and self._draft is not None
+                    and not self._draft.detached):
+                self._draft.free(req.slot)
             req.spec_ready = False
         req.pinned = []
         req.pending = []
@@ -2120,7 +1919,7 @@ class ServingEngine:
         FAILED/TIMEOUT) and record the status. Pure host state — no
         telemetry here (callers observe through ``_observe_*``)."""
         if req.slot is not None:
-            self.pool.free_sequence(req.slot)
+            self._caches.free(req.slot)
             self._slots[req.slot] = None
         self._to_replay_form(req)
         req.status = status
@@ -2140,11 +1939,10 @@ class ServingEngine:
             self._finalize(req, OK)
             # once per finished request  # tracecheck: disable=TRC007
             self._m.finished.inc()
-            if self._m.enabled:
-                # lifecycle close event  # tracecheck: disable=TRC007
-                self._m.event("request.complete", req.t_submit,
-                              time.perf_counter(), rid=req.rid,
-                              tokens=len(req.tokens), step=self._step_no)
+            # lifecycle close event  # tracecheck: disable=TRC007
+            self._m.event("request.complete", req.t_submit,
+                          time.perf_counter(), rid=req.rid,
+                          tokens=len(req.tokens), step=self._step_no)
 
     def _sweep_deadlines(self) -> None:
         """Step-boundary deadline enforcement: terminate every queued or
@@ -2230,11 +2028,8 @@ class ServingEngine:
                 # a real scheduler bookkeeping bug surfaces loudly
                 # after max_retries consecutive failures instead of
                 # spinning forever.
-                if (self.pool.k_pages and self.pool.k_pages[0] is None) \
-                        or (self._state is not None
-                            and self._state.detached) \
-                        or (self._draft_pool is not None
-                            and self._draft_pool.k_pages[0] is None):
+                if self._caches.detached or (
+                        self._draft is not None and self._draft.detached):
                     self._rebuild_pool()    # a detached pool stays dead
                 self._consec_failures += 1
                 self._observe_recovery(0, 0, time.perf_counter() - t0)
@@ -2297,19 +2092,13 @@ class ServingEngine:
         compiled prefill/decode programs (keyed on that geometry) serve
         the replays without a retrace. The prefix cache indexed pages of
         the dead pool and restarts empty."""
-        self.pool = PagedKVCache(**self._pool_geom)
-        self._shard_pool(self.pool)
-        if self._state is not None:
-            # the donated state arrays died with the pools; every
-            # request replays from its prompt, so fresh zeros are right
-            self._state = RecurrentStateCache(**self._state_geom)
-        if self._draft_pool is not None:
+        self._caches.rebuild()
+        if self._draft is not None:
             # the draft pool dies with the target's (a spec fault leaves
             # one detached, and a rebuilt target invalidates the draft's
             # cursor lockstep either way); replay re-syncs from host
             # state through the draft chunk program
-            self._draft_pool = PagedKVCache(**self._draft_geom)
-            self._shard_pool(self._draft_pool)
+            self._draft.rebuild()
         self._prefix = (PrefixCache(self.pool, replica=self.replica,
                                     host_tier_pages=self.host_tier_pages)
                         if self._prefix_enabled else None)
@@ -2319,7 +2108,7 @@ class ServingEngine:
         """Undo a partial admission (page exhaustion mid-``allocate``):
         return the slot's pages, drop adopted pins, clear teacher-forced
         state — the request goes back to the queue head intact."""
-        self.pool.free_sequence(slot)
+        self._caches.free(slot)
         if req.pinned and self._prefix is not None:
             self._prefix.unpin(req.pinned)
         req.pinned = []
@@ -2492,15 +2281,13 @@ class ServingEngine:
                         continue
                     while self._slots[dst] is not None:
                         dst += 1    # always < target: target covers active
-                    self.pool.move_sequence(s, dst)
-                    if self._state is not None:
-                        self._state.move(s, dst)
-                        # once per moved request, not per token
-                        # tracecheck: disable=TRC007
-                        self._m.state_moves.inc()
+                    self._caches.move(s, dst)
+                    # once per moved request, not per token
+                    # tracecheck: disable=TRC007
+                    self._m.state_moves.inc(self._caches.state_rows)
                     if req.spec_ready:
                         # the draft pool mirrors the target's slot layout
-                        self._draft_pool.move_sequence(s, dst)
+                        self._draft.move(s, dst)
                     self._last_tok[dst] = self._last_tok[s]
                     self._slots[dst] = req
                     self._slots[s] = None
@@ -2585,7 +2372,7 @@ class ServingEngine:
         and the deadline stay; admission later replays it from prompt +
         emitted tokens (greedy => bit-identical continuation)."""
         slot = req.slot
-        self.pool.free_sequence(slot)
+        self._caches.free(slot)
         self._slots[slot] = None
         self._last_tok[slot] = 0
         self._to_replay_form(req)
@@ -2603,11 +2390,6 @@ class ServingEngine:
     # the reserved null scribble page — are garbage a later dispatch
     # overwrites before any real row attends to it, so γ needs no
     # tail-fitting constraint (new tokens just truncate to the budget).
-
-    def _store_draft(self, states) -> None:
-        self._draft_pool.install_pools(self._canon_pairs(
-            [(_val(st.k_pages), _val(st.v_pages)) for st in states],
-            self._draft_pool))
 
     def _spec_occupancy_cap(self, n_rows: int) -> int:
         """Largest γ rung the decode-slot budget affords with
@@ -2666,10 +2448,10 @@ class ServingEngine:
         slot = req.slot
         L = int(self.pool.seq_lens[slot])
         if not req.spec_ready:
-            self._draft_pool.allocate(
+            self._draft.allocate(
                 slot, L + 1 + req.max_new_tokens - len(req.tokens))
             req.spec_ready = True
-        cur = int(self._draft_pool.seq_lens[slot])
+        cur = int(self._draft.pool.seq_lens[slot])
         if cur >= L:
             return
         feed = np.concatenate(
@@ -2681,16 +2463,16 @@ class ServingEngine:
             ids = np.zeros((width,), np.int32)
             ids[:end - cur] = feed[cur:end]
             bt = jnp.asarray(
-                self._draft_pool.block_tables[slot:slot + 1])
+                self._draft.pool.block_tables[slot:slot + 1])
             sl = jnp.asarray(np.full((1,), cur, np.int32))
-            dpools = self._draft_pool.take_pools()
+            dpools = self._draft.take_caches()
             self._f_spec_draft.check(rid=req.rid, op="sync")
             _tok, states = fn(self._draft_params, self._draft_buffers,
                               jnp.asarray(ids[None]), dpools, bt, sl,
                               jnp.int32(end - cur - 1))
-            self._store_draft(states)
+            self._draft.install_caches(states)
             cur = end
-        self._draft_pool.seq_lens[slot] = L
+        self._draft.pool.seq_lens[slot] = L
 
     def _spec_round(self, req: Request, gamma: int) -> None:
         """One propose/verify round for one decode-ready request.
@@ -2706,13 +2488,13 @@ class ServingEngine:
         sample = req.temperature > 0.0
         self._spec_sync(req)
         L = int(self.pool.seq_lens[slot])
-        t0 = time.perf_counter() if self._m.enabled else 0.0
+        t0 = time.perf_counter()
         # --- draft: γ proposals in ONE dispatch
         dfn = self._spec_draft_program(gamma, sample, req.top_k)
-        dbt = jnp.asarray(self._draft_pool.block_tables[slot:slot + 1])
-        dsl = jnp.asarray(self._draft_pool.seq_lens[slot:slot + 1])
+        dbt = jnp.asarray(self._draft.pool.block_tables[slot:slot + 1])
+        dsl = jnp.asarray(self._draft.pool.seq_lens[slot:slot + 1])
         tok = jnp.asarray(self._last_tok[slot:slot + 1][:, None])
-        dpools = self._draft_pool.take_pools()
+        dpools = self._draft.take_caches()
         self._f_spec_draft.check(rid=req.rid, op="draft")
         if sample:
             key = jax.random.PRNGKey(
@@ -2726,7 +2508,7 @@ class ServingEngine:
             props, dstates = dfn(self._draft_params,
                                  self._draft_buffers, tok, dpools,
                                  dbt, dsl)
-        self._store_draft(dstates)
+        self._draft.install_caches(dstates)
         # the verify chunk's ids need the concrete proposals — the
         # round's one designed draft->host sync point
         props_np = np.asarray(props).astype(np.int32).reshape(-1)
@@ -2737,7 +2519,7 @@ class ServingEngine:
         vfn = self._spec_verify_program(gamma, sample, req.top_k)
         bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
         sl = jnp.asarray(self.pool.seq_lens[slot:slot + 1])
-        pools = self.pool.take_pools()
+        pools = self._caches.take_caches()
         self._f_spec_verify.check(rid=req.rid)
         if sample:
             greedy, prows, states = vfn(
@@ -2748,7 +2530,7 @@ class ServingEngine:
             prows = None
             greedy, states = vfn(self._params, self._buffers,
                                  jnp.asarray(ids[None]), pools, bt, sl)
-        self._store(states)
+        self._caches.install_caches(states)
         # --- acceptance (host): longest agreeing prefix + correction
         if sample:
             new_toks, accepted = self._spec_accept_sample(
@@ -2773,30 +2555,20 @@ class ServingEngine:
         # positions hold stale writes the next dispatch overwrites
         # before anything attends to them
         self.pool.seq_lens[slot] = L + len(new_toks)
-        self._draft_pool.seq_lens[slot] = L + len(new_toks)
-        now = time.perf_counter() if self._m.enabled else 0.0
-        first = not req.tokens
-        if self._prefix is not None and first:
+        self._draft.pool.seq_lens[slot] = L + len(new_toks)
+        now = time.perf_counter()
+        if self._prefix is not None and not req.tokens:
             # first generated token of a shared admission: the verify
             # chunk just wrote the last prompt position — register the
             # full pages so repeats of this prompt deepen the cache
             self._prefix.register(req.prompt,
                                   self.pool.block_tables[slot])
+        # ONE latency sample per round: a round delivers its tokens as
+        # a burst, so the host-visible gap is the round gap
+        self._observe_token(req, now)
         for t in new_toks:
             req.tokens.append(int(t))
             self._emit(req, int(t))
-        if self._m.enabled:
-            if first:
-                # TTFT closes on the round's first token
-                # tracecheck: disable=TRC007
-                self._m.ttft.observe(now - req.t_submit)
-                self._first_known[req.rid] = now
-            else:
-                # ONE inter-token sample per round: a round delivers
-                # its tokens as a burst, so the host-visible gap is the
-                # round gap  # tracecheck: disable=TRC007
-                self._m.itl.observe(now - req.t_last)
-        req.t_last = now
         self._last_tok[slot] = int(new_toks[-1])
         # --- adaptive γ: accept-rate EMA moves the rung
         rate = accepted / gamma
@@ -2871,7 +2643,7 @@ class ServingEngine:
         memo = (kind,) + tuple(extra)
         fn = self._spec_fns.get(memo)
         if fn is None:
-            pool = self._draft_pool if draft else self.pool
+            pool = (self._draft if draft else self._caches).pool
             key = DecodeKey(
                 kind=kind,
                 model_sig=self._draft_sig if draft else self._model_sig,
@@ -2999,8 +2771,7 @@ class ServingEngine:
                     # a BYPASS admission must not clear the pressure the
                     # still-blocked head just published
                     self._observe_page_pressure(0)
-            if self._m.enabled:
-                fill.args["admitted"] = admitted
+            fill.args["admitted"] = admitted
         # ONE prefill-compute unit per step (one monolithic prefill OR
         # one chunk — admitting several prefills back to back would
         # stack their stalls on every decoding request; the load bench
@@ -3021,7 +2792,7 @@ class ServingEngine:
         if not decode_rows:
             return
 
-        if self._draft_pool is not None and self._spec_step(decode_rows):
+        if self._draft is not None and self._spec_step(decode_rows):
             # the rows were served by speculation rounds (draft scan +
             # verify chunk per row); the batched decode must not run
             # again this step
@@ -3039,39 +2810,33 @@ class ServingEngine:
                           active=len(decode_rows), bucket=b):
             # tracecheck: disable=TRC007
             with self._m.span("engine.decode.stage"):
-                host = [self.pool.block_tables[:b], self.pool.seq_lens[:b],
-                        self._last_tok[:b, None]]
-                if self._state is not None:
-                    # the rows whose recurrence advances: an idle or
-                    # mid-prefill row's pages take a garbage write that
-                    # is overwritten later, its STATE must not move
-                    live = np.zeros((b,), np.int32)
-                    live[[r.slot for r in decode_rows]] = 1
-                    host.append(live)
+                host = self._caches.decode_inputs(
+                    b, [r.slot for r in decode_rows])
                 # ONE transfer call for the step's small inputs: each
                 # ``jnp.asarray`` is a dispatch of its own, a third of a
                 # millisecond of the host's share of every step
-                bt, sl, last, *extra = jax.device_put(host)
+                bt, sl, *extra, last = jax.device_put(
+                    host + [self._last_tok[:b, None]])
                 if self._stacked is not None:
                     # N-layer program signature: the stacked per-group
                     # weight structs ride as traced args (never baked
                     # constants)
                     extra = [self._stacked]
-                t0 = time.perf_counter() if self._m.enabled else 0.0
-                pools = self.take_caches()
+                t0 = time.perf_counter()
+                pools = self._caches.take_caches()
                 self._f_decode.check()
             # tracecheck: disable=TRC007
             with self._m.span("engine.decode.dispatch"):
                 toks, states = fn(self._params, self._buffers, last,
                                   pools, bt, sl, *extra)
-                self._store(states)
+                self._caches.install_caches(states)
             # tracecheck: disable=TRC007
             with self._m.span("engine.decode.pull"):
                 # the scheduler's designed sync point: admission/eviction
                 # need the concrete token ids  # tracecheck: disable=TRC002
                 toks = np.asarray(toks)
 
-        now = time.perf_counter() if self._m.enabled else 0.0
+        now = time.perf_counter()
         if self.tp_degree > 1:
             # sharded dispatch envelope: compute + the per-layer psum
             # pair, observed host-side OUTSIDE the shard_map body
@@ -3117,8 +2882,7 @@ class ServingEngine:
                     # first token of a shared admission: TTFT closes here
                     # tracecheck: disable=TRC007
                     self._m.ttft.observe(now - req.t_submit)
-                    if self._m.enabled:
-                        self._first_known[req.rid] = now
+                    self._first_known[req.rid] = now
                 req.t_last = now
                 req.tokens.append(tok)
                 self._emit(req, tok)
@@ -3131,11 +2895,8 @@ class ServingEngine:
     # (the per-token writes stay inline above under pragma'd lines).
 
     def _observe_step_begin(self, n_active: int) -> None:
-        m = self._m
-        if not m.enabled:
-            return
         if n_active:
-            m.decode_steps.inc()
+            self._m.decode_steps.inc()
         else:
             # idle step: nothing decoded, but keep the gauges honest
             self._observe_step_end()
@@ -3146,8 +2907,6 @@ class ServingEngine:
         reads 0 everywhere instead of freezing at shortfall-time or
         pre-free values."""
         m = self._m
-        if not m.enabled:
-            return
         # telemetry's own cost, under its own name
         with m.span("engine.ledger", step=self._step_no):
             m.queue_depth.set(len(self._queue))
@@ -3156,13 +2915,24 @@ class ServingEngine:
                 m.page_pressure.set(0)  # an empty queue has no pressure
             self._observe_pool_ledger()
 
+    def _observe_token(self, req: Request, now: float) -> None:
+        """A prefill's token or a speculation round's burst became known
+        on the host at ``now`` (call BEFORE appending it): the first
+        token of a request closes its TTFT; a later one — a replayed
+        prefill's too, it continues the sequence — is an inter-token
+        gap, not a second TTFT."""
+        if req.tokens:
+            self._m.itl.observe(now - req.t_last)
+        else:
+            self._m.ttft.observe(now - req.t_submit)
+            self._first_known[req.rid] = now
+        req.t_last = now
+
     def _observe_decode(self, rows: List[Request]) -> None:
         """One batched decode dispatch is about to be made: the rows
         that decode, the rows the rung's program computes, and the
         cached tokens those rows must read."""
         m = self._m
-        if not m.enabled:
-            return
         m.decode_rows.inc(len(rows))
         m.decode_slots.inc(self.bucket)
         m.decode_live_tokens.inc(
@@ -3182,7 +2952,7 @@ class ServingEngine:
         when the free-list epoch moved — steady-state decode steps
         never touch the list and pay nothing for it."""
         m = self._m
-        led = self.pool.ledger(fragmentation=False)
+        led = self._caches.ledger()
         pinned = (self._prefix.pinned_page_count()
                   if self._prefix is not None else 0)
         # the r09 gauges read the same pool state: set them from the
@@ -3216,34 +2986,30 @@ class ServingEngine:
             bytes_in_use=led["bytes_in_use"],
             pages_shared=led["pages_shared"], pages_pinned=pinned,
             pages_spilled=led["pages_spilled"])
-        if self._state is not None:
+        if led["state_bytes"]:
             # the state store is billed beside the pages: all of it is
             # resident, the seated slots' share is what is in use
             seated = self.max_batch - self._slots.count(None)
-            m.state_bytes.set(self._state.nbytes)
+            m.state_bytes.set(led["state_bytes"])
             m.state_slots_live.set(seated)
             m.counter_track(
                 "recurrent_state", time.perf_counter(),
-                bytes_resident=self._state.nbytes,
-                bytes_in_use=seated * self._state.bytes_per_slot)
+                bytes_resident=led["state_bytes"],
+                bytes_in_use=seated * led["state_bytes_per_slot"])
 
     def _observe_page_pressure(self, short: int) -> None:
         """Admission is (or stopped being) page-blocked: publish how
         many pages short the queue head is."""
-        if self._m.enabled:
-            self._m.page_pressure.set(short)
+        self._m.page_pressure.set(short)
 
     def _observe_timeouts(self, n: int) -> None:
-        if self._m.enabled:
-            self._m.requests_timeout.inc(n)
+        self._m.requests_timeout.inc(n)
 
     def _observe_recovery(self, n_replayed: int, n_failed: int,
                           dt: float) -> None:
         """One replay-recovery event: how many requests were re-queued,
         how many were terminated FAILED, and the recovery wall clock."""
         m = self._m
-        if not m.enabled:
-            return
         m.recoveries.inc()
         if n_replayed:
             m.retries.inc(n_replayed)
@@ -3257,18 +3023,13 @@ class ServingEngine:
     def _observe_evict_shortfall(self, short: int) -> None:
         """``evict()`` freed fewer pages than the admission asked for:
         record how many, and the pinned-page pressure that explains it."""
-        m = self._m
-        if not m.enabled or self._prefix is None:
-            return
-        m.evict_short.inc(short)
-        m.prefix_pinned.set(self._prefix.pinned_page_count())
+        self._m.evict_short.inc(short)
+        self._m.prefix_pinned.set(self._prefix.pinned_page_count())
 
     def _observe_preemption(self, req: Request) -> None:
         """One victim unseated for a tighter deadline: count it and the
         decode tokens its replay will regenerate."""
         m = self._m
-        if not m.enabled:
-            return
         m.preemptions.inc()
         if req.tokens:
             m.preempted_tokens.inc(len(req.tokens))
@@ -3279,8 +3040,6 @@ class ServingEngine:
         (the adaptive-γ signal), accepted/rejected token counters, the
         γ gauge and a timeline event."""
         m = self._m
-        if not m.enabled:
-            return
         m.spec_rounds_c.inc()
         m.spec_accept.observe(rate)
         if accepted:
@@ -3298,51 +3057,32 @@ class ServingEngine:
         the unit a long-prompt arrival can stall decode by — and the
         real tokens it computed. The final chunk also closes the
         per-request prefill counter."""
-        if self._m.enabled:
-            self._m.prefill_chunk_s.observe(dt)
-            self._m.prefill_tokens.inc(tokens)
-            if final:
-                self._m.prefills.inc()
+        self._m.prefill_chunk_s.observe(dt)
+        self._m.prefill_tokens.inc(tokens)
+        if final:
+            self._m.prefills.inc()
 
     def _observe_collective(self, dt: float) -> None:
         """One tensor-parallel decode dispatch retired: bank the wall
         clock of the sharded envelope (per-layer psum pair + compute).
         Host-side only — the shard_map body itself never writes
         telemetry (MSH006); a tp=1 engine never reaches here."""
-        if self._m.enabled:
-            self._m.collective_s.observe(dt)
+        self._m.collective_s.observe(dt)
 
     def _observe_stall(self, dt: float) -> None:
         """Scheduler + prefill work ran this step while decode-ready
-        requests waited: that wall clock is the decode stall. The host
-        probe (``max_decode_stall``) updates regardless of telemetry —
-        the load bench asserts its bound."""
+        requests waited: that wall clock is the decode stall (the load
+        bench asserts the bound of the ``max_decode_stall`` probe)."""
         if dt > self.max_decode_stall:
             self.max_decode_stall = dt
-        if self._m.enabled:
-            self._m.decode_stall_s.observe(dt)
+        self._m.decode_stall_s.observe(dt)
 
     def _observe_bucket(self, migrated: bool = False) -> None:
         """The bucket gauge only moves on migration (plus once at
         construction), so it refreshes there instead of per step."""
-        if self._m.enabled:
-            self._m.bucket.set(self.bucket)
-            if migrated:
-                self._m.migrations.inc()
-
-
-def _pool_head_dim(model, head_dim: int, kv_dtype: str) -> int:
-    """The row width of ``model``'s KV pool. A model that only ever runs
-    the generic path (``forward_with_cache``, which pads to the pool's
-    width) gets the lane-padded width that keeps a plain pool's default
-    layout row-major on the TPU (``padded_head_dim``); one that
-    publishes a fused block-decode layout keeps its head's own width,
-    which those kernels address the pool by, and so does an int8 pool
-    (written by the scatter either way)."""
-    if (kv_dtype == "native"
-            and getattr(model, "block_decode_spec", None) is None):
-        return padded_head_dim(head_dim)
-    return head_dim
+        self._m.bucket.set(self.bucket)
+        if migrated:
+            self._m.migrations.inc()
 
 
 # ------------------------------------------------------ program builders
@@ -3361,31 +3101,14 @@ def _pool_head_dim(model, head_dim: int, kv_dtype: str) -> int:
 # pool-shaped copy
 # (tests/test_chip_compile.py::test_serving_program_copies_no_pool).
 
-def _cache_entries(model, pools, paged_cls, bt, sl, **recurrent):
-    """The per-layer cache entries a program hands ``model``, by its
-    ``cache_spec()`` (read while tracing). No recurrent layer: every
-    layer is paged and ``pools`` is the list of ``(k, v)``. Else
-    ``pools`` is ``(pairs, rows)`` (``ServingEngine.take_caches``) and
-    the recurrent layers take a ``RecurrentState`` over their rows,
-    with the call's ``slot`` / ``n_valid`` / ``live``. ALL of ``pools``
-    is donated, so the state-update kernel and the row write-backs work
-    in place like the page writes."""
-    layout = recurrent_layout(model.cache_spec())
-    if layout is None:
-        return [paged_cls(k, v, bt, sl) for k, v in pools]
-    pairs, rows = (iter(p) for p in pools)
-    return [RecurrentState(*next(rows), **recurrent) if rec
-            else paged_cls(*next(pairs), bt, sl) for rec in layout]
-
-
 def _build_prefill(note_trace, model):
     from ..jit import functional_call
 
     def serving_prefill(params, buffers, ids, pools, bt, sl, *slot):
         # ``slot``: only for a recurrent model, the state row to use
         note_trace()
-        states = _cache_entries(model, pools, PagedDecodeState, bt, sl,
-                                **({"slot": slot[0]} if slot else {}))
+        states = cache_entries(model, pools, PagedDecodeState, bt, sl,
+                               **({"slot": slot[0]} if slot else {}))
         logits, states = functional_call(
             model, params, ids, states, jnp.int32(0),
             buffers=buffers, method="forward_with_cache")
@@ -3414,7 +3137,7 @@ def _build_chunk_prefill(note_trace, model):
         # ``slot`` and must not see the pad: pad rows are causally
         # invisible to attention, but a recurrence would absorb them
         note_trace()
-        states = _cache_entries(
+        states = cache_entries(
             model, pools, PagedChunkState, bt, sl,
             **({"slot": slot[0], "n_valid": last_idx + 1} if slot else {}))
         logits, states = functional_call(
@@ -3434,8 +3157,8 @@ def _build_generic_decode(note_trace, model):
     def serving_decode_generic(params, buffers, toks, pools, bt, sl, *live):
         # ``live``: only for a recurrent model, the rows that advance
         note_trace()
-        states = _cache_entries(model, pools, PagedDecodeState, bt, sl,
-                                **({"live": live[0]} if live else {}))
+        states = cache_entries(model, pools, PagedDecodeState, bt, sl,
+                               **({"live": live[0]} if live else {}))
         # offset=None -> per-slot positions from states.seq_lens
         logits, states = functional_call(
             model, params, toks, states, None,

@@ -32,12 +32,9 @@ from .train_step import TrainStep
 
 
 def _fit_recovery_metrics():
-    """Lazily-bound fit-recovery counters on the r09 registry (None
-    with telemetry off). Resolved per fit-recovery event — a cold path
-    by definition."""
+    """Lazily-bound fit-recovery counters on the r09 registry. Resolved
+    per fit-recovery event — a cold path by definition."""
     from .. import observability as obs
-    if not obs.enabled():
-        return None
     r = obs.registry()
     return {
         "retries": r.counter(
@@ -342,8 +339,7 @@ class Model:
             f"<= {max_retries} retries)")
         last = exc
         for attempt in range(1, max_retries + 1):
-            if m:
-                m["retries"].inc()
+            m["retries"].inc()
             try:
                 step_obj.sync()
             except InjectedFault as e:
@@ -362,15 +358,13 @@ class Model:
             if dispatched:
                 # the update applied before the raise; resuming from the
                 # sync is the exactly-once behavior
-                if m:
-                    m["recoveries"].inc()
+                m["recoveries"].inc()
                 return {"step": step, "loss": step_obj._last_loss}
             time.sleep(min(backoff * (2 ** (attempt - 1)), 2.0))
             try:
                 logs = self._async_batch(step_obj, batch, step,
                                          epoch_base)
-                if m:
-                    m["recoveries"].inc()
+                m["recoveries"].inc()
                 return logs
             except Exception as e:
                 last = e
@@ -391,8 +385,7 @@ class Model:
         for attempt in range(3):
             try:
                 self.save(path)
-                if m:
-                    m["ckpts"].inc()
+                m["ckpts"].inc()
                 return path
             except Exception as e:
                 err = e
@@ -405,8 +398,7 @@ class Model:
     def _handle_nan(self, policy, save_dir, loss, where):
         """Apply the fit ``nan_policy`` to one non-finite loss."""
         m = _fit_recovery_metrics()
-        if m:
-            m["nans"].inc()
+        m["nans"].inc()
         if policy == "raise":
             raise FloatingPointError(
                 f"Model.fit: non-finite loss {loss} at {where} "

@@ -43,8 +43,6 @@ class _TrainTelemetry:
     TrainStep; the probe attributes sync_count/trace_count stay the
     test surface — these mirror them onto the exportable registry)."""
 
-    enabled = True
-
     def __init__(self):
         r = obs.registry()
         self.span = obs.tracer().span
@@ -62,15 +60,6 @@ class _TrainTelemetry:
         self.staleness = r.gauge(
             "train_metrics_staleness",
             "steps between the displayed loss and the newest dispatch")
-
-
-class _NullTrainTelemetry:
-    enabled = False
-
-    def __init__(self):
-        self.span = obs.null_span
-        self.syncs = self.throttles = self.traces = obs.NULL
-        self.in_flight = self.staleness = obs.NULL
 
 
 def _split_axes(spec) -> set:
@@ -138,14 +127,13 @@ class TrainStep:
         self.sync_count = 0      # host-blocking loss pulls (probe-visible)
         self.throttle_count = 0  # hard-window blocks (0 in a healthy loop)
         self._trace_count = 0    # step-fn retraces (probe-visible)
-        self._m = (_TrainTelemetry() if obs.enabled()
-                   else _NullTrainTelemetry())
+        self._m = _TrainTelemetry()
         # memwatch: bank the compiled step's CompiledMemoryStats when a
         # dispatch (re)traced (construction-time binding, r09 idiom)
-        self._memwatch = obs.enabled() and obs.memory.enabled()
+        self._memwatch = obs.memory.enabled()
         self._memwatch_model_sig = None   # computed on first capture
         # fault-injection sites (paddle_tpu.testing.faults): bound at
-        # construction like telemetry — NULL stubs when disabled
+        # construction — NULL stubs when FLAGS_fault_inject is unset
         from ..testing import faults
         self._f_dispatch = faults.site("train_dispatch")
         self._f_sync = faults.site("train_sync")
@@ -614,8 +602,6 @@ class TrainStep:
         post-donation state (``self.params`` already holds the returned
         live arrays with identical avals)."""
         m = self._m
-        if not m.enabled:
-            return
         m.in_flight.set(len(self._inflight))
         if self._trace_count != self._traces_seen:
             m.traces.inc(self._trace_count - self._traces_seen)
@@ -689,11 +675,10 @@ class TrainStep:
         self._last_loss = val
         self.last_metrics = {"loss": val, "loss_step": idx,
                              "staleness": self._step_count - 1 - idx}
-        if self._m.enabled:
-            # once per pull (every k steps)  # tracecheck: disable=TRC007
-            self._m.syncs.inc()
-            self._m.staleness.set(self.last_metrics["staleness"])
-            self._m.in_flight.set(len(self._inflight))
+        # once per pull (every k steps)  # tracecheck: disable=TRC007
+        self._m.syncs.inc()
+        self._m.staleness.set(self.last_metrics["staleness"])
+        self._m.in_flight.set(len(self._inflight))
         return self.last_metrics
 
     def sync(self) -> Optional[float]:
@@ -710,10 +695,9 @@ class TrainStep:
             self.sync_count += 1
             self.last_metrics = {"loss": self._last_loss, "loss_step": idx,
                                  "staleness": 0}
-            if self._m.enabled:
-                self._m.syncs.inc()
-                self._m.staleness.set(0)
-                self._m.in_flight.set(0)
+            self._m.syncs.inc()
+            self._m.staleness.set(0)
+            self._m.in_flight.set(0)
         return self._last_loss
 
     @property

@@ -372,10 +372,10 @@ class DataLoader:
             from .. import observability as obs
             restart_budget = n * max(0, int(
                 _flags.get_flag("dataloader_max_worker_restarts")))
-            m_restarts = (obs.registry().counter(
+            m_restarts = obs.registry().counter(
                 "io_worker_restarts",
                 "process DataLoader workers restarted after dying "
-                "mid-epoch") if obs.enabled() else obs.NULL)
+                "mid-epoch")
             sampler_it = enumerate(iter(self.batch_sampler))
             pending = {}        # bidx -> indices, fed but not delivered
             buffered = {}
@@ -601,10 +601,9 @@ class DevicePrefetcher:
         self.depth = max(1, int(depth))
         # counts the staging dispatches; how long one takes is the
         # stage function's to say (TrainStep.stage: ``train.stage``)
-        self._m_staged = (obs.registry().counter(
+        self._m_staged = obs.registry().counter(
             "io_batches_staged",
             "batches staged host->device by DevicePrefetcher")
-            if obs.enabled() else obs.NULL)
 
     def __iter__(self):
         buf = collections.deque()

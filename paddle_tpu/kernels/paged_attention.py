@@ -1075,6 +1075,11 @@ class PagedKVCache:
     def free_page_count(self) -> int:
         return len(self._free)
 
+    def sequence_pages(self, seq_idx: int) -> np.ndarray:
+        """The page ids sequence ``seq_idx`` holds, in position order (a
+        view of its block-table row: read it before freeing)."""
+        return self.block_tables[seq_idx, :int(self._pages_used[seq_idx])]
+
     def ledger(self, fragmentation: bool = True) -> dict:
         """The memwatch pool ledger: pages/bytes in use, free, and
         shared (rc > 1, O(1)-maintained on ref transitions like the r09

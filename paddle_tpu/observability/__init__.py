@@ -19,7 +19,12 @@ histograms) and ``io.DevicePrefetcher``. ``tools/telemetry_dump.py``
 renders snapshots; ``bench.py`` and the ``tools/*_bench.py`` drivers
 embed a snapshot in their ``BENCH_*.json`` output.
 
-Everything is gated behind ``FLAGS_telemetry`` (default on). The
+There is one binding: every instrumented object binds the live
+instruments at construction, always (the benchmark reads the spans and
+counters, so "off" was a mode nothing could be measured in; what "on"
+costs is inside the cells' run-to-run spread, PERF.md section 6). Only
+memwatch's per-program ``memory_analysis()`` keeps a gate of its own,
+``FLAGS_memwatch``. The
 contract is HOST-SIDE ONLY: a telemetry write must never be reachable
 under trace (it would fire once at trace time and freeze, or fail on a
 tracer) — tracecheck rule TRC007 enforces this, and additionally
@@ -43,37 +48,23 @@ Usage::
 from __future__ import annotations
 
 from .metrics import (Counter, Gauge, Histogram, LATENCY_BUCKETS,
-                      MetricsRegistry, NULL, exponential_buckets, registry,
+                      MetricsRegistry, exponential_buckets, registry,
                       series_quantile)
-from .tracing import (NULL_SPAN, Span, SpanTracer, null_counter, null_event,
-                      null_span, tracer)
+from .tracing import Span, SpanTracer, tracer
 from .export import to_prometheus
 from . import memory
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "NULL",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "LATENCY_BUCKETS", "exponential_buckets", "registry",
-    "series_quantile", "Span", "SpanTracer", "NULL_SPAN", "tracer",
-    "null_span", "null_event", "null_counter", "to_prometheus", "enabled",
+    "series_quantile", "Span", "SpanTracer", "tracer", "to_prometheus",
     "span", "snapshot", "memory",
 ]
 
 
-def enabled() -> bool:
-    """Resolve ``FLAGS_telemetry``. Call at CONSTRUCTION time and bind
-    either real instruments or the ``NULL``/``null_span`` stubs — never
-    per hot-path call (instrumented objects keep whichever binding they
-    were built under; rebuild after toggling the flag)."""
-    from .. import flags
-    return bool(flags.get_flag("telemetry"))
-
-
 def span(name: str, **args):
-    """Convenience scoped span honoring ``FLAGS_telemetry`` per call —
-    for warm paths (epoch boundaries, loaders). Hot paths pre-bind
-    ``tracer().span`` instead."""
-    if not enabled():
-        return NULL_SPAN
+    """Convenience scoped span for warm paths (epoch boundaries,
+    loaders). Hot paths pre-bind ``tracer().span`` instead."""
     return tracer().span(name, **args)
 
 

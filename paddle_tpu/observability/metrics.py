@@ -11,9 +11,7 @@ Design constraints (the serving/train hot paths dictate them):
     construction time (``registry().counter(...)``) and pre-bound on
     the instrumented object; a hot-path write is one attribute read
     plus a float add / list-index bump — no registry lookup, no lock,
-    no flag read per call. With ``FLAGS_telemetry=0`` the construction
-    site binds the shared :data:`NULL` stub instead, so the hot path
-    pays one no-op method call and nothing else.
+    no flag read per call.
   - **Exportable.** :meth:`MetricsRegistry.snapshot` returns a pure
     JSON-able dict (the format ``BENCH_*.json`` artifacts embed);
     :func:`~paddle_tpu.observability.export.to_prometheus` renders the
@@ -34,7 +32,7 @@ import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "NULL",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "exponential_buckets", "LATENCY_BUCKETS", "registry",
     "series_quantile",
 ]
@@ -172,43 +170,6 @@ def series_quantile(entry: Dict[str, Any], q: float) -> Optional[float]:
     if mx is not None:
         v = min(v, mx)
     return v
-
-
-class _NullInstrument:
-    """Shared no-op stub every instrument kind collapses to when
-    ``FLAGS_telemetry`` is off: construction sites bind this once and
-    the hot path pays a single no-op method call."""
-
-    __slots__ = ()
-
-    def inc(self, n: float = 1.0) -> None:
-        pass
-
-    def dec(self, n: float = 1.0) -> None:
-        pass
-
-    def set(self, v: float) -> None:
-        pass
-
-    def observe(self, v: float) -> None:
-        pass
-
-    def labels(self, **kv) -> "_NullInstrument":
-        return self
-
-    def quantile(self, q: float) -> None:
-        return None
-
-    @property
-    def value(self) -> float:
-        return 0.0
-
-    @property
-    def count(self) -> int:
-        return 0
-
-
-NULL = _NullInstrument()
 
 
 class _Family:

@@ -32,8 +32,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["SpanTracer", "Span", "NULL_SPAN", "tracer", "null_span",
-           "null_event", "null_counter"]
+__all__ = ["SpanTracer", "Span", "tracer"]
 
 try:                                    # the annotation is optional:
     import jax                          # pure-host tools can trace spans
@@ -56,9 +55,7 @@ def _open_stack() -> list:
 
 class Span:
     """One timed scope. Context manager; also usable as a decorator
-    (``@tracer().span("load")`` — note the enabled/disabled decision is
-    then frozen at decoration time; prefer the ``with`` form for code
-    whose telemetry flag may toggle)."""
+    (``@tracer().span("load")``)."""
 
     __slots__ = ("_tracer", "name", "args", "_t0", "_ann", "id", "parent")
 
@@ -103,38 +100,6 @@ class Span:
             with Span(self._tracer, self.name, self.args):
                 return fn(*a, **kw)
         return wrapper
-
-
-class _NullSpan:
-    """No-op stand-in bound when telemetry is off."""
-
-    __slots__ = ()
-    id = parent = 0
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def __call__(self, fn):
-        return fn
-
-
-NULL_SPAN = _NullSpan()
-
-
-def null_span(name: str, **args) -> _NullSpan:
-    return NULL_SPAN
-
-
-def null_event(name: str, t0: float, t1: float, parent: int = 0,
-               **args) -> None:
-    return None
-
-
-def null_counter(name: str, t: float, **values) -> None:
-    return None
 
 
 class SpanTracer:

@@ -72,19 +72,18 @@ def export_protobuf(dir_name: str, worker_name: Optional[str] = None):
 
 class RecordEvent:
     """User-scope annotation -> jax.profiler.TraceAnnotation, mirrored
-    into the observability span ring (``FLAGS_telemetry``) so RecordEvent
+    into the observability span ring so RecordEvent
     scopes land in the exported Chrome-trace timeline alongside engine/
     train spans — and observability spans land in jax.profiler captures
     through the same TraceAnnotation primitive."""
 
     def __init__(self, name: str, event_type=None):
-        from ..observability import enabled as _tel_on, tracer as _tracer
+        from ..observability import tracer as _tracer
 
         self.name = name
         self._ann = jax.profiler.TraceAnnotation(name)
-        # bind-at-construction like every other instrumented site: one
-        # flag resolve per RecordEvent, zero per begin/end pair
-        self._mirror = _tracer().event if _tel_on() else None
+        # bound at construction like every other instrumented site
+        self._mirror = _tracer().event
         self._t0 = None
 
     def __enter__(self):
@@ -102,8 +101,7 @@ class RecordEvent:
     def end(self):
         self._ann.__exit__(None, None, None)
         if self._t0 is not None:
-            if self._mirror is not None:
-                self._mirror(self.name, self._t0, time.perf_counter())
+            self._mirror(self.name, self._t0, time.perf_counter())
             self._t0 = None
 
 

@@ -222,11 +222,10 @@ class FaultSite:
         self._rng = (random.Random(spec.seed)
                      if spec.p is not None else None)
         from .. import observability as obs
-        self._m = (obs.registry().counter(
+        self._m = obs.registry().counter(
             "faults_injected",
             "deterministic faults fired by FLAGS_fault_inject sites",
             labels=("site",)).labels(site=spec.name)
-            if obs.enabled() else obs.NULL)
 
     def check(self, **ctx) -> None:
         """Count one pass through the site; raise when the schedule says

@@ -372,7 +372,7 @@ def gpt3_345m_serving(chips):
     arguments."""
     import paddle_tpu as paddle
     from paddle_tpu import models
-    from paddle_tpu.generation import serving
+    from paddle_tpu.generation import cache_manager, serving
 
     layers, hkv, d, page, max_pages, max_batch = 2, 16, 64, 64, 16, 32
     one_chip = SingleDeviceSharding(chips[0])
@@ -393,7 +393,7 @@ def gpt3_345m_serving(chips):
 
     def programs():
         # called from a test: ``_on_tpu`` is function-scoped
-        width = serving._pool_head_dim(model, d, "native")
+        width = cache_manager.pool_head_dim(model, d, "native")
         pool = S((hkv, max_batch * max_pages + 1, page, width), BF16)
         pools = [(pool, pool)] * layers
 
@@ -453,7 +453,7 @@ def granite_hybrid_serving(chips):
     arguments."""
     import paddle_tpu as paddle
     from paddle_tpu import models
-    from paddle_tpu.generation import serving
+    from paddle_tpu.generation import cache_manager, serving
     from paddle_tpu.kernels.recurrent_state import RecurrentSpec
 
     slots, page, max_pages = 32, 64, 20
@@ -474,7 +474,7 @@ def granite_hybrid_serving(chips):
     shapes = ((slots,) + rec.ssm_shape, (slots,) + rec.conv_shape)
 
     def programs():
-        width = serving._pool_head_dim(model, 64, "native")
+        width = cache_manager.pool_head_dim(model, 64, "native")
         pool = S((8, slots * max_pages + 1, page, width), BF16)
         pools = ([(pool, pool)], [(S(shapes[0], F32), S(shapes[1], BF16))] * 2)
 

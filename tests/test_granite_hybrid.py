@@ -16,7 +16,7 @@ import paddle_tpu as paddle
 from benchmark.lib.system import load_reference
 from paddle_tpu import flags
 from paddle_tpu import observability as obs
-from paddle_tpu.generation import serving
+from paddle_tpu.generation import cache_manager, serving
 from paddle_tpu.generation.program_cache import clear_decode_program_cache
 from paddle_tpu.generation.serving import ServingEngine
 from paddle_tpu.jit import functional_call
@@ -149,8 +149,9 @@ class _Caches:
         pools = (self.pool.take_pools(), self.state.take_arrays())
         bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
         sl = jnp.full((1,), start, jnp.int32)
-        states = serving._cache_entries(self.model, pools, cls, bt, sl,
-                                        slot=jnp.int32(slot), **recurrent)
+        states = cache_manager.cache_entries(self.model, pools, cls, bt, sl,
+                                             slot=jnp.int32(slot),
+                                             **recurrent)
         logits, states = functional_call(
             self.model, params, jnp.asarray(ids[None]), states,
             jnp.int32(start), buffers=buffers, method="forward_with_cache")
@@ -366,15 +367,14 @@ def test_engine_int8_kv_is_the_page_layers_only(tiny, prompts):
 # ----------------------------------------------------------- telemetry
 @pytest.fixture
 def telemetry():
-    prior = flags.get_flag("telemetry")
-
-    def switch(on):
-        flags.set_flags({"telemetry": on})
+    """An empty registry and ring, before and after."""
+    def fresh():
         obs.registry().clear()
         obs.tracer().clear()
         clear_decode_program_cache()
-    yield switch
-    switch(prior)
+    fresh()
+    yield
+    fresh()
 
 
 def _value(name):
@@ -384,7 +384,6 @@ def _value(name):
 
 @pytest.mark.telemetry
 def test_state_counters_and_gauges(tiny, prompts, telemetry):
-    telemetry(True)
     _, model, _, _ = tiny
     prior = flags.get_flag("serving_bucket_patience")
     flags.set_flags({"serving_bucket_patience": 1})
@@ -407,16 +406,3 @@ def test_state_counters_and_gauges(tiny, prompts, telemetry):
     assert _value("serving_state_slots_live") == 0
     names = {e["name"] for e in obs.tracer().events()}
     assert "recurrent_state" in names
-
-
-@pytest.mark.telemetry
-def test_telemetry_off_leaves_no_residue(tiny, prompts, telemetry):
-    telemetry(False)
-    _, model, _, _ = tiny
-    eng = make_engine(model)
-    rids = [eng.submit(p, 6 + i) for i, p in enumerate(prompts[:3])]
-    out = eng.run()
-    for i, (p, r) in enumerate(zip(prompts, rids)):
-        assert_greedy(tiny, p, out[r], 6 + i)
-    assert obs.registry().snapshot()["metrics"] == {}
-    assert len(obs.tracer()) == 0

@@ -1,7 +1,6 @@
 """Runtime telemetry (paddle_tpu/observability): registry correctness,
-span tracing, the instrumented serving/train/cache subsystems, the
-FLAGS_telemetry=off zero-residue contract, and the TRC007 tracecheck
-rule ("no telemetry write reachable under trace").
+span tracing, the instrumented serving/train/cache subsystems, and the
+TRC007 tracecheck rule ("no telemetry write reachable under trace").
 """
 
 import json
@@ -10,7 +9,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu import flags, observability as obs
+from paddle_tpu import observability as obs
 from paddle_tpu.generation.program_cache import (clear_decode_program_cache,
                                                  decode_program_cache)
 from paddle_tpu.generation.serving import ServingEngine
@@ -22,16 +21,12 @@ pytestmark = pytest.mark.telemetry
 
 @pytest.fixture(autouse=True)
 def _fresh_telemetry():
-    """Each test sees an empty registry/ring and telemetry ON; the
-    decode program cache is dropped so it rebinds instruments under the
-    test's flag state."""
-    prior = flags.get_flag("telemetry")
-    flags.set_flags({"telemetry": True})
+    """Each test sees an empty registry/ring; the decode program cache
+    is dropped so it binds its instruments on the cleared registry."""
     obs.registry().clear()
     obs.tracer().clear()
     clear_decode_program_cache()
     yield
-    flags.set_flags({"telemetry": prior})
     obs.registry().clear()
     obs.tracer().clear()
     clear_decode_program_cache()
@@ -736,59 +731,6 @@ class TestTrainTelemetry:
         # the prefetcher staged batches through the instrumented path
         snap = obs.registry().snapshot()
         assert metric(snap, "io_batches_staged")["value"] >= 2
-
-
-# -------------------------------------------------------- off = no-op
-class TestTelemetryOff:
-    def test_zero_residue(self):
-        flags.set_flags({"telemetry": False})
-        clear_decode_program_cache()
-        paddle.seed(88)
-        cfg = LlamaConfig.tiny()
-        eng, out = _run_engine(LlamaForCausalLM(cfg), cfg, n_req=2,
-                               prefix_cache=True)
-        assert all(len(v) == 5 for v in out.values())
-        assert obs.registry().snapshot()["metrics"] == {}
-        assert len(obs.tracer()) == 0
-        # the scripted run reaches every span and counter of the step:
-        # chunks, a padded last chunk, both migrations, callbacks
-        eng, rids, steps = _scripted_run()
-        seen = []
-        rid = eng.submit(np.arange(19, dtype=np.int32), 2,
-                         on_token=lambda r, t, d: seen.append(t))
-        eng.run()
-        assert steps == 5 and len(seen) == 3 and eng._step_no > 5
-        assert obs.registry().snapshot()["metrics"] == {}
-        assert len(obs.tracer()) == 0
-        assert not eng._first_known
-        # the cache skipped the timing wrapper entirely
-        assert decode_program_cache().compile_seconds(eng.decode_key) == 0.0
-        assert decode_program_cache().stats()["compile_seconds"] == {}
-
-    def test_off_train_step_leaves_nothing(self):
-        from paddle_tpu.hapi import TrainStep
-
-        flags.set_flags({"telemetry": False})
-        paddle.seed(89)
-        cfg = GPTConfig.tiny()
-        model = GPTForCausalLM(cfg)
-        opt = paddle.optimizer.AdamW(1e-4, parameters=model.parameters())
-
-        def loss_fn(logits, y):
-            import paddle_tpu.nn.functional as F
-            return F.cross_entropy(
-                logits.reshape([-1, logits.shape[-1]]), y.reshape([-1]))
-
-        step = TrainStep(model, opt, loss_fn=loss_fn, metrics_every=1)
-        rng = np.random.default_rng(9)
-        ids = rng.integers(0, cfg.vocab_size, (2, 9))
-        x = paddle.to_tensor(ids[:, :-1].astype(np.int32))
-        y = paddle.to_tensor(ids[:, 1:].astype(np.int32))
-        step(x, y)
-        step.sync()
-        assert step.sync_count >= 1        # probes still work
-        assert obs.registry().snapshot()["metrics"] == {}
-        assert len(obs.tracer()) == 0
 
 
 # ------------------------------------------------- tracecheck: TRC007

@@ -259,7 +259,7 @@ class TestLanePaddedPool:
     def test_width_is_chosen_from_backend_model_and_pool_dtype(
             self, monkeypatch):
         from paddle_tpu import flags
-        from paddle_tpu.generation.serving import _pool_head_dim
+        from paddle_tpu.generation.cache_manager import pool_head_dim
 
         class Generic:                  # forward_with_cache only
             pass
@@ -269,10 +269,10 @@ class TestLanePaddedPool:
                 return None
 
         assert pa.padded_head_dim(64) == 64             # the CPU: as is
-        assert _pool_head_dim(Generic(), 64, "native") == 64
+        assert pool_head_dim(Generic(), 64, "native") == 64
         monkeypatch.setattr(flags, "is_tpu_backend", lambda: True)
         assert [pa.padded_head_dim(d) for d in (64, 80, 128, 256)] \
             == [128, 128, 128, 256]
-        assert _pool_head_dim(Generic(), 64, "native") == 128
-        assert _pool_head_dim(Generic(), 64, "int8") == 64
-        assert _pool_head_dim(Fused(), 64, "native") == 64
+        assert pool_head_dim(Generic(), 64, "native") == 128
+        assert pool_head_dim(Generic(), 64, "int8") == 64
+        assert pool_head_dim(Fused(), 64, "native") == 64

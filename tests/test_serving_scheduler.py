@@ -533,8 +533,6 @@ class TestZeroSteadyStateRetrace:
         from paddle_tpu.generation.program_cache import (
             clear_decode_program_cache)
 
-        if not obs.enabled():
-            pytest.skip("FLAGS_telemetry off")
         clear_decode_program_cache()
         model = gpt_model()
         rng = np.random.default_rng(13)

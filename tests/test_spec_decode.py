@@ -277,27 +277,22 @@ class TestSteadyState:
     def test_zero_steady_state_retrace(self):
         cfg, target, draft = gpt_pair()
         p = np.array([3, 5, 7, 11, 2, 9], np.int32)
-        prev = flags.get_flags(("telemetry",))
-        flags.set_flags({"telemetry": True})
-        try:
-            eng = ServingEngine(target, max_batch=2, page_size=8,
-                                max_seq_len=64, draft_model=draft)
-            eng.submit(p, 12)
-            eng.run()                           # warm every rung touched
-            cache = decode_program_cache()
-            t0 = sum(cache.stats()["traces"].values())
-            import paddle_tpu.observability as obs
-            fam0 = obs.snapshot()["metrics"].get("program_cache_traces")
-            c0 = sum(s.get("value", 0) for s in fam0["series"]) if fam0 \
-                else 0
-            eng.submit(p, 12)
-            eng.run()
-            t1 = sum(cache.stats()["traces"].values())
-            fam1 = obs.snapshot()["metrics"].get("program_cache_traces")
-            c1 = sum(s.get("value", 0) for s in fam1["series"]) if fam1 \
-                else 0
-        finally:
-            flags.set_flags(prev)
+        eng = ServingEngine(target, max_batch=2, page_size=8,
+                            max_seq_len=64, draft_model=draft)
+        eng.submit(p, 12)
+        eng.run()                           # warm every rung touched
+        cache = decode_program_cache()
+        t0 = sum(cache.stats()["traces"].values())
+        import paddle_tpu.observability as obs
+        fam0 = obs.snapshot()["metrics"].get("program_cache_traces")
+        c0 = sum(s.get("value", 0) for s in fam0["series"]) if fam0 \
+            else 0
+        eng.submit(p, 12)
+        eng.run()
+        t1 = sum(cache.stats()["traces"].values())
+        fam1 = obs.snapshot()["metrics"].get("program_cache_traces")
+        c1 = sum(s.get("value", 0) for s in fam1["series"]) if fam1 \
+            else 0
         assert t0 > 0
         assert t1 == t0                         # cache-level probe
         assert c1 == c0                         # telemetry-level probe
@@ -305,17 +300,12 @@ class TestSteadyState:
     def test_spec_telemetry_series(self):
         cfg, target, draft = gpt_pair()
         p = np.array([3, 5, 7, 11], np.int32)
-        prev = flags.get_flags(("telemetry",))
-        flags.set_flags({"telemetry": True})
-        try:
-            eng = ServingEngine(target, max_batch=2, page_size=8,
-                                max_seq_len=64, draft_model=draft)
-            eng.submit(p, 8)
-            eng.run()
-            import paddle_tpu.observability as obs
-            snap = obs.snapshot()["metrics"]
-        finally:
-            flags.set_flags(prev)
+        eng = ServingEngine(target, max_batch=2, page_size=8,
+                            max_seq_len=64, draft_model=draft)
+        eng.submit(p, 8)
+        eng.run()
+        import paddle_tpu.observability as obs
+        snap = obs.snapshot()["metrics"]
         for name in ("serving_spec_rounds", "serving_spec_tokens_accepted",
                      "serving_spec_accept_rate", "serving_spec_gamma"):
             fam = snap.get(name)

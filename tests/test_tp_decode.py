@@ -158,12 +158,9 @@ class TestRefusals:
 class TestCollectiveTelemetry:
     @pytest.fixture(autouse=True)
     def _armed(self):
-        prior = flags.get_flag("telemetry")
-        flags.set_flags({"telemetry": True})
         obs.registry().clear()
         clear_decode_program_cache()
         yield
-        flags.set_flags({"telemetry": prior})
         obs.registry().clear()
         clear_decode_program_cache()
 

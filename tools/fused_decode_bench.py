@@ -175,11 +175,11 @@ def main() -> int:
     # the banked row carries its own retrace/cache/latency evidence
     # (tools/telemetry_dump.py renders it back)
     from paddle_tpu import observability as obs
-    telemetry = obs.registry().snapshot() if obs.enabled() else None
+    telemetry = obs.registry().snapshot()
     emit({
         "metric": "fused_decode_step_ms",
         "telemetry": telemetry,
-        "memory": obs.memory.section() if obs.enabled() else None,
+        "memory": obs.memory.section(),
         "value": fused["step_ms"],
         "unit": "ms_per_step",
         "vs_baseline": speedup,

@@ -162,7 +162,7 @@ def main() -> int:
     # the banked row carries its own sync/throttle/retrace evidence
     # (tools/telemetry_dump.py renders it back)
     from paddle_tpu import observability as obs
-    telemetry = obs.registry().snapshot() if obs.enabled() else None
+    telemetry = obs.registry().snapshot()
     emit({
         "metric": "fit_async_step_ms",
         "telemetry": telemetry,

@@ -197,8 +197,8 @@ def capture_suite() -> dict:
                                    LlamaForCausalLM)
     from paddle_tpu.observability import memory as memwatch
 
-    prior = flags.snapshot(("telemetry", "memwatch")).as_tuple()
-    flags.set_flags({"telemetry": True, "memwatch": True})
+    prior = flags.snapshot(("memwatch",)).as_tuple()
+    flags.set_flags({"memwatch": True})
     clear_decode_program_cache()
     memwatch.clear_program_table()
     rng = np.random.default_rng(13)

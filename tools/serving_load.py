@@ -355,7 +355,7 @@ def bench(per_tenant, seed, quick=False):
         "telemetry": obs.snapshot(),
         # memwatch: the chunk/ladder programs' compiled-memory rows ride
         # the banked artifact (telemetry_dump --memory renders them)
-        "memory": obs.memory.section() if obs.enabled() else None,
+        "memory": obs.memory.section(),
     }
 
 
@@ -740,7 +740,7 @@ def bench_fleet(seed, quick=False):
         "sections": sections,
         "ok": bool(ok),
         "telemetry": obs.snapshot(),
-        "memory": obs.memory.section() if obs.enabled() else None,
+        "memory": obs.memory.section(),
     }
 
 
@@ -949,7 +949,7 @@ def bench_spec(seed, quick=False):
         "sections": sections,
         "ok": bool(ok),
         "telemetry": obs.snapshot(),
-        "memory": obs.memory.section() if obs.enabled() else None,
+        "memory": obs.memory.section(),
     }
 
 
@@ -1128,7 +1128,7 @@ def bench_kv_quant(seed, quick=False):
         "token_agreement_per_request": [round(a, 4) for a in agree],
         "ok": bool(ok),
         "telemetry": obs.snapshot(),
-        "memory": obs.memory.section() if obs.enabled() else None,
+        "memory": obs.memory.section(),
     }
 
 

@@ -20,11 +20,7 @@ Three modes:
      open in chrome://tracing or Perfetto). The zero->aha path for the
      telemetry subsystem.
 
-  3. **Overhead mode** (``--demo --overhead``): the same load twice —
-     FLAGS_telemetry on vs off — reporting the steady-state decode
-     step-time delta (acceptance bar: < 2% on CPU).
-
-  4. **Span mode** (``--spans``): SELF time by span name — a span's
+  3. **Span mode** (``--spans``): SELF time by span name — a span's
      length less what its children (the records whose ``parent`` is its
      ``id``) cover — from the live ring with ``--demo``, or from a
      Chrome-trace JSON written by ``tracer().save``. Where a serving
@@ -237,7 +233,7 @@ def render_self_times(events) -> str:
     return "\n".join(lines)
 
 
-def run_demo(n_requests: int, tokens: int, trace_path, overhead: bool,
+def run_demo(n_requests: int, tokens: int, trace_path,
              programs: bool = False, spans: bool = False):
     import numpy as np
 
@@ -255,15 +251,13 @@ def run_demo(n_requests: int, tokens: int, trace_path, overhead: bool,
     prompts = [rng.integers(0, cfg.vocab_size, (8 + (i % 3) * 4,))
                .astype(np.int32) for i in range(n_requests)]
 
-    import time
-
     max_seq = 32 + tokens               # prompts are <= 16 tokens
 
     def mixed_load():
         """The snapshot/timeline workload: staggered lengths + prefix
-        cache, telemetry on."""
-        flags.set_flags({"telemetry": True, "memwatch": True})
-        clear_decode_program_cache()     # rebind cache telemetry+memwatch
+        cache, memwatch on."""
+        flags.set_flags({"memwatch": True})
+        clear_decode_program_cache()     # rebind the cache's memwatch
         eng = ServingEngine(model, max_batch=4, page_size=8,
                             max_seq_len=max_seq, prefix_cache=True)
         for p in prompts:
@@ -271,25 +265,7 @@ def run_demo(n_requests: int, tokens: int, trace_path, overhead: bool,
         eng.run()
         return decode_program_cache().trace_count(eng.decode_key) - 1
 
-    def interleaved_drain(eng, arms, out, phase):
-        """One steady-state drain, alternating the telemetry binding
-        per STEP: both arms sample identical machine conditions, which
-        is the only way ~µs instrument writes resolve against tens-of-
-        µs shared-CPU step noise. ``phase`` rotates which arm takes the
-        even steps across drains."""
-        for _ in range(4):
-            eng.submit(prompts[0], tokens)
-        eng.step()                       # prefill step (untimed)
-        i = phase
-        while eng.has_work():
-            which = i % 2
-            eng._m = arms[which]
-            t0 = time.perf_counter()
-            eng.step()
-            out[which].append((time.perf_counter() - t0) * 1e3)
-            i += 1
-
-    prior = flags.snapshot(("telemetry", "memwatch")).as_tuple()
+    prior = flags.snapshot(("memwatch",)).as_tuple()
     try:
         retraces = mixed_load()
         snap = obs.registry().snapshot()
@@ -298,39 +274,6 @@ def run_demo(n_requests: int, tokens: int, trace_path, overhead: bool,
             print(f"chrome trace -> {trace_path} "
                   f"({len(obs.tracer())} events)", file=sys.stderr)
         result = {"steady_retraces": retraces}
-        if overhead:
-            # ONE engine, ONE compiled executable, telemetry binding
-            # alternated per STEP. Two confounders force this design:
-            # separate engines compile separate executables whose
-            # memory layouts alone differ by more per step than the
-            # instrument writes being measured, and shared-CPU drift is
-            # tens of µs over a window — per-step alternation under
-            # identical process conditions is the estimator that
-            # resolves single-digit-µs telemetry cost. p10 of each
-            # arm's distribution is compared (min is a single fragile
-            # sample; the median still carries scheduler tail noise).
-            from paddle_tpu.generation.serving import _NullEngineTelemetry
-
-            flags.set_flags({"telemetry": True})
-            clear_decode_program_cache()
-            eng = ServingEngine(model, max_batch=4, page_size=8,
-                                max_seq_len=max_seq)
-            for _ in range(4):
-                eng.submit(prompts[0], 4)
-            eng.run()                    # compile prefill+decode (untimed)
-            real_m = eng._m
-            arms = {0: real_m, 1: _NullEngineTelemetry()}
-            out = {0: [], 1: []}
-            for r in range(8):
-                interleaved_drain(eng, arms, out, phase=r)
-            eng._m = real_m
-            on_s, off_s = sorted(out[0]), sorted(out[1])
-            on = on_s[len(on_s) // 10]
-            off = off_s[len(off_s) // 10]
-            result.update(
-                step_ms_on=round(on, 3), step_ms_off=round(off, 3),
-                overhead_pct=(round((on - off) / off * 100, 2)
-                              if off else None))
         print(json.dumps(result), file=sys.stderr)
         # the census reads LIVE cache state, so render it before the
         # finally clears the cache (the snapshot survives, keys don't)
@@ -365,8 +308,6 @@ def main() -> int:
     ap.add_argument("--demo", action="store_true",
                     help="run a tiny in-process ServingEngine load and "
                     "dump ITS telemetry")
-    ap.add_argument("--overhead", action="store_true",
-                    help="with --demo: A/B telemetry on vs off step time")
     ap.add_argument("--trace", metavar="PATH",
                     help="with --demo: write the Chrome-trace timeline")
     ap.add_argument("--requests", type=int, default=8)
@@ -377,7 +318,7 @@ def main() -> int:
     prog_text = None
     if args.demo:
         snap, prog_text = run_demo(args.requests, args.tokens, args.trace,
-                                   args.overhead, programs=args.programs,
+                                   programs=args.programs,
                                    spans=args.spans)
     else:
         if args.programs:
