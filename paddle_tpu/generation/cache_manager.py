@@ -317,8 +317,11 @@ class CacheManager:
         tables, the KV cursors and, only for a recurrent model, the
         rows whose recurrence advances (``live_slots``): an idle or
         mid-prefill row's pages take a garbage write that is
-        overwritten later, its STATE must not move."""
-        host = [self.pool.block_tables[:b], self.pool.seq_lens[:b]]
+        overwritten later, its STATE must not move. COPIES, not views:
+        the engine advances the cursors as soon as the step is
+        dispatched, while the transfer may still be reading these."""
+        host = [self.pool.block_tables[:b].copy(),
+                self.pool.seq_lens[:b].copy()]
         if self.state is not None:
             live = np.zeros((b,), np.int32)
             live[live_slots] = 1

@@ -13,7 +13,12 @@ decode step over max_batch slots. Ragged per-slot positions ride the
 paged kernel's seq_lens; idle slots write into the reserved null page and
 their outputs are ignored. The host loop between tokens is where the
 scheduler lives — admission, eviction, and result collection are plain
-Python on block tables.
+Python on block tables. That loop runs BESIDE the device, not between
+its steps: decode step N+1 is dispatched while step N's tokens are still
+on the device (the program takes its input tokens from the last step's
+output), and the host reads step N's tokens after that dispatch, one
+step late. Whatever needs the values, or moves a seated row, reads the
+step in flight first (``ServingEngine._settle``).
 
 Greedy decoding (the deterministic serving mode); sampling composes the
 same way via the logits hook.
@@ -24,7 +29,8 @@ from __future__ import annotations
 import functools
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 import numpy as np
 
@@ -111,6 +117,26 @@ class Request:
     # transient: the draft pool holds this slot's allocation (the draft
     # KV cursor itself is the draft pool's seq_lens row)
     spec_ready: bool = False
+    # transient: tokens of this request that dispatched decode steps
+    # will give and the host has not read yet (0 or 1 between steps)
+    in_flight: int = 0
+
+
+class _Flight(NamedTuple):
+    """A batched decode step that was dispatched and not read yet."""
+    toks: Any                   # (rung,) int32 on the device
+    rows: List[Tuple[int, Request]]     # (slot, the request it decodes)
+    t0: float                   # when its dispatch began
+
+
+class _ReadFirst(Exception):
+    """The scheduler is about to move or end a seated row while a
+    decode step is in flight: :meth:`ServingEngine._step_inner` reads
+    that step (``reason`` names why) and schedules again."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
 
 
 _POOL_STATES = ("used", "free", "shared", "pinned", "spilled")
@@ -157,6 +183,7 @@ class _EngineTelemetry:
                                labels=rl).labels(replica=replica, tp=tp)
 
         self.span = t.span
+        self.annotation = t.annotation
         self.event = t.event
         self.submitted = c(
             "serving_requests_submitted", "requests accepted by submit()")
@@ -192,6 +219,25 @@ class _EngineTelemetry:
             "serving_decode_table_pages",
             "page slots of the dispatched decode steps' block tables "
             "(rung x pages a row): what a grid over the table visits")
+        # ---- the overlap of host and device: a decode step is either
+        # dispatched behind one that is still in flight, or the step
+        # before it was read first, for a reason. With the steps dropped
+        # unread the two add up to serving_decode_steps
+        self.decode_overlapped = c(
+            "serving_decode_overlapped",
+            "decode steps dispatched while the step before them was "
+            "still in flight, its tokens unread: the host's work for "
+            "the step ran beside the device")
+        settles = r.counter(
+            "serving_decode_settles",
+            "decode steps whose tokens were read (or dropped) with no "
+            "step dispatched behind them, by what needed them: a rung "
+            "migration, a preemption, a deadline, a handoff, "
+            "speculation, the last decoding row gone, or a recovery, an "
+            "export or the watchdog, which drop the step",
+            labels=rl + ("reason",))
+        self.decode_settles = lambda reason: settles.labels(
+            replica=replica, tp=tp, reason=reason)
         self.prefill_tokens = c(
             "serving_prefill_tokens",
             "prompt tokens put through prefill compute (a monolithic "
@@ -1014,7 +1060,11 @@ class ServingEngine:
         self._queue: List[Request] = []
         self._results: Dict[int, List[int]] = {}
         self._status: Dict[int, str] = {}
+        # the newest token of each slot that the HOST knows: the next
+        # decode step's input unless the step in flight holds a newer one
         self._last_tok = np.zeros((max_batch,), np.int32)
+        # the decode step dispatched and not read yet (None: none)
+        self._flying: Optional[_Flight] = None
         self._next_rid = 0
         self._prefill_fn = None
         self._chunk_fn = None
@@ -1157,7 +1207,9 @@ class ServingEngine:
         return rid
 
     def has_work(self) -> bool:
-        return bool(self._queue) or any(s is not None for s in self._slots)
+        # a step in flight is work: its tokens are read by the next step()
+        return (bool(self._queue) or self._flying is not None
+                or any(s is not None for s in self._slots))
 
     def load(self) -> Tuple[int, int]:
         """``(deadline_bearing, total)`` live request counts (queued +
@@ -1254,6 +1306,9 @@ class ServingEngine:
         callbacks do NOT ride the exported requests (host bundles stay
         transportable): grab them with :meth:`take_callbacks` and
         re-bind each via ``inject_request(req, on_token=...)``."""
+        # the replica may be lost: the step in flight is dropped, not
+        # read, and its rows replay that token elsewhere
+        self._settle("export", read=False)
         live = [r for r in self._slots if r is not None]
         pool_alive = not self._caches.detached
         out = sorted(live + self._queue, key=lambda r: r.rid)
@@ -1315,6 +1370,7 @@ class ServingEngine:
         ``adopt_request(..., on_token=)``); transfer it however the
         deployment likes (the dryrun harness rides the deterministic
         p2p mailbox)."""
+        self._settle("handoff")     # the cursor and last token leave
         req = next((r for r in self._slots
                     if r is not None and r.rid == rid), None)
         if req is None or req.slot is None:
@@ -1368,6 +1424,7 @@ class ServingEngine:
         req: Request = bundle["request"]
         pages = bundle["pages"]
         state = bundle["state"]
+        self._settle("handoff")     # a slot the step in flight frees
         slot = self._slots.index(None) if None in self._slots else None
         self._caches.adopt_slot(
             slot, len(req.prompt) + int(req.max_new_tokens), pages,
@@ -1880,6 +1937,7 @@ class ServingEngine:
         req.feed = None
         req.slot = None
         req.bypassed = 0
+        req.in_flight = 0
 
     def _emit(self, req: Request, tok: Optional[int],
               done: bool = False) -> None:
@@ -1956,6 +2014,10 @@ class ServingEngine:
                     if r.deadline is not None and now > r.deadline]
         if not expired:
             return
+        if any(r.slot is not None for r in expired):
+            # a seated row ends with the tokens it has, the one in
+            # flight too
+            self._values_first("deadline")
         rids = {r.rid for r in expired}
         self._queue = [r for r in self._queue if r.rid not in rids]
         for req in expired:
@@ -1965,6 +2027,9 @@ class ServingEngine:
     def _expire_all(self, why: str) -> None:
         """The ``run(max_wall=...)`` watchdog tripped: terminate every
         remaining request TIMEOUT instead of spinning forever."""
+        # a wedged backend is what the watchdog is for: the step in
+        # flight is dropped, not waited for
+        self._settle("watchdog", read=False)
         remaining = [r for r in self._slots if r is not None]
         remaining += list(self._queue)
         self._queue = []
@@ -2013,6 +2078,9 @@ class ServingEngine:
         makes the replayed continuation bit-identical), and back off
         exponentially while nothing progresses."""
         t0 = time.perf_counter()
+        # the step in flight is dropped with the pools it wrote: replay
+        # starts from the tokens the host has
+        self._settle("recovery", read=False)
         live = [r for r in self._slots if r is not None]
         failed_adm = self._failed_admission
         self._failed_admission = None
@@ -2250,16 +2318,15 @@ class ServingEngine:
             admittable += 1
         demand = max(1, min(active + admittable, self.max_batch))
         target = next(r for r in self.ladder if r >= demand)
-        if target > self.bucket:
-            self._migrate(target)
-            self._shrink_wait = 0
-        elif target < self.bucket:
+        if target < self.bucket \
+                and self._shrink_wait + 1 < self.bucket_patience:
             self._shrink_wait += 1
-            if self._shrink_wait >= self.bucket_patience:
-                self._migrate(target)
-                self._shrink_wait = 0
-        else:
-            self._shrink_wait = 0
+            return
+        if target != self.bucket:
+            # rows change slots and the next step's program its rung
+            self._values_first("migrate")
+            self._migrate(target)
+        self._shrink_wait = 0
 
     def _migrate(self, target: int) -> None:
         """Move the decode batch to rung ``target``: shrinking compacts
@@ -2359,6 +2426,8 @@ class ServingEngine:
                     victim = r
             if victim is None:
                 return              # nobody meaningfully slacker
+            # the victim replays from its tokens: all of them
+            self._values_first("preempt")
             # fault check BEFORE any mutation: an injected preemption
             # failure propagates into replay recovery cleanly
             self._f_preempt.check(rid=victim.rid)
@@ -2700,23 +2769,33 @@ class ServingEngine:
 
     def _step_inner(self) -> None:  # tracecheck: hotpath
         n = self._step_no
-        # one span a phase a step  # tracecheck: disable=TRC007
-        with self._m.span("engine.schedule", step=n,
-                          queued=len(self._queue)):
-            self._sweep_deadlines()
-            self._probe_memo.clear()    # prefix probes are per-step
-            # decode-ready requests present BEFORE this step's scheduler
-            # + prefill work: the population that work below is stalling
-            waiting = any(r is not None and r.prefill_pos is None
-                          for r in self._slots)
-            t_sched = time.perf_counter()
-            # the step's admission order, sorted once and shared by the
-            # migration demand estimate and the slot-fill loop below
-            order = self._admission_order() if self._queue else []
-            self._maybe_migrate(order)
-            # SLO preemption runs BEFORE the slot fill: an unseated
-            # victim's slot admits the endangered head in this very step
-            self._preempt_for(order)
+        while True:
+            try:
+                # one span a phase a step  # tracecheck: disable=TRC007
+                with self._m.span("engine.schedule", step=n,
+                                  queued=len(self._queue)):
+                    self._sweep_deadlines()
+                    self._probe_memo.clear()    # prefix probes are per-step
+                    # decode-ready requests present BEFORE this step's
+                    # scheduler + prefill work: the population that work
+                    # below is stalling
+                    waiting = any(r is not None and r.prefill_pos is None
+                                  for r in self._slots)
+                    t_sched = time.perf_counter()
+                    # the step's admission order, sorted once and shared
+                    # by the migration demand estimate and the slot-fill
+                    # loop below
+                    order = self._admission_order() if self._queue else []
+                    self._maybe_migrate(order)
+                    # SLO preemption runs BEFORE the slot fill: an
+                    # unseated victim's slot admits the endangered head
+                    # in this very step
+                    self._preempt_for(order)
+                break
+            except _ReadFirst as first:
+                # outside the span, so the wait for the device is not
+                # the scheduler's; then the same schedule, from its top
+                self._settle(first.reason)
         # the step's ONE prefill-compute unit alternates between new
         # monolithic admissions and in-flight chunks under contention:
         # admissions always winning would starve a mid-prefill long
@@ -2786,37 +2865,54 @@ class ServingEngine:
         if waiting and did_prefill:
             self._observe_stall(time.perf_counter() - t_sched)
 
+        # the rows this step decodes, from COUNTS: a row whose budget
+        # the step in flight fills is not dispatched again (it ends when
+        # that step is read); an end by EOS is a value, seen one step
+        # late, and costs the row one more step whose token is dropped
         decode_rows = [r for r in self._slots
-                       if r is not None and r.prefill_pos is None]
-        self._observe_step_begin(len(decode_rows))
+                       if r is not None and r.prefill_pos is None
+                       and len(r.tokens) + r.in_flight < r.max_new_tokens]
         if not decode_rows:
+            self._settle("drained")     # no step goes out behind it
+            self._observe_step_begin(0)
             return
+        self._observe_step_begin(len(decode_rows))
 
         if self._draft is not None and self._spec_step(decode_rows):
             # the rows were served by speculation rounds (draft scan +
             # verify chunk per row); the batched decode must not run
-            # again this step
+            # again this step, and nothing is ever left in flight
+            self._m.decode_settles("speculation").inc()
             self._observe_step_end()
             return
 
         b = self.bucket
         fn = self._decode_program(b)
         self._observe_decode(decode_rows)
-        # the decode dispatch, from its uploads to the token pull, and
-        # its three parts: real spans, so a device capture can say which
-        # device time ran under a decode dispatch and which part of the
-        # host's share is which  # tracecheck: disable=TRC007
+        flying = self._flying
+        # the decode dispatch, from its uploads to the read and emit of
+        # the step BEFORE it, and its three parts (profiler scopes: a
+        # device capture can say which device time ran under a decode
+        # dispatch and which part of the host's share is which; the
+        # ring keeps only the whole)  # tracecheck: disable=TRC007
         with self._m.span("engine.decode_step", step=n,
-                          active=len(decode_rows), bucket=b):
-            # tracecheck: disable=TRC007
-            with self._m.span("engine.decode.stage"):
-                host = self._caches.decode_inputs(
-                    b, [r.slot for r in decode_rows])
+                          active=len(decode_rows), bucket=b,
+                          overlapped=flying is not None):
+            with self._m.annotation("engine.decode.stage"):
+                # a row takes its token from the step in flight (-1: the
+                # program reads that step's output, still on the
+                # device) unless the host knows a newer one: a prefill's
+                # or a final chunk's of this step, a forced prompt token
+                feed = self._last_tok[:b].copy()
+                for req in decode_rows:
+                    if req.in_flight:
+                        feed[req.slot] = -1
                 # ONE transfer call for the step's small inputs: each
                 # ``jnp.asarray`` is a dispatch of its own, a third of a
                 # millisecond of the host's share of every step
-                bt, sl, *extra, last = jax.device_put(
-                    host + [self._last_tok[:b, None]])
+                bt, sl, *extra, feed = jax.device_put(
+                    self._caches.decode_inputs(
+                        b, [r.slot for r in decode_rows]) + [feed])
                 if self._stacked is not None:
                     # N-layer program signature: the stacked per-group
                     # weight structs ride as traced args (never baked
@@ -2825,48 +2921,96 @@ class ServingEngine:
                 t0 = time.perf_counter()
                 pools = self._caches.take_caches()
                 self._f_decode.check()
-            # tracecheck: disable=TRC007
-            with self._m.span("engine.decode.dispatch"):
-                toks, states = fn(self._params, self._buffers, last,
-                                  pools, bt, sl, *extra)
+            with self._m.annotation("engine.decode.dispatch"):
+                # with nothing in flight every row's token is in
+                # ``feed``, which then stands in for the last output
+                toks, states = fn(
+                    self._params, self._buffers,
+                    (feed if flying is None else flying.toks, feed),
+                    pools, bt, sl, *extra)
                 self._caches.install_caches(states)
-            # tracecheck: disable=TRC007
-            with self._m.span("engine.decode.pull"):
-                # the scheduler's designed sync point: admission/eviction
-                # need the concrete token ids  # tracecheck: disable=TRC002
-                toks = np.asarray(toks)
+            # the host's state advances from counts, at the dispatch:
+            # every cursor by one, a forced prompt suffix by one token
+            rows = []
+            for req in decode_rows:
+                if req.temperature > 0.0 and not req.pending:
+                    # a sampled request never takes a token from the
+                    # greedy batch step — the spec verify program is its
+                    # sampler. The row's KV write at the cursor was a
+                    # correct (and repeatable) prefix write, but the
+                    # cursor must NOT advance: the next speculation
+                    # round re-feeds this position through its verify
+                    # chunk
+                    continue
+                self.pool.seq_lens[req.slot] += 1
+                if req.pending:
+                    # still teacher-forcing the prompt suffix (prefix-
+                    # cache admission): the model output is a prompt-
+                    # position logit, not a generated token — feed the
+                    # next suffix token
+                    self._last_tok[req.slot] = req.pending.pop(0)
+                    continue
+                req.in_flight += 1
+                rows.append((req.slot, req))
+            self._flying = _Flight(toks, rows, t0)
+            if flying is not None:
+                # the late read: step N's tokens, behind step N+1's
+                # dispatch. Where the device is the longer side the host
+                # waits here for N with N+1 queued behind it
+                self._read(flying)
+            if self._draft is not None:
+                # a speculation round works from the values
+                self._settle("speculation")
+        self._observe_step_end()
 
+    # --------------------------------------------- the step in flight
+    def _values_first(self, reason: str) -> None:
+        """The scheduler is about to move or end a seated row: not
+        with a decode step in flight (see :class:`_ReadFirst`)."""
+        if self._flying is not None:
+            raise _ReadFirst(reason)
+
+    def _settle(self, reason: str, read: bool = True) -> None:
+        """Bring the host level with the device: read the decode step
+        in flight, if there is one, with nothing dispatched behind it.
+        Whatever needs the token VALUES or moves seated rows calls this
+        first; ``reason`` labels the count. ``read=False`` drops the
+        step unread (its pools or its replica are lost, or may be): the
+        rows replay that token from the tokens the host has."""
+        flying, self._flying = self._flying, None
+        if flying is None:
+            return
+        self._m.decode_settles(reason).inc()
+        if read:
+            self._read(flying)
+        else:
+            for _slot, req in flying.rows:
+                req.in_flight = 0
+
+    def _read(self, flight: _Flight) -> None:  # tracecheck: hotpath
+        """Read one dispatched decode step's tokens and emit them: the
+        scheduler's one sync with the device."""
+        with self._m.annotation("engine.decode.pull"):
+            # admission/eviction need the concrete token ids
+            # tracecheck: disable=TRC002
+            toks = np.asarray(flight.toks)
         now = time.perf_counter()
         if self.tp_degree > 1:
             # sharded dispatch envelope: compute + the per-layer psum
             # pair, observed host-side OUTSIDE the shard_map body
             # (meshcheck MSH006 keeps telemetry off the traced path)
-            self._observe_collective(now - t0)
+            self._observe_collective(now - flight.t0)
         # tracecheck: disable=TRC007
-        with self._m.span("engine.emit", step=n):
-            for slot, req in enumerate(self._slots):
-                if req is None:
-                    continue            # idle row wrote the null page; ignore
-                if req.prefill_pos is not None:
-                    # mid-chunk-prefill slot: its decode row computed (and
-                    # wrote) garbage at the cursor position — the next chunk
-                    # overwrites that position and the cursor never advanced
+        with self._m.span("engine.emit", step=self._step_no):
+            for slot, req in flight.rows:
+                if req.slot != slot:
+                    # ended by EOS when the step before this one was
+                    # read, with this step already dispatched: the extra
+                    # token is dropped (its KV write landed in a page
+                    # the row still held; a page freed since is reused
+                    # only by programs dispatched later)
                     continue
-                if req.temperature > 0.0 and not req.pending:
-                    # a sampled request never takes a token from the greedy
-                    # batch step — the spec verify program is its sampler.
-                    # The row's KV write at the cursor was a correct (and
-                    # repeatable) prefix write, but the cursor must NOT
-                    # advance: the next speculation round re-feeds this
-                    # position through its verify chunk
-                    continue
-                self.pool.seq_lens[slot] += 1
-                if req.pending:
-                    # still teacher-forcing the prompt suffix (prefix-cache
-                    # admission): the model output is a prompt-position logit,
-                    # not a generated token — feed the next suffix token
-                    self._last_tok[slot] = req.pending.pop(0)
-                    continue
+                req.in_flight -= 1
                 tok = int(toks[slot])
                 if self._prefix is not None and not req.tokens:
                     # first generated token of a shared admission: the whole
@@ -2888,7 +3032,6 @@ class ServingEngine:
                 self._emit(req, tok)
                 self._last_tok[slot] = tok
                 self._finish_if_done(req)
-        self._observe_step_end()
 
     # ------------------------------------------------- telemetry helpers
     # NOT hotpath-marked: plain host bookkeeping called once per step()
@@ -2933,6 +3076,8 @@ class ServingEngine:
         that decode, the rows the rung's program computes, and the
         cached tokens those rows must read."""
         m = self._m
+        if self._flying is not None:
+            m.decode_overlapped.inc()   # it goes out behind that step
         m.decode_rows.inc(len(rows))
         m.decode_slots.inc(self.bucket)
         m.decode_live_tokens.inc(
@@ -3149,6 +3294,18 @@ def _build_chunk_prefill(note_trace, model):
     return jax.jit(serving_prefill_chunk, donate_argnums=(3,))
 
 
+def _step_tokens(toks):
+    """The prologue every batched decode program shares: the step's
+    ``(b, 1)`` input tokens from ``toks = (last, feed)``. ``last`` is
+    the ``(b,)`` output of the decode step dispatched before this one,
+    which the host may not have read yet; ``feed`` is the host's word on
+    each row, a token (>= 0) that overrides it or -1 for "the
+    device's". So a step's input never waits for the host to read the
+    last step's output."""
+    last, feed = toks
+    return jnp.where(feed >= 0, feed, last)[:, None]
+
+
 def _build_generic_decode(note_trace, model):
     """The unfused decode step: one functional_call through the model's
     forward_with_cache (every layer an op chain XLA schedules)."""
@@ -3157,6 +3314,7 @@ def _build_generic_decode(note_trace, model):
     def serving_decode_generic(params, buffers, toks, pools, bt, sl, *live):
         # ``live``: only for a recurrent model, the rows that advance
         note_trace()
+        toks = _step_tokens(toks)
         states = cache_entries(model, pools, PagedDecodeState, bt, sl,
                                **({"live": live[0]} if live else {}))
         # offset=None -> per-slot positions from states.seq_lens
@@ -3313,6 +3471,7 @@ def _build_fused_decode(note_trace, spec, snap):
 
     def serving_decode_fused(params, buffers, toks, pools, bt, sl):
         note_trace()
+        toks = _step_tokens(toks)
         allp = {**buffers, **params}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
         states = []
@@ -3352,6 +3511,7 @@ def _build_fused_nlayer_decode(note_trace, spec, snap):
 
     def serving_decode_fused_nlayer(params, buffers, toks, pools, bt, sl, stacked):
         note_trace()
+        toks = _step_tokens(toks)
         allp = {**buffers, **params}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
         states = []
@@ -3428,6 +3588,7 @@ def _build_fused_nlayer_decode_tp(note_trace, spec, snap, mesh, axis, tp):
 
     def serving_decode_fused_tp(params, buffers, toks, pools, bt, sl, stacked):
         note_trace()
+        toks = _step_tokens(toks)
         allp = {**buffers, **params}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
         x, out_pools = sharded(x, list(pools), bt, sl, stacked)

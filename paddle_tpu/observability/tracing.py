@@ -24,6 +24,7 @@ rule TRC007.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import os
@@ -117,6 +118,16 @@ class SpanTracer:
     # ------------------------------------------------------------ record
     def span(self, name: str, **args) -> Span:
         return Span(self, name, args)
+
+    def annotation(self, name: str):
+        """A scope for the profiler alone: the ``TraceAnnotation`` a
+        :class:`Span` enters, with no ring record. For a phase that
+        recurs every step and is read only from a device capture (the
+        decode dispatch's three parts): in the ring its records would
+        crowd out the ones that are read there."""
+        if _ANNOTATION is None:
+            return contextlib.nullcontext()
+        return _ANNOTATION(name)
 
     def event(self, name: str, t0: float, t1: float, parent: int = 0,
               **args) -> None:

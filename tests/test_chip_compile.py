@@ -401,7 +401,8 @@ def gpt3_345m_serving(chips):
             return [pools, S((b, max_pages), I32), S((b,), I32)]
         return pool.shape, {
             "serving_decode_generic": (serving._build_generic_decode,
-                                       [S((16, 1), I32)] + tail(16)),
+                                       [(S((16,), I32), S((16,), I32))]
+                                       + tail(16)),
             "serving_prefill_chunk": (serving._build_chunk_prefill,
                                       [S((1, 256), I32)] + tail(1)
                                       + [S((), I32)]),
@@ -482,7 +483,8 @@ def granite_hybrid_serving(chips):
             return [pools, S((b, max_pages), I32), S((b,), I32)]
         return {
             "serving_decode_generic": (serving._build_generic_decode,
-                                       [S((32, 1), I32)] + tail(32)
+                                       [(S((32,), I32), S((32,), I32))]
+                                       + tail(32)
                                        + [S((32,), I32)]),
             "serving_prefill_chunk": (serving._build_chunk_prefill,
                                       [S((1, 256), I32)] + tail(1)

@@ -11,6 +11,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import flags
+from paddle_tpu import observability as obs
 from paddle_tpu.generation.serving import ServingEngine
 from paddle_tpu.models import (GPTConfig, GPTForCausalLM, LlamaConfig,
                                LlamaForCausalLM)
@@ -635,3 +636,270 @@ class TestPrefixCache:
         req = next(s for s in eng._slots if s is not None)
         assert req.pending == []            # went through full prefill
         assert eng.run()[r1] == ref
+
+
+# ------------------------------------------ the overlapped decode step
+# Decode step N+1 is dispatched while step N's tokens are still on the
+# device, and the host reads them one step late. None of it may show in
+# a request's tokens or in the order its callback sees them: every case
+# runs on the tiny GPT and on the tiny granite hybrid (recurrent rows
+# beside pages), against each model's own greedy reference.
+
+def _counter(eng, name, **labels):
+    """The engine's own series of ``name`` (its ``replica`` label),
+    summed over whatever labels are not given."""
+    fam = obs.registry().snapshot()["metrics"].get(name, {"series": []})
+    want = dict(labels, replica=eng.replica)
+    return sum(s["value"] for s in fam["series"]
+               if all(s["labels"].get(k) == v for k, v in want.items()))
+
+
+class _Tiny:
+    """One tiny model: engines over it, prompts for it, and the check
+    that ``tokens`` are the greedy continuation of ``prompt``."""
+
+    def __init__(self, kind):
+        self.kind = kind
+        if kind == "gpt":
+            paddle.seed(3101)
+            self.cfg = GPTConfig.tiny()
+            self.model = GPTForCausalLM(self.cfg)
+        else:
+            import dataclasses
+            from benchmark.lib.system import load_reference
+            from paddle_tpu.models import (GraniteHybridConfig,
+                                           GraniteHybridForCausalLM)
+            paddle.seed(2804)
+            # an embedding that does not drown the mixers: at the
+            # published multipliers a random model repeats its last token
+            self.cfg = GraniteHybridConfig.tiny(embedding_multiplier=1.0,
+                                                initializer_range=0.1)
+            self.model = GraniteHybridForCausalLM(self.cfg)
+            self.model.eval()
+            self._ref = load_reference("granite-4.0-h-micro")
+            self._weights = dict(self.model.raw_state()[0])
+            self._md = dataclasses.asdict(self.cfg)
+        self._n = 0
+
+    def engine(self, **kw):
+        self._n += 1
+        kw = {"max_batch": 4, "page_size": 8, "max_seq_len": 64,
+              "prefill_chunk": 16, "bucket_ladder": (1, 2, 4),
+              "replica": f"ov-{self.kind}-{self._n}", **kw}
+        eng = ServingEngine(self.model, **kw)
+        eng.bucket_patience = 1
+        return eng
+
+    def prompts(self, lens, seed=5):
+        rng = np.random.default_rng(seed)
+        return [rng.integers(0, self.cfg.vocab_size, (n,)).astype(np.int32)
+                for n in lens]
+
+    def check(self, prompt, tokens, n=None):
+        tokens = list(tokens)
+        assert tokens and (n is None or len(tokens) == n)
+        if self.kind == "gpt":
+            assert tokens == solo(self.model, prompt, len(tokens))
+            return
+        import jax.numpy as jnp
+        ids = jnp.asarray(list(prompt) + tokens[:-1], jnp.int32)
+        logits = np.asarray(self._ref.logits(self._weights, ids, self._md))
+        assert tokens == logits[len(prompt) - 1:].argmax(-1).tolist()
+
+
+@pytest.fixture(scope="module", params=["gpt", "granite"])
+def tiny(request):
+    return _Tiny(request.param)
+
+
+def _stream(seen):
+    def on_token(rid, tok, done):
+        seen.append((rid, tok, done))
+    return on_token
+
+
+def _step_until_flying(eng, rid, tokens=1):
+    """Step until ``rid`` has ``tokens`` on the host and a decode step
+    is in flight."""
+    while len(eng.poll(rid)["tokens"]) < tokens or eng._flying is None:
+        eng.step()
+
+
+class TestOverlappedDecode:
+    def test_rows_join_while_a_step_is_in_flight(self, tiny):
+        """(a) a monolithic prefill and a chunked one admitted behind a
+        step in flight: every request's tokens, and the order its
+        callback sees them in, are its solo greedy run's."""
+        eng = tiny.engine()
+        prompts = tiny.prompts((5, 9, 33, 7))
+        budgets = (10, 6, 5, 8)
+        seen = []
+        rids = [eng.submit(prompts[0], budgets[0], on_token=_stream(seen))]
+        _step_until_flying(eng, rids[0], 2)
+        for p, n in zip(prompts[1:], budgets[1:]):
+            assert eng._flying is not None
+            rids.append(eng.submit(p, n, on_token=_stream(seen)))
+            eng.step()
+        out = eng.run()
+        for p, r, n in zip(prompts, rids, budgets):
+            tiny.check(p, out[r], n)
+            mine = [(t, d) for rid, t, d in seen if rid == r]
+            assert mine == [(t, False) for t in out[r]] + [(None, True)]
+        assert _counter(eng, "serving_decode_overlapped") > 0
+        assert eng._flying is None and not eng.has_work()
+
+    def test_end_by_budget_dispatches_no_extra_row(self, tiny):
+        """(b) an end by ``max_new_tokens`` is a count: the row is not
+        dispatched behind its own last step."""
+        eng = tiny.engine()
+        prompts = tiny.prompts((6, 11))
+        budgets = (7, 4)
+        rids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+        out = eng.run()
+        for p, r, n in zip(prompts, rids, budgets):
+            tiny.check(p, out[r], n)
+        # the prefill gives a request's first token, a decode row each
+        # of the others
+        assert _counter(eng, "serving_decode_rows") == \
+            sum(n - 1 for n in budgets)
+
+    def test_end_by_eos_costs_one_dropped_row(self, tiny):
+        """(b) an end by EOS is a value, seen one step late: the row
+        rode exactly one more step, whose token is never emitted."""
+        # a prompt whose greedy run has, past the prefill's token, one
+        # that did not occur before it: the request ends there, by value
+        eng = tiny.engine()
+        runs = [(p, eng.submit(p, 8))
+                for p in tiny.prompts((7,) * 12, seed=9)]
+        out = eng.run()
+        prompt, free, at = next(
+            (p, out[r], i) for p, r in runs for i in range(1, 7)
+            if out[r][i] not in out[r][:i])
+        tiny.check(prompt, free, 8)
+        eng = tiny.engine()
+        seen = []
+        rid = eng.submit(prompt, 8, eos_token_id=free[at],
+                         on_token=_stream(seen))
+        out = eng.run()
+        assert out[rid] == free[:at + 1]
+        assert [t for _r, t, d in seen if not d] == free[:at + 1]
+        assert seen[-1] == (rid, None, True)
+        assert _counter(eng, "serving_decode_rows") == at + 1
+        assert eng.pool.free_page_count() == eng.pool.num_pages - 1
+
+    def test_migration_reads_the_step_in_flight_first(self, tiny):
+        """(c) the ladder compacts rows into low slots only after the
+        step in flight is read."""
+        eng = tiny.engine()
+        prompts = tiny.prompts((5, 19, 8, 11))
+        budgets = (2, 2, 2, 12)
+        rids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+        out = eng.run()
+        for p, r, n in zip(prompts, rids, budgets):
+            tiny.check(p, out[r], n)
+        assert eng.bucket_migrations >= 2
+        assert _counter(eng, "serving_decode_settles", reason="migrate") >= 1
+
+    def test_preemption_reads_the_step_in_flight_first(self, tiny):
+        """(c) a victim is unseated with ALL its tokens, the one in
+        flight too, and replays from them."""
+        eng = tiny.engine(max_batch=2, bucket_ladder=(2,))
+        eng.preempt_enabled, eng.preempt_horizon = True, 3600.0
+        prompts = tiny.prompts((10, 9, 6))
+        long_rids = [eng.submit(p, 14) for p in prompts[:2]]
+        _step_until_flying(eng, long_rids[1], 3)
+        held = [len(eng.poll(r)["tokens"]) for r in long_rids]
+        tight = eng.submit(prompts[2], 3, deadline=600.0)
+        out = eng.run()
+        assert eng.preemptions == 1
+        assert _counter(eng, "serving_decode_settles", reason="preempt") == 1
+        for p, r in zip(prompts, long_rids + [tight]):
+            tiny.check(p, out[r], 3 if r == tight else 14)
+        # the victim replayed what it held PLUS the token that was in
+        # flight when it was unseated: nothing was decoded twice
+        assert _counter(eng, "serving_preempted_tokens_replayed") \
+            in (held[0] + 1, held[1] + 1)
+
+    def test_deadline_expiry_keeps_the_token_in_flight(self, tiny):
+        """(c) a seated request that times out ends with every token
+        that was dispatched for it."""
+        import time
+        eng = tiny.engine()
+        prompt = tiny.prompts((9,))[0]
+        rid = eng.submit(prompt, 20)
+        _step_until_flying(eng, rid, 3)
+        req = next(r for r in eng._slots if r is not None)
+        held = len(req.tokens)
+        assert req.in_flight == 1
+        req.deadline = time.perf_counter() - 1.0
+        eng.step()
+        assert eng.status(rid) == "TIMEOUT"
+        partial = eng.results()[rid]
+        assert len(partial) == held + 1
+        tiny.check(prompt, partial)
+        assert _counter(eng, "serving_decode_settles", reason="deadline") == 1
+        assert not eng.has_work()
+
+    def test_dispatch_fault_with_a_step_in_flight_replays(self, tiny):
+        """(d) the step in flight is dropped with the pools it wrote;
+        replay from the tokens the host has is bit-identical."""
+        prompts = tiny.prompts((5, 19, 8))
+        budgets = (9, 7, 8)
+        with fault_spec("decode_dispatch:every=4:times=2"):
+            eng = tiny.engine()
+            rids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+            out = eng.run()
+        for p, r, n in zip(prompts, rids, budgets):
+            tiny.check(p, out[r], n)
+            assert eng.status(r) == "OK"
+        assert _counter(eng, "serving_decode_settles", reason="recovery") >= 1
+        # every decode step that went out was read late, read first or
+        # dropped; the two that faulted before their dispatch count as
+        # steps (and as overlapped, where one was in flight) and went
+        # nowhere
+        steps = _counter(eng, "serving_decode_steps")
+        assert steps - 2 <= _counter(eng, "serving_decode_overlapped") \
+            + _counter(eng, "serving_decode_settles") <= steps
+
+    def test_the_step_in_flight_is_work(self, tiny):
+        """(e) ``has_work`` / ``take_results`` / ``run`` when all that
+        is left is a step whose tokens are unread."""
+        eng = tiny.engine()
+        prompt = tiny.prompts((6,))[0]
+        rid = eng.submit(prompt, 3)
+        eng.step()                      # prefill + the 2nd token's step
+        eng.step()                      # the 3rd token's step; 2nd read
+        req = next(r for r in eng._slots if r is not None)
+        assert len(req.tokens) == 2 and req.in_flight == 1
+        assert eng._flying is not None
+        rows = _counter(eng, "serving_decode_rows")
+        assert eng.has_work()
+        assert eng.take_results() == {} and eng.status(rid) == "PENDING"
+        out = eng.run()                 # reads it; dispatches nothing
+        tiny.check(prompt, out[rid], 3)
+        assert _counter(eng, "serving_decode_rows") == rows == 2
+        assert not eng.has_work() and eng._flying is None
+
+    def test_staged_inputs_are_copies(self, tiny):
+        """(f) the host advances its cursors as soon as a step is
+        dispatched; what it staged for that step is its own memory and
+        keeps the values the program must read."""
+        eng = tiny.engine()
+        prompt = tiny.prompts((6,))[0]
+        rid = eng.submit(prompt, 6)
+        staged = []
+        stage = eng._caches.decode_inputs
+        eng._caches.decode_inputs = \
+            lambda b, live: (staged.append(stage(b, live)), staged[-1])[1]
+        eng.step()
+        eng.step()
+        for host in staged:
+            for arr in host:
+                assert not np.shares_memory(arr, eng.pool.seq_lens)
+                assert not np.shares_memory(arr, eng.pool.block_tables)
+        slot = next(r for r in eng._slots if r is not None).slot
+        # step 1 staged the prefill's cursor, step 2 the cursor after
+        # one decode token; the pool's own is already one further
+        assert [int(h[1][slot]) for h in staged] == [6, 7]
+        assert int(eng.pool.seq_lens[slot]) == 8
+        tiny.check(prompt, eng.run()[rid], 6)
