@@ -1,0 +1,50 @@
+#!/usr/bin/env python3
+"""The control of ``sdar-30b-a3b-chat.serve-blocks``'s ``correct``, on
+the chip at the cell's sizes and the cell's own sample:
+
+    python3 benchmark/tests/control_blocks.py --seed <n>
+
+Builds the cell's system as ``benchmark/run.py`` does, runs its set-up
+(``blocks.warm_up``) and prints one JSON line with two readings, both
+through the cell's own comparison (``blocks.compare`` under the
+reference's ``TIE_ATOL`` / ``TIE_RTOL``): the ENGINE's reveals, which
+must read ``correct: true``, and the CONTROL's: the plain reference
+itself with both operands of every weight matmul rounded to
+``float8_e4m3fn``, the nearest precision below the configuration's
+bfloat16, which must read ``correct: false``. Exit code 1 where either
+fails. A limit that a seed's control passes is too wide; PERF.md
+(sections 4 and 6) has the readings the limit was set between.
+"""
+
+import argparse
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))       # benchmark/: run.py
+import run  # noqa: E402
+
+CELL = "sdar-30b-a3b-chat.serve-blocks"
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, required=True)
+    args = ap.parse_args(argv)
+    _, cell, config, traffic = run.load_cell(CELL)
+    run.require_chips(cell["chips"])
+
+    import jax.numpy as jnp
+    from benchmark.lib import blocks
+    from benchmark.lib import traffic as traffic_lib
+    system, _ = run.build_system(cell, config, traffic, args.seed)
+    requests = traffic_lib.schedule(traffic, args.seed, 50.0)
+    line = dict(seed=args.seed, **blocks.control_readings(
+        system, requests, jnp.float8_e4m3fn))
+    print(json.dumps(line), flush=True)
+    return int(not line["engine"]["correct"] or line["control"]["correct"])
+
+
+if __name__ == "__main__":
+    sys.exit(main())

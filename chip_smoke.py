@@ -18,6 +18,12 @@ points a user calls, on ONE TPU chip, in this one process:
                 ServingEngine: one prompt through a full and a padded
                 prefill chunk, then decoded, against the benchmark's plain
                 reference
+  blocks        sdar-30b-a3b-chat (6 layers of 128 experts, the
+                benchmark's configuration and weights) through
+                ServingEngine: one prompt whose whole blocks pass a full
+                and a padded block-causal chunk and whose remainder starts
+                the first block, three blocks generated, every reveal held
+                against the plain reference
 
 ``python chip_smoke.py --chips 4`` runs ONLY the Fleet hybrid path
 (dp2 x mp2 TrainStep at Llama-2-7B widths, 2 layers) and its one-chip twin.
@@ -74,6 +80,12 @@ class Sizes:
     # file, benchmark/configs/granite-4.0-h-micro.json
     granite: Optional[dict] = None
     prefill_chunk: Optional[int] = None     # None: FLAGS_serving_prefill_chunk
+    # 416 whole-block tokens (a full chunk, then a padded one) + 3 that
+    # start the first block; 1 + 4 + 4 new tokens
+    sdar_prompt_len: int = 419
+    sdar_new_tokens: int = 9
+    # None: benchmark/configs/sdar-30b-a3b-chat.json
+    sdar: Optional[dict] = None
     seed: int = 0
 
 
@@ -465,6 +477,77 @@ def phase_granite_hybrid(s: Sizes) -> dict:
         serve_s=round(serve_s, 2))
 
 
+# --------------------------- blocks: sparse experts, diffusion over blocks
+def phase_sdar_blocks(s: Sizes) -> dict:
+    """The block step end to end at the published widths: the prompt's
+    whole blocks through a full and a padded block-causal chunk, its
+    remainder as the known part of the first block, then denoising and
+    commit forwards through the grouped expert matmul and the decode
+    paged attention with a block of query rows; every reveal is held
+    against the plain reference's by the benchmark's own near-tie rule.
+    (The benchmark's check seats prompts of one chunk or less that are
+    whole blocks: without this phase nothing on the chip compares the
+    chunk carry, the pad or a remainder.)"""
+    import os
+
+    from benchmark.lib import blocks, registry, system
+    from paddle_tpu.generation.program_cache import decode_program_cache
+    from paddle_tpu.kernels import grouped_matmul
+
+    registry.load_all()
+    config = s.sdar
+    if config is None:
+        here = os.path.dirname(os.path.abspath(__file__))
+        with open(os.path.join(here, "benchmark", "configs",
+                               "sdar-30b-a3b-chat.json")) as fh:
+            config = json.load(fh)
+    sysm = system.build_serve(config, {}, s.seed + 4, 1)
+    eng, ref, model = sysm.engine, sysm.ref, config["model"]
+    blen, mask = model["block_length"], model["mask_token_id"]
+    n, new = s.sdar_prompt_len, s.sdar_new_tokens
+    whole = n - n % blen
+    assert eng.chunk < whole < 2 * eng.chunk and n % blen, (eng.chunk, n)
+
+    def refuse_recovery(exc):
+        raise AssertionError("a serving dispatch failed") from exc
+    eng._recover_dispatch = refuse_recovery
+    rng = np.random.default_rng(s.seed + 4)
+    prompt = rng.integers(0, mask, (n,)).astype(np.int32)
+    t0 = time.perf_counter()
+    rid = eng.submit(prompt, new)
+    eng.record_blocks([rid])
+    toks = eng.run()[rid]
+    serve_s = time.perf_counter() - t0
+    records = eng.block_records()[rid]
+    assert eng.status(rid) == "OK" and len(toks) == new, eng.statuses()
+    assert eng.chunk_dispatches == 2, eng.chunk_dispatches
+    text = decode_program_cache().lowered(eng.decode_key).as_text()
+    kernels = {k: k in text for k in (grouped_matmul.KERNEL_NAME,
+                                      "paged_attention")}
+    if s.on_chip:
+        assert all(kernels.values()), kernels
+
+    rows, ids, cursors, picked = blocks.checked_forwards(
+        [(prompt, rid)], {rid: toks}, {rid: records}, blen)
+    ties, wrong = blocks.compare(
+        rows, blocks.reference_verdict(sysm, ids, cursors, picked), ref)
+    for tie in ties:
+        print(json.dumps({"near_tie": tie}), flush=True)
+    assert not wrong, \
+        f"a reveal differs from the reference beyond a near-tie: {wrong}"
+    reveals = len(rows)
+    hist = eng.expert_histogram()
+    return _emit(
+        "blocks", config=config["name"], layers=model["num_hidden_layers"],
+        n_params=int(sum(p.size for p in sysm.model.parameters())),
+        prompt_len=n, chunk=eng.chunk, chunk_dispatches=eng.chunk_dispatches,
+        new_tokens=new, tokens=[int(t) for t in toks], statuses="OK",
+        forwards=len(records), reveals=reveals, near_ties=len(ties),
+        tie_atol=ref.TIE_ATOL, step_kind=eng.decode_key.kind,
+        kernels=kernels, experts_touched=int((hist > 0).sum()),
+        serve_s=round(serve_s, 2))
+
+
 # ------------------------------------------------- four chips: dp2 x mp2
 def _hybrid_losses(s: Sizes, mesh, x, y):
     """Build the model from the seed, take ``hybrid_steps`` steps on
@@ -593,6 +676,9 @@ def run_phases(s: Sizes, chips: int = 1) -> list:
     clear_decode_program_cache()
     gc.collect()
     lines.append(phase_granite_hybrid(s))
+    clear_decode_program_cache()
+    gc.collect()
+    lines.append(phase_sdar_blocks(s))
     return lines
 
 

@@ -22,6 +22,16 @@ step in flight first (``ServingEngine._settle``).
 
 Greedy decoding (the deterministic serving mode); sampling composes the
 same way via the logits hook.
+
+A model that publishes ``block_spec()`` generates by DIFFUSION OVER
+BLOCKS (``models/sdar_moe.py``): its batched step is the BLOCK step
+(``ServingEngine._block_step``). A row's unit is a block of B positions
+at its cursor; a denoising forward reveals the most confident masked
+position of the block, and once none is masked a commit forward stores
+the block's K/V, advances the cursor by B and hands the block's tokens
+to the host — B + 1 forwards for B tokens a row. The block's token ids
+stay on the device between steps and the host knows every row's phase
+from counts, so the step rides the same one-step-late read.
 """
 
 from __future__ import annotations
@@ -43,7 +53,8 @@ from ..analysis import key_vocab
 # matches jax.Array, whose ``_value`` property is the array copied to the
 # host — on the chip that pulled the whole KV pool back every dispatch
 from ..core.tensor import _val
-from ..kernels.paged_attention import PagedDecodeState, PagedKVCache
+from ..kernels.paged_attention import (PagedBlockState, PagedDecodeState,
+                                       PagedKVCache)
 from ..testing import faults
 from .cache_manager import (CacheManager, cache_entries,
                             has_recurrent_layers, kv_heads)
@@ -118,14 +129,24 @@ class Request:
     # KV cursor itself is the draft pool's seq_lens row)
     spec_ready: bool = False
     # transient: tokens of this request that dispatched decode steps
-    # will give and the host has not read yet (0 or 1 between steps)
+    # will give and the host has not read yet (0 or 1 between steps; a
+    # block model's commit forward puts a block's new tokens in flight)
     in_flight: int = 0
+    # ---- block diffusion (a model with ``block_spec()``) --------------
+    # transient: positions of the row's current block still masked (0:
+    # the next forward commits the block), and how many of its leading
+    # positions are prompt tokens (the prompt's remainder, first block)
+    masks: int = 0
+    known: int = 0
 
 
 class _Flight(NamedTuple):
-    """A batched decode step that was dispatched and not read yet."""
+    """A batched decode step that was dispatched and not read yet. A
+    block step's ``toks`` are the rows' blocks after the forward,
+    (rung, B), and its ``rows`` only those that COMMIT in it, each with
+    the count of leading block positions that are prompt tokens."""
     toks: Any                   # (rung,) int32 on the device
-    rows: List[Tuple[int, Request]]     # (slot, the request it decodes)
+    rows: List[tuple]           # (slot, the request it decodes[, known])
     t0: float                   # when its dispatch began
 
 
@@ -165,7 +186,8 @@ class _EngineTelemetry:
     everywhere it is registered, so a tp=2 engine's series never merge
     with a solo replica's in a mixed fleet."""
 
-    def __init__(self, replica: str = "0", tp: str = "1"):
+    def __init__(self, replica: str = "0", tp: str = "1",
+                 block: bool = False, experts: bool = False):
         r = obs.registry()
         t = obs.tracer()
         rl = ("replica", "tp")
@@ -403,6 +425,36 @@ class _EngineTelemetry:
             "recurrent-state rows snapshotted to the host for a "
             "harvest_request handoff")
         self.counter_track = t.counter
+        if block:
+            # ---- block diffusion: a step, a row and a token are three
+            # things there. Bound only by an engine whose model
+            # generates by blocks, so no other engine has the series
+            self.block_forwards = c(
+                "serving_block_forwards",
+                "row-forwards of the dispatched block steps: one a row "
+                "that ran a denoising or a commit forward")
+            self.block_commits = c(
+                "serving_block_commits",
+                "commit forwards: rows whose block was stored and whose "
+                "cursor advanced by the block length")
+            self.block_tokens = c(
+                "serving_block_tokens",
+                "tokens the commit forwards committed (a block less the "
+                "prompt tokens it began with)")
+        if experts:
+            # ---- expert layers: bound only by an engine whose model
+            # returns its expert layers' counts
+            self.moe_assignments = c(
+                "moe_assignments",
+                "token-to-expert assignments the expert layers computed "
+                "(every step and prefill, pad tokens too), from the "
+                "counts kept on the device and read by "
+                "ServingEngine.expert_histogram")
+            self.moe_experts_touched = c(
+                "moe_experts_touched",
+                "(call, layer, expert) triples that got at least one "
+                "assignment: how many experts' weights the grouped "
+                "matmuls had to read")
 
 
 class _PrefixTelemetry:
@@ -919,6 +971,51 @@ class ServingEngine:
                     f"tp_degree={self.tp_degree} with a recurrent model: "
                     "the recurrent-state store and the state-update kernel "
                     "are not sharded over heads")
+        # ---- expert layers: the width of the counts a model that
+        # publishes ``expert_counts_width()`` returns from every
+        # program, else 0 (``expert_histogram``)
+        width = getattr(model, "expert_counts_width", None)
+        self._counts_width = 0 if width is None else int(width())
+        # ---- generation by diffusion over blocks: (block length, mask
+        # token id) of a model that publishes ``block_spec()``, else None
+        spec = getattr(model, "block_spec", None)
+        self._block: Optional[Tuple[int, int]] = None
+        if spec is not None:
+            spec = spec()
+            self._block = (int(spec["block_length"]),
+                           int(spec["mask_token_id"]))
+            blen = self._block[0]
+            if page_size % blen:
+                raise ValueError(
+                    f"page_size {page_size} is no multiple of the model's "
+                    f"block length {blen}: a block must not straddle a page")
+            # none of these falls back to a path that gives other tokens
+            if draft_model is not None:
+                raise ValueError(
+                    "draft_model= with a block-diffusion model: a step "
+                    "there commits a block of tokens every few forwards; "
+                    "a draft's token-by-token proposals have no verify "
+                    "program to go through")
+            if prefix_cache and not self.chunk:
+                raise ValueError(
+                    "prefix_cache=True with a block-diffusion model needs "
+                    "chunked prefill (prefill_chunk > 0): a shared prefix "
+                    "ends on a page boundary, which is a block boundary, "
+                    "and the rest of the prompt's whole blocks prefill "
+                    "from that cursor in chunks; the token-at-a-time "
+                    "suffix path of the decode step does not exist here")
+            if self.tp_degree > 1:
+                raise ValueError(
+                    f"tp_degree={self.tp_degree} with a block-diffusion "
+                    "model: the block step has no sharded program (its "
+                    "expert layer is told which experts it holds, and the "
+                    "exchange between holders is not built)")
+        if self._counts_width and draft_model is not None:
+            raise ValueError(
+                "draft_model= with a model that counts its expert "
+                "layers' assignments: the speculation programs do not "
+                "return them, so moe_assignments and moe_experts_touched "
+                "would undercount")
         self._tp_mesh = None
         self._tp_axis = "mp"
         pool_sharding = None
@@ -1063,6 +1160,16 @@ class ServingEngine:
         # the newest token of each slot that the HOST knows: the next
         # decode step's input unless the step in flight holds a newer one
         self._last_tok = np.zeros((max_batch,), np.int32)
+        # block model: the newest BLOCK of each slot that the host knows,
+        # or -1 throughout where the step in flight holds a newer one
+        self._blk = (None if self._block is None else np.full(
+            (max_batch, self._block[0]), self._block[1], np.int32))
+        self._block_fns: Dict[int, object] = {}     # bucket rung -> fn
+        # per-expert assignment counts of the block steps, kept on the
+        # device and read by expert_histogram(), never in the step
+        self._expert_hist = None
+        # set-up probe (record_blocks): rid -> the row's forwards
+        self._block_records: Optional[Dict[int, list]] = None
         # the decode step dispatched and not read yet (None: none)
         self._flying: Optional[_Flight] = None
         self._next_rid = 0
@@ -1135,7 +1242,9 @@ class ServingEngine:
                            if draft_model is not None else None)
         # telemetry binding is per-engine and resolved once here; the
         # replica id labels every series so fleet engines coexist
-        self._m = _EngineTelemetry(self.replica, str(self.tp_degree))
+        self._m = _EngineTelemetry(self.replica, str(self.tp_degree),
+                                   block=self._block is not None,
+                                   experts=bool(self._counts_width))
         # pool-ledger fragmentation memo: recompute only when the pool's
         # free-list epoch moved (steady-state decode never moves it)
         self._pool_frag_epoch = -1
@@ -1441,6 +1550,10 @@ class ServingEngine:
             self._callbacks[req.rid] = on_token
         self._slots[slot] = req
         self._last_tok[slot] = int(bundle["last_token"])
+        if self._block is not None:
+            # the block it was denoising starts again from masks (the
+            # cursor counts committed blocks only)
+            self._seat_block(req, slot)
         return req.rid
 
     # ------------------------------------------------- compiled programs
@@ -1635,6 +1748,22 @@ class ServingEngine:
         self.decode_key = self._decode_keys.get(bucket, self.decode_key)
         return fn
 
+    def _block_program(self, bucket: int):
+        """The block step for one bucket rung (a block-diffusion
+        model's batched step), compiled once per rung and cached like
+        the decode step's."""
+        fn = self._block_fns.get(bucket)
+        if fn is None:
+            from .program_cache import decode_program_cache
+            key = self._key("block_step", bucket=bucket, extra=self._block)
+            fn = decode_program_cache().get(key, functools.partial(
+                _build_block_step, model=self.model,  # keycheck: disable=KEY002 — the documented model-object closure (model_sig rides the key)
+                mask_id=self._block[1]))
+            self._block_fns[bucket] = fn
+            self._decode_keys[bucket] = key
+        self.decode_key = self._decode_keys.get(bucket, self.decode_key)
+        return fn
+
     # ----------------------------------------------------------- internals
     # What a request keeps per layer kind lives behind ``self._caches``
     # (and, for the draft model, ``self._draft``): the dispatch sites
@@ -1661,6 +1790,19 @@ class ServingEngine:
         self._caches.reset(slot)
         self._m.state_resets.inc(self._caches.state_rows)
 
+    def _adopt_prefix(self, req: Request, slot: int, pages: List[int],
+                      n_cached: int) -> None:
+        """Seat ``slot`` on the cached prompt pages, read-only, with its
+        cursor behind them."""
+        self.pool.adopt_shared(slot, pages)
+        if self._prefix is not None:
+            # pin count on adoption: evict() must never free pages an
+            # in-flight request's block table still points at
+            self._prefix.pin(pages)
+            req.pinned = [int(p) for p in pages]
+        self.pool.seq_lens[slot] = n_cached
+        self._m.shared_admits.inc()
+
     def _admit_shared(self, req: Request, slot: int, pages: List[int],
                       n_cached: int) -> None:
         """Prefix-cache admission: adopt the cached prompt pages
@@ -1673,13 +1815,7 @@ class ServingEngine:
         suffix, with chunking enabled, prefills from the adopted-prefix
         cursor in chunks instead — the chunk program natively starts at
         a nonzero position."""
-        self.pool.adopt_shared(slot, pages)
-        if self._prefix is not None:
-            # pin count on adoption: evict() must never free pages an
-            # in-flight request's block table still points at
-            self._prefix.pin(pages)
-            req.pinned = [int(p) for p in pages]
-        self.pool.seq_lens[slot] = n_cached
+        self._adopt_prefix(req, slot, pages, n_cached)
         suffix = req.prompt[n_cached:]
         self._caches.allocate(slot, len(suffix) + req.max_new_tokens)
         if self.chunk and len(suffix) > 2 * self.pool.page_size:
@@ -1690,7 +1826,6 @@ class ServingEngine:
             req.pending = [int(t) for t in suffix[1:]]
         req.slot = slot
         self._slots[slot] = req
-        self._m.shared_admits.inc()
 
     def _covers_enough(self, req: Request, n_cached: int) -> bool:
         """The monolithic-mode coverage threshold: the suffix replays
@@ -1737,6 +1872,8 @@ class ServingEngine:
         # REQUEST, not per token)  # tracecheck: disable=TRC007
         self._m.event("request.queued", req.t_submit, time.perf_counter(),
                       rid=req.rid, step=self._step_no)
+        if self._block is not None:
+            return self._admit_block(req, slot)
         replay = bool(req.tokens)
         if self._prefix is not None and not replay \
                 and self._hit_worth_taking(req):
@@ -1766,6 +1903,62 @@ class ServingEngine:
         self._prefill(req, slot, feed)
         return True         # monolithic prefill compute ran this step
 
+    def _admit_block(self, req: Request, slot: int) -> bool:
+        """Admission of a block-diffusion request. Prefill covers the
+        WHOLE BLOCKS of the feed (the prompt; on replay prompt + emitted
+        tokens, which is whole blocks by then) under the block-causal
+        mask and gives no token; what is left of the prompt, fewer than
+        a block, becomes the known part of the first generated block
+        (``_seat_block``). Shared prefix pages cover whole blocks (a
+        page is a multiple of a block), and the rest prefills from that
+        cursor in chunks."""
+        blen = self._block[0]
+        feed = self._admission_feed(req)
+        whole = len(feed) - len(feed) % blen
+        remaining = req.max_new_tokens - len(req.tokens)
+        n_cached = 0
+        if self._prefix is not None and not req.tokens:
+            pages, n_cached = self._prefix.lookup(
+                req.prompt, max_cover=len(req.prompt) - 1)
+            if pages:
+                self._adopt_prefix(req, slot, pages, n_cached)
+        # a page is whole blocks, so the span rounded up to whole blocks
+        # (the last block's surplus is dropped at emission) needs no
+        # page more than the span itself
+        self._caches.allocate(slot, len(feed) + remaining - n_cached)
+        if whole == n_cached:               # nothing left to prefill
+            self._seat_block(req, slot)
+            return False
+        if n_cached or (self.chunk and whole > self.chunk):
+            req.feed = feed[:whole]
+            req.prefill_pos = n_cached
+            req.slot = slot
+            self._slots[slot] = req
+            return False    # chunks dispatch one per step, not here
+        self._prefill(req, slot, feed[:whole])
+        return True
+
+    def _seat_block(self, req: Request, slot: int,
+                    register: bool = False) -> None:
+        """The row's whole blocks are cached: seat it at that cursor
+        with its first block to denoise, the feed's remainder known and
+        the rest masks. ``register``: a first prefill just wrote the
+        prompt's pages, which the prefix cache may now share."""
+        if register and self._prefix is not None:
+            self._prefix.register(req.prompt, self.pool.block_tables[slot])
+        blen, mask = self._block
+        feed = self._admission_feed(req)
+        whole = len(feed) - len(feed) % blen
+        self.pool.seq_lens[slot] = whole
+        self._blk[slot] = mask
+        self._blk[slot, :len(feed) - whole] = feed[whole:]
+        req.known = len(feed) - whole
+        req.masks = blen - req.known
+        req.prefill_pos = None
+        req.feed = None
+        req.slot = slot
+        self._slots[slot] = req
+
     def _prefill(self, req: Request, slot: int,
                  feed: Optional[np.ndarray] = None) -> None:
         """Monolithic b=1 whole-prompt prefill (prompts at or under the
@@ -1780,8 +1973,9 @@ class ServingEngine:
         # across engine instances over the same model
         fn = self._prefill_program()
 
-        remaining = req.max_new_tokens - len(req.tokens)
-        self._caches.allocate(slot, p + remaining)
+        if self._block is None:     # else _admit_block allocated the span
+            remaining = req.max_new_tokens - len(req.tokens)
+            self._caches.allocate(slot, p + remaining)
         self._reset_state(slot)
         bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
         # per-request prefill timeline span  # tracecheck: disable=TRC007
@@ -1789,18 +1983,25 @@ class ServingEngine:
                           step=self._step_no):
             pools = self._caches.take_caches()
             self._f_prefill.check()
-            tok, states = fn(self._params, self._buffers,
-                             jnp.asarray(feed[None]),
-                             pools, bt, jnp.zeros((1,), jnp.int32),
-                             *self._caches.slot_args(slot))
+            tok, states, *counts = fn(
+                self._params, self._buffers, jnp.asarray(feed[None]),
+                pools, bt, jnp.zeros((1,), jnp.int32),
+                *self._caches.slot_args(slot))
+            self._note_expert_counts(counts)
             # b=1 prefill wrote THROUGH slot's block table into the
             # shared pool arrays; adopt them and the slot's bookkeeping
             self._caches.install_caches(states)
-            tok = int(tok)              # the span owns the token pull
+            if self._block is None:
+                tok = int(tok)          # the span owns the token pull
         # once per admitted request  # tracecheck: disable=TRC007
         self._m.prefills.inc()
         # tracecheck: disable=TRC007
         self._m.prefill_tokens.inc(p)
+        if self._block is not None:
+            # the prompt's whole blocks are cached; no token comes of a
+            # prefill here (never pulled: the dispatch stays async)
+            self._seat_block(req, slot, register=not replay)
+            return
         if req.temperature > 0.0:
             # a sampled request never takes the prefill's greedy argmax:
             # park the cursor ONE position short with the last fed token
@@ -1853,15 +2054,16 @@ class ServingEngine:
             t0 = time.perf_counter()
             pools = self._caches.take_caches()
             self._f_chunk.check()
-            tok, states = fn(self._params, self._buffers,
-                             jnp.asarray(ids[None]), pools, bt, sl,
-                             jnp.int32(end - pos - 1),
-                             *self._caches.slot_args(slot))
+            tok, states, *counts = fn(
+                self._params, self._buffers, jnp.asarray(ids[None]), pools,
+                bt, sl, jnp.int32(end - pos - 1),
+                *self._caches.slot_args(slot))
+            self._note_expert_counts(counts)
             self._caches.install_caches(states)
             self.pool.seq_lens[slot] = end
             req.prefill_pos = end
             self.chunk_dispatches += 1
-            if last:
+            if last and self._block is None:
                 # designed sync: the first generated token. A non-final
                 # argmax is garbage-padded and never pulled, so that
                 # dispatch stays async
@@ -1871,6 +2073,9 @@ class ServingEngine:
         if not last:
             return
         replay = bool(req.tokens)
+        if self._block is not None:
+            self._seat_block(req, slot, register=not replay)
+            return
         if req.temperature > 0.0:
             # sampled request: discard the final chunk's greedy argmax
             # and park the cursor one short (see _prefill) — the spec
@@ -1938,6 +2143,7 @@ class ServingEngine:
         req.slot = None
         req.bypassed = 0
         req.in_flight = 0
+        req.masks = req.known = 0
 
     def _emit(self, req: Request, tok: Optional[int],
               done: bool = False) -> None:
@@ -2245,7 +2451,12 @@ class ServingEngine:
         chunked admissions are cursor-only host bookkeeping."""
         if self._shared_adopt_pages(req):
             return False
-        if self.chunk and len(req.prompt) + len(req.tokens) > self.chunk:
+        n = len(req.prompt) + len(req.tokens)
+        if self._block is not None:
+            n -= n % self._block[0]         # whole blocks prefill
+            if not n:
+                return False
+        if self.chunk and n > self.chunk:
             return False
         return True
 
@@ -2356,6 +2567,8 @@ class ServingEngine:
                         # the draft pool mirrors the target's slot layout
                         self._draft.move(s, dst)
                     self._last_tok[dst] = self._last_tok[s]
+                    if self._blk is not None:
+                        self._blk[dst] = self._blk[s]
                     self._slots[dst] = req
                     self._slots[s] = None
                     req.slot = dst
@@ -2886,6 +3099,11 @@ class ServingEngine:
             self._observe_step_end()
             return
 
+        if self._block is not None:
+            self._block_step(decode_rows)
+            self._observe_step_end()
+            return
+
         b = self.bucket
         fn = self._decode_program(b)
         self._observe_decode(decode_rows)
@@ -2924,10 +3142,11 @@ class ServingEngine:
             with self._m.annotation("engine.decode.dispatch"):
                 # with nothing in flight every row's token is in
                 # ``feed``, which then stands in for the last output
-                toks, states = fn(
+                toks, states, *counts = fn(
                     self._params, self._buffers,
                     (feed if flying is None else flying.toks, feed),
                     pools, bt, sl, *extra)
+                self._note_expert_counts(counts)
                 self._caches.install_caches(states)
             # the host's state advances from counts, at the dispatch:
             # every cursor by one, a forced prompt suffix by one token
@@ -2963,6 +3182,180 @@ class ServingEngine:
                 self._settle("speculation")
         self._observe_step_end()
 
+    # --------------------------------------------------- the block step
+    def _block_step(self, rows: List[Request]) -> None:  # tracecheck: hotpath
+        """One forward of every row's current block (a block-diffusion
+        model's batched step). A row with masked positions left runs a
+        DENOISING forward: on the device, per masked position the argmax
+        token and its softmax probability, the most confident revealed.
+        A row with none left runs the COMMIT forward: its K/V stay, its
+        cursor advances by the block and the block's tokens go to the
+        host one step late, behind the next dispatch. Which it is the
+        host knows from counts, without reading a token."""
+        n = self._step_no
+        blen, mask = self._block
+        b = self.bucket
+        fn = self._block_program(b)
+        self._observe_decode(rows)
+        flying = self._flying
+        # tracecheck: disable=TRC007
+        with self._m.span("engine.block_step", step=n, active=len(rows),
+                          bucket=b, overlapped=flying is not None):
+            with self._m.annotation("engine.block.stage"):
+                # a row's block is the host's word on it, or -1s for
+                # "what the step in flight leaves" (still on the device)
+                feed = self._blk[:b].copy()
+                commit = np.zeros((b,), np.int32)
+                for req in rows:
+                    if not req.masks:
+                        commit[req.slot] = 1
+                tables, cursors = self._caches.decode_inputs(b, ())
+                bt, sl, commit_d, feed_d = jax.device_put(
+                    [tables, cursors, commit, feed])
+                hist = self._expert_counts()
+                t0 = time.perf_counter()
+                pools = self._caches.take_caches()
+                self._f_decode.check()
+            with self._m.annotation("engine.block.dispatch"):
+                blocks, conf, states, *hist = fn(
+                    self._params, self._buffers,
+                    (feed_d if flying is None else flying.toks, feed_d),
+                    pools, bt, sl, commit_d, *hist)
+                self._caches.install_caches(states)
+                if hist:
+                    self._expert_hist = hist[0]
+            if self._block_records is not None:
+                self._record_blocks(rows, feed, cursors, blocks, conf,
+                                    flying)
+            # the host's state advances from counts, at the dispatch
+            done, new_tokens = [], 0
+            for req in rows:
+                slot = req.slot
+                if req.masks:
+                    req.masks -= 1
+                    self._blk[slot] = -1        # the device's is newer
+                    continue
+                self.pool.seq_lens[slot] += blen
+                req.in_flight += blen - req.known
+                new_tokens += blen - req.known
+                done.append((slot, req, req.known))
+                # the next block starts as masks, which the host knows
+                req.known, req.masks = 0, blen
+                self._blk[slot] = mask
+            # three counter writes a step: what the step, its rows and
+            # their tokens were, where the dispatch is made
+            m = self._m
+            m.block_forwards.inc(len(rows))  # tracecheck: disable=TRC007
+            if done:
+                m.block_commits.inc(len(done))  # tracecheck: disable=TRC007
+                m.block_tokens.inc(new_tokens)  # tracecheck: disable=TRC007
+            self._flying = _Flight(blocks, done, t0)
+            if flying is not None:
+                self._read(flying)
+
+    def _emit_blocks(self, flight: _Flight, blocks: np.ndarray, now: float,
+                     level: bool) -> None:
+        """A read block step: emit the committed blocks' tokens (less
+        the prompt tokens a first block began with, and less the
+        surplus over a request's budget), and with nothing dispatched
+        behind it (``level``) take every block the device held newer
+        than the host back into the host's copy."""
+        if level:
+            stale = self._blk[:len(blocks), 0] < 0
+            self._blk[:len(blocks)][stale] = blocks[stale]
+        for slot, req, known in flight.rows:
+            if req.slot != slot:
+                continue            # ended by EOS a commit earlier
+            req.in_flight -= blocks.shape[1] - known
+            if self._prefix is not None and not req.tokens:
+                self._prefix.register(req.prompt,
+                                      self.pool.block_tables[slot])
+            n0 = len(req.tokens)
+            for tok in blocks[slot, known:]:
+                if len(req.tokens) >= req.max_new_tokens:
+                    break           # the last block's surplus
+                self._observe_token(req, now)
+                req.tokens.append(int(tok))
+                self._emit(req, int(tok))
+                if tok == req.eos_token_id:
+                    break
+            # tracecheck: disable=TRC007
+            self._m.event("request.block_commit", flight.t0, now,
+                          parent=self._step_span, rid=req.rid,
+                          tokens=len(req.tokens) - n0, step=self._step_no)
+            self._finish_if_done(req)
+
+    def record_blocks(self, rids) -> None:
+        """Set-up probe: from now on keep, for the requests ``rids``,
+        every forward of their blocks: the cursor, the block's ids
+        before the forward and after it, the log-confidence the step
+        computed for every position of the block (the reveal takes the
+        largest among the masked), and whether it committed
+        (``block_records``). Each recorded step is read at once, so
+        this is for checks, not for a timed window; ``rids`` None ends
+        it."""
+        self._block_records = (None if rids is None
+                               else {int(r): [] for r in rids})
+
+    def block_records(self) -> Dict[int, list]:
+        """What :meth:`record_blocks` kept so far: rid -> a list of
+        ``dict(cursor, before, after, log_conf, commit)`` in forward
+        order."""
+        return dict(self._block_records or {})
+
+    def _record_blocks(self, rows, feed, cursors, blocks, conf,
+                       flying) -> None:
+        last = None if flying is None else np.asarray(flying.toks)
+        after, conf = np.asarray(blocks), np.asarray(conf)
+        for req in rows:
+            rec = self._block_records.get(req.rid)
+            if rec is None:
+                continue
+            before = feed[req.slot]
+            if before[0] < 0:
+                before = last[req.slot]
+            rec.append(dict(cursor=int(cursors[req.slot]),
+                            before=before.copy(),
+                            after=after[req.slot].copy(),
+                            log_conf=conf[req.slot].copy(),
+                            commit=not req.masks))
+
+    def _expert_counts(self) -> tuple:
+        """``(hist,)``: the device array a step's expert counts
+        accumulate in (``model.expert_counts_width()`` wide), made on
+        first use; ``()`` for a model without expert layers."""
+        if not self._counts_width:
+            return ()
+        if self._expert_hist is None:
+            self._expert_hist = jnp.zeros((self._counts_width,), jnp.int32)
+        return (self._expert_hist,)
+
+    def _note_expert_counts(self, counts) -> None:
+        """A prefill or decode program of a model with expert layers
+        returned its counts: add them on the device (one small dispatch
+        a call; the block step accumulates inside its own program)."""
+        if counts:
+            self._expert_hist = (counts[0] if self._expert_hist is None
+                                 else self._expert_hist + counts[0])
+
+    def expert_histogram(self) -> Optional[np.ndarray]:
+        """The assignments each held expert got since the last call,
+        (experts held,), from every program that ran the expert layers
+        — read from the device HERE (a sync), never in the step — then
+        zeroed. The counters move by what was read: ``moe_assignments``
+        by their sum, ``moe_experts_touched`` by the (call, layer,
+        expert) triples that got at least one assignment, which is how
+        many experts' weights the grouped matmuls had to read. None
+        where nothing was counted (always, for a model without expert
+        layers)."""
+        if self._expert_hist is None:
+            return None
+        hist = np.asarray(self._expert_hist)
+        self._expert_hist = None
+        self._m.moe_assignments.inc(int(hist[:-1].sum()))
+        self._m.moe_experts_touched.inc(int(hist[-1]))
+        return hist[:-1]
+
     # --------------------------------------------- the step in flight
     def _values_first(self, reason: str) -> None:
         """The scheduler is about to move or end a seated row: not
@@ -2982,19 +3375,26 @@ class ServingEngine:
             return
         self._m.decode_settles(reason).inc()
         if read:
-            self._read(flying)
+            self._read(flying, level=True)
         else:
-            for _slot, req in flying.rows:
-                req.in_flight = 0
+            for row in flying.rows:
+                row[1].in_flight = 0
 
-    def _read(self, flight: _Flight) -> None:  # tracecheck: hotpath
+    def _read(self, flight: _Flight, level: bool = False) -> None:  # tracecheck: hotpath
         """Read one dispatched decode step's tokens and emit them: the
-        scheduler's one sync with the device."""
+        scheduler's one sync with the device. ``level``: nothing was
+        dispatched behind it, so the host's copies come level with the
+        device (a block model's blocks)."""
         with self._m.annotation("engine.decode.pull"):
             # admission/eviction need the concrete token ids
             # tracecheck: disable=TRC002
             toks = np.asarray(flight.toks)
         now = time.perf_counter()
+        if self._block is not None:
+            # tracecheck: disable=TRC007
+            with self._m.span("engine.emit", step=self._step_no):
+                self._emit_blocks(flight, toks, now, level)
+            return
         if self.tp_degree > 1:
             # sharded dispatch envelope: compute + the per-layer psum
             # pair, observed host-side OUTSIDE the shard_map body
@@ -3083,9 +3483,11 @@ class ServingEngine:
         m.decode_live_tokens.inc(
             int(sum(self.pool.seq_lens[r.slot] for r in rows)))
         # every row of the rung rides the kernel, decoding or not (an
-        # idle slot reads the null page, a mid-prefill row its cursor)
+        # idle slot reads the null page, a mid-prefill row its cursor);
+        # with the step's own token, or block of them
         b, page = self.bucket, self.pool.page_size
-        held = -(-(self.pool.seq_lens[:b] + 1) // page)
+        own = 1 if self._block is None else self._block[0]
+        held = -(-(self.pool.seq_lens[:b] + own) // page)
         m.decode_read_pages.inc(int(held.sum()))
         m.decode_table_pages.inc(b * self.pool.block_tables.shape[1])
 
@@ -3246,18 +3648,33 @@ class ServingEngine:
 # pool-shaped copy
 # (tests/test_chip_compile.py::test_serving_program_copies_no_pool).
 
-def _build_prefill(note_trace, model):
+def _forward_with_cache(model, params, buffers, ids, states, offset):
+    """``model.forward_with_cache`` traced functionally: ``(logits,
+    states, counts)``. ``counts`` is ``()``, or for a model that
+    publishes ``expert_counts_width()`` the 1-tuple of what its expert
+    layers counted in this call: the program returns it as one value
+    more, and the engine accumulates it on the device."""
     from ..jit import functional_call
+    if not hasattr(model, "expert_counts_width"):
+        return functional_call(
+            model, params, ids, states, offset, buffers=buffers,
+            method="forward_with_cache") + ((),)
+    logits, states, counts = functional_call(
+        model, params, ids, states, offset, buffers=buffers,
+        method="forward_with_cache", expert_counts=True)
+    return logits, states, (counts,)
 
+
+def _build_prefill(note_trace, model):
     def serving_prefill(params, buffers, ids, pools, bt, sl, *slot):
         # ``slot``: only for a recurrent model, the state row to use
         note_trace()
         states = cache_entries(model, pools, PagedDecodeState, bt, sl,
                                **({"slot": slot[0]} if slot else {}))
-        logits, states = functional_call(
-            model, params, ids, states, jnp.int32(0),
-            buffers=buffers, method="forward_with_cache")
-        return (jnp.argmax(logits[0, -1].astype(jnp.float32)), states)
+        logits, states, counts = _forward_with_cache(
+            model, params, buffers, ids, states, jnp.int32(0))
+        return (jnp.argmax(logits[0, -1].astype(jnp.float32)), states,
+                *counts)
 
     return jax.jit(serving_prefill, donate_argnums=(3,))
 
@@ -3273,7 +3690,6 @@ def _build_chunk_prefill(note_trace, model):
     real rows and ``last_idx`` picks the real tail's logits). The argmax
     return is meaningful only on the final chunk — earlier dispatches
     never pull it, so they stay async."""
-    from ..jit import functional_call
     from ..kernels.paged_attention import PagedChunkState
 
     def serving_prefill_chunk(params, buffers, ids, pools, bt, sl, last_idx,
@@ -3285,11 +3701,10 @@ def _build_chunk_prefill(note_trace, model):
         states = cache_entries(
             model, pools, PagedChunkState, bt, sl,
             **({"slot": slot[0], "n_valid": last_idx + 1} if slot else {}))
-        logits, states = functional_call(
-            model, params, ids, states, sl[0],
-            buffers=buffers, method="forward_with_cache")
+        logits, states, counts = _forward_with_cache(
+            model, params, buffers, ids, states, sl[0])
         return (jnp.argmax(logits[0, last_idx].astype(jnp.float32)),
-                states)
+                states, *counts)
 
     return jax.jit(serving_prefill_chunk, donate_argnums=(3,))
 
@@ -3309,8 +3724,6 @@ def _step_tokens(toks):
 def _build_generic_decode(note_trace, model):
     """The unfused decode step: one functional_call through the model's
     forward_with_cache (every layer an op chain XLA schedules)."""
-    from ..jit import functional_call
-
     def serving_decode_generic(params, buffers, toks, pools, bt, sl, *live):
         # ``live``: only for a recurrent model, the rows that advance
         note_trace()
@@ -3318,13 +3731,52 @@ def _build_generic_decode(note_trace, model):
         states = cache_entries(model, pools, PagedDecodeState, bt, sl,
                                **({"live": live[0]} if live else {}))
         # offset=None -> per-slot positions from states.seq_lens
-        logits, states = functional_call(
-            model, params, toks, states, None,
-            buffers=buffers, method="forward_with_cache")
+        logits, states, counts = _forward_with_cache(
+            model, params, buffers, toks, states, None)
         return (jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1),
-                states)
+                states, *counts)
 
     return jax.jit(serving_decode_generic, donate_argnums=(3,))
+
+
+def _build_block_step(note_trace, model, mask_id):
+    """The block step of a block-diffusion model: every row's block of
+    B token ids through ``forward_with_cache`` over ``PagedBlockState``
+    (the block's K/V written at the row's cursor, all B queries
+    attending cursor + B positions), then the published reveal on the
+    device: per masked position the argmax token and the log of its
+    softmax probability, the most confident masked position of each row
+    takes its token. A row with no masked position (a commit forward)
+    comes back as it went in; beside the blocks the program returns the
+    (b, B) log-confidences it chose by, which only the set-up probe
+    reads (``record_blocks``). ``blocks = (last, feed)`` as the decode
+    step's ``toks``: ``feed`` is the host's word on a row's block, -1s
+    for "the last step's output", which the host may not have read.
+    ``hist``: only for a model with expert layers, what their counts
+    accumulate in; it comes back as one value more."""
+    def serving_block_step(params, buffers, blocks, pools, bt, sl, commit,
+                           *hist):
+        note_trace()
+        last, feed = blocks
+        ids = jnp.where(feed >= 0, feed, last)              # (b, B)
+        states = cache_entries(
+            model, pools,
+            lambda k, v, bt_, sl_: PagedBlockState(k, v, bt_, sl_, commit),
+            bt, sl)
+        # offset=None -> per-row positions from states.seq_lens
+        logits, states, counts = _forward_with_cache(
+            model, params, buffers, ids, states, None)
+        logits = logits.astype(jnp.float32)                 # (b, B, V)
+        token = jnp.argmax(logits, axis=-1).astype(ids.dtype)
+        log_conf = (jnp.max(logits, axis=-1)
+                    - jax.nn.logsumexp(logits, axis=-1))
+        masked = ids == mask_id
+        pick = jnp.argmax(jnp.where(masked, log_conf, -jnp.inf), axis=1)
+        here = masked & (jnp.arange(ids.shape[1])[None, :] == pick[:, None])
+        return (jnp.where(here, token, ids), log_conf, states,
+                *(h + c for h, c in zip(hist, counts)))
+
+    return jax.jit(serving_block_step, donate_argnums=(3,))
 
 
 def _spec_filtered_probs(rows, temperature, top_k, top_p):

@@ -11,7 +11,12 @@ one-hot dispatch/combine einsums with a fixed expert capacity, fully
 differentiable and shape-static so XLA tiles it onto the MXU and inserts
 the token<->expert all-to-all from shardings (experts sharded over a mesh
 axis, tokens over dp).
+
+Serving takes the other formulation: :class:`DroplessMoE`
+(``dropless.py``) sorts tokens by expert and runs one grouped matmul a
+projection, with no capacity and no dropped token.
 """
 
+from .dropless import DroplessMoE, dropless_moe  # noqa: F401
 from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate  # noqa: F401
 from .moe_layer import Experts, MoELayer, top_k_dispatch  # noqa: F401

@@ -92,10 +92,41 @@ class PagedChunkState(NamedTuple):
     seq_lens: Any
 
 
+class PagedBlockState(NamedTuple):
+    """The block-diffusion step's twin of :class:`PagedDecodeState`: every
+    row of the batch runs a BLOCK of S query tokens at positions
+    ``seq_lens .. seq_lens+S-1``. The block's K/V are written there (a
+    later forward of the same block overwrites them) and all S queries
+    attend every cached token and the whole block, ``seq_lens + S``
+    positions: within a block attention is full, so the decode kernel
+    serves it with ``S`` times as many query rows a KV head. ``commit``
+    (B,) int32 says which rows the step finishes: the returned
+    ``seq_lens`` advance by S there and stay elsewhere (a row that is
+    still denoising runs its block again from the same cursor).
+
+    ``seq_lens`` are multiples of S and the page size is too, so a
+    block never straddles a page."""
+    k_pages: Any
+    v_pages: Any
+    block_tables: Any
+    seq_lens: Any
+    commit: Any
+
+
 def is_paged_state(entry) -> bool:
-    """Static (trace-time) test for either paged-cache state flavor —
+    """Static (trace-time) test for any paged-cache state flavor —
     the dispatch models use to route attention onto the paged path."""
-    return isinstance(entry, (PagedDecodeState, PagedChunkState))
+    return isinstance(entry, (PagedDecodeState, PagedChunkState,
+                              PagedBlockState))
+
+
+def _block_bits(block: int) -> int:
+    """``block - 1`` for a power-of-two block length: a query at ``q``
+    sees keys up to ``q | (block - 1)``, the end of its own block (1 is
+    plain causal attention)."""
+    if block < 1 or block & (block - 1):
+        raise ValueError(f"block length must be a power of two, got {block}")
+    return block - 1
 
 
 def _interpret() -> bool:
@@ -420,7 +451,8 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, seq_lens,
 # -------------------------------------- chunk-native prefill attention
 def _paged_chunk_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
                         sm_scale: float, page_size: int, s_chunk: int,
-                        rows: int, max_pages: int, quant: bool = False):
+                        rows: int, max_pages: int, quant: bool = False,
+                        block_bits: int = 0):
     if quant:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -456,13 +488,14 @@ def _paged_chunk_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
             preferred_element_type=jnp.float32) * sm_scale
         # row r holds (rep head r // s_chunk, chunk token r % s_chunk);
         # its query sits at absolute position start + r % s_chunk and
-        # sees every pool position up to and including itself
+        # sees every pool position up to and including itself — or,
+        # block-causal, up to the end of its own block
         r_iota = jax.lax.broadcasted_iota(
             jnp.int32, (rows_pad, page_size), 0)
         q_pos = start + jax.lax.rem(r_iota, s_chunk)
         kv_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (rows_pad, page_size), 1)
-        s = jnp.where(kv_pos <= q_pos, s, _NEG_INF)
+        s = jnp.where(kv_pos <= (q_pos | block_bits), s, _NEG_INF)
 
         m_prev = m_ref[:, 0:1]
         l_prev = l_ref[:, 0:1]
@@ -488,7 +521,8 @@ def _paged_chunk_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
 def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, block_tables: jax.Array,
                           start: jax.Array,
-                          sm_scale: Optional[float] = None) -> jax.Array:
+                          sm_scale: Optional[float] = None,
+                          block: int = 1) -> jax.Array:
     """Chunked-prefill attention read straight through the block table —
     the copy-free replacement for ``gather_paged_view`` +
     ``cached_attention`` on the chunk hot path (the r12 leftover).
@@ -504,6 +538,10 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
 
     q:     (B, S, H, D) — the chunk's queries
     start: (B,) int32   — written length BEFORE this chunk (the cursor)
+    block: 1 is causal; a power of two B is BLOCK-causal (block
+           diffusion): a query sees every key up to the end of its own
+           block of B positions, ``k <= q | (B - 1)``. ``start`` and S
+           are then multiples of B, so the chunk holds whole blocks.
     Returns (B, S, H, D) in q's dtype. Rows past the real prompt tail
     (final-chunk padding) emit garbage the caller discards.
     """
@@ -547,7 +585,8 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
     out = pl.pallas_call(
         functools.partial(_paged_chunk_kernel, sm_scale=float(sm_scale),
                           page_size=page_size, s_chunk=s, rows=rows,
-                          max_pages=max_pages, quant=quant),
+                          max_pages=max_pages, quant=quant,
+                          block_bits=_block_bits(block)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, max_pages),
@@ -577,7 +616,7 @@ _CHUNK_GROUP_KEYS = 128
 
 
 def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, start,
-                              sm_scale=None):
+                              sm_scale=None, block: int = 1):
     """Copy-free XLA twin of :func:`paged_chunk_attention` (CPU tests,
     and the fallback wherever pallas is off): ``lax.fori_loop`` over
     page GROUPS with online softmax, so the live workspace is one
@@ -605,6 +644,7 @@ def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, start,
     qg = (q.astype(jnp.float32) * sm_scale).transpose(0, 2, 1, 3)
     qg = qg.reshape(b, hkv, rep, s, d)
     q_pos = st[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (B, S)
+    q_pos = q_pos | _block_bits(block)      # block-causal: the block's end
 
     def body(j, carry):
         acc, m, l = carry
@@ -813,6 +853,45 @@ def _prompt_write(k_pages, v_pages, k_new, v_new, bt, st, *, interpret):
     return _page_write(k_pages, v_pages, staged(k_new), staged(v_new),
                        _table_pages(bt, page_idx), lo, hi,
                        name="paged_prompt_write", interpret=interpret)
+
+
+# its jnp twin is write_paged_prompt_at_xla: a block is a short prompt at
+# an offset  # kernelcheck: disable=KRN006
+def write_paged_block(k_pages, v_pages, k_new, v_new, block_tables, start):
+    """The block step's write: k_new/v_new (B, S, Hkv, D) land at
+    positions [start, start+S) of each row, S a power of two that
+    divides the page size and ``start`` (B,) a multiple of it, so each
+    row writes ONE page. On the TPU (plain pools) the page-write kernel
+    over a one-page grid, the block tiled over the page's rows (row
+    ``r`` takes token ``r % S``, which is the token it holds where it
+    lies in ``[start % page, start % page + S)``); elsewhere, and for
+    ``QuantizedPages`` pools, the scatter: a block is a short prompt at
+    an offset."""
+    if not _write_kernel_applies(k_pages):
+        return write_paged_prompt_at_xla(k_pages, v_pages, k_new, v_new,
+                                         block_tables, start)
+    return _block_write(k_pages, v_pages, k_new, v_new,
+                        jnp.asarray(block_tables, jnp.int32),
+                        jnp.asarray(start, jnp.int32),
+                        interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _block_write(k_pages, v_pages, k_new, v_new, bt, st, *, interpret):
+    b, s, hkv, d = k_new.shape
+    page_size = k_pages.shape[2]
+    if page_size % s:
+        raise ValueError(f"block of {s} does not divide the page size "
+                         f"{page_size}")
+    off = (st % page_size)[:, None]
+
+    def tiled(x):
+        return jnp.tile(jnp.swapaxes(x, 1, 2), (1, 1, page_size // s, 1))
+
+    return _page_write(k_pages, v_pages, tiled(k_new), tiled(v_new),
+                       _table_pages(bt, (st // page_size)[:, None]),
+                       off, off + s, name="paged_block_write",
+                       interpret=interpret)
 
 
 def padded_head_dim(head_dim: int) -> int:

@@ -709,7 +709,8 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
     return out, None
 
 
-def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
+def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
+                                       block=1):
     """Paged (block-table) variant of the decode attention (reference:
     block_multihead_attention's two phases). ``state`` is a per-layer
     :class:`~paddle_tpu.kernels.paged_attention.PagedDecodeState` or —
@@ -732,16 +733,27 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
     attends against the pool through the Pallas block-table kernel (XLA
     gather fallback when pallas is off). Returns ``(out, new_state)``.
 
+    Block step (``PagedBlockState``, block diffusion): every row's S
+    tokens are one block at ``seq_lens .. seq_lens+S-1``; they write
+    there and all attend ``seq_lens + S`` positions — the decode kernel
+    with S times as many query rows a KV head. The returned ``seq_lens``
+    advance by S only where ``state.commit`` is set.
+
     ``scale``: the softmax scale handed to the kernels as ``sm_scale``
     (q is never pre-scaled in its own dtype); None is ``1 / sqrt(D)`` of
-    the head's own width."""
+    the head's own width. ``block``: a power of two B makes both prefill
+    phases BLOCK-causal (a query sees keys up to the end of its own
+    block of B, ``k <= q | (B - 1)``), read through the block table as
+    the chunked phase is; the prompt then holds whole blocks."""
     from .. import flags
     from ..kernels.decode_attention import cached_attention
-    from ..kernels.paged_attention import (PagedChunkState, QuantizedPages,
+    from ..kernels.paged_attention import (PagedBlockState, PagedChunkState,
+                                           QuantizedPages,
                                            paged_attention,
                                            paged_attention_xla,
                                            paged_chunk_attention,
                                            paged_chunk_attention_xla,
+                                           write_paged_block,
                                            write_paged_kv,
                                            write_paged_prompt,
                                            write_paged_prompt_at)
@@ -749,6 +761,7 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
     use_pallas = (flags.snapshot(("use_pallas",)).use_pallas
                   and flags.is_tpu_backend())
     chunked = isinstance(state, PagedChunkState)
+    block_step = isinstance(state, PagedBlockState)
 
     # a quantized pool reaches here as a NamedTuple whose FIELDS were
     # Tensor-wrapped by functional_call's tree walk (the tuple itself is
@@ -758,7 +771,7 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
             return QuantizedPages(_val(p.q), _val(p.scale))
         return p
 
-    def fn(qv, kv, vv, kp, vp, bt, sl):
+    def fn(qv, kv, vv, kp, vp, bt, sl, *commit):
         s = qv.shape[1]
         d = qv.shape[-1]
         if kp.shape[-1] != d:
@@ -771,7 +784,20 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
         else:
             qp, kvp, vvp = qv, kv, vv
         sm_scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
-        if s > 1 and chunked:
+        if block_step:
+            kp2, vp2 = write_paged_block(kp, vp, kvp, vvp, bt, sl)
+            # (B, S, H, D) -> (B, Hkv * S * rep, D): the S queries of a
+            # KV head's rep query heads are that head's query rows
+            b, _, h, dp = qp.shape
+            hkv = kp.shape[0]
+            rows = qp.reshape(b, s, hkv, h // hkv, dp).transpose(
+                0, 2, 1, 3, 4).reshape(b, hkv * s * (h // hkv), dp)
+            attend = paged_attention if use_pallas else paged_attention_xla
+            out = attend(rows, kp2, vp2, bt, sl + s, sm_scale=sm_scale)
+            out = out.reshape(b, hkv, s, h // hkv, dp).transpose(
+                0, 2, 1, 3, 4).reshape(b, s, h, dp)[..., :d]
+            sl2 = sl + s * commit[0].astype(sl.dtype)
+        elif s > 1 and (chunked or block > 1):
             if qv.shape[0] != 1:
                 raise NotImplementedError(
                     "chunked paged prefill is per-request (B = 1); got "
@@ -785,7 +811,8 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
             # view is ever materialized.
             attend = (paged_chunk_attention if use_pallas
                       else paged_chunk_attention_xla)
-            out = attend(qp, kp2, vp2, bt, sl, sm_scale=sm_scale)[..., :d]
+            out = attend(qp, kp2, vp2, bt, sl, sm_scale=sm_scale,
+                         block=block)[..., :d]
             sl2 = sl + s
         elif s > 1:
             # whole-prompt prefill contract: the sequences must be
@@ -814,8 +841,9 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None):
     out, kp2, vp2, sl2 = apply_op(
         "paged_sdpa", fn, query, key, value,
         _raw_pages(state.k_pages), _raw_pages(state.v_pages),
-        state.block_tables, state.seq_lens)
-    return out, type(state)(kp2, vp2, state.block_tables, sl2)
+        state.block_tables, state.seq_lens,
+        *((state.commit,) if block_step else ()))
+    return out, type(state)(kp2, vp2, state.block_tables, sl2, *state[4:])
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -42,6 +42,20 @@ TINY = dataclasses.replace(
         program=dict(config_class="GraniteHybridConfig",
                      model_class="GraniteHybridForCausalLM"),
         serve=dict(max_batch=2, page_size=8, max_seq_len=64,
+                   prefill_chunk=16)),
+    sdar_prompt_len=27, sdar_new_tokens=9,
+    sdar=dict(
+        name="sdar-30b-a3b-chat", arch="sdar_moe", dtype="float32",
+        model=dict(vocab_size=256, hidden_size=64, num_hidden_layers=2,
+                   num_attention_heads=4, num_key_value_heads=2,
+                   head_dim=16, moe_intermediate_size=32, num_experts=8,
+                   num_experts_per_tok=2, norm_topk_prob=True,
+                   max_position_embeddings=512, rms_norm_eps=1e-6,
+                   rope_theta=1000000, block_length=4, mask_token_id=250,
+                   initializer_range=0.3),
+        program=dict(config_class="SDARMoEConfig",
+                     model_class="SDARMoEForCausalLM"),
+        serve=dict(max_batch=2, page_size=8, max_seq_len=64,
                    prefill_chunk=16)))
 
 
@@ -57,8 +71,8 @@ def cache_env(monkeypatch):
 def test_one_chip_phases_at_tiny_size():
     lines = chip_smoke.run_phases(TINY)
     assert [ln["phase"] for ln in lines] == [
-        "device", "train", "serve", "fused_decode", "hybrid"]
-    device, train, serve, fused, hybrid = lines
+        "device", "train", "serve", "fused_decode", "hybrid", "blocks"]
+    device, train, serve, fused, hybrid, blocks = lines
     assert device["platform"] == "cpu" and device["peak_flops"] is None
     assert train["traces"] == 1 and train["losses"][-1] < train["losses"][0]
     # no Pallas custom call can exist on the CPU — and none is claimed
@@ -72,6 +86,12 @@ def test_one_chip_phases_at_tiny_size():
     assert hybrid["chunk_dispatches"] == 2 and hybrid["near_ties"] == 0
     assert len(hybrid["tokens"]) == 4 and hybrid["statuses"] == "OK"
     assert hybrid["ssm_update_custom_call"] is False     # the jnp twin
+    # 24 whole-block tokens through a full and a padded chunk, 3 known in
+    # the first block: (1 + 4 + 4) denoising and 3 commit forwards
+    assert blocks["chunk_dispatches"] == 2 and blocks["near_ties"] == 0
+    assert blocks["forwards"] == 12 and blocks["reveals"] == 9
+    assert len(blocks["tokens"]) == 9 and blocks["step_kind"] == "block_step"
+    assert not any(blocks["kernels"].values())           # the jnp twins
 
 
 def test_four_chip_phase_on_virtual_devices():

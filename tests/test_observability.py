@@ -644,6 +644,121 @@ class TestStepSpans:
         assert not (RING_ONLY & in_trace)
 
 
+# -------------------------------------------- the block step's telemetry
+BLOCK_SPANS = {"engine.block_step", "request.block_commit"}
+BLOCK_COUNTERS = {"serving_block_forwards", "serving_block_commits",
+                  "serving_block_tokens", "moe_assignments",
+                  "moe_experts_touched"}
+
+
+class TestBlockStepTelemetry:
+    def test_a_block_step_leaves_its_spans_and_counters(self, tmp_path):
+        """Two requests of a block-diffusion model: a prompt of 6 (one
+        whole block, 2 known in the first generated block) for 5 tokens
+        and a prompt of 8 for 4. Row-forwards, commits and tokens by
+        hand; the shared decode counters keep their meaning."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        from paddle_tpu.models import SDARMoEConfig, SDARMoEForCausalLM
+
+        paddle.seed(91)
+        cfg = SDARMoEConfig.tiny()
+        eng = ServingEngine(SDARMoEForCausalLM(cfg), max_batch=2,
+                            page_size=8, max_seq_len=32)
+        rng = np.random.default_rng(3)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            rids = [eng.submit(rng.integers(0, 100, (n,)).astype(np.int32),
+                               new) for n, new in ((6, 5), (8, 4))]
+            out = eng.run()
+            hist = eng.expert_histogram()
+        finally:
+            jax.profiler.stop_trace()
+        assert [len(out[r]) for r in rids] == [5, 4]
+        snap = obs.registry().snapshot()
+        value = lambda name: metric(snap, name)["value"]  # noqa: E731
+        # request 0: blocks of 2 + 4 new tokens (the second's surplus of
+        # one is dropped at emission): (2 + 1) + (4 + 1) forwards;
+        # request 1: one block of 4: 4 + 1
+        assert value("serving_block_forwards") == 3 + 5 + 5
+        assert value("serving_block_commits") == 3
+        assert value("serving_block_tokens") == 2 + 4 + 4
+        assert value("serving_decode_rows") == 13
+        assert value("serving_decode_slots") \
+            == 2 * value("serving_decode_steps")
+        assert value("serving_prefill_tokens") == 4 + 8
+        # every row of the rung routes, and prefill's tokens: (slots x
+        # block + prompt tokens) x top-k x layers
+        assert value("moe_assignments") == hist.sum() \
+            == (value("serving_decode_slots") * 4 + 4 + 8) * 2 * 2
+        calls = value("serving_decode_steps") + 2
+        assert 2 * 2 * calls <= value("moe_experts_touched") \
+            <= 2 * 8 * calls
+        spans = _spans()
+        names = {e["name"] for e in spans}
+        assert BLOCK_SPANS <= names and "engine.decode_step" not in names
+        steps = [e for e in spans if e["name"] == "engine.block_step"]
+        assert len(steps) == value("serving_decode_steps")
+        commits = [e for e in spans if e["name"] == "request.block_commit"]
+        assert sorted((e["args"]["rid"], e["args"]["tokens"])
+                      for e in commits) == [(rids[0], 2), (rids[0], 3),
+                                            (rids[1], 4)]
+        path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))[0]
+        in_trace = {e.name for plane in ProfileData.from_file(path).planes
+                    for line in plane.lines for e in line.events}
+        assert {"engine.block_step", "engine.block.stage",
+                "engine.block.dispatch"} <= in_trace
+
+    def test_experts_served_token_by_token_count_without_blocks(self):
+        """The two capabilities are apart: an expert model that does NOT
+        generate by blocks (no ``block_spec``; a block of 1 is plain
+        causal) is served by the decode step, which returns its expert
+        layers' counts; the ``moe_*`` counters bind and move, the block
+        step's series and spans do not exist."""
+        from paddle_tpu.models import SDARMoEConfig, SDARMoEForCausalLM
+
+        class TokenByToken(SDARMoEForCausalLM):
+            block_spec = None
+
+        paddle.seed(92)
+        eng = ServingEngine(TokenByToken(SDARMoEConfig.tiny(block_length=1)),
+                            max_batch=2, page_size=8, max_seq_len=32)
+        rng = np.random.default_rng(4)
+        rids = [eng.submit(rng.integers(0, 100, (n,)).astype(np.int32), 3)
+                for n in (5, 7)]
+        out = eng.run()
+        assert [len(out[r]) for r in rids] == [3, 3]
+        hist = eng.expert_histogram()
+        snap = obs.registry().snapshot()
+        value = lambda name: metric(snap, name)["value"]  # noqa: E731
+        # prompt tokens and every slot of every decode step route: top-k
+        # 2 over 2 layers
+        assert value("moe_assignments") == hist.sum() \
+            == (5 + 7 + value("serving_decode_slots")) * 2 * 2
+        assert value("moe_experts_touched") > 0
+        assert eng.expert_histogram() is None       # read and zeroed
+        assert not (BLOCK_COUNTERS - {"moe_assignments",
+                                      "moe_experts_touched"}) \
+            & set(snap["metrics"])
+        names = {e["name"] for e in _spans()}
+        assert "engine.decode_step" in names and not BLOCK_SPANS & names
+        with pytest.raises(ValueError, match="would undercount"):
+            ServingEngine(TokenByToken(SDARMoEConfig.tiny(block_length=1)),
+                          max_batch=2, page_size=8, max_seq_len=32,
+                          draft_model=TokenByToken(
+                              SDARMoEConfig.tiny(block_length=1)))
+
+    def test_a_plain_decode_step_leaves_none_of_them(self):
+        _scripted_run()
+        snap = obs.registry().snapshot()
+        assert not BLOCK_COUNTERS & set(snap["metrics"])
+        assert not BLOCK_SPANS & {e["name"] for e in _spans()}
+        assert metric(snap, "serving_decode_steps")["value"] > 0
+
+
 def _program_names():
     from jax.sharding import Mesh
 
@@ -673,6 +788,8 @@ def _program_names():
         "serving_decode_fused_tp":
             lambda: serving._build_fused_nlayer_decode_tp(
                 None, spec, None, mesh(), "mp", 2),
+        "serving_block_step":
+            lambda: serving._build_block_step(None, model, 0),
     }
 
 
