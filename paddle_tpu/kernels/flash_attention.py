@@ -14,7 +14,8 @@ MXU/VMEM hierarchy:
   - Causal kv blocks strictly above the diagonal are predicated off with
     pl.when (no MXU work issued).
   - bwd: two kernels recomputing P from (q, k, saved logsumexp) — dq sweeps
-    kv blocks, dk/dv sweeps q blocks — FlashAttention-2's backward with
+    kv blocks, dk/dv sweeps q blocks on TRANSPOSED score blocks (k.q^T),
+    so P^T and dS^T are made in place — FlashAttention-2's backward with
     D_i = rowsum(dO * O) precomputed outside.
   - varlen (flash_attn_unpadded / segment masking): optional int32 segment
     ids mask cross-segment attention, the TPU-idiomatic replacement for
@@ -23,7 +24,15 @@ MXU/VMEM hierarchy:
     their loss contribution is masked out by the caller. Rows whose segment
     matches NO kv position emit zeros (fwd) and zero grads (bwd).
 
-All matmuls run with preferred_element_type=float32; inputs may be bf16.
+Every product accumulates in float32 and takes its operands in the dtype
+q, k, v and dO arrive in: bf16 inputs go to the MXU as stored, with P and dS
+rounded to bf16 right before their products (FlashAttention-2's bf16
+arithmetic; softmax statistics, exp, lse, delta and every accumulator stay
+float32); float32 inputs keep float32 products, which Mosaic, at the default
+precision, computes in one bf16 pass all the same. ``sm_scale`` goes on the
+float32 scores, never on an operand, and on dq and dk once each outside the
+kernels. See ``_dot``; KERNEL_DECISIONS.md "Flash attention operands" has
+the chip's timings, and what a block step does wait for.
 Layout at this level is (BH, S, D); the (B, S, H, D) paddle-convention
 wrapper is ``flash_attention_bshd``.
 """
@@ -137,8 +146,9 @@ def _rep(x):
     keeps the persistent arrays compact (the residuals saved across layers
     are the 2-D forms).
 
-    Known cost (advisor r2): four such transients coexist across the two
-    bwd pallas_calls (~128 MB each at BH=256, S=4096). The fix — compact
+    Known cost (advisor r2): such transients feed dq's pallas_call
+    (~128 MB each at BH=256, S=4096; dk/dv's takes the compact rows in
+    either layout). The fix — compact
     (BH, S) stats loaded as (1, block_q) lane rows and transposed
     in-kernel, plus a scratch-stat forward — is implemented behind
     FLAGS_flash_compact_stats (parity-tested in interpret mode, compiled
@@ -174,39 +184,83 @@ def _dims(ref_shape):
     return ref_shape[1], ref_shape[2]
 
 
+# =================================================== the products' operands
+_NT = (((1,), (1,)), ((), ()))    # a @ b.T
+_NN = (((1,), (0,)), ((), ()))    # a @ b
+
+
+def _dot(a, b, dims):
+    """``a . b`` accumulated in float32. bf16 operands go to the MXU as
+    stored, one pass (bf16 x bf16 is exact in float32); DEFAULT is pinned
+    there because Mosaic takes no float32-precision request on bf16
+    operands and a process may set ``jax_default_matmul_precision``. Any
+    other pairing multiplies in float32 at the precision the process
+    asks for."""
+    if a.dtype == b.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(a, b, dims,
+                                   precision=jax.lax.Precision.DEFAULT,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32),
+                               dims, preferred_element_type=jnp.float32)
+
+
+def _beside(x, stored):
+    """A float32 block (P, dS) as the operand of a product with
+    ``stored``: rounded to bf16 where that one is bf16 — here, after all
+    the block's float32 element-wise work — else as it is."""
+    return x.astype(jnp.bfloat16) if stored.dtype == jnp.bfloat16 else x
+
+
 # ============================================================ forward kernel
-def _masked_scores(q_ref, k_ref, seg_col, seg_kv_ref, q_blk, kv_blk,
-                   causal, sm_scale):
-    """Scaled (bq, bk) score block with causal + segment masking — the
-    shared core of all four kernels. ``seg_col``: the q-side segment ids
-    as a (bq, 1) column (None when unsegmented)."""
-    block_q, d = _dims(q_ref.shape)
-    block_k = k_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32) * sm_scale              # (bq, d)
-    k = k_ref[0].astype(jnp.float32)                         # (bk, d)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+def _masked_scores(q, k, seg_q, seg_kv, q_blk, kv_blk, causal, sm_scale,
+                   transposed=False):
+    """Scaled score block with causal + segment masking — the shared core
+    of all four kernels: the ``(bq, d)`` and ``(bk, d)`` blocks as
+    stored, ``sm_scale`` on the float32 product, which rounds no operand.
+    ``(bq, bk)``, or ``(bk, bq)`` with ``transposed`` (``k . q^T``: what
+    dk/dv's products take on the left with nothing to transpose). ``seg_q`` / ``seg_kv``: the segment ids
+    laid along their own axis of the block — ``(bq, 1)`` and ``(1, bk)``,
+    or ``(1, bq)`` and ``(bk, 1)`` transposed — or None."""
+    block_q, block_k = q.shape[0], k.shape[0]
+    s = (_dot(k, q, _NT) if transposed else _dot(q, k, _NT)) * sm_scale
     if causal:
+        q_axis = 1 if transposed else 0
         q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+            jnp.int32, s.shape, q_axis)
         kv_pos = kv_blk * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, s.shape, 1 - q_axis)
         s = jnp.where(q_pos >= kv_pos, s, _NEG_INF)
-    if seg_col is not None:
-        s = jnp.where(seg_col == seg_kv_ref[0], s, _NEG_INF)
+    if seg_q is not None:
+        s = jnp.where(seg_q == seg_kv, s, _NEG_INF)
     return s
+
+
+def _lanes(x, n):
+    """A lane-replicated ``(rows, 128)`` stat as ``(rows, n)``: a prefix
+    of its lanes, or its lane tile repeated — the same vregs, no relayout
+    — where ``n`` allows; else column 0 broadcast."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _softmax_update(s, m_prev, l_prev):
     """One online-softmax step: returns (m_new, l_new, p, alpha) for a
-    score block against the running (bq, 1) stats."""
+    score block against the running stats, which are lane-replicated
+    ``(bq, 128)`` and stay so through every per-row operation (alpha
+    too): as ``(bq, 1)`` columns, one number a vreg, each of them and
+    each broadcast over the block was a relayout a block step, and that,
+    not the products, was what the forward waited for (KERNEL_DECISIONS.md
+    "Flash attention operands")."""
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # clamp for fully-masked rows: with m_new == -inf, exp(s - m_new)
     # would be exp(0) = 1 for every masked score — clamping to 0 makes
     # p = exp(-1e30) = 0 so masked rows emit zeros, and the saved
     # lse = 0 + log(1) keeps the backward's p = exp(-1e30 - 0) = 0 too
     m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(s - m_new)
+    p = jnp.exp(s - _lanes(m_new, s.shape[1]))
     alpha = jnp.exp(m_prev - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
     return m_new, l_new, p, alpha
@@ -233,17 +287,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
 
     @pl.when(run)
     def _step():
-        seg_col = seg_q_ref[0][:, :1] if seg_q_ref is not None else None
-        s = _masked_scores(q_ref, k_ref, seg_col, seg_kv_ref, qi, kj,
+        seg_q = seg_kv = None
+        if seg_q_ref is not None:
+            seg_q, seg_kv = seg_q_ref[0][:, :1], seg_kv_ref[0]
+        s = _masked_scores(q_ref[0], k_ref[0], seg_q, seg_kv, qi, kj,
                            causal, sm_scale)
-        # stat refs are (block_q, 128) lane-replicated; compute on column 0
-        m_new, l_new, p, alpha = _softmax_update(
-            s, m_ref[0][:, :1], l_ref[0][:, :1])
-        l_ref[0] = jnp.broadcast_to(l_new, l_ref[0].shape)
-        m_ref[0] = jnp.broadcast_to(m_new, m_ref[0].shape)
-        acc_ref[0] = alpha * acc_ref[0] + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        m_ref[0], l_ref[0], p, alpha = _softmax_update(s, m_ref[0], l_ref[0])
+        v = v_ref[0]
+        acc_ref[0] = (_lanes(alpha, acc_ref.shape[2]) * acc_ref[0]
+                      + _dot(_beside(p, v), v, _NN))
 
 
 def _fwd_kernel_compact(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
@@ -272,17 +324,16 @@ def _fwd_kernel_compact(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
         # seg block is (1, 1, bq) — Mosaic needs the sublane dim of every
         # compact stat block to equal the (size-1) array dim, so compact
         # stats ride (BH, 1, S) through every pallas boundary
-        seg_col = (jnp.transpose(seg_q_ref[0])               # (bq, 1)
-                   if seg_q_ref is not None else None)
-        s = _masked_scores(q_ref, k_ref, seg_col, seg_kv_ref, qi, kj,
+        seg_q = seg_kv = None
+        if seg_q_ref is not None:
+            seg_q, seg_kv = jnp.transpose(seg_q_ref[0]), seg_kv_ref[0]
+        s = _masked_scores(q_ref[0], k_ref[0], seg_q, seg_kv, qi, kj,
                            causal, sm_scale)
-        m_new, l_new, p, alpha = _softmax_update(
-            s, m_ref[:, :1], l_ref[:, :1])
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        m_ref[...], l_ref[...], p, alpha = _softmax_update(
+            s, m_ref[...], l_ref[...])
+        v = v_ref[0]
+        acc_ref[...] = (_lanes(alpha, d) * acc_ref[...]
+                        + _dot(_beside(p, v), v, _NN))
 
     if causal:
         final_kj = jnp.minimum((qi * block_q + block_q - 1) // block_k,
@@ -438,7 +489,8 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k,
 
 # =========================================================== backward kernels
 def _col(ref, compact):
-    """Read a per-q-row stat as a (block_q, 1) column. Replicated layout:
+    """Read a per-q-row stat as a (block_q, 1) column (dq's kernel; dk/dv
+    take the rows as they are). Replicated layout:
     ref block (1, bq, 128), column 0. Compact layout: ref block (1, 1, bq)
     lane row (stats ride (BH, 1, S) — the size-1 sublane dim satisfies
     Mosaic's block-shape rule), transposed in-kernel (the relayout the
@@ -464,34 +516,35 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _step():
-        do = do_ref[0].astype(jnp.float32)
+        k = k_ref[0]
         lse = _col(lse_ref, compact)                         # (bq, 1)
         delta = _col(delta_ref, compact)                     # (bq, 1)
-        seg_col = (_col(seg_q_ref, compact)
-                   if seg_q_ref is not None else None)
-        s = _masked_scores(q_ref, k_ref, seg_col, seg_kv_ref, qi, kj,
-                           causal, sm_scale)
+        seg_q = seg_kv = None
+        if seg_q_ref is not None:
+            seg_q, seg_kv = _col(seg_q_ref, compact), seg_kv_ref[0]
+        s = _masked_scores(q_ref[0], k, seg_q, seg_kv, qi, kj, causal,
+                           sm_scale)
         p = jnp.exp(s - lse)                                 # (bq, bk)
-        dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(do_ref[0], v_ref[0], _NT)
         ds = p * (dp - delta)
-        dq_ref[0] = dq_ref[0] + jax.lax.dot_general(
-            ds, k_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_ref[0] = dq_ref[0] + _dot(_beside(ds, k), k, _NN)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     seg_q_ref, seg_kv_ref, dk_ref, dv_ref, *, causal,
-                    sm_scale, compact=False):
+                    sm_scale):
     # grid: (b_kv, ki, rep, qj) — dk/dv blocks are revisited across the
     # (rep, qj) sweep (GQA: every query head in the group accumulates
-    # into its kv head's gradient)
+    # into its kv head's gradient). Everything here is TRANSPOSED,
+    # (bk, bq): P^T and dS^T are what dv's and dk's products take on the
+    # left, so neither block is ever transposed, and the per-q-row stats
+    # are wanted as the (1, bq) lane rows they are stored as, in either
+    # stat layout.
     ki = pl.program_id(1)
     r = pl.program_id(2)
     qj = pl.program_id(3)
     block_k = k_ref.shape[1]
-    block_q, d = _dims(q_ref.shape)
+    block_q = q_ref.shape[1]
 
     @pl.when((qj == 0) & (r == 0))
     def _init():
@@ -503,25 +556,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _step():
-        do = do_ref[0].astype(jnp.float32)
-        lse = _col(lse_ref, compact)                         # (bq, 1)
-        delta = _col(delta_ref, compact)                     # (bq, 1)
-        seg_col = (_col(seg_q_ref, compact)
-                   if seg_q_ref is not None else None)
-        s = _masked_scores(q_ref, k_ref, seg_col, seg_kv_ref, qj, ki,
-                           causal, sm_scale)
-        p = jnp.exp(s - lse)
-        dv_ref[0] = dv_ref[0] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_ref[0] = dk_ref[0] + jax.lax.dot_general(
-            ds, q_ref[0].astype(jnp.float32) * sm_scale,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        q, do = q_ref[0], do_ref[0]
+        seg_q = seg_kv = None
+        if seg_q_ref is not None:
+            seg_q, seg_kv = seg_q_ref[0], jnp.transpose(seg_kv_ref[0])
+        st = _masked_scores(q, k_ref[0], seg_q, seg_kv, qj, ki, causal,
+                            sm_scale, transposed=True)
+        pt = jnp.exp(st - lse_ref[0])                        # (bk, bq)
+        dv_ref[0] = dv_ref[0] + _dot(_beside(pt, do), do, _NN)
+        dpt = _dot(v_ref[0], do, _NT)
+        dst = pt * (dpt - delta_ref[0])
+        # against q as stored: dk takes sm_scale once, outside the kernel
+        dk_ref[0] = dk_ref[0] + _dot(_beside(dst, q), q, _NN)
 
 
 def _bwd(causal, sm_scale, block_q, block_k, h, hkv, compact, res, g):
@@ -561,21 +607,24 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
         delta = delta - dlse.astype(jnp.float32)
 
     has_seg = seg_q is not None
+    # stats + ids compact, (BH, 1, S): (1, 1, bq) lane rows at a kernel's
+    # boundary (no replicated HBM transients at all). dkv takes them so
+    # in either layout: rows are what it wants
+    rows = [lse[:, None, :], delta[:, None, :]]
+    if has_seg:
+        rows += [seg_q[:, None, :], seg_kv[:, None, :]]
     if compact:
-        # stats + q-side ids ride compact (BH, 1, S): (1, 1, bq) lane
-        # rows, transposed in-kernel (no replicated HBM transients at all)
+        # dq transposes them to columns in-kernel
         stat_spec_dq = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
-        seg2 = ([seg_q[:, None, :], seg_kv[:, None, :]]
-                if has_seg else [])
-        common = ([q, k, v, do, lse[:, None, :], delta[:, None, :]]
-                  + seg2)
+        stats_dq = rows
     else:
         # q-side rows lane-replicated transiently for the kernel boundary;
         # kv-side ids ride compact as (BH, 1, S) row vectors
         stat_spec_dq = pl.BlockSpec((1, bq, _LANES),
                                     lambda b, i, j: (b, i, 0))
-        seg2 = [_rep(seg_q), seg_kv[:, None, :]] if has_seg else []
-        common = [q, k, v, do, _rep(lse), _rep(delta)] + seg2
+        stats_dq = [_rep(lse), _rep(delta)]
+        if has_seg:
+            stats_dq += [_rep(seg_q), seg_kv[:, None, :]]
 
     in_specs_dq = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),   # q
@@ -606,7 +655,7 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
         out_shape=_sds((bh, sq, d), jnp.float32, q),
         interpret=_interpret(),
         name="flash_bwd_dq",
-    )(*common)
+    )(q, k, v, do, *stats_dq)
     dq = (dq * sm_scale).astype(q.dtype)
 
     # dkv grid: (b_kv, kv block, group member, q sweep) — dk/dv blocks are
@@ -615,13 +664,8 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
     def q_index(b, i, r, j):
         return ((b // hkv) * h + (b % hkv) * rep + r, j, 0)
 
-    if compact:
-        stat_spec_dkv = pl.BlockSpec(
-            (1, 1, bq),
-            lambda b, i, r, j: (q_index(b, i, r, j)[0], 0, j))
-    else:
-        stat_spec_dkv = pl.BlockSpec(
-            (1, bq, _LANES), lambda b, i, r, j: q_index(b, i, r, j))
+    stat_spec_dkv = pl.BlockSpec(
+        (1, 1, bq), lambda b, i, r, j: (q_index(b, i, r, j)[0], 0, j))
 
     in_specs_dkv = [
         pl.BlockSpec((1, bq, d), q_index),                     # q
@@ -636,12 +680,12 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
             stat_spec_dkv,
             pl.BlockSpec((1, 1, bk), lambda b, i, r, j: (b, 0, i))]
         dkv_kernel = functools.partial(_bwd_dkv_kernel, causal=causal,
-                                       sm_scale=sm_scale, compact=compact)
+                                       sm_scale=sm_scale)
     else:
         dkv_kernel = functools.partial(
             lambda qr, kr, vr, dor, lr, der, dkr, dvr, **kw: _bwd_dkv_kernel(
                 qr, kr, vr, dor, lr, der, None, None, dkr, dvr, **kw),
-            causal=causal, sm_scale=sm_scale, compact=compact)
+            causal=causal, sm_scale=sm_scale)
 
     bh_kv = k.shape[0]
     dk, dv = pl.pallas_call(
@@ -654,9 +698,9 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
                    _sds((bh_kv, skv, d), jnp.float32, q)],
         interpret=_interpret(),
         name="flash_bwd_dkv",
-    )(*common)
-    # dk already carries sm_scale via the scaled q used in ds
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype), None, None
+    )(q, k, v, do, *rows)
+    dk = (dk * sm_scale).astype(k.dtype)
+    return dq, dk, dv.astype(v.dtype), None, None
 
 
 # ============================================================== public entry

@@ -321,6 +321,7 @@ _MATRIX = {
     "flash_fwd-noncausal-d80-s1024": _flash(2 * 8, 1024, 80, causal=False),
     "flash_bwd-noncausal-d80-s1024": _flash(2 * 8, 1024, 80, causal=False,
                                             grad=True),
+    "flash_bwd-d192-s1024": _flash(2 * 8, 1024, 192, grad=True),
     "flash_prefill-d64": _prefill(1, 512, 16, 16, 64, 1024),
     "rms_norm_fwd-8192x1024": _rms(8192, 1024),
     "rms_norm_bwd-8192x1024": _rms(8192, 1024, grad=True),

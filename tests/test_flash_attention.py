@@ -154,6 +154,24 @@ class TestFlashBackward:
         np.testing.assert_allclose(np.asarray(gk), 0.0, atol=1e-6)
         np.testing.assert_allclose(np.asarray(gv), 0.0, atol=1e-6)
 
+    def test_head_of_one_and_a_half_lane_tiles(self):
+        """A head of 192: wider than the 128 lanes the running stats are
+        kept in and no multiple of them, so alpha reaches the accumulator
+        by ``_lanes``' column broadcast."""
+        q, k, v = make_qkv(bh=2, s=256, d=192, seed=6)
+        np.testing.assert_allclose(
+            np.asarray(flash_attention(q, k, v, causal=True)),
+            np.asarray(dense_ref(q, k, v, causal=True)),
+            atol=2e-5, rtol=2e-5)
+        gf = jax.grad(lambda *a: jnp.sum(flash_attention(*a) ** 2),
+                      argnums=(0, 1, 2))(q, k, v)
+        gd = jax.grad(lambda *a: jnp.sum(dense_ref(*a, causal=True) ** 2),
+                      argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(gf, gd, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4,
+                err_msg=f"d{name}")
+
     def test_bf16_close(self):
         q, k, v = make_qkv(bh=1, s=128, d=64, seed=4, dtype=jnp.bfloat16)
         out = flash_attention(q, k, v, causal=True)
@@ -161,6 +179,170 @@ class TestFlashBackward:
                         v.astype(jnp.float32), causal=True)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref), atol=3e-2, rtol=3e-2)
+
+
+def _kernel_eqns(jaxpr, kernel=None, found=None):
+    """{kernel name: [equation, ...]} of everything inside each
+    ``pallas_call`` of ``jaxpr``, however deep (``pl.when`` bodies are
+    ``cond`` branches)."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        inside = kernel
+        if eqn.primitive.name == "pallas_call":
+            inside = eqn.params["name"]
+            found.setdefault(inside, [])
+        elif kernel is not None:
+            found[kernel].append(eqn)
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)      # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    _kernel_eqns(sub, inside, found)
+    return found
+
+
+def _grad_kernels(h, hkv, d, dtype, segments, compact, block=128):
+    """The equations of the three kernels in the gradient of a causal
+    call at ``2 x heads`` rows of 256 tokens, under one stat layout."""
+    import paddle_tpu
+    q = jax.ShapeDtypeStruct((2 * h, 256, d), dtype)
+    kv = jax.ShapeDtypeStruct((2 * hkv, 256, d), dtype)
+    seg = (jnp.zeros((2 * h, 256), jnp.int32) if segments else None)
+
+    def loss(q_, k_, v_):
+        return flash_attention(
+            q_, k_, v_, segment_ids=seg, causal=True, block_q=block,
+            block_k=block, n_heads=h,
+            n_kv_heads=hkv).astype(jnp.float32).sum()
+
+    was = paddle_tpu.get_flags("flash_compact_stats")
+    paddle_tpu.set_flags({"flash_compact_stats": compact})
+    try:
+        return _kernel_eqns(jax.make_jaxpr(
+            jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv).jaxpr)
+    finally:
+        paddle_tpu.set_flags(
+            {"flash_compact_stats": was["FLAGS_flash_compact_stats"]})
+
+
+# what the call looks like: (heads, kv heads, head dim, segment ids)
+_OPERAND_CASES = {
+    "causal-d64": (1, 1, 64, False),
+    "gqa-d128": (4, 2, 128, False),
+    "segments-d64": (1, 1, 64, True),
+}
+
+
+@pytest.mark.parametrize("compact", [True, False],
+                         ids=["compact", "replicated"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(_OPERAND_CASES))
+def test_products_take_operands_in_the_input_dtype(case, dtype, compact):
+    """Every product of the three kernels multiplies in the dtype q, k, v
+    (and so dO) arrive in and accumulates in float32: bf16 inputs reach
+    the MXU as stored, with P and dS rounded right before their products;
+    float32 inputs keep float32 products. Fails the day someone puts an
+    ``astype(jnp.float32)`` back in front of a product."""
+    h, hkv, d, segments = _OPERAND_CASES[case]
+    dots = {
+        kernel: [tuple(str(v.aval.dtype) for v in (*e.invars, *e.outvars))
+                 for e in eqns if e.primitive.name == "dot_general"]
+        for kernel, eqns in _grad_kernels(h, hkv, d, dtype, segments,
+                                          compact).items()}
+    fwd = "flash_fwd" if compact else "flash_fwd_stats"
+    name = jnp.dtype(dtype).name
+    assert {k: len(v) for k, v in dots.items()} == {
+        fwd: 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}, dots
+    for kernel, products in dots.items():
+        for product in products:
+            assert product == (name, name, "float32"), (kernel, products)
+
+
+@pytest.mark.parametrize("compact", [True, False],
+                         ids=["compact", "replicated"])
+@pytest.mark.parametrize("case", list(_OPERAND_CASES))
+def test_block_step_keeps_stats_lane_wide_and_transposes_no_block(
+        case, compact):
+    """What the block step waited for on the chip, pinned where the CPU
+    can see it (KERNEL_DECISIONS.md "Flash attention operands"): the
+    forward's running statistics stay a lane tile wide (its two ``exp``
+    are of the (bq, bk) block and of a (bq, 128) stat, never of a
+    (bq, 1) column), and dk/dv work on transposed scores, so no product
+    contracts its left operand's rows and nothing two-dimensional is
+    transposed there."""
+    h, hkv, d, segments = _OPERAND_CASES[case]
+    kernels = _grad_kernels(h, hkv, d, jnp.bfloat16, segments, compact,
+                            block=256)
+    fwd = kernels["flash_fwd" if compact else "flash_fwd_stats"]
+    exps = [e.invars[0].aval.shape for e in fwd if e.primitive.name == "exp"]
+    assert sorted(exps) == [(256, 128), (256, 256)], exps
+    dkv = kernels["flash_bwd_dkv"]
+    for e in dkv:
+        if e.primitive.name == "dot_general":
+            (lhs_contract, _), _ = e.params["dimension_numbers"]
+            assert lhs_contract == (1,), e
+        if e.primitive.name == "transpose":
+            assert 1 in e.invars[0].aval.shape, e    # an id row at most
+
+
+# bf16 gradients against the float32 dense twin on the same values:
+# (heads, kv heads, head dim, causal)
+_BF16_GRAD_CASES = {
+    "causal-d64": (1, 1, 64, True),
+    "causal-d128": (1, 1, 128, True),
+    "gqa-d64": (4, 2, 64, True),
+    "gqa-d128": (4, 2, 128, True),
+    "full-d64": (1, 1, 64, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_BF16_GRAD_CASES))
+def test_bf16_grads_match_float32_ref(case):
+    """dq, dk, dv of the kernels on bf16 inputs (bf16 products, P and dS
+    rounded to bf16) against ``flash_attention_ref`` in float32 on the
+    same values, at this file's bf16 tolerance."""
+    from paddle_tpu.kernels.flash_attention import flash_attention_ref
+    h, hkv, d, causal = _BF16_GRAD_CASES[case]
+    rng = np.random.default_rng(17)
+    q = jnp.asarray(rng.standard_normal((2 * h, 256, d)) * 0.5, jnp.bfloat16)
+    k, v = (jnp.asarray(rng.standard_normal((2 * hkv, 256, d)) * 0.5,
+                        jnp.bfloat16) for _ in range(2))
+    w = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def loss(fn, **kw):
+        return lambda q_, k_, v_: (fn(
+            q_, k_, v_, causal=causal, n_heads=h, n_kv_heads=hkv,
+            **kw).astype(jnp.float32) * w).sum()
+
+    got = jax.grad(loss(flash_attention, block_q=128, block_k=128),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(flash_attention_ref), argnums=(0, 1, 2))(
+        *(t.astype(jnp.float32) for t in (q, k, v)))
+    for a, b, name in zip(got, want, "qkv"):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=3e-2, rtol=3e-2, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_bf16_fully_masked_rows_zero_grads(d):
+    """Rows whose segment matches no kv position: zeros stay zeros
+    through the bf16 products, forward and backward."""
+    q, k, v = make_qkv(bh=1, s=256, d=d, seed=2, dtype=jnp.bfloat16)
+    seg_q = jnp.full((1, 256), 3, jnp.int32)
+    seg_kv = jnp.full((1, 256), 5, jnp.int32)
+
+    def loss(q_, k_, v_):
+        return jnp.sum(flash_attention(
+            q_, k_, v_, segment_ids=seg_q, kv_segment_ids=seg_kv,
+            causal=False, block_q=128, block_k=128).astype(jnp.float32) ** 2)
+
+    out = flash_attention(q, k, v, segment_ids=seg_q, kv_segment_ids=seg_kv,
+                          causal=False, block_q=128, block_k=128)
+    assert not np.asarray(out, np.float32).any()
+    for g in jax.grad(loss, argnums=(0, 1, 2))(q, k, v):
+        assert not np.asarray(g, np.float32).any()
 
 
 class TestGQA:

@@ -1,9 +1,36 @@
-"""On-chip flash-vs-dense attention microbench (fwd+bwd)."""
-import time, functools, json, sys
-import numpy as np
-import jax, jax.numpy as jnp
+"""On-chip flash-attention microbench.
 
-from paddle_tpu.kernels.flash_attention import flash_attention_bshd
+``python tools/attn_bench.py`` — flash vs XLA dense, fwd+bwd, a block
+sweep at 4,096 and the GQA rows (what ATTN_BENCH_r05.json was made by).
+
+``python tools/attn_bench.py --kernels bh=256,s=1024,d=64 ...`` — the
+three training kernels ALONE at the given (BH, S, D) shapes (``h``/``hkv``
+for GQA, ``scale`` for a softmax scale other than 1/sqrt(d)): device time
+of ``flash_fwd`` / ``flash_bwd_dq`` / ``flash_bwd_dkv`` a call, read from a
+profiler trace of ten fwd+bwd calls, beside the host's fwd+bwd time. The
+package it times is the first ``paddle_tpu`` on ``sys.path`` (the
+checkout's own unless ``PYTHONPATH`` names another, e.g. an unpacked
+parent commit), so two commits are two runs of this one file.
+"""
+import functools
+import glob
+import json
+import os
+import sys
+import tempfile
+import time
+
+sys.path.append(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                flash_attention_bshd)
+
+_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
 
 def dense_bshd(q, k, v):
     qt, kt, vt = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
@@ -13,13 +40,22 @@ def dense_bshd(q, k, v):
     p = jax.nn.softmax(s.astype(jnp.float32), -1).astype(q.dtype)
     return jnp.swapaxes(jnp.einsum("bhst,bhtd->bhsd", p, vt), 1, 2)
 
-def bench(fn, *args):
-    # Time a jitted scalar and float() it (the host transfer closes the
-    # run). Sum ALL of dq/dk/dv: summing only dq lets XLA DCE prune the dk/dv
+
+def grad_sum(fn):
+    # Sum ALL of dq/dk/dv: summing only dq lets XLA DCE prune the dk/dv
     # backward kernels and understate the backward cost.
     loss = lambda *a: fn(*a).astype(jnp.float32).sum()
-    g = jax.jit(lambda *a: sum(t.astype(jnp.float32).sum()
-                               for t in jax.grad(loss, argnums=(0, 1, 2))(*a)))
+    return jax.jit(lambda *a: sum(t.astype(jnp.float32).sum()
+                                  for t in jax.grad(loss, argnums=(0, 1, 2))(*a)))
+
+
+def bench(fn, *args):
+    return timed(grad_sum(fn), *args)
+
+
+def timed(g, *args):
+    # Time a jitted scalar and float() it (the host transfer closes the
+    # run).
     float(g(*args))
     ts = []
     for _ in range(5):
@@ -28,10 +64,70 @@ def bench(fn, *args):
         ts.append(time.perf_counter() - t0)
     return sorted(ts)[2]
 
+
 def dense_gqa_bshd(q, k, v):
     rep = q.shape[2] // k.shape[2]
     return dense_bshd(q, jnp.repeat(k, rep, axis=2),
                       jnp.repeat(v, rep, axis=2))
+
+
+def kernel_ms(g, args, calls=10):
+    """Device milliseconds a call of each flash kernel, from a trace of
+    ``calls`` runs of the compiled ``g``. A Pallas call's op is named
+    after its ``name=`` inside JAX's transform wrappers
+    (``transpose_jvp_flash_bwd_dq__``): the longest kernel name found in
+    the instruction's own name counts it."""
+    from jax.profiler import ProfileData
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        for _ in range(calls):
+            float(g(*args))
+        jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(tmp, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        data = ProfileData.from_file(path)
+    total = dict.fromkeys(_KERNELS, 0.0)
+    for plane in data.planes:
+        if not plane.name.startswith("/device:TPU:0"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for e in line.events:
+                inst = e.name.split(" = ", 1)[0]
+                hit = [k for k in _KERNELS if k in inst]
+                if hit:
+                    total[max(hit, key=len)] += e.duration_ns
+    return {k: round(v / calls / 1e6, 4) for k, v in total.items()}
+
+
+def kernels(specs):
+    rng = np.random.default_rng(0)
+    for spec in specs:
+        kv = dict(item.split("=") for item in spec.split(","))
+        bh, s, d = int(kv["bh"]), int(kv["s"]), int(kv["d"])
+        h = int(kv.get("h", 1))
+        hkv = int(kv.get("hkv", h))
+        scale = float(kv["scale"]) if "scale" in kv else None
+        dtype = jnp.dtype(kv.get("dtype", "bfloat16"))
+        q = jnp.asarray(rng.standard_normal((bh, s, d)), dtype)
+        k, v = (jnp.asarray(rng.standard_normal((bh // h * hkv, s, d)), dtype)
+                for _ in range(2))
+        fn = functools.partial(flash_attention, causal=True, sm_scale=scale,
+                               n_heads=h, n_kv_heads=hkv)
+        g = grad_sum(fn)
+        rec = {"spec": spec,
+               "fwd_bwd_host_ms": round(timed(g, q, k, v) * 1e3, 3),
+               "package": os.path.dirname(os.path.dirname(
+                   sys.modules[flash_attention.__module__].__file__)),
+               "backend": jax.default_backend()}
+        rec.update(kernel_ms(g, (q, k, v)))
+        print(json.dumps(rec), flush=True)
+
+
+if __name__ == "__main__" and sys.argv[1:2] == ["--kernels"]:
+    kernels(sys.argv[2:])
+    sys.exit(0)
 
 rng = np.random.default_rng(0)
 tf_4096 = None
