@@ -24,6 +24,13 @@ points a user calls, on ONE TPU chip, in this one process:
                 and a padded block-causal chunk and whose remainder starts
                 the first block, three blocks generated, every reveal held
                 against the plain reference
+  window        trinity-mini (5 layers: one dense, three window layers
+                and a global one among the sparse four; the benchmark's
+                configuration and weights) through ServingEngine: one
+                prompt longer than window + chunk through its chunks,
+                the window layers' pages given back between them and
+                taken again, then decode, every token held against the
+                plain reference
 
 ``python chip_smoke.py --chips 4`` runs ONLY the Fleet hybrid path
 (dp2 x mp2 TrainStep at Llama-2-7B widths, 2 layers) and its one-chip twin.
@@ -86,6 +93,11 @@ class Sizes:
     sdar_new_tokens: int = 9
     # None: benchmark/configs/sdar-30b-a3b-chat.json
     sdar: Optional[dict] = None
+    # four chunks, past window + chunk: pages given back are taken again
+    trinity_prompt_len: int = 3840
+    trinity_new_tokens: int = 8
+    # None: benchmark/configs/trinity-mini.json
+    trinity: Optional[dict] = None
     seed: int = 0
 
 
@@ -656,6 +668,84 @@ def phase_hybrid(s: Sizes) -> dict:
         sharded_s=round(t1 - t0, 2), one_chip_s=round(t2 - t1, 2), **facts)
 
 
+# ------------------- window: window and global layers over two page pools
+def phase_trinity_window(s: Sizes) -> dict:
+    """The window pool end to end at the published widths: a prompt
+    longer than window + chunk through its chunks, the window layers'
+    pages before the window given back between them and taken again by
+    the same row, then decode through the windowed paged attention; every
+    token is held against the plain reference's argmax by the benchmark's
+    own near-tie rule, and the row never holds more window pages than
+    its bound. (The benchmark's check does the same on four prompts;
+    this phase is the one place a single long row is looked at alone,
+    page by page.)"""
+    import os
+
+    from benchmark.lib import afmoe, registry
+    from paddle_tpu.generation.program_cache import decode_program_cache
+
+    registry.load_all()
+    config = s.trinity
+    if config is None:
+        here = os.path.dirname(os.path.abspath(__file__))
+        with open(os.path.join(here, "benchmark", "configs",
+                               "trinity-mini.json")) as fh:
+            config = json.load(fh)
+    sysm = afmoe.build_serve_afmoe(config, {}, s.seed + 5, 1)
+    eng, ref, model = sysm.engine, sysm.ref, config["model"]
+    n, new = s.trinity_prompt_len, s.trinity_new_tokens
+    window, caches = model["sliding_window"], eng._caches
+    assert n > window + eng.chunk, (n, window, eng.chunk)
+
+    def refuse_recovery(exc):
+        raise AssertionError("a serving dispatch failed") from exc
+    eng._recover_dispatch = refuse_recovery
+    rng = np.random.default_rng(s.seed + 5)
+    prompt = rng.integers(0, sysm.vocab, (n,)).astype(np.int32)
+    t0 = time.perf_counter()
+    rid = eng.submit(prompt, new)
+    most = 0
+    while eng.has_work():
+        eng.run_step()
+        most = max(most, len(caches.window.sequence_pages(0)))
+    toks = eng.results()[rid]
+    serve_s = time.perf_counter() - t0
+    assert eng.status(rid) == "OK" and len(toks) == new, eng.statuses()
+    assert eng.chunk_dispatches == -(-n // eng.chunk), eng.chunk_dispatches
+    span_pages = -(-(n + new) // eng.pool.page_size)
+    assert 0 < most <= caches._row_bound < span_pages, (most, span_pages)
+    released = caches.window_pages_released
+    assert released > 0, released
+    assert caches.window.free_page_count() == caches.window.num_pages - 1
+    text = decode_program_cache().lowered(eng.decode_key).as_text()
+    kernels = {k: k in text for k in ("paged_attention", "gmm")}
+    if s.on_chip:
+        assert all(kernels.values()), kernels
+
+    checked = [(prompt, toks)]
+    ties, wrong, gaps = afmoe.compare(
+        [toks], afmoe.deciding_logits(sysm, checked)[:, :new], ref)
+    for tie in ties:
+        print(json.dumps({"near_tie": tie}), flush=True)
+    assert not wrong, \
+        f"a token differs from the reference beyond a near-tie: {wrong}"
+    hist = eng.expert_histogram()
+    return _emit(
+        "window", config=config["name"], layers=model["num_hidden_layers"],
+        layer_types=model["layer_types"],
+        n_params=int(sum(p.size for p in sysm.model.parameters())),
+        prompt_len=n, window=window, chunk=eng.chunk,
+        chunk_dispatches=eng.chunk_dispatches, new_tokens=new,
+        tokens=[int(t) for t in toks], statuses="OK",
+        span_pages=span_pages, window_pages_most=most,
+        window_row_bound=caches._row_bound, window_pages_released=released,
+        pool_pages=[eng.pool.num_pages, caches.window.num_pages],
+        near_ties=len(ties), largest_gap=max(gaps, default=0.0),
+        tie_atol=ref.TIE_ATOL, decode_kind=eng.decode_key.kind,
+        kernels=kernels, experts_touched=int((hist > 0).sum()),
+        serve_s=round(serve_s, 2))
+
+
 # ------------------------------------------------------------------ main
 def run_phases(s: Sizes, chips: int = 1) -> list:
     """Every phase of the ``chips`` mode at sizes ``s``; raises on the
@@ -679,6 +769,9 @@ def run_phases(s: Sizes, chips: int = 1) -> list:
     clear_decode_program_cache()
     gc.collect()
     lines.append(phase_sdar_blocks(s))
+    clear_decode_program_cache()
+    gc.collect()
+    lines.append(phase_trinity_window(s))
     return lines
 
 

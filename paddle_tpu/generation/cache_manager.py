@@ -1,17 +1,33 @@
 """What a sequence keeps on one engine, per layer kind, and how it travels.
 
 A model's ``cache_spec()`` says per layer which kind of state a request
-owns there: ``(kv_heads, head_dim)`` is KV **pages** of the shared pool
-(:class:`~paddle_tpu.kernels.paged_attention.PagedKVCache`, addressed
-through a block table, shareable, grown a page at a time), a
-:class:`~paddle_tpu.kernels.recurrent_state.RecurrentSpec` is one **row**
-of the recurrent-state store
-(:class:`~paddle_tpu.kernels.recurrent_state.RecurrentStateCache`,
-indexed by slot, fixed in size). This module is the only code that knows
-that there are two kinds. :class:`CacheManager` owns both stores of one
-model on one engine: their geometry and placement, a slot's life
-(allocate / reset / move / free), the handoff of a seated request to
-another engine, the donation handoff around every dispatch, and the
+owns there. There are three kinds:
+
+- ``(kv_heads, head_dim)``: KV **pages** of the shared pool
+  (:class:`~paddle_tpu.kernels.paged_attention.PagedKVCache`, addressed
+  through a block table, shareable, grown a page at a time and kept for
+  the life of the request): a GLOBAL attention layer;
+- :class:`~paddle_tpu.kernels.paged_attention.WindowKV`: pages of a
+  SECOND pool of the same class, with a block table and a free list of
+  its own, for the WINDOW attention layers. A row there holds the pages
+  of its last ``window`` positions and of the step being written, and no
+  more: before every dispatch the pages that fell wholly out of the
+  row's window go back to the free list (their table slots to the null
+  page, which the windowed kernels never visit) and the pages the step
+  writes are taken. The pool is SIZED so that taking them cannot fail:
+  a row holds at most ``min(span pages, ceil((window + step) / page) +
+  1)``, and the pool has that bound for every slot, or as many pages as
+  the global pool, whose admission then covers both;
+- :class:`~paddle_tpu.kernels.recurrent_state.RecurrentSpec`: one **row**
+  of the recurrent-state store
+  (:class:`~paddle_tpu.kernels.recurrent_state.RecurrentStateCache`,
+  indexed by slot, fixed in size).
+
+This module is the only code that knows that there is more than one
+kind. :class:`CacheManager` owns the stores of one model on one engine:
+their geometry and placement, a slot's life (allocate / reset / move /
+free), what a dispatch is handed (block tables, cursors, the donated
+arrays), the handoff of a seated request to another engine, and the
 ledger. :func:`cache_entries` is the traced half of the same decision:
 which entry a layer of which kind is handed inside a compiled program,
 and :meth:`CacheManager.install_caches` is its inverse. A new layer kind
@@ -31,22 +47,24 @@ import jax.numpy as jnp
 
 # unwraps a paddle Tensor and ONLY that (see serving.py's import)
 from ..core.tensor import _val
-from ..kernels.paged_attention import (HostPage, PagedKVCache,
+from ..kernels.paged_attention import (HostPage, PagedKVCache, WindowKV,
                                        padded_head_dim)
 from ..kernels.recurrent_state import (RecurrentSpec, RecurrentState,
                                        RecurrentStateCache,
                                        is_recurrent_state, recurrent_layout)
 
 __all__ = ["CacheManager", "cache_entries", "has_recurrent_layers",
-           "kv_heads", "pool_head_dim"]
+           "has_window_layers", "kv_heads", "pool_head_dim"]
 
 
-def _split_spec(model) -> Tuple[list, List[RecurrentSpec]]:
-    """``model.cache_spec()`` by kind: the paged layers' ``(kv_heads,
-    head_dim)`` and the recurrent layers' specs. A plain list is "all
-    pages"."""
+def _split_spec(model) -> Tuple[list, List[WindowKV], List[RecurrentSpec]]:
+    """``model.cache_spec()`` by kind: the global layers' ``(kv_heads,
+    head_dim)``, the window layers' and the recurrent layers' specs. A
+    plain list is "all pages, kept whole"."""
     full = model.cache_spec()
-    return ([e for e in full if not isinstance(e, RecurrentSpec)],
+    return ([e for e in full
+             if not isinstance(e, (WindowKV, RecurrentSpec))],
+            [e for e in full if isinstance(e, WindowKV)],
             [e for e in full if isinstance(e, RecurrentSpec)])
 
 
@@ -56,10 +74,17 @@ def has_recurrent_layers(model) -> bool:
     return recurrent_layout(model.cache_spec()) is not None
 
 
+def has_window_layers(model) -> bool:
+    """Does ``model`` have window attention layers, whose pages are given
+    back as the window slides? Asked like :func:`has_recurrent_layers`."""
+    return any(isinstance(e, WindowKV) for e in model.cache_spec())
+
+
 def kv_heads(model) -> int:
     """The KV-head count of ``model``'s paged layers: what a
     tensor-parallel pool is partitioned over."""
-    return _split_spec(model)[0][0][0]
+    paged, window, _ = _split_spec(model)
+    return (paged or window)[0][0]
 
 
 def pool_head_dim(model, head_dim: int, kv_dtype: str) -> int:
@@ -78,49 +103,100 @@ def pool_head_dim(model, head_dim: int, kv_dtype: str) -> int:
 
 def cache_entries(model, pools, paged_cls, bt, sl, **recurrent):
     """The per-layer cache entries a program hands ``model``, by its
-    ``cache_spec()`` (read while tracing). No recurrent layer: every
-    layer is paged and ``pools`` is the list of ``(k, v)``. Else
-    ``pools`` is ``(pairs, rows)`` (:meth:`CacheManager.take_caches`) and
-    the recurrent layers take a ``RecurrentState`` over their rows,
-    with the call's ``slot`` / ``n_valid`` / ``live``. ALL of ``pools``
-    is donated, so the state-update kernel and the row write-backs work
-    in place like the page writes."""
-    layout = recurrent_layout(model.cache_spec())
-    if layout is None:
+    ``cache_spec()`` (read while tracing). Every layer global: ``pools``
+    is the list of ``(k, v)`` and ``bt`` the block tables. Else
+    ``pools`` is one list a store the model has, in the order global
+    pairs, window pairs, recurrent rows
+    (:meth:`CacheManager.take_caches`); a model with window layers is
+    handed ``bt`` as the pair ``(global tables, window tables)``
+    (:meth:`CacheManager.tables`), and its window layers' entries are
+    over the window pool and its tables; the recurrent layers take a
+    ``RecurrentState`` over their rows, with the call's ``slot`` /
+    ``n_valid`` / ``live``. ALL of ``pools`` is donated, so the
+    state-update kernel and the row write-backs work in place like the
+    page writes."""
+    spec = model.cache_spec()
+    kinds = [WindowKV if isinstance(e, WindowKV)
+             else RecurrentSpec if isinstance(e, RecurrentSpec) else tuple
+             for e in spec]
+    if all(k is tuple for k in kinds):
         return [paged_cls(k, v, bt, sl) for k, v in pools]
-    pairs, rows = (iter(p) for p in pools)
-    return [RecurrentState(*next(rows), **recurrent) if rec
-            else paged_cls(*next(pairs), bt, sl) for rec in layout]
+    present = [k for k in (tuple, WindowKV, RecurrentSpec) if k in kinds]
+    store = {k: iter(p) for k, p in zip(present, pools)}
+    tables = {tuple: bt}
+    if WindowKV in store:
+        tables[tuple], tables[WindowKV] = bt
+    return [RecurrentState(*next(store[k]), **recurrent)
+            if k is RecurrentSpec
+            else paged_cls(*next(store[k]), tables[k], sl) for k in kinds]
 
 
 class CacheManager:
     """One model's per-request layer state on one engine: the page pool
-    (``pool``) and, where ``cache_spec()`` names recurrent layers, the
-    state store (``state``, else None).
+    of its global layers (``pool``); where ``cache_spec()`` names window
+    layers, their pool (``window``, else None); where it names recurrent
+    layers, the state store (``state``, else None).
 
     ``pool_sharding`` / ``tp_degree``: the canonical kv-head
     ``NamedSharding`` of a tensor-parallel engine; a pool whose kv-head
     count ``tp_degree`` does not divide stays replicated (a narrow
-    draft model)."""
+    draft model). ``step_tokens``: the most tokens ONE dispatch writes
+    into a row (the prefill chunk; 0: a whole prompt, up to
+    ``max_seq_len``), which with the window bounds what a window row
+    holds."""
 
     def __init__(self, model, *, max_batch: int, page_size: int,
                  num_pages: int, max_seq_len: int, kv_dtype: str, dtype,
-                 pool_sharding=None, tp_degree: int = 1):
-        paged, recurrent = _split_spec(model)
+                 pool_sharding=None, tp_degree: int = 1,
+                 step_tokens: int = 0):
+        paged, window, recurrent = _split_spec(model)
+        some = (paged or window)[0]
+        # of the paged layers in layer order, which are window layers
+        # (install_caches)
+        self._is_window = [isinstance(e, WindowKV)
+                           for e in model.cache_spec()
+                           if not isinstance(e, RecurrentSpec)]
         # the geometry is kept so that rebuild() allocates FRESH stores
         # of the identical shape (the same compiled programs apply)
         self._pool_geom = dict(
             num_layers=len(paged), num_pages=num_pages, page_size=page_size,
-            num_kv_heads=paged[0][0],
-            head_dim=pool_head_dim(model, paged[0][1], kv_dtype),
+            num_kv_heads=some[0],
+            head_dim=pool_head_dim(model, some[1], kv_dtype),
             max_batch=max_batch, max_seq_len=max_seq_len, dtype=dtype,
             reserve_null_page=True, kv_dtype=kv_dtype)
+        self._window_geom = None
+        # pages the window pool took back, over the manager's life
+        self.window_pages_released = 0
+        if window:
+            if not paged:
+                raise NotImplementedError(
+                    "a model of window layers only: the engine keeps its "
+                    "cursors and its admission in the global pool's "
+                    "books, which such a model would leave empty")
+            if len({tuple(e) for e in window}) != 1 \
+                    or tuple(window[0][:2]) != tuple(some[:2]):
+                raise NotImplementedError(
+                    "window layers of different windows or head "
+                    f"geometries in one model: {sorted(set(window))}")
+            self.window_len = int(window[0].window)
+            # the most pages a window row holds: its window and the step
+            # being written, and one more where neither starts on a page
+            step = step_tokens or max_seq_len
+            self._row_bound = min(
+                -(-max_seq_len // page_size),
+                -(-(self.window_len + step) // page_size) + 1)
+            # every slot at its bound, and never more than the global
+            # pool has: either way the rows the global pool admitted
+            # find their pages here, so admission prices that pool alone
+            self._window_geom = dict(
+                self._pool_geom, num_layers=len(window),
+                num_pages=min(num_pages, 1 + max_batch * self._row_bound))
         # one row a slot a recurrent layer, NOT addressed through the
         # block table
         self._state_geom = (dict(specs=recurrent, max_batch=max_batch,
                                  dtype=dtype) if recurrent else None)
         self._sharding = (pool_sharding
-                          if paged[0][0] % tp_degree == 0 else None)
+                          if some[0] % tp_degree == 0 else None)
         self.rebuild()
 
     def rebuild(self) -> None:
@@ -128,6 +204,12 @@ class CacheManager:
         recovery: the donated arrays died with the failed dispatch, and
         every request replays from its prompt, so zeros are right)."""
         self.pool = PagedKVCache(**self._pool_geom)
+        self.window: Optional[PagedKVCache] = None
+        if self._window_geom is not None:
+            self.window = PagedKVCache(**self._window_geom)
+            # per slot: the tokens its request spans
+            self._span = np.zeros((self.pool.block_tables.shape[0],),
+                                  np.int64)
         if self._sharding is not None:
             # every per-layer pool leaf onto the canonical kv-head
             # sharding (the int8 payload and its per-token-row scale band
@@ -160,28 +242,102 @@ class CacheManager:
         return 0 if self.state is None else 1
 
     @property
+    def page_budget(self) -> tuple:
+        """The page geometry a compiled program is specialised to, part
+        of its cache key: ``(num_pages, page_size, max_pages_per_seq)``
+        of the pool, and for a model with window layers their pool's
+        page count behind them (it follows from the engine's chunk)."""
+        pool = self.pool
+        budget = (pool.num_pages, pool.page_size, pool.max_pages_per_seq)
+        if self.window is not None:
+            budget += (self.window.num_pages,)
+        return budget
+
+    @property
     def detached(self) -> bool:
         """A donating dispatch holds a store's arrays, or died holding
         them (only :meth:`rebuild` brings those back)."""
-        return (bool(self.pool.k_pages) and self.pool.k_pages[0] is None) \
+        return any(bool(p.k_pages) and p.k_pages[0] is None
+                   for p in (self.pool, self.window) if p is not None) \
             or (self.state is not None and self.state.detached)
 
     def ledger(self) -> dict:
         """The pool's ledger (``PagedKVCache.ledger``, fragmentation
         left to its epoch memo) beside the state store's bill: all of it
-        is resident whether or not a slot is taken."""
+        is resident whether or not a slot is taken. A model with window
+        layers adds their pool's bill under ``window_*``: the pages and
+        bytes in use, a page's bytes, and the pages given back so far."""
         led = self.pool.ledger(fragmentation=False)
         led["state_bytes"] = 0 if self.state is None else self.state.nbytes
         led["state_bytes_per_slot"] = (
             0 if self.state is None else self.state.bytes_per_slot)
+        if self.window is not None:
+            win = self.window.ledger(fragmentation=False)
+            led.update(window_pages_in_use=win["pages_in_use"],
+                       window_bytes_in_use=win["bytes_in_use"],
+                       window_pages_released=self.window_pages_released)
         return led
+
+    def window_read_tokens(self, slots) -> Optional[int]:
+        """The positions a window layer reads for the rows ``slots`` in
+        the decode step about to go out, ``min(len + 1, window)`` each;
+        None for a model without window layers."""
+        if self.window is None:
+            return None
+        return int(np.minimum(self.pool.seq_lens[list(slots)] + 1,
+                              self.window_len).sum())
+
+    def window_read_pairs(self, pos: int, n: int) -> Optional[int]:
+        """The query-key pairs a window layer computes for ``n`` queries
+        at positions ``pos ..``, ``min(p + 1, window)`` each; None for a
+        model without window layers."""
+        if self.window is None:
+            return None
+        return int(np.minimum(np.arange(pos + 1, pos + n + 1),
+                              self.window_len).sum())
 
     # -------------------------------------------------------- a slot's life
     def allocate(self, slot: int, n_tokens: int) -> None:
         """Pages for ``n_tokens`` more tokens of ``slot``'s sequence
         (``PagedKVCache.allocate``: RuntimeError when the pool is
-        exhausted, what was popped so far recorded and freeable)."""
+        exhausted, what was popped so far recorded and freeable). The
+        window pool only notes the span: its pages are taken a dispatch
+        at a time (:meth:`tables`, :meth:`decode_inputs`)."""
         self.pool.allocate(slot, n_tokens)
+        if self.window is not None:
+            self._span[slot] = int(self.pool.seq_lens[slot]) + int(n_tokens)
+
+    def _slide(self, slot: int, ahead: int) -> None:
+        """Before a dispatch that writes ``ahead`` tokens at ``slot``'s
+        cursor: the window pages wholly before the first position the
+        step's first query sees go back, and the pages the step writes
+        are taken (inside the request's span: a padded chunk's tail
+        falls on the null page, as in the global pool). The pool has
+        every slot's bound (``__init__``), so the ``allocate`` cannot
+        run out while every row stays inside its own."""
+        win = self.window
+        cursor = int(self.pool.seq_lens[slot])
+        win.seq_lens[slot] = cursor
+        self.window_pages_released += win.release_before(
+            slot, cursor + 1 - self.window_len)
+        win.allocate(slot, max(0, min(ahead, int(self._span[slot]) - cursor)))
+        assert (win._pages_used[slot] - win._pages_first[slot]
+                <= self._row_bound), "a window row past its bound"
+
+    def tables(self, slot: int, n_tokens: int):
+        """The block tables of ``slot`` alone, for a b=1 program that
+        writes ``n_tokens`` at its cursor: an array, or for a model with
+        window layers the pair :func:`cache_entries` takes apart."""
+        # COPIES, as in :meth:`decode_inputs`: the CPU backend may alias
+        # an aligned host view instead of copying it, and a non-final
+        # chunk is not waited for, so the program can still be reading
+        # its tables when a move, a free or the next slide rewrites the
+        # rows
+        bt = jnp.asarray(self.pool.block_tables[slot:slot + 1].copy())
+        if self.window is None:
+            return bt
+        self._slide(slot, n_tokens)
+        return bt, jnp.asarray(self.window.block_tables[slot:slot + 1].copy())
 
     def reset(self, slot: int) -> None:
         """Admission: the slot's recurrent rows start from zero (they
@@ -193,18 +349,32 @@ class CacheManager:
     def move(self, src: int, dst: int) -> None:
         """Relocate a sequence to the empty slot ``dst`` (the ladder
         compacting): pages never copy (a host-side block-table row
-        move), rows are indexed by slot, so those DO move, one device
-        row copy a layer."""
+        move, in each pool), rows are indexed by slot, so those DO
+        move, one device row copy a layer."""
         self.pool.move_sequence(src, dst)
+        if self.window is not None:
+            self.window.move_sequence(src, dst)
+            self._span[dst], self._span[src] = self._span[src], 0
         if self.state is not None:
             self.state.move(src, dst)
 
     def free(self, slot: int) -> None:
-        """Return the slot's pages (rows are not freed: the next
-        admission resets them)."""
+        """Return the slot's pages, of both pools (rows are not freed:
+        the next admission resets them)."""
         self.pool.free_sequence(slot)
+        if self.window is not None:
+            self.window.free_sequence(slot)
+            self._span[slot] = 0
 
     # ------------------------------------------------------------- handoff
+    def _no_window_handoff(self, what: str) -> None:
+        if self.window is not None:
+            raise NotImplementedError(
+                f"{what}: a row of a model with window layers does not "
+                "travel with its pages yet (its window pool holds a "
+                "moving part of the sequence); hand it over as tokens "
+                "(export_requests / inject_request), which replays it")
+
     def export_slot(self, slot: int):
         """Detach what ``slot``'s sequence has written, as host state:
         ``(pages, seq_len, state)`` — its pages verbatim (``HostPage``,
@@ -212,6 +382,7 @@ class CacheManager:
         recurrent rows (None for a model that has none): they are as
         much the sequence's written state, and as little recomputed.
         The slot's pages return to the pool."""
+        self._no_window_handoff("harvest_request")
         if self.detached:
             raise RuntimeError("harvest_request: pool is detached")
         seq_len = int(self.pool.seq_lens[slot])
@@ -235,6 +406,7 @@ class CacheManager:
         refusal leaves no page allocated. ``slot`` None is the engine
         having no free slot: refused here, after the bundle's own
         checks, where that refusal has always come."""
+        self._no_window_handoff("adopt_request")
         if self.detached:
             raise RuntimeError("adopt_request: pool is detached")
         if (state is None) != (self.state is None):
@@ -287,13 +459,19 @@ class CacheManager:
 
     def take_caches(self):
         """What a donating serving program is handed as its ``pools``:
-        the per-layer ``(k, v)`` pairs, and for a model with recurrent
-        layers ``(pairs, [(ssm, conv) a recurrent layer])``. Every store
-        is detached until :meth:`install_caches`."""
+        the per-layer ``(k, v)`` pairs; for a model with more than one
+        store, one list a store in the order :func:`cache_entries`
+        reads: global pairs, window pairs, ``[(ssm, conv) a recurrent
+        layer]``. Every store is detached until
+        :meth:`install_caches`."""
         pairs = self.pool.take_pools()
-        if self.state is None:
+        if self.window is None and self.state is None:
             return pairs
-        return pairs, self.state.take_arrays()
+        return ((pairs,)
+                + (() if self.window is None
+                   else (self.window.take_pools(),))
+                + (() if self.state is None
+                   else (self.state.take_arrays(),)))
 
     def install_caches(self, states) -> None:
         """Take a program's returned per-layer entries apart again (the
@@ -304,6 +482,11 @@ class CacheManager:
                 [(_val(st.ssm), _val(st.conv)) for st in states
                  if is_recurrent_state(st)])
             states = [st for st in states if not is_recurrent_state(st)]
+        if self.window is not None:
+            self.window.install_pools(
+                [(_val(st.k_pages), _val(st.v_pages))
+                 for st, w in zip(states, self._is_window) if w])
+            states = [st for st, w in zip(states, self._is_window) if not w]
         self.pool.install_pools(self._canonical(
             [(_val(st.k_pages), _val(st.v_pages)) for st in states]))
 
@@ -320,8 +503,16 @@ class CacheManager:
         overwritten later, its STATE must not move. COPIES, not views:
         the engine advances the cursors as soon as the step is
         dispatched, while the transfer may still be reading these."""
-        host = [self.pool.block_tables[:b].copy(),
-                self.pool.seq_lens[:b].copy()]
+        tables = self.pool.block_tables[:b].copy()
+        if self.window is not None:
+            # the decoding rows write one token at their cursors (a
+            # mid-prefill row's garbage write lands on whatever its
+            # window table holds there: its next chunk's page, or the
+            # null page)
+            for slot in live_slots:
+                self._slide(slot, 1)
+            tables = (tables, self.window.block_tables[:b].copy())
+        host = [tables, self.pool.seq_lens[:b].copy()]
         if self.state is not None:
             live = np.zeros((b,), np.int32)
             live[live_slots] = 1

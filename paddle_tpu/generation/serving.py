@@ -57,7 +57,8 @@ from ..kernels.paged_attention import (PagedBlockState, PagedDecodeState,
                                        PagedKVCache)
 from ..testing import faults
 from .cache_manager import (CacheManager, cache_entries,
-                            has_recurrent_layers, kv_heads)
+                            has_recurrent_layers, has_window_layers,
+                            kv_heads)
 from .program_cache import ProgramBuildError
 
 __all__ = ["ServingEngine", "Request"]
@@ -233,6 +234,24 @@ class _EngineTelemetry:
             "serving_decode_live_tokens",
             "cached tokens of the decoding rows, summed over the decode "
             "steps — the KV each step had to read")
+        self.decode_window_tokens = c(
+            "serving_decode_window_tokens",
+            "positions a WINDOW layer reads for the decoding rows, "
+            "min(len + 1, window) each, summed over the decode steps "
+            "(written only for a model with window layers)")
+        self.chunk_attn_pairs = c(
+            "serving_chunk_attn_pairs",
+            "query-key pairs a layer that keeps the whole cache had to "
+            "compute in the chunk dispatches: a real query at position p "
+            "sees p + 1 keys (pad rows of a final chunk are not counted)")
+        self.chunk_window_pairs = c(
+            "serving_chunk_window_pairs",
+            "the same in a WINDOW layer, min(p + 1, window) keys a query "
+            "(written only for a model with window layers)")
+        self.window_pages_released = c(
+            "serving_window_pages_released",
+            "pages the window layers' pool took back from rows whose "
+            "window slid past them")
         self.decode_read_pages = c(
             "serving_decode_read_pages",
             "pool pages the decode steps' paged attention reads: over ALL "
@@ -390,6 +409,17 @@ class _EngineTelemetry:
         self.pool_bytes = {s: pbytes.labels(replica=replica, tp=tp,
                                             state=s)
                            for s in _POOL_STATES}
+        # ---- a model with window layers keeps a second pool: each by
+        # the pages in use, written only by an engine that has both
+        by_pool = r.gauge(
+            "serving_kv_pool_bytes",
+            "bytes of the KV pages in use, by pool: global (the layers "
+            "that keep every page of a request) and window (the layers "
+            "whose pages are given back as the window slides)",
+            labels=("replica", "tp", "pool"))
+        self.kv_pool_bytes = {p: by_pool.labels(replica=replica, tp=tp,
+                                                pool=p)
+                              for p in ("global", "window")}
         self.pool_frag = g(
             "kv_pool_fragmentation",
             "free-list fragmentation: 1 - largest contiguous free run "
@@ -971,6 +1001,28 @@ class ServingEngine:
                     f"tp_degree={self.tp_degree} with a recurrent model: "
                     "the recurrent-state store and the state-update kernel "
                     "are not sharded over heads")
+        if has_window_layers(model) or (
+                draft_model is not None and has_window_layers(draft_model)):
+            # a window layer's pages are given back as its window slides;
+            # each of these counts on pages that stay
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with a model that has window "
+                    "layers: a page that fell out of a row's window is "
+                    "given back to the pool, so a cached prefix's window "
+                    "pages are not there to share")
+            if draft_model is not None:
+                raise ValueError(
+                    "draft_model= with a model that has window layers: "
+                    "the speculation programs address one pool through "
+                    "one block table, and a rejected draft token would "
+                    "roll the cursor back over pages already given back")
+            if self.tp_degree > 1:
+                raise ValueError(
+                    f"tp_degree={self.tp_degree} with a model that has "
+                    "window layers: the window pool has no sharded "
+                    "placement (the sharded decode program is built over "
+                    "one pool)")
         # ---- expert layers: the width of the counts a model that
         # publishes ``expert_counts_width()`` returns from every
         # program, else 0 (``expert_histogram``)
@@ -1068,7 +1120,8 @@ class ServingEngine:
         # owner, generation/cache_manager.py
         geom = dict(max_batch=max_batch, page_size=page_size,
                     max_seq_len=max_seq_len, kv_dtype=self.kv_dtype,
-                    pool_sharding=pool_sharding, tp_degree=self.tp_degree)
+                    pool_sharding=pool_sharding, tp_degree=self.tp_degree,
+                    step_tokens=self.chunk)
         self._caches = CacheManager(model, num_pages=num_pages, dtype=dtype,
                                     **geom)
         maxpos = getattr(getattr(model, "config", None),
@@ -1249,6 +1302,8 @@ class ServingEngine:
         # free-list epoch moved (steady-state decode never moves it)
         self._pool_frag_epoch = -1
         self._pool_frag = 0.0
+        # the window pool's released pages already counted
+        self._window_released_seen = 0
         self._observe_bucket()
 
     # ------------------------------------------------------------ frontend
@@ -1574,8 +1629,7 @@ class ServingEngine:
         return DecodeKey(
             kind=kind, model_sig=self._model_sig,
             batch_bucket=self.max_batch if bucket is None else bucket,
-            page_budget=(self.pool.num_pages, self.pool.page_size,
-                         self.pool.max_pages_per_seq),
+            page_budget=self._caches.page_budget,
             dtype=str(self.pool.k_pages[0].dtype),
             flags=self._flags.as_tuple(), extra=extra)
 
@@ -1977,7 +2031,7 @@ class ServingEngine:
             remaining = req.max_new_tokens - len(req.tokens)
             self._caches.allocate(slot, p + remaining)
         self._reset_state(slot)
-        bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
+        bt = self._caches.tables(slot, p)
         # per-request prefill timeline span  # tracecheck: disable=TRC007
         with self._m.span("request.prefill", rid=req.rid, prompt_len=p,
                           step=self._step_no):
@@ -2049,7 +2103,7 @@ class ServingEngine:
         # span a chunk  # tracecheck: disable=TRC007
         with self._m.span("engine.prefill_chunk", step=self._step_no,
                           rid=req.rid, pos=pos, last=last):
-            bt = jnp.asarray(self.pool.block_tables[slot:slot + 1])
+            bt = self._caches.tables(slot, c)
             sl = jnp.asarray(np.full((1,), pos, np.int32))
             t0 = time.perf_counter()
             pools = self._caches.take_caches()
@@ -2069,7 +2123,7 @@ class ServingEngine:
                 # dispatch stays async
                 tok = int(tok)
         tnow = time.perf_counter()
-        self._observe_chunk(tnow - t0, end - pos, final=last)
+        self._observe_chunk(tnow - t0, pos, end - pos, final=last)
         if not last:
             return
         replay = bool(req.tokens)
@@ -3482,6 +3536,9 @@ class ServingEngine:
         m.decode_slots.inc(self.bucket)
         m.decode_live_tokens.inc(
             int(sum(self.pool.seq_lens[r.slot] for r in rows)))
+        windowed = self._caches.window_read_tokens(r.slot for r in rows)
+        if windowed is not None:
+            m.decode_window_tokens.inc(windowed)
         # every row of the rung rides the kernel, decoding or not (an
         # idle slot reads the null page, a mid-prefill row its cursor);
         # with the step's own token, or block of them
@@ -3533,6 +3590,14 @@ class ServingEngine:
             bytes_in_use=led["bytes_in_use"],
             pages_shared=led["pages_shared"], pages_pinned=pinned,
             pages_spilled=led["pages_spilled"])
+        if "window_bytes_in_use" in led:
+            # the window layers' pool is billed beside the global one,
+            # each by the pages in use
+            m.kv_pool_bytes["global"].set(led["bytes_in_use"])
+            m.kv_pool_bytes["window"].set(led["window_bytes_in_use"])
+            m.window_pages_released.inc(
+                led["window_pages_released"] - self._window_released_seen)
+            self._window_released_seen = led["window_pages_released"]
         if led["state_bytes"]:
             # the state store is billed beside the pages: all of it is
             # resident, the seated slots' share is what is in use
@@ -3598,14 +3663,20 @@ class ServingEngine:
         m.event("engine.spec_round", t0, t1, parent=self._step_span,
                 step=self._step_no, gamma=gamma, accepted=accepted)
 
-    def _observe_chunk(self, dt: float, tokens: int,
+    def _observe_chunk(self, dt: float, pos: int, tokens: int,
                        final: bool = False) -> None:
         """One chunked-prefill dispatch retired: bank its wall clock —
-        the unit a long-prompt arrival can stall decode by — and the
-        real tokens it computed. The final chunk also closes the
-        per-request prefill counter."""
+        the unit a long-prompt arrival can stall decode by — the real
+        tokens it computed, from cursor ``pos``, and the query-key
+        pairs its attention had to compute in a layer of each kind.
+        The final chunk also closes the per-request prefill counter."""
         self._m.prefill_chunk_s.observe(dt)
         self._m.prefill_tokens.inc(tokens)
+        self._m.chunk_attn_pairs.inc(
+            tokens * pos + tokens * (tokens + 1) // 2)
+        windowed = self._caches.window_read_pairs(pos, tokens)
+        if windowed is not None:
+            self._m.chunk_window_pairs.inc(windowed)
         if final:
             self._m.prefills.inc()
 
@@ -3648,21 +3719,39 @@ class ServingEngine:
 # pool-shaped copy
 # (tests/test_chip_compile.py::test_serving_program_copies_no_pool).
 
-def _forward_with_cache(model, params, buffers, ids, states, offset):
+def _forward_with_cache(model, params, buffers, ids, states, offset, **kw):
     """``model.forward_with_cache`` traced functionally: ``(logits,
     states, counts)``. ``counts`` is ``()``, or for a model that
     publishes ``expert_counts_width()`` the 1-tuple of what its expert
     layers counted in this call: the program returns it as one value
-    more, and the engine accumulates it on the device."""
+    more, and the engine accumulates it on the device. ``kw``: the
+    model's own keywords (:func:`_prefill_row`)."""
     from ..jit import functional_call
     if not hasattr(model, "expert_counts_width"):
         return functional_call(
             model, params, ids, states, offset, buffers=buffers,
-            method="forward_with_cache") + ((),)
+            method="forward_with_cache", **kw) + ((),)
     logits, states, counts = functional_call(
         model, params, ids, states, offset, buffers=buffers,
-        method="forward_with_cache", expert_counts=True)
+        method="forward_with_cache", expert_counts=True, **kw)
     return logits, states, (counts,)
+
+
+def _prefill_row(model, params, buffers, ids, states, offset, at):
+    """A b=1 prefill's forward: ``(row, states, counts)``, ``row`` the
+    ``(V,)`` logits of position ``at``, the ONE position a prefill
+    program reads. A model that publishes ``logits_at_position`` is
+    handed the position and runs its head on that row alone (at a
+    vocabulary of 200k and a chunk of 1,024 the whole ``(S, V)`` is
+    0.8 GB of logits and 4 ms of head); any other computes them all and
+    the row is indexed here, as it always was."""
+    if getattr(model, "logits_at_position", False):
+        logits, states, counts = _forward_with_cache(
+            model, params, buffers, ids, states, offset, logits_at=at)
+        return logits[0, 0], states, counts
+    logits, states, counts = _forward_with_cache(
+        model, params, buffers, ids, states, offset)
+    return logits[0, at], states, counts
 
 
 def _build_prefill(note_trace, model):
@@ -3671,10 +3760,10 @@ def _build_prefill(note_trace, model):
         note_trace()
         states = cache_entries(model, pools, PagedDecodeState, bt, sl,
                                **({"slot": slot[0]} if slot else {}))
-        logits, states, counts = _forward_with_cache(
-            model, params, buffers, ids, states, jnp.int32(0))
-        return (jnp.argmax(logits[0, -1].astype(jnp.float32)), states,
-                *counts)
+        row, states, counts = _prefill_row(
+            model, params, buffers, ids, states, jnp.int32(0),
+            ids.shape[1] - 1)
+        return jnp.argmax(row.astype(jnp.float32)), states, *counts
 
     return jax.jit(serving_prefill, donate_argnums=(3,))
 
@@ -3701,10 +3790,9 @@ def _build_chunk_prefill(note_trace, model):
         states = cache_entries(
             model, pools, PagedChunkState, bt, sl,
             **({"slot": slot[0], "n_valid": last_idx + 1} if slot else {}))
-        logits, states, counts = _forward_with_cache(
-            model, params, buffers, ids, states, sl[0])
-        return (jnp.argmax(logits[0, last_idx].astype(jnp.float32)),
-                states, *counts)
+        row, states, counts = _prefill_row(
+            model, params, buffers, ids, states, sl[0], last_idx)
+        return jnp.argmax(row.astype(jnp.float32)), states, *counts
 
     return jax.jit(serving_prefill_chunk, donate_argnums=(3,))
 

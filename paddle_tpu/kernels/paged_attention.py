@@ -17,7 +17,11 @@ TPU-native pieces:
     materialization of a contiguous per-sequence view) and a page past
     a row's length is neither fetched nor computed. Online softmax
     accumulates across pages in VMEM; GQA reads the unexpanded pool at
-    Hkv bandwidth (q heads ride the products' sublane dim).
+    Hkv bandwidth (q heads ride the products' sublane dim). With a
+    static ``window`` a row reads only the pages that hold its last
+    ``window`` positions: the kernel is handed the row's table from the
+    first of them on, ``ceil(window / page) + 1`` slots wide, so the
+    pages before them cost neither a fetch nor a slot of the grid.
   - ``paged_attention_xla`` — gather-based reference (CPU tests, and the
     fallback wherever pallas is off). Materializes the gathered view —
     correct, but pays the copy the kernel avoids.
@@ -111,6 +115,17 @@ class PagedBlockState(NamedTuple):
     block_tables: Any
     seq_lens: Any
     commit: Any
+
+
+class WindowKV(NamedTuple):
+    """A WINDOW attention layer's entry in a model's ``cache_spec()``
+    (a global layer's is the plain ``(kv_heads, head_dim)``): its pages
+    live in a pool of their own whose rows hold the last ``window``
+    positions and the step being written, and give the pages before
+    them back (``generation/cache_manager.py``)."""
+    kv_heads: int
+    head_dim: int
+    window: int
 
 
 def is_paged_state(entry) -> bool:
@@ -253,7 +268,7 @@ def _slot_pages(bt, n_pages, ppb: int):
 
 def _paged_decode_kernel(pg_ref, sl_ref, q_ref, *rest, sm_scale: float,
                          page_size: int, ppb: int, max_pages: int,
-                         quant: bool, grp: int):
+                         quant: bool, grp: int, window: Optional[int]):
     # per slot: a K and a V page block, then (quantized pools) their
     # scale columns in the same order
     k_refs, v_refs = rest[:ppb], rest[ppb:2 * ppb]
@@ -309,7 +324,12 @@ def _paged_decode_kernel(pg_ref, sl_ref, q_ref, *rest, sm_scale: float,
                 preferred_element_type=jnp.float32) * sm_scale
             pos = pg * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 2)                 # (hkv, rep_pad, keys)
-            s = jnp.where(pos < seq_len, s, _NEG_INF)
+            seen = pos < seq_len
+            if window is not None:
+                # the query, at seq_len - 1, sees the last ``window``
+                # positions (the table starts at the page of the first)
+                seen &= pos >= seq_len - window
+            s = jnp.where(seen, s, _NEG_INF)
 
             # the running max and sum are lane-broadcast in their scratch
             m_prev, l_prev = m_ref[:, :, 0:1], l_ref[:, :, 0:1]
@@ -337,7 +357,8 @@ def _paged_decode_kernel(pg_ref, sl_ref, q_ref, *rest, sm_scale: float,
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, seq_lens: jax.Array,
-                    sm_scale: Optional[float] = None) -> jax.Array:
+                    sm_scale: Optional[float] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Single-token decode attention against a paged pool.
 
     q:            (B, H, D) — one query token per sequence
@@ -346,6 +367,11 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                   ``block_tables[b, i]`` (entries past the used count are
                   never fetched)
     seq_lens:     (B,) int32 — valid tokens per sequence
+    window:       static; the query (at ``seq_lens - 1``) sees only the
+                  last ``window`` positions, ``j >= seq_lens - window``.
+                  Pages wholly before them are never addressed, so their
+                  block-table entries may be anything (a window pool
+                  gives them back: :meth:`PagedKVCache.release_before`)
     Returns (B, H, D) in q's dtype.
     """
     h, d = q.shape[1:]
@@ -357,18 +383,53 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return _paged_decode(q, k_pages, v_pages,
                          jnp.asarray(block_tables, jnp.int32),
                          jnp.asarray(seq_lens, jnp.int32),
-                         sm_scale=float(sm_scale), interpret=_interpret())
+                         sm_scale=float(sm_scale), interpret=_interpret(),
+                         **_window_kw(window))
+
+
+def _window_kw(window) -> dict:
+    """``window`` as a keyword of a jitted kernel wrapper, left out where
+    there is none: a call without a window is the call it always was,
+    cache key and all."""
+    if window is None:
+        return {}
+    if int(window) < 1:
+        raise ValueError(f"window must be a positive length, got {window}")
+    return {"window": int(window)}
+
+
+def _table_from(bt, first_pos, span: int, page_size: int):
+    """What a windowed reader is handed: each row's block table from the
+    page of its first visible position ``first_pos`` (B,) on, as many
+    slots as ``span`` positions can lie on, and the positions that come
+    before that page (B,). Lengths and cursors counted less that shift
+    address the narrow table as the whole ones address the wide one
+    (queries and keys shift alike), and no slot before the window is
+    ever addressed."""
+    width = min(bt.shape[1], -(-span // page_size) + 1)
+    first = jnp.maximum(first_pos, 0) // page_size
+    slots = first[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+    return (jnp.take_along_axis(bt, jnp.minimum(slots, bt.shape[1] - 1),
+                                axis=1), first * page_size)
 
 
 # jitted like the writers below: the layers of one program share ONE
 # trace and ONE Mosaic lowering of the kernel
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _paged_decode(q, k_pages, v_pages, bt, sl, *, sm_scale, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "window"))
+def _paged_decode(q, k_pages, v_pages, bt, sl, *, sm_scale, interpret,
+                  window=None):
     b, h, d = q.shape
     hkv, _, page_size, _ = k_pages.shape
     rep = h // hkv
     max_pages = bt.shape[1]
     quant = isinstance(k_pages, QuantizedPages)
+    if window is not None:
+        # the query, at sl - 1, sees from sl - window on: a window's
+        # slots a row, whatever the table's width (a slot costs its
+        # bookkeeping, live or dead)
+        bt, shift = _table_from(bt, sl - window, window, page_size)
+        sl, max_pages = sl - shift, bt.shape[1]
     ppb, grp = _pages_per_step(hkv, page_size, d, max_pages,
                                jnp.dtype(k_pages.dtype).itemsize, quant)
     n_blk = -(-max_pages // ppb)
@@ -398,7 +459,7 @@ def _paged_decode(q, k_pages, v_pages, bt, sl, *, sm_scale, interpret):
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
                           page_size=page_size, ppb=ppb, max_pages=max_pages,
-                          quant=quant, grp=grp),
+                          quant=quant, grp=grp, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_blk),
@@ -422,9 +483,10 @@ def _paged_decode(q, k_pages, v_pages, bt, sl, *, sm_scale, interpret):
 
 
 def paged_attention_xla(q, k_pages, v_pages, block_tables, seq_lens,
-                        sm_scale=None):
+                        sm_scale=None, window=None):
     """Gather-based reference: materializes each sequence's contiguous
-    view (the copy the Pallas kernel avoids), then masked attention."""
+    view (the copy the Pallas kernel avoids), then masked attention
+    (under ``window`` over the last ``window`` positions only)."""
     b, h, d = q.shape
     hkv, _, page_size, _ = k_pages.shape
     rep = h // hkv
@@ -442,6 +504,8 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, seq_lens,
     qg = q.reshape(b, hkv, rep, d).astype(jnp.float32)
     s = jnp.einsum("bhrd,bhtd->bhrt", qg, k.astype(jnp.float32)) * sm_scale
     mask = jnp.arange(t)[None, :] < sl[:, None]
+    if window is not None:
+        mask &= jnp.arange(t)[None, :] >= sl[:, None] - window
     s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhrt,bhtd->bhrd", p, v.astype(jnp.float32))
@@ -452,7 +516,7 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, seq_lens,
 def _paged_chunk_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
                         sm_scale: float, page_size: int, s_chunk: int,
                         rows: int, max_pages: int, quant: bool = False,
-                        block_bits: int = 0):
+                        block_bits: int = 0, window: Optional[int] = None):
     if quant:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -495,7 +559,10 @@ def _paged_chunk_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         q_pos = start + jax.lax.rem(r_iota, s_chunk)
         kv_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (rows_pad, page_size), 1)
-        s = jnp.where(kv_pos <= (q_pos | block_bits), s, _NEG_INF)
+        seen = kv_pos <= (q_pos | block_bits)
+        if window is not None:
+            seen &= kv_pos > q_pos - window
+        s = jnp.where(seen, s, _NEG_INF)
 
         m_prev = m_ref[:, 0:1]
         l_prev = l_ref[:, 0:1]
@@ -522,7 +589,8 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, block_tables: jax.Array,
                           start: jax.Array,
                           sm_scale: Optional[float] = None,
-                          block: int = 1) -> jax.Array:
+                          block: int = 1,
+                          window: Optional[int] = None) -> jax.Array:
     """Chunked-prefill attention read straight through the block table —
     the copy-free replacement for ``gather_paged_view`` +
     ``cached_attention`` on the chunk hot path (the r12 leftover).
@@ -542,6 +610,12 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
            diffusion): a query sees every key up to the end of its own
            block of B positions, ``k <= q | (B - 1)``. ``start`` and S
            are then multiples of B, so the chunk holds whole blocks.
+    window: static; query ``i`` of the chunk also sees no key before
+           ``start + i + 1 - window`` (``q - k < window``). The kernel
+           is handed the table from the page of ``start + 1 - window``
+           on, ``ceil((window + S) / page) + 1`` slots wide: key pages
+           wholly before it are never addressed, so their block-table
+           entries may be anything (causal only).
     Returns (B, S, H, D) in q's dtype. Rows past the real prompt tail
     (final-chunk padding) emit garbage the caller discards.
     """
@@ -569,6 +643,31 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
     def kv_index(b_, h_, j, bt_ref, sl_ref):
         return (h_, bt_ref[b_, j], 0, 0)
 
+    kernel_kw = {}
+    if window is not None:
+        if block != 1 or int(window) < 1:
+            raise ValueError("a window is a positive length over the "
+                             f"causal mask; got window={window}, "
+                             f"block={block}")
+        # the chunk's first query, at ``start``, sees from ``start + 1 -
+        # window`` on, and no later query sees further back: the grid is
+        # the window and the chunk wide, whatever the table's width
+        kernel_kw = {"window": int(window)}
+        bt, shift = _table_from(bt, st + 1 - int(window), int(window) + s,
+                                page_size)
+        st, max_pages = st - shift, bt.shape[1]
+
+    # the query and output blocks (double-buffered), the three scratch
+    # accumulators and the score temporaries all grow with the chunk's
+    # rows; past Mosaic's default scope of 16 MiB the call says what it
+    # needs (v5e has 128 MiB). A call under it is the call it always was
+    vmem = rows_pad * (4 * d * q.dtype.itemsize + (d + 2 * _LANES) * 4
+                       + 4 * page_size * 4)
+    call_kw = {}
+    if vmem > (12 << 20):
+        call_kw["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20))
+
     quant = isinstance(k_pages, QuantizedPages)
     in_specs = [
         pl.BlockSpec((1, 1, rows_pad, d), q_index),
@@ -586,7 +685,7 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
         functools.partial(_paged_chunk_kernel, sm_scale=float(sm_scale),
                           page_size=page_size, s_chunk=s, rows=rows,
                           max_pages=max_pages, quant=quant,
-                          block_bits=_block_bits(block)),
+                          block_bits=_block_bits(block), **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, max_pages),
@@ -601,6 +700,7 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows_pad, d), q.dtype),
         interpret=_interpret(),
         name="paged_chunk_attention",
+        **call_kw,
     )(bt, st, *operands)
     out = out[:, :, :rows].reshape(b, hkv, rep, s, d)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
@@ -616,7 +716,7 @@ _CHUNK_GROUP_KEYS = 128
 
 
 def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, start,
-                              sm_scale=None, block: int = 1):
+                              sm_scale=None, block: int = 1, window=None):
     """Copy-free XLA twin of :func:`paged_chunk_attention` (CPU tests,
     and the fallback wherever pallas is off): ``lax.fori_loop`` over
     page GROUPS with online softmax, so the live workspace is one
@@ -645,6 +745,8 @@ def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, start,
     qg = qg.reshape(b, hkv, rep, s, d)
     q_pos = st[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (B, S)
     q_pos = q_pos | _block_bits(block)      # block-causal: the block's end
+    if window is not None and block != 1:
+        raise ValueError("a window is over the causal mask (block=1)")
 
     def body(j, carry):
         acc, m, l = carry
@@ -657,6 +759,8 @@ def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, start,
         kv_pos = (j * grp * page_size
                   + jnp.arange(grp * page_size, dtype=jnp.int32))
         vis = kv_pos[None, None, :] <= q_pos[:, :, None]           # (B,S,Gp)
+        if window is not None:
+            vis &= kv_pos[None, None, :] > q_pos[:, :, None] - window
         sc = jnp.where(vis[:, None, None], sc, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
         m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
@@ -1140,6 +1244,9 @@ class PagedKVCache:
                                      np.int32)
         self.seq_lens = np.zeros((max_batch,), np.int32)
         self._pages_used = np.zeros((max_batch,), np.int32)
+        # a row's table slots before this one were given back
+        # (``release_before``): it holds slots [first, used)
+        self._pages_first = np.zeros((max_batch,), np.int32)
         # per-page reference counts: a page may be owned by one sequence
         # (rc=1), shared read-only across sequences with a common prompt
         # prefix, and/or pinned by a prefix cache — it returns to the
@@ -1157,7 +1264,23 @@ class PagedKVCache:
     def sequence_pages(self, seq_idx: int) -> np.ndarray:
         """The page ids sequence ``seq_idx`` holds, in position order (a
         view of its block-table row: read it before freeing)."""
-        return self.block_tables[seq_idx, :int(self._pages_used[seq_idx])]
+        return self.block_tables[seq_idx, int(self._pages_first[seq_idx]):
+                                 int(self._pages_used[seq_idx])]
+
+    def release_before(self, seq_idx: int, position: int) -> int:
+        """Give back, from the front, the pages of ``seq_idx`` that lie
+        WHOLLY before ``position`` (a window layer's reader never visits
+        them again); their table slots go to page 0. Returns how many
+        pages went."""
+        first = int(self._pages_first[seq_idx])
+        upto = min(max(int(position), 0) // self.page_size,
+                   int(self._pages_used[seq_idx]))
+        for i in range(first, upto):
+            self.unref_page(int(self.block_tables[seq_idx, i]))
+        if upto > first:
+            self.block_tables[seq_idx, first:upto] = 0
+            self._pages_first[seq_idx] = upto
+        return max(0, upto - first)
 
     def ledger(self, fragmentation: bool = True) -> dict:
         """The memwatch pool ledger: pages/bytes in use, free, and
@@ -1360,16 +1483,19 @@ class PagedKVCache:
         self.block_tables[dst, n:] = 0
         self.seq_lens[dst] = self.seq_lens[src]
         self._pages_used[dst] = self._pages_used[src]
+        self._pages_first[dst] = self._pages_first[src]
         self.block_tables[src, :n] = 0
         self.seq_lens[src] = 0
         self._pages_used[src] = 0
+        self._pages_first[src] = 0
 
     def free_sequence(self, seq_idx: int) -> None:
         n = int(self._pages_used[seq_idx])
-        for i in range(n):
+        for i in range(int(self._pages_first[seq_idx]), n):
             self.unref_page(int(self.block_tables[seq_idx, i]))
         self.block_tables[seq_idx, :n] = 0
         self._pages_used[seq_idx] = 0
+        self._pages_first[seq_idx] = 0
         self.seq_lens[seq_idx] = 0
 
     # ----------------------------------------------------------- writing

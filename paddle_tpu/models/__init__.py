@@ -6,6 +6,7 @@ GPT-3 345M, Llama-2 7B/70B, an ERNIE-style MoE, and an SD UNet — plus
 the BERT/ERNIE encoder family (MLM/NSP pretraining + classification).
 """
 
+from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertForMaskedLM, BertForPretraining,
     BertForSequenceClassification, BertModel, BertPretrainingCriterion,

@@ -710,7 +710,7 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
 
 
 def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
-                                       block=1):
+                                       block=1, window=None):
     """Paged (block-table) variant of the decode attention (reference:
     block_multihead_attention's two phases). ``state`` is a per-layer
     :class:`~paddle_tpu.kernels.paged_attention.PagedDecodeState` or —
@@ -744,7 +744,12 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
     the head's own width. ``block``: a power of two B makes both prefill
     phases BLOCK-causal (a query sees keys up to the end of its own
     block of B, ``k <= q | (B - 1)``), read through the block table as
-    the chunked phase is; the prompt then holds whole blocks."""
+    the chunked phase is; the prompt then holds whole blocks.
+    ``window``: a static length W makes every phase a WINDOW layer's (a
+    query at ``i`` sees ``i - W < j <= i``): the kernels visit only the
+    pages that hold those positions, so ``state`` may be over a pool
+    whose rows give the pages before them back. A whole prompt longer
+    than the window reads through the block table as a chunk does."""
     from .. import flags
     from ..kernels.decode_attention import cached_attention
     from ..kernels.paged_attention import (PagedBlockState, PagedChunkState,
@@ -762,6 +767,12 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
                   and flags.is_tpu_backend())
     chunked = isinstance(state, PagedChunkState)
     block_step = isinstance(state, PagedBlockState)
+    if window is not None and (block_step or block != 1):
+        raise NotImplementedError(
+            "a window is over the causal mask: no block step or "
+            "block-causal prefill under one")
+    # a call without a window is the call it always was
+    windowed = {} if window is None else {"window": int(window)}
 
     # a quantized pool reaches here as a NamedTuple whose FIELDS were
     # Tensor-wrapped by functional_call's tree walk (the tuple itself is
@@ -797,7 +808,8 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
             out = out.reshape(b, hkv, s, h // hkv, dp).transpose(
                 0, 2, 1, 3, 4).reshape(b, s, h, dp)[..., :d]
             sl2 = sl + s * commit[0].astype(sl.dtype)
-        elif s > 1 and (chunked or block > 1):
+        elif s > 1 and (chunked or block > 1
+                        or (window is not None and s > window)):
             if qv.shape[0] != 1:
                 raise NotImplementedError(
                     "chunked paged prefill is per-request (B = 1); got "
@@ -812,7 +824,7 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
             attend = (paged_chunk_attention if use_pallas
                       else paged_chunk_attention_xla)
             out = attend(qp, kp2, vp2, bt, sl, sm_scale=sm_scale,
-                         block=block)[..., :d]
+                         block=block, **windowed)[..., :d]
             sl2 = sl + s
         elif s > 1:
             # whole-prompt prefill contract: the sequences must be
@@ -834,7 +846,7 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
             kp2, vp2 = write_paged_kv(kp, vp, kvp[:, 0], vvp[:, 0], bt, sl)
             attend = paged_attention if use_pallas else paged_attention_xla
             out = attend(qp[:, 0], kp2, vp2, bt, sl + 1,
-                         sm_scale=sm_scale)[:, None, :, :d]
+                         sm_scale=sm_scale, **windowed)[:, None, :, :d]
             sl2 = sl + 1
         return out, kp2, vp2, sl2
 

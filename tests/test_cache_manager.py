@@ -268,3 +268,184 @@ def test_rebuild_keeps_the_geometry(zoo, kind):
             eng.decode_key) == keys
     assert serve() == want
     assert dict(cache.stats()["traces"]) == traces
+
+
+# ------------------------------------- two pools: window layers (PR 34)
+# a tiny afmoe: layers window, window, window, global, window; window 16,
+# and dispatches of at most 8 tokens: a window row holds at most
+# ceil((16 + 8) / 8) + 1 = 4 pages
+WINDOW_GEOM = dict(GEOM, max_seq_len=96, num_pages=1 + 4 * 12, step_tokens=8)
+
+
+@pytest.fixture(scope="module")
+def windowed():
+    paddle.seed(34)
+    model = models.AfmoeForCausalLM(models.AfmoeConfig.tiny())
+    model.eval()
+    return model
+
+
+def window_manager(windowed, **over):
+    return CacheManager(windowed, **{**WINDOW_GEOM, **over})
+
+
+def held(m, slot):
+    return len(m.window.sequence_pages(slot))
+
+
+def test_two_pools_by_the_spec(windowed, zoo):
+    from paddle_tpu.generation.cache_manager import has_window_layers
+    assert has_window_layers(windowed)
+    assert not any(has_window_layers(zoo[k]) for k in KINDS)
+    assert kv_heads(windowed) == 2
+    m = window_manager(windowed)
+    assert len(m.pool.k_pages) == 1 and len(m.window.k_pages) == 4
+    assert m.window_len == 16 and m._row_bound == 4
+    # every slot at its bound, and the null page
+    assert m.window.num_pages == 1 + 4 * 4
+    assert m.page_budget == (49, 8, 12, 17)
+    # never more than the global pool has
+    assert window_manager(windowed, num_pages=9).window.num_pages == 9
+    # a whole prompt a dispatch (no chunk): the bound is the row's width
+    assert window_manager(windowed, step_tokens=0)._row_bound == 12
+    assert CacheManager(zoo["gpt"], **GEOM).page_budget == (17, 8, 4)
+
+
+def test_window_row_releases_from_the_front_and_stays_in_its_bound(windowed):
+    m = window_manager(windowed)
+    m.allocate(1, 90)                       # 12 pages of the global pool
+    led = m.ledger()
+    assert led["pages_in_use"] == 12 and led["window_pages_in_use"] == 0
+    assert m._span.tolist() == [0, 90, 0, 0]
+    most, tables = 0, []
+    # a prompt of 61 in chunks of 8, then decode to 90
+    cursor = 0
+    while cursor < 90:
+        step = 8 if cursor < 61 else 1
+        if step == 8:
+            _, wt = m.tables(1, step)
+            wt = np.asarray(wt)[0]
+            cursor = min(cursor + 8, 61)
+        else:
+            (_, wt), _ = m.decode_inputs(2, [1])
+            wt = wt[1]
+            cursor += 1
+        tables.append(wt.copy())
+        most = max(most, held(m, 1))
+        assert held(m, 1) == m.ledger()["window_pages_in_use"]
+        # every position the next read visits has a page
+        first = max(0, m.pool.seq_lens[1] + 1 - 16) // 8
+        assert (wt[first:-(-cursor // 8)] > 0).all()
+        m.pool.seq_lens[1] = cursor         # as the engine does
+    # the window's two pages and the chunk's one (the bound's fourth is
+    # for a window or a chunk that starts inside a page)
+    assert most == 3 <= m._row_bound
+    assert m.window_pages_released == 12 - held(m, 1)
+    assert m.ledger()["window_pages_released"] == m.window_pages_released
+    # released slots point at the null page; a page came back to the row
+    assert (tables[-1][:8] == 0).all()
+    assert len({int(p) for t in tables for p in t if p}) <= 4 < 12
+    m.free(1)
+    led = m.ledger()
+    assert led["pages_in_use"] == 0 and led["window_pages_in_use"] == 0
+    assert m.window.free_page_count() == m.window.num_pages - 1
+    assert not m._span.any()
+
+
+def test_window_pool_holds_every_slot_at_its_bound(windowed):
+    """Admission prices the global pool alone, and that is enough: the
+    window pool has every slot's bound, or as many pages as the global
+    pool, so whatever rows the global pool admitted find their window
+    pages, at every cursor, with the most a dispatch writes."""
+    for kw in (dict(), dict(num_pages=1 + 20, max_batch=2)):
+        m = window_manager(windowed, **kw)
+        rows = m.pool.block_tables.shape[0]
+        spans = [90] * rows if not kw else [90, 60]   # 12 + 8 = 20 pages
+        for slot, span in enumerate(spans):
+            m.allocate(slot, span)
+        assert m.pool.free_page_count() == 0 if kw else True
+        for cursor in range(0, 88, 8):
+            for slot, span in enumerate(spans):
+                if cursor < span:
+                    m.tables(slot, 8)       # would raise if the pool ran out
+                    assert held(m, slot) <= m._row_bound
+            for slot, span in enumerate(spans):
+                m.pool.seq_lens[slot] = min(cursor + 8, span)
+        for slot in range(len(spans)):
+            m.free(slot)
+        assert m.window.free_page_count() == m.window.num_pages - 1
+
+
+def test_tables_hands_out_copies_of_both_rows(windowed, zoo):
+    """The CPU backend aliases a 64-byte-aligned host view instead of
+    copying it (a row of 12 int32 is 48 bytes, so one slot in four of
+    such a table is aligned), and a non-final chunk is not waited for:
+    what ``tables`` handed a program must not change when a move, a
+    free or the next slide rewrites the rows."""
+    for m in (window_manager(windowed),
+              CacheManager(zoo["gpt"], **dict(GEOM, max_seq_len=96,
+                                              num_pages=49))):
+        for slot in range(4):
+            m.allocate(slot, 90)
+            handed = m.tables(slot, 8)
+            handed = handed if isinstance(handed, tuple) else (handed,)
+            want = [np.array(t) for t in handed]
+            assert all((w[0, :1] > 0).all() for w in want)
+            m.free(slot)                    # zeroes the rows
+            for pool in (m.pool, m.window):
+                if pool is not None:
+                    pool.block_tables[slot] = 7
+            for t, w in zip(handed, want):
+                np.testing.assert_array_equal(np.asarray(t), w)
+
+
+def test_move_and_rebuild_cover_both_pools(windowed):
+    m = window_manager(windowed)
+    m.allocate(3, 40)
+    m.tables(3, 8)
+    m.pool.seq_lens[3] = 8
+    m.tables(3, 8)
+    m.pool.seq_lens[3] = 32
+    m.tables(3, 8)                          # gives two pages back
+    want = (m.window.sequence_pages(3).copy(), int(m.window._pages_first[3]),
+            m.pool.sequence_pages(3).copy())
+    assert want[1] == 2
+    m.move(3, 0)
+    np.testing.assert_array_equal(m.window.sequence_pages(0), want[0])
+    np.testing.assert_array_equal(m.pool.sequence_pages(0), want[2])
+    assert m.window._pages_first[0] == 2 and held(m, 3) == 0
+    assert m._span.tolist() == [40, 0, 0, 0]
+    released = m.window_pages_released
+    m.rebuild()
+    led = m.ledger()
+    assert led["pages_in_use"] == 0 and led["window_pages_in_use"] == 0
+    assert not m._span.any()
+    assert m.window_pages_released == released  # a running total
+
+
+def test_take_then_install_with_two_pools(windowed):
+    m = window_manager(windowed)
+    taken = m.take_caches()
+    assert m.detached and len(taken) == 2
+    assert [len(p) for p in taken] == [1, 4]
+    bt = (jnp.zeros((4, 12), jnp.int32), jnp.ones((4, 12), jnp.int32))
+    sl = jnp.zeros((4,), jnp.int32)
+    entries = cache_entries(windowed, taken, PagedDecodeState, bt, sl)
+    assert len(entries) == 5
+    # each layer is handed the table of its kind
+    assert [int(e.block_tables[0, 0]) for e in entries] == [1, 1, 1, 0, 1]
+    assert entries[3].k_pages.shape[1] == 49
+    assert entries[0].k_pages.shape[1] == 17
+    m.install_caches(entries)
+    assert not m.detached
+    assert all(k is not None for k in m.window.k_pages + m.pool.k_pages)
+
+
+def test_export_of_a_window_row_refuses_loudly(windowed):
+    m = window_manager(windowed)
+    m.allocate(0, 24)
+    with pytest.raises(NotImplementedError, match="does not travel"):
+        m.export_slot(0)
+    with pytest.raises(NotImplementedError, match="does not travel"):
+        m.adopt_slot(1, 24, [], 0, None)
+    assert m.ledger()["pages_in_use"] == 3

@@ -145,17 +145,20 @@ def _pool(S, hkv, d, page, n_pages, int8):
 
 
 def _paged(h, hkv, d, *, int8=False, chunk=0, b=8, page=64, max_pages=16,
-           block=1):
+           block=1, window=None):
     def build(S):
         pool = _pool(S, hkv, d, page, 256, int8)
         if chunk:
-            attend = (paged_chunk_attention if block == 1 else
-                      lambda *a: paged_chunk_attention(*a, block=block))
+            attend = (paged_chunk_attention if block == 1 and not window
+                      else lambda *a: paged_chunk_attention(
+                          *a, block=block, window=window))
             return attend, [
                 S((b, chunk, h, d), BF16), pool, pool,
                 S((b, max_pages), I32), S((b,), I32)]
-        return paged_attention, [S((b, h, d), BF16), pool, pool,
-                                 S((b, max_pages), I32), S((b,), I32)]
+        attend = (paged_attention if not window else
+                  lambda *a: paged_attention(*a, window=window))
+        return attend, [S((b, h, d), BF16), pool, pool,
+                        S((b, max_pages), I32), S((b,), I32)]
     return build
 
 
@@ -303,6 +306,16 @@ _TIER1 = {
     "paged_block_write-gqa4-d128-b64": _block_write(4, 128),
     "paged_chunk-sdar-blockcausal4-c256": _paged(32, 4, 128, chunk=256, b=1,
                                                  max_pages=20, block=4),
+    # trinity-mini: the windowed reads at the cell's table width (384
+    # pages a row) and chunk (1,024 queries: 8,192 query rows a KV head,
+    # for which the chunk kernel asks for its VMEM), and the global
+    # layer's chunk
+    "paged_attention-trinity-window2048-b32": _paged(
+        32, 4, 128, b=32, max_pages=384, window=2048),
+    "paged_chunk-trinity-window2048-c1024": _paged(
+        32, 4, 128, chunk=1024, b=1, max_pages=384, window=2048),
+    "paged_chunk-trinity-global-c1024": _paged(
+        32, 4, 128, chunk=1024, b=1, max_pages=384),
     "gmm-gate_up-e128": _grouped(2048, 1536),
     "gmm-down-e128": _grouped(768, 2048),
     "fused_block-int8kv-gqa-b32": _fused(0, 32, int8=True, **_GQA),
@@ -643,6 +656,69 @@ def test_sdar_serving_programs_compile_and_copy_no_pool(chips, program):
     header = text.split("\n", 1)[0]
     assert header.count("may-alias") + header.count("must-alias") \
         == 2 * layers, header[:400]
+
+
+# ------------------- window and global layers over two pools (trinity-mini)
+@pytest.mark.parametrize("program", ["serving_decode_generic",
+                                     "serving_prefill_chunk"])
+def test_afmoe_serving_programs_compile_over_two_pools(chips, program):
+    """trinity-mini as its cell runs it (one dense and four sparse
+    layers, of which three window layers and one global; 32 rows; a
+    global pool of 12,289 pages and a window pool of 1,569; a chunk of
+    1,024): the decode step and the chunk compile for the chip, hold
+    each layer's kernels under their names (the windowed reads keep
+    the names ``paged_attention`` and ``paged_chunk_attention``), alias
+    every pool of BOTH stores to its output and copy none."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.generation import serving
+
+    rows, page, max_pages, window_pages = 32, 64, 384, 49
+    one_chip = SingleDeviceSharding(chips[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    types = models.AfmoeConfig().layer_types[:5]
+    cfg = models.AfmoeConfig(num_hidden_layers=5, num_dense_layers=1,
+                             layer_types=types)
+    with paddle.LazyGuard():
+        model = models.AfmoeForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    model.eval()
+    params, buffers = ({k: S(v.shape, v.dtype) for k, v in state.items()}
+                       for state in model.raw_state())
+    whole = S((4, rows * max_pages + 1, page, 128), BF16)
+    sliding = S((4, rows * window_pages + 1, page, 128), BF16)
+    pools = ([(whole, whole)], [(sliding, sliding)] * 4)
+    if program == "serving_decode_generic":
+        fn = serving._build_generic_decode(lambda: None, model)
+        tables = (S((rows, max_pages), I32), S((rows, max_pages), I32))
+        args = [(S((rows,), I32), S((rows,), I32)), pools, tables,
+                S((rows,), I32)]
+        kernels = {"paged_attention": 5, "paged_kv_write": 5, "gmm": 8}
+    else:
+        fn = serving._build_chunk_prefill(lambda: None, model)
+        tables = (S((1, max_pages), I32), S((1, max_pages), I32))
+        args = [S((1, 1024), I32), pools, tables, S((1,), I32), S((), I32)]
+        kernels = {"paged_chunk_attention": 5, "paged_prompt_write": 5,
+                   "gmm": 8}
+    text = fn.lower(params, buffers, *args).compile().as_text()
+    assert f"HloModule jit_{program}" in text
+    for name, count in kernels.items():
+        found = re.findall(rf"%{name}\S* = ", text)
+        assert len(found) == count, (name, found)
+    # the chunk's head runs on the ONE position the program reads
+    assert "1024,200192]" not in text
+    for pool in (whole, sliding):
+        shape = ",".join(map(str, pool.shape))
+        copies = [ln.strip()[:120] for ln in text.splitlines()
+                  if re.search(rf"= bf16\[{shape}\]\S* copy\(", ln)]
+        assert not copies, copies
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") \
+        == 2 * 5, header[:400]
 
 
 def _abstract(shape, dtype):

@@ -56,7 +56,26 @@ TINY = dataclasses.replace(
         program=dict(config_class="SDARMoEConfig",
                      model_class="SDARMoEForCausalLM"),
         serve=dict(max_batch=2, page_size=8, max_seq_len=64,
-                   prefill_chunk=16)))
+                   prefill_chunk=16)),
+    trinity_prompt_len=61, trinity_new_tokens=6,
+    trinity=dict(
+        name="trinity-mini", arch="afmoe", dtype="float32",
+        model=dict(vocab_size=256, hidden_size=64, intermediate_size=96,
+                   moe_intermediate_size=32, num_hidden_layers=5,
+                   num_dense_layers=1, num_attention_heads=4,
+                   num_key_value_heads=2, head_dim=16, num_experts=8,
+                   num_experts_per_tok=2, num_shared_experts=1,
+                   score_func="sigmoid", route_norm=True, route_scale=2.826,
+                   n_group=1, topk_group=1, sliding_window=16,
+                   layer_types=["sliding_attention"] * 3
+                   + ["full_attention", "sliding_attention"],
+                   mup_enabled=True, max_position_embeddings=512,
+                   rms_norm_eps=1e-5, rope_theta=10000,
+                   initializer_range=0.3),
+        program=dict(config_class="AfmoeConfig",
+                     model_class="AfmoeForCausalLM"),
+        serve=dict(max_batch=2, page_size=8, max_seq_len=96,
+                   prefill_chunk=8)))
 
 
 @pytest.fixture
@@ -71,8 +90,9 @@ def cache_env(monkeypatch):
 def test_one_chip_phases_at_tiny_size():
     lines = chip_smoke.run_phases(TINY)
     assert [ln["phase"] for ln in lines] == [
-        "device", "train", "serve", "fused_decode", "hybrid", "blocks"]
-    device, train, serve, fused, hybrid, blocks = lines
+        "device", "train", "serve", "fused_decode", "hybrid", "blocks",
+        "window"]
+    device, train, serve, fused, hybrid, blocks, window = lines
     assert device["platform"] == "cpu" and device["peak_flops"] is None
     assert train["traces"] == 1 and train["losses"][-1] < train["losses"][0]
     # no Pallas custom call can exist on the CPU — and none is claimed
@@ -92,6 +112,14 @@ def test_one_chip_phases_at_tiny_size():
     assert blocks["forwards"] == 12 and blocks["reveals"] == 9
     assert len(blocks["tokens"]) == 9 and blocks["step_kind"] == "block_step"
     assert not any(blocks["kernels"].values())           # the jnp twins
+    # 61 tokens through eight chunks of 8, then decode: 9 pages in all,
+    # of which the window layers' row never held more than 3 of its 4
+    assert window["chunk_dispatches"] == 8 and window["near_ties"] == 0
+    assert window["span_pages"] == 9 and window["window_row_bound"] == 4
+    assert window["window_pages_most"] == 3
+    assert window["window_pages_released"] == 6
+    assert len(window["tokens"]) == 6 and window["statuses"] == "OK"
+    assert window["largest_gap"] == 0.0                 # f32 on the CPU
 
 
 def test_four_chip_phase_on_virtual_devices():

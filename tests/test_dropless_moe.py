@@ -155,3 +155,115 @@ def test_layer_holds_a_share_and_leaves_counts():
     np.testing.assert_array_equal(layer(x).numpy(), y.numpy())
     with pytest.raises(ValueError, match="not inside"):
         DroplessMoE(H, F, E, K, first=6, count=4)
+
+
+# ------------------------------- the sigmoid router, the bias, the shared
+def _loop_sigmoid(x, router, gate_up, down, bias, scale, top_k=K,
+                  weigh_by_biased=False, select_by_bare=False):
+    """The ``afmoe`` router token by token in float64: each expert
+    scored by a sigmoid of its own, the top k CHOSEN by score + bias,
+    WEIGHTED by the bare scores of the chosen, normalised and scaled.
+    The two flags are the two ways to get it wrong."""
+    x, router, gate_up, down, bias = (np.asarray(a, np.float64) for a in
+                                      (x, router, gate_up, down, bias))
+    out = np.zeros_like(x)
+    tops = []
+    for t, row in enumerate(x):
+        s = 1.0 / (1.0 + np.exp(-(row @ router)))
+        top = np.argsort(-(s if select_by_bare else s + bias),
+                         kind="stable")[:top_k]
+        w = (s + bias if weigh_by_biased else s)[top]
+        w = w / (w.sum() + 1e-20) * scale
+        tops.append(sorted(top))
+        for e, we in zip(top, w):
+            gu = row @ gate_up[e]
+            g, u = gu[:F], gu[F:]
+            out[t] += we * ((g / (1 + np.exp(-g)) * u) @ down[e])
+    return out, tops
+
+
+def _sigmoid_case():
+    router, gate_up, down = _weights(4)
+    x = jax.random.normal(jax.random.PRNGKey(13), (29, H), jnp.float32)
+    # a bias as large as the scores' spread: it changes the choice
+    bias = jax.random.normal(jax.random.PRNGKey(17), (E,), jnp.float32) * 0.3
+    return x, router, gate_up, down, bias
+
+
+def test_sigmoid_router_selects_by_biased_score_and_weighs_by_bare():
+    x, router, gate_up, down, bias = _sigmoid_case()
+    y, counts = dropless_moe(x, router, gate_up, down, top_k=K,
+                             score_func="sigmoid", select_bias=bias,
+                             route_scale=2.826)
+    want, tops = _loop_sigmoid(x, router, gate_up, down, bias, 2.826)
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
+    assert int(counts.sum()) == 29 * K
+    # the case shows something: the bias changes some token's choice, and
+    # either wrong reading of it changes the output
+    _, bare_tops = _loop_sigmoid(x, router, gate_up, down, bias, 2.826,
+                                 select_by_bare=True)
+    assert tops != bare_tops
+    for wrong in (dict(select_by_bare=True), dict(weigh_by_biased=True)):
+        other, _ = _loop_sigmoid(x, router, gate_up, down, bias, 2.826,
+                                 **wrong)
+        assert np.max(np.abs(other - want)) > 1e-3
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.826])
+def test_route_scale_multiplies_the_normalised_weights(scale):
+    x, router, gate_up, down, _ = _sigmoid_case()
+    one, _ = dropless_moe(x, router, gate_up, down, top_k=K,
+                          score_func="sigmoid")
+    got, _ = dropless_moe(x, router, gate_up, down, top_k=K,
+                          score_func="sigmoid", route_scale=scale)
+    np.testing.assert_allclose(got, scale * np.asarray(one), rtol=1e-5,
+                               atol=1e-6)
+
+
+def test_softmax_call_is_unchanged_by_the_new_arguments():
+    """The defaults spelled out are the call without them, to the bit."""
+    x, router, gate_up, down, _ = _sigmoid_case()
+    a, ca = dropless_moe(x, router, gate_up, down, top_k=K)
+    b, cb = dropless_moe(x, router, gate_up, down, top_k=K,
+                         score_func="softmax", select_bias=None,
+                         route_scale=1.0)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(ca), np.asarray(cb))
+    with pytest.raises(ValueError, match="score_func"):
+        dropless_moe(x, router, gate_up, down, top_k=K, score_func="tanh")
+
+
+def test_shared_expert_counts_once_over_the_shares():
+    """Every holder of a share has the shared expert alike: the shares'
+    outputs added up hold it once a holder, so the uncut layer is their
+    sum less the surplus copies."""
+    import paddle_tpu as paddle
+    kw = dict(score_func="sigmoid", route_scale=2.826, select_bias=True,
+              shared_intermediate_size=F)
+    whole = DroplessMoE(H, F, E, K, **kw)
+    rng = np.random.default_rng(1)
+    state = {n: rng.normal(size=tuple(p.shape)).astype("float32") * 0.2
+             for n, p in whole.named_parameters()}
+    whole.load_raw_state({n: jnp.asarray(v) for n, v in state.items()})
+    x = paddle.to_tensor(rng.normal(size=(3, 7, H)).astype("float32"))
+    parts = 0.0
+    for first in (0, 4):
+        share = DroplessMoE(H, F, E, K, first=first, count=4, **kw)
+        cut = dict(state, gate_up=state["gate_up"][first:first + 4],
+                   down=state["down"][first:first + 4])
+        share.load_raw_state({n: jnp.asarray(v) for n, v in cut.items()})
+        parts = parts + share(x).numpy()
+    xv = jnp.asarray(x.numpy().reshape(-1, H))
+    gu = xv @ state["shared_gate_up"]
+    shared = np.asarray((jax.nn.silu(gu[:, :F]) * gu[:, F:])
+                        @ state["shared_down"]).reshape(3, 7, H)
+    np.testing.assert_allclose(parts - shared, whole(x).numpy(), rtol=1e-4,
+                               atol=1e-5)
+    # and the shared expert is there at all
+    routed, _ = dropless_moe(
+        xv, *(jnp.asarray(state[n]) for n in ("router", "gate_up", "down")),
+        top_k=K, score_func="sigmoid", route_scale=2.826,
+        select_bias=jnp.asarray(state["expert_bias"]))
+    np.testing.assert_allclose(
+        whole(x).numpy().reshape(-1, H) - np.asarray(routed),
+        shared.reshape(-1, H), rtol=1e-4, atol=1e-5)

@@ -447,3 +447,139 @@ class TestBlockMultiheadAttention:
                 np.array([5, 0], np.int32),
                 np.array([1, 0], np.int32),       # inactive row
                 np.array([[1, 2], [3, 4]], np.int32))
+
+
+# ------------------------------------------------------- a window (PR 34)
+def _dense_window(q_rows, q_pos, k, v, window):
+    """Plain masked attention of query rows at absolute positions
+    ``q_pos`` over contiguous k/v (hkv, t, d): ``j <= i`` and, under a
+    window, ``i - j < window``."""
+    h, d = q_rows.shape[1:]
+    rep = h // k.shape[0]
+    out = np.zeros(q_rows.shape, np.float32)
+    j = np.arange(k.shape[1])
+    for r, i in enumerate(q_pos):
+        seen = j <= i
+        if window is not None:
+            seen &= i - j < window
+        for head in range(h):
+            s = (q_rows[r, head] @ k[head // rep].T) / np.sqrt(d)
+            s = np.where(seen, s, -np.inf)
+            p = np.exp(s - s.max())
+            out[r, head] = (p / p.sum()) @ v[head // rep]
+    return out
+
+
+def _contiguous(pages, bt_row, t):
+    arr = np.asarray(pages, np.float32)
+    return np.concatenate([arr[:, p] for p in bt_row], axis=1)[:, :t]
+
+
+class TestWindow:
+    """``window=`` in the decode and the chunk kernels (interpret mode)
+    and their XLA twins against the dense mask, at lengths under, at and
+    over the window and across a page edge; pages before the window are
+    never read, so their table entries may point anywhere."""
+
+    PAGE, WINDOW = 8, 20
+
+    def _pool(self, rng, n_pages=24):
+        return make_pool(rng, hkv=2, num_pages=n_pages, page=self.PAGE, d=32)
+
+    # under the window, at it, one over, at a page edge of the window's
+    # start (28 - 20 = 8), past it, and far past
+    @pytest.mark.parametrize("lens", [(5, 19, 20, 21), (28, 29, 44, 64)])
+    def test_decode_matches_dense_mask(self, lens):
+        rng = np.random.default_rng(3)
+        kp, vp = self._pool(rng, n_pages=40)
+        b, h = len(lens), 4
+        bt = rng.permutation(np.arange(1, 40))[:b * 8].reshape(b, 8)
+        bt = bt.astype(np.int32)
+        sl = np.asarray(lens, np.int32)
+        q = jnp.asarray(rng.standard_normal((b, h, 32)), jnp.float32)
+        want = np.stack([
+            _dense_window(np.asarray(q)[r:r + 1], [sl[r] - 1],
+                          _contiguous(kp, bt[r], sl[r]),
+                          _contiguous(vp, bt[r], sl[r]), self.WINDOW)[0]
+            for r in range(b)])
+        # what a window pool does to the pages before the window: the
+        # table slot goes to the null page, whose content is not the row's
+        freed = bt.copy()
+        for r in range(b):
+            freed[r, :max(0, sl[r] - self.WINDOW) // self.PAGE] = 0
+        for attend in (paged_attention, paged_attention_xla):
+            for table in (bt, freed):
+                got = attend(q, kp, vp, jnp.asarray(table), jnp.asarray(sl),
+                             window=self.WINDOW)
+                np.testing.assert_allclose(np.asarray(got), want,
+                                           rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("start", [0, 8, 13, 24, 40])
+    def test_chunk_matches_dense_mask(self, start):
+        from paddle_tpu.kernels.paged_attention import (
+            paged_chunk_attention, paged_chunk_attention_xla)
+        rng = np.random.default_rng(4)
+        kp, vp = self._pool(rng)
+        s, h = 16, 4
+        bt = rng.permutation(np.arange(1, 24))[:8].astype(np.int32)[None]
+        q = jnp.asarray(rng.standard_normal((1, s, h, 32)), jnp.float32)
+        t = start + s
+        want = _dense_window(np.asarray(q)[0], start + np.arange(s),
+                             _contiguous(kp, bt[0], t),
+                             _contiguous(vp, bt[0], t), self.WINDOW)
+        freed = bt.copy()
+        freed[0, :max(0, start + 1 - self.WINDOW) // self.PAGE] = 0
+        for attend in (paged_chunk_attention, paged_chunk_attention_xla):
+            for table in (bt, freed):
+                got = attend(q, kp, vp, jnp.asarray(table),
+                             jnp.asarray([start], jnp.int32),
+                             window=self.WINDOW)
+                np.testing.assert_allclose(np.asarray(got)[0], want,
+                                           rtol=2e-5, atol=2e-5)
+
+    def test_the_window_bites(self):
+        """Past the window the windowed read differs from the full one
+        (else the cases above would show nothing)."""
+        rng = np.random.default_rng(5)
+        kp, vp = self._pool(rng)
+        bt = jnp.asarray(np.arange(1, 9, dtype=np.int32)[None])
+        q = jnp.asarray(rng.standard_normal((1, 4, 32)), jnp.float32)
+        sl = jnp.asarray([44], jnp.int32)
+        full = paged_attention(q, kp, vp, bt, sl)
+        cut = paged_attention(q, kp, vp, bt, sl, window=self.WINDOW)
+        assert float(jnp.max(jnp.abs(full - cut))) > 1e-3
+
+    @pytest.mark.parametrize("kernel", ["decode", "decode_xla", "chunk",
+                                        "chunk_xla"])
+    def test_no_window_is_todays_output(self, kernel):
+        """``window=None`` is the call without the keyword, to the bit,
+        and so is a window no row reaches."""
+        from paddle_tpu.kernels.paged_attention import (
+            paged_chunk_attention, paged_chunk_attention_xla)
+        rng = np.random.default_rng(6)
+        kp, vp = self._pool(rng)
+        bt = jnp.asarray(rng.permutation(np.arange(1, 17)).reshape(2, 8)
+                         .astype(np.int32))
+        if kernel.startswith("decode"):
+            fn = paged_attention if kernel == "decode" else paged_attention_xla
+            args = (jnp.asarray(rng.standard_normal((2, 4, 32)), jnp.float32),
+                    kp, vp, bt, jnp.asarray([37, 64], jnp.int32))
+        else:
+            fn = (paged_chunk_attention if kernel == "chunk"
+                  else paged_chunk_attention_xla)
+            args = (jnp.asarray(rng.standard_normal((1, 16, 4, 32)),
+                                jnp.float32),
+                    kp, vp, bt[:1], jnp.asarray([21], jnp.int32))
+        base = np.asarray(fn(*args))
+        np.testing.assert_array_equal(np.asarray(fn(*args, window=None)),
+                                      base)
+        np.testing.assert_array_equal(np.asarray(fn(*args, window=4096)),
+                                      base)
+
+    def test_bad_window_refused(self):
+        rng = np.random.default_rng(7)
+        kp, vp = self._pool(rng)
+        q = jnp.zeros((1, 4, 32), jnp.float32)
+        with pytest.raises(ValueError, match="window"):
+            paged_attention(q, kp, vp, jnp.zeros((1, 8), jnp.int32),
+                            jnp.asarray([3], jnp.int32), window=0)
