@@ -54,7 +54,8 @@ from ..analysis import key_vocab
 # host — on the chip that pulled the whole KV pool back every dispatch
 from ..core.tensor import _val
 from ..kernels.paged_attention import (PagedBlockState, PagedDecodeState,
-                                       PagedKVCache)
+                                       PagedKVCache, chunk_tile_pairs,
+                                       chunk_tiling)
 from ..testing import faults
 from .cache_manager import (CacheManager, cache_entries,
                             has_recurrent_layers, has_window_layers,
@@ -248,6 +249,17 @@ class _EngineTelemetry:
             "serving_chunk_window_pairs",
             "the same in a WINDOW layer, min(p + 1, window) keys a query "
             "(written only for a model with window layers)")
+        self.chunk_attn_tile_pairs = c(
+            "serving_chunk_attn_tile_pairs",
+            "query-key pairs of the (query tile, key block) pairs "
+            "paged_chunk_attention computed for those chunks in such a "
+            "layer, masked pairs and pad rows included: "
+            "serving_chunk_attn_pairs over this is the fill of what was "
+            "computed")
+        self.chunk_window_tile_pairs = c(
+            "serving_chunk_window_tile_pairs",
+            "the same in a WINDOW layer, beside "
+            "serving_chunk_window_pairs")
         self.window_pages_released = c(
             "serving_window_pages_released",
             "pages the window layers' pool took back from rows whose "
@@ -1124,6 +1136,10 @@ class ServingEngine:
                     step_tokens=self.chunk)
         self._caches = CacheManager(model, num_pages=num_pages, dtype=dtype,
                                     **geom)
+        # how the kernel cuts the chunk program's attention reads, per
+        # layer kind (here, where a model it cannot be said of is refused
+        # before a step's recovery could swallow the refusal)
+        self._chunk_cut = self._chunk_tilings() if self.chunk else ()
         maxpos = getattr(getattr(model, "config", None),
                          "max_position_embeddings", None)
         if maxpos is not None and max_seq_len > maxpos:
@@ -1693,6 +1709,35 @@ class ServingEngine:
                           extra=(self.chunk,)),
                 functools.partial(_build_chunk_prefill, model=self.model))  # keycheck: disable=KEY002 — the documented model-object closure (model_sig rides the key)
         return self._chunk_fn
+
+    def _chunk_tilings(self) -> tuple:
+        """How ``paged_chunk_attention`` cuts the chunk program's reads
+        (``chunk_tiling`` of the shapes that program hands the kernel:
+        the chunk, the pool's page, the table's width, the model's
+        query heads over the pool's KV heads): in a global layer and, behind
+        it, in a window layer of a model that has them. What
+        ``serving_chunk_*_tile_pairs`` count by
+        (``tests/test_afmoe.py`` holds them to what the kernel's wrapper
+        computes while the program is traced)."""
+        pool = self.pool
+        heads = getattr(getattr(self.model, "config", None),
+                        "num_attention_heads", None)
+        if heads is None or int(heads) % pool.num_kv_heads:
+            raise ValueError(
+                "chunked prefill (prefill_chunk > 0) needs the model's "
+                "config.num_attention_heads, a multiple of its "
+                f"{pool.num_kv_heads} KV heads: the chunk kernel tiles its "
+                f"query rows by their ratio; got {heads!r}")
+        heads = int(heads)
+        shapes = dict(
+            s=self.chunk, rep=heads // pool.num_kv_heads,
+            page_size=pool.page_size,
+            max_pages=pool.block_tables.shape[1],
+            block=1 if self._block is None else self._block[0])
+        cut = (chunk_tiling(**shapes),)
+        if self._caches.window is not None:
+            cut += (chunk_tiling(window=self._caches.window_len, **shapes),)
+        return cut
 
     def _stacked_weights(self, spec) -> tuple:
         """Build (once) the per-group MultiBlockDecodeWeights the N-layer
@@ -3668,7 +3713,8 @@ class ServingEngine:
         """One chunked-prefill dispatch retired: bank its wall clock —
         the unit a long-prompt arrival can stall decode by — the real
         tokens it computed, from cursor ``pos``, and the query-key
-        pairs its attention had to compute in a layer of each kind.
+        pairs its attention had to compute in a layer of each kind,
+        beside the pairs the kernel's tiles made of them.
         The final chunk also closes the per-request prefill counter."""
         self._m.prefill_chunk_s.observe(dt)
         self._m.prefill_tokens.inc(tokens)
@@ -3677,6 +3723,11 @@ class ServingEngine:
         windowed = self._caches.window_read_pairs(pos, tokens)
         if windowed is not None:
             self._m.chunk_window_pairs.inc(windowed)
+        # what the kernel's tiling made of them, per layer kind
+        for counter, tl in zip((self._m.chunk_attn_tile_pairs,
+                                self._m.chunk_window_tile_pairs),
+                               self._chunk_cut):
+            counter.inc(chunk_tile_pairs(tl, pos))
         if final:
             self._m.prefills.inc()
 

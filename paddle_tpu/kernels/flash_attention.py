@@ -253,7 +253,11 @@ def _softmax_update(s, m_prev, l_prev):
     too): as ``(bq, 1)`` columns, one number a vreg, each of them and
     each broadcast over the block was a relayout a block step, and that,
     not the products, was what the forward waited for (KERNEL_DECISIONS.md
-    "Flash attention operands")."""
+    "Flash attention operands").
+
+    Also the serving chunk read's step (``paged_attention.
+    _paged_chunk_kernel``): the clamp below is what makes a chunk row
+    that sees no key emit zeros there, so a change to it changes both."""
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # clamp for fully-masked rows: with m_new == -inf, exp(s - m_new)
     # would be exp(0) = 1 for every masked score — clamping to 0 makes

@@ -22,6 +22,11 @@ TPU-native pieces:
     ``window`` positions: the kernel is handed the row's table from the
     first of them on, ``ceil(window / page) + 1`` slots wide, so the
     pages before them cost neither a fetch nor a slot of the grid.
+  - ``paged_chunk_attention`` — Pallas chunked-prefill kernel: a grid
+    step is a tile of the chunk's query tokens (with all the query heads
+    of a KV head) against a key block of several pages fetched the same
+    way; a tile walks only the blocks between the first and the last key
+    it can see, and masks only those on the edges (``chunk_tiling``).
   - ``paged_attention_xla`` — gather-based reference (CPU tests, and the
     fallback wherever pallas is off). Materializes the gathered view —
     correct, but pays the copy the kernel avoids.
@@ -41,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _NN, _NT, _dot, _lanes, _softmax_update
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -226,6 +233,9 @@ _TEMP_VMEM_BYTES = 8 << 20
 # product and a softmax update a page. On the chip 256 beat 128 and 64 at
 # every length tried (KERNEL_DECISIONS.md "Decode paged attention").
 _GROUP_KEYS = 256
+# Rows a query tile of the chunk kernel is cut to whole multiples of: the
+# sublanes of a packed (bf16) tile.
+_ROW_ALIGN = 16
 
 
 def _pages_per_step(hkv: int, page_size: int, d: int, max_pages: int,
@@ -245,25 +255,32 @@ def _pages_per_step(hkv: int, page_size: int, d: int, max_pages: int,
     return ppb, max(1, min(_GROUP_KEYS // page_size, ppb))
 
 
+def _parked(pages, live):
+    """``pages`` (steps, slots) in grid order with the dead entries
+    replaced by what the slot fetched at its last live step — of an
+    earlier row, if need be — so that a dead step moves nothing; before
+    a slot's first live step it waits on that step's page, so the step
+    itself moves nothing either (the parking rule of ``_page_write``).
+    Flat, as the index maps read it."""
+    pages = jnp.where(live, pages, 0)
+    step = jnp.arange(pages.shape[0], dtype=jnp.int32)[:, None]
+    prev = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    first = jnp.argmax(live, axis=0).astype(jnp.int32)[None, :]
+    held = jnp.where(prev >= 0, prev, first)
+    return jnp.take_along_axis(pages, held, axis=0).reshape(-1)
+
+
 def _slot_pages(bt, n_pages, ppb: int):
-    """The pool page each slot of each grid step fetches, ``(B * n_blk *
-    ppb,)`` int32 in grid order. A live slot (page ``j * ppb + i`` of a
-    row that holds it) fetches its page; a dead one repeats what the
-    slot fetched at its last live step — of an earlier row, if need be —
-    so it moves nothing; before a slot's first live step it waits on
-    that step's page, so the step itself moves nothing either (the
-    parking rule of ``_page_write``)."""
+    """The pool page each slot of each grid step of the decode kernel
+    fetches, ``(B * n_blk * ppb,)`` int32 in grid order: a live slot
+    (page ``j * ppb + i`` of a row that holds it) fetches its page, a
+    dead one is parked (``_parked``)."""
     b, max_pages = bt.shape
     n_blk = -(-max_pages // ppb)
     bt = jnp.pad(bt, ((0, 0), (0, n_blk * ppb - max_pages)))
     live = (jnp.arange(n_blk * ppb, dtype=jnp.int32)[None, :]
             < n_pages[:, None]).reshape(b * n_blk, ppb)
-    pages = jnp.where(live, bt.reshape(b * n_blk, ppb), 0)
-    step = jnp.arange(b * n_blk, dtype=jnp.int32)[:, None]
-    prev = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
-    first = jnp.argmax(live, axis=0).astype(jnp.int32)[None, :]
-    held = jnp.where(prev >= 0, prev, first)
-    return jnp.take_along_axis(pages, held, axis=0).reshape(-1)
+    return _parked(bt.reshape(b * n_blk, ppb), live)
 
 
 def _paged_decode_kernel(pg_ref, sl_ref, q_ref, *rest, sm_scale: float,
@@ -513,17 +530,132 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, seq_lens,
 
 
 # -------------------------------------- chunk-native prefill attention
-def _paged_chunk_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
-                        sm_scale: float, page_size: int, s_chunk: int,
-                        rows: int, max_pages: int, quant: bool = False,
-                        block_bits: int = 0, window: Optional[int] = None):
+# One grid step is a TILE of query tokens against a KEY BLOCK of ``ppb``
+# pages (about ``_GROUP_KEYS`` keys, the pages side by side as one
+# operand, fetched like the decode kernel's: the pool rides in ``ppb``
+# times over and a slot that has nothing to fetch repeats the page it
+# holds). A tile is a contiguous run of chunk tokens with all the ``rep``
+# query heads of the KV head (row = token * rep + head), so its first and
+# last position bound the keys it can see: the grid walks a tile's key
+# blocks from the first it sees on, a (tile, block) pair past the last is
+# not computed, a pair wholly inside them takes no mask, and only the
+# pairs on the causal diagonal, at the window's edge and at the table's
+# end pay for one. The statistics are lane-replicated and touched once a
+# block (``flash_attention._softmax_update``). Every size comes from the
+# call's shapes (``chunk_tiling``); KERNEL_DECISIONS.md "Chunk paged
+# attention" has the chip timings that set the rule.
+
+class ChunkTiling(NamedTuple):
+    """How ``paged_chunk_attention`` cuts a call of its shapes, and what
+    the serving engine's ``serving_chunk_*_tile_pairs`` counters count
+    by (``chunk_tile_pairs``)."""
+    tile: int          # query tokens a tile
+    n_tiles: int       # tiles the (padded) chunk makes
+    ppb: int           # pages a key block
+    n_blk: int         # key-block steps of the grid: the most a tile sees
+    page_size: int
+    max_pages: int     # the block table's width
+    window: Optional[int]
+    block_bits: int    # ``block - 1`` of a block-causal call, else 0
+
+    @property
+    def keys(self) -> int:
+        return self.ppb * self.page_size
+
+
+def chunk_tiling(s: int, rep: int, page_size: int, max_pages: int,
+                 window: Optional[int] = None, block: int = 1) -> ChunkTiling:
+    """The tiling of a chunk call, from its own shapes: ``s`` query
+    tokens of ``rep`` query heads a KV head, a table ``max_pages`` wide.
+
+    - a key block is ``_GROUP_KEYS`` keys, so the score tile's lanes are
+      full and the statistics are touched once per block;
+    - a tile is as many tokens as keep the float32 score temporaries of
+      one (tile, block) pair inside ``_TEMP_VMEM_BYTES`` (four copies:
+      the scores, the mask, ``p`` and the ``exp``'s argument), the chunk
+      cut into the fewest equal tiles of whole sublane tiles of rows.
+
+    A grid step holds ONE KV head, whatever their number: several a step
+    where a head's whole chunk is under a tile (gpt3-345m: eight) were
+    10-24 us a call faster alone and did not separate end to end
+    (KERNEL_DECISIONS.md "Chunk paged attention")."""
+    ppb = max(1, min(_GROUP_KEYS // page_size, max_pages))
+    keys = ppb * page_size
+    rows = max(_ROW_ALIGN, _TEMP_VMEM_BYTES // (4 * 4 * keys))
+    align = _ROW_ALIGN // math.gcd(_ROW_ALIGN, rep)
+    n_tiles = -(-s // max(align, rows // rep // align * align))
+    tile = -(-s // (n_tiles * align)) * align
+    n_blk = -(-max_pages // ppb)
+    if window is not None:
+        # a tile's first query sees ``window - 1`` positions back, its
+        # last sits ``tile - 1`` further on
+        n_blk = min(n_blk, -(-(window + tile - 1) // keys) + 1)
+    return ChunkTiling(tile=tile, n_tiles=n_tiles, ppb=ppb,
+                       n_blk=n_blk, page_size=page_size, max_pages=max_pages,
+                       window=None if window is None else int(window),
+                       block_bits=_block_bits(block))
+
+
+def _chunk_tile_keys(tl: ChunkTiling, start, xp=np):
+    """``(lo, hi)``: the first and the last key position each tile of a
+    chunk at cursor ``start`` (B,) can see, ``(B, n_tiles)`` each.
+    Causal: nothing after the tile's last query (block-causal: the end
+    of its block), nothing past the table (a padded final chunk may
+    point there); windowed: nothing before the first query's window.
+    ``xp`` is numpy on the host and ``jax.numpy`` in the kernel's
+    wrapper: one rule for the kernel and for the counter."""
+    p0 = start[:, None] + xp.arange(tl.n_tiles, dtype=start.dtype)[None, :] \
+        * tl.tile
+    hi = xp.minimum((p0 + tl.tile - 1) | tl.block_bits,
+                    tl.max_pages * tl.page_size - 1)
+    lo = xp.zeros_like(p0) if tl.window is None \
+        else xp.maximum(p0 + 1 - tl.window, 0)
+    # a tile that lies past the table still takes the table's last block
+    return xp.minimum(lo, hi), hi
+
+
+def chunk_tile_pairs(tl: ChunkTiling, start: int) -> int:
+    """Query-key pairs (a query token against a key, per KV head and
+    query head one) of the (tile, key block) pairs the kernel COMPUTES
+    for a chunk at cursor ``start``, masked pairs and pad rows included:
+    what ``serving_chunk_*_tile_pairs`` add a chunk."""
+    lo, hi = _chunk_tile_keys(tl, np.full((1,), start, np.int64))
+    return int((hi // tl.keys + 1 - lo // tl.keys).sum()) * tl.tile * tl.keys
+
+
+def _chunk_slots(tl: ChunkTiling, bt, start):
+    """What the kernel's index maps and body read: the pool page of
+    every slot of every grid step, ``(B * n_tiles * n_blk * ppb,)`` in
+    grid order, and per tile its first key block and the block after its
+    last (``(B * n_tiles,)`` each). A slot is live where its page holds
+    a key some query of the tile can see."""
+    lo, hi = _chunk_tile_keys(tl, start, jnp)
+    idx = ((lo // tl.keys)[:, :, None, None]
+           + jnp.arange(tl.n_blk, dtype=jnp.int32)[None, None, :, None]) \
+        * tl.ppb + jnp.arange(tl.ppb, dtype=jnp.int32)[None, None, None, :]
+    live = ((idx >= (lo // tl.page_size)[:, :, None, None])
+            & (idx <= (hi // tl.page_size)[:, :, None, None]))
+    idx = jnp.minimum(idx, tl.max_pages - 1).reshape(bt.shape[0], -1)
+    pages = jnp.take_along_axis(bt, idx, axis=1)
+    pg = _parked(pages.reshape(-1, tl.ppb), live.reshape(-1, tl.ppb))
+    return pg, (lo // tl.keys).reshape(-1), (hi // tl.keys + 1).reshape(-1)
+
+
+def _paged_chunk_kernel(pg_ref, lo_ref, hi_ref, st_ref, q_ref, *rest,
+                        sm_scale: float, tl: ChunkTiling, rep: int,
+                        quant: bool):
+    # per slot: a K and a V page block, then (quantized pools) their
+    # scale columns in the same order
+    ppb = tl.ppb
+    k_refs, v_refs = rest[:ppb], rest[ppb:2 * ppb]
+    rest = rest[2 * ppb:]
     if quant:
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
+        ks_refs, vs_refs = rest[:ppb], rest[ppb:2 * ppb]
+        rest = rest[2 * ppb:]
     out_ref, acc_ref, m_ref, l_ref = rest
 
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    b, t, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    keys, d = tl.keys, acc_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -531,58 +663,59 @@ def _paged_chunk_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    start = sl_ref[b]
-    # tokens live after the chunk's own write; a PADDED final chunk can
-    # point past the block table, so clamp to the grid width (the
-    # dropped pad writes never landed in the pool anyway)
-    n_pages = jnp.clip((start + s_chunk + page_size - 1) // page_size,
-                       1, max_pages)
+    tile = b * tl.n_tiles + t
+    blk = lo_ref[tile] + j
+    k_lo = blk * keys
+    p0 = st_ref[b] + t * tl.tile                       # the first query's
 
-    @pl.when(j < n_pages)
-    def _accumulate():
-        rows_pad = acc_ref.shape[0]
-        q = q_ref[0, 0].astype(jnp.float32)            # (rows_pad, d)
-        k = k_ref[0, 0].astype(jnp.float32)            # (page, d)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def block_of(refs):
+        pages = [ref[0, 0] for ref in refs]
+        return pages[0] if ppb == 1 else jnp.concatenate(pages, axis=0)
+
+    def pair(masked):
+        """The step's tile against its key block."""
+        k, v = block_of(k_refs), block_of(v_refs)         # (keys, d)
         if quant:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        # row r holds (rep head r // s_chunk, chunk token r % s_chunk);
-        # its query sits at absolute position start + r % s_chunk and
-        # sees every pool position up to and including itself — or,
-        # block-causal, up to the end of its own block
-        r_iota = jax.lax.broadcasted_iota(
-            jnp.int32, (rows_pad, page_size), 0)
-        q_pos = start + jax.lax.rem(r_iota, s_chunk)
-        kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows_pad, page_size), 1)
-        seen = kv_pos <= (q_pos | block_bits)
-        if window is not None:
-            seen &= kv_pos > q_pos - window
-        s = jnp.where(seen, s, _NEG_INF)
+            # dequantize in VMEM: (keys, d) * (keys, 1)
+            k = k.astype(jnp.float32) * block_of(ks_refs)
+            v = v.astype(jnp.float32) * block_of(vs_refs)
+        # operands as stored: bf16 x bf16 is exact in float32 (``_dot``)
+        s = _dot(q_ref[0, 0], k, _NT) * sm_scale          # (rows, keys)
+        if masked:
+            # row r of the tile holds query head r % rep of token
+            # r // rep, at position p0 + r // rep: it sees every key up
+            # to itself (block-causal: up to the end of its block) and,
+            # windowed, none at ``window`` or more behind it
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            tok = (row >> (rep.bit_length() - 1) if rep & (rep - 1) == 0
+                   else jax.lax.div(row, rep))
+            q_pos = p0 + tok
+            kv_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            seen = kv_pos <= (q_pos | tl.block_bits)
+            if tl.window is not None:
+                seen &= kv_pos > q_pos - tl.window
+            s = jnp.where(seen, s, _NEG_INF)
+        m_ref[...], l_ref[...], p, alpha = _softmax_update(
+            s, m_ref[...], l_ref[...])
+        # p stays float32, as in the decode kernel
+        acc_ref[...] = _lanes(alpha, d) * acc_ref[...] + _dot(p, v, _NN)
 
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_new = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-            l_ref.shape)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    live = blk < hi_ref[tile]
+    # no mask where every query of the tile sees every key of the block:
+    # the block ends by the first query's last key and, windowed, starts
+    # inside the last query's window
+    whole = k_lo + keys - 1 <= (p0 | tl.block_bits)
+    if tl.window is not None:
+        whole &= k_lo > p0 + tl.tile - 1 - tl.window
+    pl.when(live & whole)(functools.partial(pair, False))
+    pl.when(live & jnp.logical_not(whole))(functools.partial(pair, True))
 
-    @pl.when(j == n_pages - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _emit():
-        l = l_ref[:, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, 0] = (acc_ref[...] / l_safe).astype(out_ref.dtype)
+        # a row that saw nothing: l is 0, emit zeros
+        l = _lanes(l_ref[...], d)
+        out_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                         ).astype(out_ref.dtype)
 
 
 def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
@@ -599,10 +732,11 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
     start+S-1`` and attends causally to the pool's already-written
     prefix PLUS its own tokens, which the caller must have written
     (``write_paged_prompt_at``) before calling — write-then-attend, the
-    same ordering the gather path used. Each grid step streams ONE pool
-    page through VMEM (grid ``(B, Hkv, max_pages)``, block-table page
-    index scalar-prefetched), online softmax across pages; nothing ever
-    materializes the ``(B, T, Hkv, D)`` per-sequence view.
+    same ordering the gather path used. A grid step is a tile of query
+    tokens against a key block of several pool pages (block-table page
+    indices scalar-prefetched, :func:`chunk_tiling`), online softmax
+    across the blocks a tile can see; nothing ever materializes the
+    ``(B, T, Hkv, D)`` per-sequence view.
 
     q:     (B, S, H, D) — the chunk's queries
     start: (B,) int32   — written length BEFORE this chunk (the cursor)
@@ -611,99 +745,103 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
            block of B positions, ``k <= q | (B - 1)``. ``start`` and S
            are then multiples of B, so the chunk holds whole blocks.
     window: static; query ``i`` of the chunk also sees no key before
-           ``start + i + 1 - window`` (``q - k < window``). The kernel
-           is handed the table from the page of ``start + 1 - window``
-           on, ``ceil((window + S) / page) + 1`` slots wide: key pages
-           wholly before it are never addressed, so their block-table
-           entries may be anything (causal only).
+           ``start + i + 1 - window`` (``q - k < window``). Pages wholly
+           before the first query's window are never addressed, so their
+           block-table entries may be anything (causal only).
     Returns (B, S, H, D) in q's dtype. Rows past the real prompt tail
     (final-chunk padding) emit garbage the caller discards.
     """
-    b, s, h, d = q.shape
-    hkv, _, page_size, _ = k_pages.shape
+    h, d = q.shape[2:]
+    hkv = k_pages.shape[0]
     if h % hkv:
         raise ValueError(f"query heads {h} not divisible by kv heads {hkv}")
-    rep = h // hkv
-    max_pages = block_tables.shape[1]
+    if window is not None and block != 1:
+        raise ValueError("a window is a positive length over the causal "
+                         f"mask; got window={window}, block={block}")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    return _paged_chunk(q, k_pages, v_pages,
+                        jnp.asarray(block_tables, jnp.int32),
+                        jnp.asarray(start, jnp.int32),
+                        sm_scale=float(sm_scale), block=int(block),
+                        interpret=_interpret(), **_window_kw(window))
 
-    rows = rep * s
-    rows_pad = -(-rows // 8) * 8
-    # (B, S, H, D) -> (B, Hkv, rep*S, D): row = rep_head * S + token
-    qg = q.transpose(0, 2, 1, 3).reshape(b, hkv, rows, d)
-    if rows_pad != rows:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
-    bt = jnp.asarray(block_tables, jnp.int32)
-    st = jnp.asarray(start, jnp.int32)
 
-    def q_index(b_, h_, j, bt_ref, sl_ref):
-        return (b_, h_, 0, 0)
+# jitted like ``_paged_decode``: one trace and one Mosaic lowering for
+# the layers of a program, the slot table built on the device inside it
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block",
+                                             "interpret", "window"))
+def _paged_chunk(q, k_pages, v_pages, bt, st, *, sm_scale, block, interpret,
+                 window=None):
+    b, s, h, d = q.shape
+    hkv, _, page_size, _ = k_pages.shape
+    rep = h // hkv
+    quant = isinstance(k_pages, QuantizedPages)
+    tl = chunk_tiling(s, rep, page_size, bt.shape[1], window=window,
+                      block=block)
+    rows, s_pad = tl.tile * rep, tl.n_tiles * tl.tile
 
-    def kv_index(b_, h_, j, bt_ref, sl_ref):
-        return (h_, bt_ref[b_, j], 0, 0)
+    # (B, S, H, D) -> (B, Hkv, S * rep, D): row = token * rep + rep head,
+    # so a run of rows is a run of tokens with all their heads
+    qg = q.reshape(b, s, hkv, rep, d).transpose(0, 2, 1, 3, 4)
+    if s_pad != s:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, s_pad - s), (0, 0), (0, 0)))
+    qg = qg.reshape(b, hkv, s_pad * rep, d)
 
-    kernel_kw = {}
-    if window is not None:
-        if block != 1 or int(window) < 1:
-            raise ValueError("a window is a positive length over the "
-                             f"causal mask; got window={window}, "
-                             f"block={block}")
-        # the chunk's first query, at ``start``, sees from ``start + 1 -
-        # window`` on, and no later query sees further back: the grid is
-        # the window and the chunk wide, whatever the table's width
-        kernel_kw = {"window": int(window)}
-        bt, shift = _table_from(bt, st + 1 - int(window), int(window) + s,
-                                page_size)
-        st, max_pages = st - shift, bt.shape[1]
+    def page_spec(i, width):
+        return pl.BlockSpec(
+            (1, 1, page_size, width),
+            lambda b_, h_, t, j, pg_ref, *_: (
+                h_, pg_ref[((b_ * tl.n_tiles + t) * tl.n_blk + j) * tl.ppb
+                           + i], 0, 0))
 
-    # the query and output blocks (double-buffered), the three scratch
-    # accumulators and the score temporaries all grow with the chunk's
-    # rows; past Mosaic's default scope of 16 MiB the call says what it
-    # needs (v5e has 128 MiB). A call under it is the call it always was
-    vmem = rows_pad * (4 * d * q.dtype.itemsize + (d + 2 * _LANES) * 4
-                       + 4 * page_size * 4)
+    q_spec = pl.BlockSpec((1, 1, rows, d),
+                          lambda b_, h_, t, j, *_: (b_, h_, t, 0))
+    pools = [k_pages, v_pages]
+    if quant:
+        # per-token int8 scale rows: 1-wide lane by contract
+        # kernelcheck: disable=KRN001
+        pools = [k_pages.q, v_pages.q, k_pages.scale, v_pages.scale]
+
+    # what a step keeps in VMEM: the query and output blocks
+    # (double-buffered), the accumulator and the two lane-wide
+    # statistics, the page blocks (double-buffered, rows under 128 lanes
+    # padded to them) and the score temporaries. Past Mosaic's default
+    # scope of 16 MiB the call says what it needs (v5e has 128 MiB); a
+    # call under it passes nothing
+    vmem = (rows * (4 * d * q.dtype.itemsize + (d + 2 * _LANES) * 4)
+            + sum(4 * tl.ppb * page_size
+                  * max(x.shape[-1], _LANES) * x.dtype.itemsize
+                  for x in pools)
+            + 4 * rows * tl.keys * 4)
     call_kw = {}
     if vmem > (12 << 20):
         call_kw["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20))
 
-    quant = isinstance(k_pages, QuantizedPages)
-    in_specs = [
-        pl.BlockSpec((1, 1, rows_pad, d), q_index),
-        pl.BlockSpec((1, 1, page_size, d), kv_index),
-        pl.BlockSpec((1, 1, page_size, d), kv_index),
-    ]
-    operands = [qg, k_pages, v_pages]
-    if quant:
-        # per-token int8 scale rows: 1-wide lane by contract
-        # kernelcheck: disable=KRN001
-        in_specs += [pl.BlockSpec((1, 1, page_size, 1), kv_index)] * 2
-        operands = [qg, k_pages.q, v_pages.q,
-                    k_pages.scale, v_pages.scale]
     out = pl.pallas_call(
-        functools.partial(_paged_chunk_kernel, sm_scale=float(sm_scale),
-                          page_size=page_size, s_chunk=s, rows=rows,
-                          max_pages=max_pages, quant=quant,
-                          block_bits=_block_bits(block), **kernel_kw),
+        functools.partial(_paged_chunk_kernel, sm_scale=sm_scale, tl=tl,
+                          rep=rep, quant=quant),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, hkv, max_pages),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, rows_pad, d), q_index),
+            num_scalar_prefetch=4,
+            grid=(b, hkv, tl.n_tiles, tl.n_blk),
+            in_specs=[q_spec] + [page_spec(i, x.shape[-1])
+                                 for x in pools for i in range(tl.ppb)],
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((rows_pad, d), jnp.float32),       # acc
-                pltpu.VMEM((rows_pad, _LANES), jnp.float32),  # m
-                pltpu.VMEM((rows_pad, _LANES), jnp.float32),  # l
+                pltpu.VMEM((rows, d), jnp.float32),         # acc
+                pltpu.VMEM((rows, _LANES), jnp.float32),    # m
+                pltpu.VMEM((rows, _LANES), jnp.float32),    # l
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rows_pad, d), q.dtype),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        interpret=interpret,
         name="paged_chunk_attention",
         **call_kw,
-    )(bt, st, *operands)
-    out = out[:, :, :rows].reshape(b, hkv, rep, s, d)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
+    )(*_chunk_slots(tl, bt, st), st, qg,
+      *(x for x in pools for _ in range(tl.ppb)))
+    out = out.reshape(b, hkv, s_pad, rep, d)[:, :, :s]
+    return out.transpose(0, 2, 1, 3, 4).reshape(b, s, h, d)
 
 
 # XLA-twin page grouping: pages per fori_loop step are batched so each

@@ -723,12 +723,14 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
     Chunked prefill (S > 1, PagedChunkState, B = 1): the chunk writes at
     positions ``seq_lens .. seq_lens+S-1`` and attends to the
     already-written prefix PLUS itself causally, reading the pool
-    through the block table page by page (``paged_chunk_attention`` on
-    chip, its copy-free XLA twin elsewhere) — no gathered per-sequence
-    view is materialized. Pad positions past the block table are dropped — but
-    the returned state's ``seq_lens`` still advance by the full static
-    S, so a PADDED final chunk overcounts by its pad tail: the driver
-    owns the true lengths (see the PagedChunkState length contract).
+    through the block table a key block of pages at a time
+    (``paged_chunk_attention`` on chip: a tile of query tokens against
+    the blocks it can see; its copy-free XLA twin elsewhere) — no
+    gathered per-sequence view is materialized. Pad positions past the
+    block table are dropped — but the returned state's ``seq_lens``
+    still advance by the full static S, so a PADDED final chunk
+    overcounts by its pad tail: the driver owns the true lengths (see
+    the PagedChunkState length contract).
     Decode (S == 1): the token writes at position ``seq_lens`` and
     attends against the pool through the Pallas block-table kernel (XLA
     gather fallback when pallas is off). Returns ``(out, new_state)``.
@@ -819,8 +821,8 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
             # past the real prompt tail (final-chunk padding) emit
             # garbage the caller discards, and their K is masked off
             # every earlier row by causality. The pool is read through
-            # the block table page by page — no gathered (B, T, Hkv, D)
-            # view is ever materialized.
+            # the block table a key block of pages at a time — no
+            # gathered (B, T, Hkv, D) view is ever materialized.
             attend = (paged_chunk_attention if use_pallas
                       else paged_chunk_attention_xla)
             out = attend(qp, kp2, vp2, bt, sl, sm_scale=sm_scale,

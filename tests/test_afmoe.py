@@ -333,7 +333,9 @@ def test_counters_and_gauges_of_the_window_pool(tiny, prompts):
     """``serving_decode_window_tokens`` counts min(len + 1, window) a
     decoding row a step, ``serving_chunk_attn_pairs`` /
     ``serving_chunk_window_pairs`` the query-key pairs of the chunks by
-    layer kind, ``serving_window_pages_released`` the pages
+    layer kind and ``serving_chunk_attn_tile_pairs`` /
+    ``serving_chunk_window_tile_pairs`` the pairs the chunk kernel's
+    tiles made of them, ``serving_window_pages_released`` the pages
     given back, ``serving_kv_pool_bytes`` each pool by its pages in
     use; the expert counters move as they do for SDAR."""
     _, model, _, _ = tiny
@@ -350,7 +352,8 @@ def test_counters_and_gauges_of_the_window_pool(tiny, prompts):
     before = {n: read(n, **mine) for n in (
         "serving_decode_window_tokens", "serving_decode_live_tokens",
         "serving_window_pages_released", "serving_chunk_attn_pairs",
-        "serving_chunk_window_pairs")}
+        "serving_chunk_window_pairs", "serving_chunk_attn_tile_pairs",
+        "serving_chunk_window_tile_pairs")}
     rid = eng.submit(prompts[5], 10)     # 40 tokens, then 9 decode steps
     peak = dict(window=0.0, **{"global": 0.0})
     while eng.has_work():
@@ -371,6 +374,15 @@ def test_counters_and_gauges_of_the_window_pool(tiny, prompts):
     assert delta["serving_chunk_attn_pairs"] == sum(range(1, 41))
     assert delta["serving_chunk_window_pairs"] \
         == sum(min(p + 1, WINDOW) for p in range(40))
+    # what the kernel's tiling computes for them: the sum over the five
+    # chunks' cursors by the engine's tilings (held to the kernel's own
+    # in the test below), never under the exact pairs
+    from paddle_tpu.kernels.paged_attention import chunk_tile_pairs
+    assert [tl.window for tl in eng._chunk_cut] == [None, WINDOW]
+    for name, tl in zip(("attn", "window"), eng._chunk_cut):
+        assert delta[f"serving_chunk_{name}_tile_pairs"] \
+            == sum(chunk_tile_pairs(tl, pos) for pos in range(0, 40, CHUNK)) \
+            >= delta[f"serving_chunk_{name}_pairs"]
     # bytes a page: layers x k and v x kv heads x page x head dim x 4
     page_bytes = 2 * 2 * PAGE * 16 * 4
     assert peak["global"] == 7 * 1 * page_bytes     # 50 tokens: 7 pages
@@ -378,6 +390,50 @@ def test_counters_and_gauges_of_the_window_pool(tiny, prompts):
     hist = eng.expert_histogram()
     # 4 sparse layers x top 2 x every token of every forward
     assert hist is not None and hist.sum() == 4 * 2 * (40 + 9)
+
+
+def test_tile_pair_counters_count_by_the_kernels_own_tiling(tiny,
+                                                            monkeypatch):
+    """The tilings ``serving_chunk_*_tile_pairs`` sum by are the ones
+    ``paged_chunk_attention``'s wrapper computes from the shapes the
+    engine's chunk program hands it: that program traced with the
+    kernels on (nothing lowers or runs), one tiling a layer kind. A
+    model without ``config.num_attention_heads`` is refused at
+    construction, where no recovery swallows it."""
+    from paddle_tpu.generation import serving
+    from paddle_tpu.kernels import paged_attention as pa
+
+    _, model, _, _ = tiny
+    eng = make_engine(model, max_batch=1, bucket_ladder=(1,))
+    monkeypatch.setattr("paddle_tpu.flags.is_tpu_backend", lambda: True)
+    seen, real = [], pa.chunk_tiling
+
+    def spy(*args, **kw):
+        seen.append(real(*args, **kw))
+        return seen[-1]
+
+    monkeypatch.setattr(pa, "chunk_tiling", spy)
+    # the wrapper keeps one trace a shape: start from none
+    pa._paged_chunk.clear_cache()
+    fn = serving._build_chunk_prefill(lambda: None, model)
+    jax.eval_shape(fn, eng._params, eng._buffers,
+                   jnp.zeros((1, CHUNK), jnp.int32),
+                   eng._caches.take_caches(), eng._caches.tables(0, CHUNK),
+                   jnp.zeros((1,), jnp.int32), jnp.int32(CHUNK - 1))
+    pa._paged_chunk.clear_cache()
+    # one trace a layer kind: a global layer's and the window layers'
+    assert sorted(seen, key=lambda tl: tl.window is not None) \
+        == list(eng._chunk_cut)
+    assert [tl.window for tl in eng._chunk_cut] == [None, WINDOW]
+
+    class Headless:
+        config = None
+
+        def __getattr__(self, name):
+            return getattr(model, name)
+
+    with pytest.raises(ValueError, match="num_attention_heads"):
+        make_engine(Headless())
 
 
 def test_prefill_programs_run_the_head_on_one_position(tiny):

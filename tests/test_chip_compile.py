@@ -145,7 +145,7 @@ def _pool(S, hkv, d, page, n_pages, int8):
 
 
 def _paged(h, hkv, d, *, int8=False, chunk=0, b=8, page=64, max_pages=16,
-           block=1, window=None):
+           block=1, window=None, q_dtype=BF16):
     def build(S):
         pool = _pool(S, hkv, d, page, 256, int8)
         if chunk:
@@ -153,7 +153,7 @@ def _paged(h, hkv, d, *, int8=False, chunk=0, b=8, page=64, max_pages=16,
                       else lambda *a: paged_chunk_attention(
                           *a, block=block, window=window))
             return attend, [
-                S((b, chunk, h, d), BF16), pool, pool,
+                S((b, chunk, h, d), q_dtype), pool, pool,
                 S((b, max_pages), I32), S((b,), I32)]
         attend = (paged_attention if not window else
                   lambda *a: paged_attention(*a, window=window))
@@ -307,9 +307,8 @@ _TIER1 = {
     "paged_chunk-sdar-blockcausal4-c256": _paged(32, 4, 128, chunk=256, b=1,
                                                  max_pages=20, block=4),
     # trinity-mini: the windowed reads at the cell's table width (384
-    # pages a row) and chunk (1,024 queries: 8,192 query rows a KV head,
-    # for which the chunk kernel asks for its VMEM), and the global
-    # layer's chunk
+    # pages a row) and chunk (1,024 queries: four tiles of 2,048 query
+    # rows a KV head), and the global layer's chunk
     "paged_attention-trinity-window2048-b32": _paged(
         32, 4, 128, b=32, max_pages=384, window=2048),
     "paged_chunk-trinity-window2048-c1024": _paged(
@@ -343,6 +342,17 @@ _MATRIX = {
     "paged_chunk-mha16-d64": _paged(16, 16, 64, chunk=256, b=1),
     "paged_chunk-int8-mha16-d64": _paged(16, 16, 64, int8=True, chunk=256,
                                          b=1),
+    # trinity-mini's chunk at the tile ``chunk_tiling`` never exceeds
+    # (2,048 query rows a step: 256 tokens x 8 heads; a chunk of 4,096 is
+    # 16 of them) where a step holds the most VMEM: an int8 pool's scale
+    # columns and float32 dequantized blocks beside the score
+    # temporaries, and float32 queries
+    "paged_chunk-int8-trinity-window2048-c1024": _paged(
+        32, 4, 128, int8=True, chunk=1024, b=1, max_pages=384, window=2048),
+    "paged_chunk-int8-trinity-global-c4096": _paged(
+        32, 4, 128, int8=True, chunk=4096, b=1, max_pages=384),
+    "paged_chunk-f32-trinity-global-c1024": _paged(
+        32, 4, 128, chunk=1024, b=1, max_pages=384, q_dtype=F32),
     # the shape the old on-chip sprint checked: d 32 exercises the
     # sub-lane-tile head path of the head-major scratch
     "ssm_decode_update-h64-d64-n128-b1": _ssm_update(1),

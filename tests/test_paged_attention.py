@@ -583,3 +583,154 @@ class TestWindow:
         with pytest.raises(ValueError, match="window"):
             paged_attention(q, kp, vp, jnp.zeros((1, 8), jnp.int32),
                             jnp.asarray([3], jnp.int32), window=0)
+
+
+# ------------------------------- the chunk kernel's tiling (PR 35)
+def _dense_chunk(q, k, v, start, window=None, block=1):
+    """Plain masked attention of the chunk ``q`` (s, h, d) at positions
+    ``start ..`` over contiguous k/v (hkv, t, d): ``j <= i | (block -
+    1)`` and, under a window, ``i - j < window``."""
+    s, h, d = q.shape
+    hkv = k.shape[0]
+    i = start + np.arange(s)[:, None]
+    j = np.arange(k.shape[1])[None, :]
+    seen = j <= (i | (block - 1))
+    if window is not None:
+        seen &= i - j < window
+    qh = np.asarray(q, np.float32).reshape(s, hkv, h // hkv, d)
+    sc = np.einsum("skrd,ktd->krst", qh, k) / np.sqrt(d)
+    sc = np.where(seen[None, None], sc, -np.inf)
+    p = np.exp(sc - sc.max(axis=-1, keepdims=True))
+    p /= p.sum(axis=-1, keepdims=True)
+    return np.einsum("krst,ktd->skrd", p, v).reshape(s, h, d)
+
+
+class TestChunkTiling:
+    """``paged_chunk_attention`` cut into query tiles by tokens and key
+    blocks of several pages (``chunk_tiling``): against the dense mask
+    where the tiling can go wrong, and the tiling's own arithmetic with
+    no kernel."""
+
+    PAGE, WIDTH, D = 16, 70, 32
+
+    def _case(self, s, h, hkv, seed=11, dtype=jnp.float32, width=None):
+        rng = np.random.default_rng(seed)
+        width = width or self.WIDTH
+        kp, vp = make_pool(rng, hkv=hkv, num_pages=width + 1,
+                           page=self.PAGE, d=self.D, dtype=dtype)
+        bt = rng.permutation(np.arange(1, width + 1)).astype(np.int32)[None]
+        q = jnp.asarray(rng.standard_normal((1, s, h, self.D)) * 0.5, dtype)
+        return q, kp, vp, bt
+
+    def _check(self, q, kp, vp, bt, start, *, pools=None, tol=2e-5, **kw):
+        """The kernel over ``bt`` (and, windowed, over the table with the
+        slots before the window given back) against the dense mask; rows
+        past the table (a padded final chunk) are the caller's to drop."""
+        from paddle_tpu.kernels.paged_attention import paged_chunk_attention
+        s = q.shape[1]
+        end = bt.shape[1] * self.PAGE
+        t, real = min(start + s, end), min(s, end - start)
+        want = _dense_chunk(np.asarray(q, np.float32)[0],
+                            _contiguous(kp, bt[0], t),
+                            _contiguous(vp, bt[0], t), start, **kw)
+        tables = [bt]
+        if kw.get("window"):
+            freed = bt.copy()
+            freed[0, :max(0, start + 1 - kw["window"]) // self.PAGE] = 0
+            tables.append(freed)
+        for table in tables:
+            got = paged_chunk_attention(
+                q, *(pools or (kp, vp)), jnp.asarray(table),
+                jnp.asarray([start], jnp.int32), **kw)
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32)[0, :real], want[:real],
+                rtol=tol, atol=tol)
+
+    def test_the_cases_cut_as_they_say(self):
+        """600 tokens of 8 query heads a KV head are three tiles, the
+        70-page table five key blocks of 16 pages, the last one short;
+        64 tokens of one head a KV head are one tile and one block."""
+        from paddle_tpu.kernels.paged_attention import chunk_tiling
+        tl = chunk_tiling(600, 8, self.PAGE, self.WIDTH)
+        assert (tl.tile, tl.n_tiles, tl.ppb, tl.n_blk) == (200, 3, 16, 5)
+        tl = chunk_tiling(600, 8, self.PAGE, self.WIDTH, window=50)
+        assert tl.n_blk == 2          # 249 positions lie on two blocks
+        tl = chunk_tiling(64, 1, self.PAGE, 10)
+        assert (tl.tile, tl.n_tiles, tl.n_blk) == (64, 1, 1)
+
+    # cursor 0; inside a page; the last visible block partly dead; a
+    # padded final chunk that points past the table
+    @pytest.mark.parametrize("start", [0, 37, 300, 700])
+    def test_causal_tiles_and_blocks(self, start):
+        self._check(*self._case(600, 8, 1), start)
+
+    # a window smaller than a tile (200 tokens), between a tile and the
+    # chunk, and larger than everything written; the second table of
+    # each has the slots before the window on the null page
+    @pytest.mark.parametrize("window,start", [(50, 300), (320, 437),
+                                              (4096, 100)])
+    def test_windowed_tiles(self, window, start):
+        self._check(*self._case(600, 8, 1), start, window=window)
+
+    def test_block_causal_tiles(self):
+        """Two KV heads here (one in the other cases): the tiles of the
+        second head read its half of the pool."""
+        self._check(*self._case(600, 16, 2), 296, block=4)
+
+    def test_one_query_head_a_kv_head(self):
+        """``rep`` 1: a row is a token; four KV heads, the whole chunk
+        of each under one tile, a grid step each."""
+        self._check(*self._case(64, 4, 4, width=10), 21)
+
+    def test_quantized_pool(self):
+        q, kp, vp, bt = self._case(600, 8, 1)
+        kq, vq = (QuantizedPages(*quantize_kv_rows(x)) for x in (kp, vp))
+        deq = [np.asarray(x.q, np.float32) * np.asarray(x.scale)
+               for x in (kq, vq)]
+        self._check(q, *deq, bt, 300, pools=(kq, vq))
+
+    def test_bf16_operands_as_stored(self):
+        """A bf16 query on a bf16 pool goes to the MXU as stored: the
+        products are exact in float32, the output rounds to bf16."""
+        self._check(*self._case(600, 8, 1, dtype=jnp.bfloat16), 300,
+                    tol=1e-2)
+
+    @pytest.mark.parametrize("window,block", [(None, 1), (50, 1), (320, 1),
+                                              (2048, 1), (None, 4)])
+    @pytest.mark.parametrize("s,rep,page,width", [
+        (600, 8, 16, 70), (64, 1, 16, 10), (1024, 8, 64, 384),
+        (256, 1, 64, 16), (256, 8, 64, 20), (100, 3, 8, 37)])
+    def test_tiling_covers_every_visible_pair(self, s, rep, page, width,
+                                              window, block):
+        """No kernel: for cursors all over the table, the live (tile,
+        key block) pairs of ``_chunk_tile_keys`` cover every query-key
+        pair a brute-force mask makes visible, ``chunk_tile_pairs`` is
+        the count of what they cover, and a tile's blocks fit the
+        grid's key-block axis."""
+        from paddle_tpu.kernels.paged_attention import (
+            _chunk_tile_keys, chunk_tile_pairs, chunk_tiling)
+        tl = chunk_tiling(s, rep, page, width, window=window,
+                          block=block)
+        assert tl.n_tiles * tl.tile >= s
+        end = width * page
+        n_blocks = -(-width // tl.ppb)
+        starts = sorted({0, block * 9, page * 3, end // 2 // block * block,
+                         max(0, end - s) // block * block,
+                         (end - s // 2) // block * block})
+        for start in starts:
+            lo, hi = _chunk_tile_keys(tl, np.asarray([start], np.int64))
+            lo_b, hi_b = lo[0] // tl.keys, hi[0] // tl.keys + 1
+            assert (hi_b > lo_b).all() and (hi_b <= n_blocks).all()
+            covered = np.zeros((tl.n_tiles * tl.tile, n_blocks * tl.keys),
+                               bool)
+            for t in range(tl.n_tiles):
+                covered[t * tl.tile:(t + 1) * tl.tile,
+                        lo_b[t] * tl.keys:hi_b[t] * tl.keys] = True
+            i = start + np.arange(s)[:, None]
+            j = np.arange(end)[None, :]
+            seen = (j <= (i | (block - 1))) & (i < end)
+            if window is not None:
+                seen &= i - j < window
+            assert not (seen & ~covered[:s, :end]).any(), start
+            assert chunk_tile_pairs(tl, start) == covered.sum()
+            assert (hi_b - lo_b <= tl.n_blk).all()
