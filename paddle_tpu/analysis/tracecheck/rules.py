@@ -595,7 +595,7 @@ def _module_imports_observability(fi: FunctionInfo) -> bool:
 # instrument/tracer write methods distinctive enough to flag by name —
 # but only in modules that import the observability package, so e.g. a
 # quantization observer's ``.observe()`` never false-positives
-_TELEMETRY_METHODS = {"inc", "dec", "observe", "span", "event"}
+_TELEMETRY_METHODS = {"inc", "dec", "observe", "span", "event", "phase"}
 
 # the sanctioned hot-path aggregation idiom (like take_* for TRC003):
 # batching a step's gauge/counter writes into one enabled-guarded

@@ -183,6 +183,7 @@ class DecodeProgramCache:
         self._lowered: Dict[DecodeKey, Any] = {}
         self.hits = 0
         self.misses = 0
+        self.traces = 0     # every key's (re)traces, never reset
         # memwatch (FLAGS_memwatch): every build additionally banks
         # the executable's CompiledMemoryStats
         self._memwatch = obs.memory.enabled()
@@ -240,6 +241,7 @@ class DecodeProgramCache:
             # is the deliberate exception to "no telemetry under trace"
             with self._lock:
                 self._trace_counts[key] = self._trace_counts.get(key, 0) + 1
+                self.traces += 1
             self._m_traces.labels(kind=key.kind,
                                   model=key.model_sig[:8],
                                   tp=_key_tp(key)).inc()
@@ -323,6 +325,14 @@ def decode_program_cache() -> DecodeProgramCache:
         if _GLOBAL is None:
             _GLOBAL = DecodeProgramCache()
         return _GLOBAL
+
+
+def traces_built() -> int:
+    """(Re)traces of the process-wide cache's programs so far: one
+    attribute read, no lock — a per-step baseline (the engine's step
+    clock says how many programs a slow step built)."""
+    cache = _GLOBAL
+    return cache.traces if cache is not None else 0
 
 
 def clear_decode_program_cache() -> None:

@@ -60,7 +60,7 @@ from ..testing import faults
 from .cache_manager import (CacheManager, cache_entries,
                             has_recurrent_layers, has_window_layers,
                             kv_heads)
-from .program_cache import ProgramBuildError
+from .program_cache import ProgramBuildError, traces_built
 
 __all__ = ["ServingEngine", "Request"]
 
@@ -207,7 +207,7 @@ class _EngineTelemetry:
                                labels=rl).labels(replica=replica, tp=tp)
 
         self.span = t.span
-        self.annotation = t.annotation
+        self.phase = t.phase
         self.event = t.event
         self.submitted = c(
             "serving_requests_submitted", "requests accepted by submit()")
@@ -467,6 +467,46 @@ class _EngineTelemetry:
             "recurrent-state rows snapshotted to the host for a "
             "harvest_request handoff")
         self.counter_track = t.counter
+        # ---- the step clock: one round of ``step()`` and the host's
+        # phases inside it, published as the round ends. Each phase a
+        # family of its own (a reader sums a family over its labels)
+        self.clock = t.step_clock("serving")
+        self.steps = c(
+            "serving_steps", "rounds of step(), idle ones too")
+        self.step_seconds = c(
+            "serving_step_seconds", "wall clock of those rounds")
+        self.slow_steps = c(
+            "serving_slow_steps",
+            "rounds over 0.1 s and over 3 times the mean of the 64 "
+            "before them: each left a record in tracer().slow_steps()")
+        self.slow_step_seconds = c(
+            "serving_slow_step_seconds",
+            "what those rounds ran over the mean they were held against")
+        # counter -> the phases whose self seconds in the round it takes
+        self.phase_seconds = [(c(name, help), [t.phase(n) for n in phases])
+                           for name, help, phases in (
+            ("serving_decode_dispatch_seconds",
+             "the host inside the call of the compiled decode (or "
+             "block) program",
+             ("engine.decode.dispatch", "engine.block.dispatch")),
+            ("serving_decode_stage_inputs_seconds",
+             "staging a decode step: the feed and the cache manager's "
+             "host tables",
+             ("engine.decode.stage.inputs", "engine.block.stage.inputs")),
+            ("serving_decode_stage_put_seconds",
+             "staging a decode step: the one device_put of its inputs",
+             ("engine.decode.stage.put", "engine.block.stage.put")),
+            ("serving_decode_stage_caches_seconds",
+             "staging a decode step: taking the pools' arrays",
+             ("engine.decode.stage.caches", "engine.block.stage.caches")),
+            ("serving_prefill_chunk_host_seconds",
+             "a prefill chunk's uploads and dispatch, its token's "
+             "wait left out",
+             ("engine.prefill_chunk",)),
+            ("serving_wait_seconds",
+             "the host waiting for the device: the read of a decode "
+             "step's tokens, of a prefill's or a final chunk's token",
+             ("engine.decode.pull", "engine.prefill.pull")))]
         if block:
             # ---- block diffusion: a step, a row and a token are three
             # things there. Bound only by an engine whose model
@@ -2091,7 +2131,10 @@ class ServingEngine:
             # shared pool arrays; adopt them and the slot's bookkeeping
             self._caches.install_caches(states)
             if self._block is None:
-                tok = int(tok)          # the span owns the token pull
+                # the wait for the device, apart from the span's own
+                # host work  # tracecheck: disable=TRC007
+                with self._m.phase("engine.prefill.pull"):
+                    tok = int(tok)
         # once per admitted request  # tracecheck: disable=TRC007
         self._m.prefills.inc()
         # tracecheck: disable=TRC007
@@ -2165,8 +2208,9 @@ class ServingEngine:
             if last and self._block is None:
                 # designed sync: the first generated token. A non-final
                 # argmax is garbage-padded and never pulled, so that
-                # dispatch stays async
-                tok = int(tok)
+                # dispatch stays async  # tracecheck: disable=TRC007
+                with self._m.phase("engine.prefill.pull"):
+                    tok = int(tok)
         tnow = time.perf_counter()
         self._observe_chunk(tnow - t0, pos, end - pos, final=last)
         if not last:
@@ -2358,20 +2402,24 @@ class ServingEngine:
         a raising callback surfaces to the caller, never as a fake
         dispatch failure."""
         self._step_no += 1
-        # the step's root span: every phase below nests in it
-        # tracecheck: disable=TRC007
-        with self._m.span("engine.step", step=self._step_no,
-                          bucket=self.bucket) as root:
-            self._step_span = root.id
-            try:
-                self._step_inner()
-                self._consec_failures = 0
-            except ProgramBuildError:
-                raise
-            except Exception as exc:
-                self._recover_dispatch(exc)
-            finally:
-                self._drain_events()
+        self._m.clock.begin(traces_built())
+        try:
+            # the step's root span: every phase below nests in it
+            # tracecheck: disable=TRC007
+            with self._m.span("engine.step", step=self._step_no,
+                              bucket=self.bucket) as root:
+                self._step_span = root.id
+                try:
+                    self._step_inner()
+                    self._consec_failures = 0
+                except ProgramBuildError:
+                    raise
+                except Exception as exc:
+                    self._recover_dispatch(exc)
+                finally:
+                    self._drain_events()
+        finally:
+            self._observe_step_clock()
 
     def _recover_dispatch(self, exc: Exception) -> None:
         """Replay recovery. The donated dispatch died, so the pool is
@@ -3208,37 +3256,47 @@ class ServingEngine:
         self._observe_decode(decode_rows)
         flying = self._flying
         # the decode dispatch, from its uploads to the read and emit of
-        # the step BEFORE it, and its three parts (profiler scopes: a
-        # device capture can say which device time ran under a decode
-        # dispatch and which part of the host's share is which; the
-        # ring keeps only the whole)  # tracecheck: disable=TRC007
+        # the step BEFORE it, and its parts (phase scopes: a device
+        # capture can say which device time ran under a decode dispatch
+        # and which part of the host's share is which, the step clock
+        # accounts each; the ring keeps only the whole)
+        # tracecheck: disable=TRC007
         with self._m.span("engine.decode_step", step=n,
                           active=len(decode_rows), bucket=b,
                           overlapped=flying is not None):
-            with self._m.annotation("engine.decode.stage"):
-                # a row takes its token from the step in flight (-1: the
-                # program reads that step's output, still on the
-                # device) unless the host knows a newer one: a prefill's
-                # or a final chunk's of this step, a forced prompt token
-                feed = self._last_tok[:b].copy()
-                for req in decode_rows:
-                    if req.in_flight:
-                        feed[req.slot] = -1
+            # tracecheck: disable=TRC007
+            with self._m.phase("engine.decode.stage"):
+                # tracecheck: disable=TRC007
+                with self._m.phase("engine.decode.stage.inputs"):
+                    # a row takes its token from the step in flight (-1:
+                    # the program reads that step's output, still on the
+                    # device) unless the host knows a newer one: a
+                    # prefill's or a final chunk's of this step, a
+                    # forced prompt token
+                    feed = self._last_tok[:b].copy()
+                    for req in decode_rows:
+                        if req.in_flight:
+                            feed[req.slot] = -1
+                    inputs = self._caches.decode_inputs(
+                        b, [r.slot for r in decode_rows]) + [feed]
                 # ONE transfer call for the step's small inputs: each
                 # ``jnp.asarray`` is a dispatch of its own, a third of a
                 # millisecond of the host's share of every step
-                bt, sl, *extra, feed = jax.device_put(
-                    self._caches.decode_inputs(
-                        b, [r.slot for r in decode_rows]) + [feed])
+                # tracecheck: disable=TRC007
+                with self._m.phase("engine.decode.stage.put"):
+                    bt, sl, *extra, feed = jax.device_put(inputs)
                 if self._stacked is not None:
                     # N-layer program signature: the stacked per-group
                     # weight structs ride as traced args (never baked
                     # constants)
                     extra = [self._stacked]
                 t0 = time.perf_counter()
-                pools = self._caches.take_caches()
-                self._f_decode.check()
-            with self._m.annotation("engine.decode.dispatch"):
+                # tracecheck: disable=TRC007
+                with self._m.phase("engine.decode.stage.caches"):
+                    pools = self._caches.take_caches()
+                    self._f_decode.check()
+            # tracecheck: disable=TRC007
+            with self._m.phase("engine.decode.dispatch"):
                 # with nothing in flight every row's token is in
                 # ``feed``, which then stands in for the last output
                 toks, states, *counts = fn(
@@ -3300,22 +3358,32 @@ class ServingEngine:
         # tracecheck: disable=TRC007
         with self._m.span("engine.block_step", step=n, active=len(rows),
                           bucket=b, overlapped=flying is not None):
-            with self._m.annotation("engine.block.stage"):
-                # a row's block is the host's word on it, or -1s for
-                # "what the step in flight leaves" (still on the device)
-                feed = self._blk[:b].copy()
-                commit = np.zeros((b,), np.int32)
-                for req in rows:
-                    if not req.masks:
-                        commit[req.slot] = 1
-                tables, cursors = self._caches.decode_inputs(b, ())
-                bt, sl, commit_d, feed_d = jax.device_put(
-                    [tables, cursors, commit, feed])
+            # the parts of the staging as the decode step's
+            # tracecheck: disable=TRC007
+            with self._m.phase("engine.block.stage"):
+                # tracecheck: disable=TRC007
+                with self._m.phase("engine.block.stage.inputs"):
+                    # a row's block is the host's word on it, or -1s for
+                    # "what the step in flight leaves" (still on the
+                    # device)
+                    feed = self._blk[:b].copy()
+                    commit = np.zeros((b,), np.int32)
+                    for req in rows:
+                        if not req.masks:
+                            commit[req.slot] = 1
+                    tables, cursors = self._caches.decode_inputs(b, ())
+                # tracecheck: disable=TRC007
+                with self._m.phase("engine.block.stage.put"):
+                    bt, sl, commit_d, feed_d = jax.device_put(
+                        [tables, cursors, commit, feed])
                 hist = self._expert_counts()
                 t0 = time.perf_counter()
-                pools = self._caches.take_caches()
-                self._f_decode.check()
-            with self._m.annotation("engine.block.dispatch"):
+                # tracecheck: disable=TRC007
+                with self._m.phase("engine.block.stage.caches"):
+                    pools = self._caches.take_caches()
+                    self._f_decode.check()
+            # tracecheck: disable=TRC007
+            with self._m.phase("engine.block.dispatch"):
                 blocks, conf, states, *hist = fn(
                     self._params, self._buffers,
                     (feed_d if flying is None else flying.toks, feed_d),
@@ -3484,7 +3552,8 @@ class ServingEngine:
         scheduler's one sync with the device. ``level``: nothing was
         dispatched behind it, so the host's copies come level with the
         device (a block model's blocks)."""
-        with self._m.annotation("engine.decode.pull"):
+        # tracecheck: disable=TRC007
+        with self._m.phase("engine.decode.pull"):
             # admission/eviction need the concrete token ids
             # tracecheck: disable=TRC002
             toks = np.asarray(flight.toks)
@@ -3535,6 +3604,23 @@ class ServingEngine:
     # ------------------------------------------------- telemetry helpers
     # NOT hotpath-marked: plain host bookkeeping called once per step()
     # (the per-token writes stay inline above under pragma'd lines).
+
+    def _observe_step_clock(self) -> None:
+        """The round is over, its root span closed: publish its length
+        and its phases' seconds as counters; a slow round has left its
+        record with the tracer."""
+        m = self._m
+        length, over = m.clock.end(self._step_no, traces_built())
+        m.steps.inc()
+        m.step_seconds.inc(length)
+        step = m.clock.step
+        for counter, phases in m.phase_seconds:
+            for phase in phases:
+                if phase.step == step:      # it ran in this round
+                    counter.inc(phase.step_seconds)
+        if over:
+            m.slow_steps.inc()
+            m.slow_step_seconds.inc(over)
 
     def _observe_step_begin(self, n_active: int) -> None:
         if n_active:

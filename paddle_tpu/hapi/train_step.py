@@ -45,7 +45,29 @@ class _TrainTelemetry:
 
     def __init__(self):
         r = obs.registry()
-        self.span = obs.tracer().span
+        t = obs.tracer()
+        self.span = t.span
+        # ---- the step clock: a step is the interval from one call of
+        # the TrainStep to the next (the device's work and the caller's
+        # loss pull lie between the calls)
+        self.clock = t.step_clock("train")
+        self.dispatch = t.phase("train.dispatch")
+        self.steps = r.counter(
+            "train_steps", "step intervals closed: one a call, by the "
+            "next call or by sync()")
+        self.dispatch_seconds = r.counter(
+            "train_dispatch_seconds",
+            "the host inside train.dispatch: the lr upload and the "
+            "call of the compiled step until it returns")
+        self.slow_steps = r.counter(
+            "train_slow_steps",
+            "step intervals over 0.1 s and over 3 times the mean of "
+            "the 64 before them: each left a record in "
+            "tracer().slow_steps()")
+        self.slow_step_seconds = r.counter(
+            "train_slow_step_seconds",
+            "what those intervals ran over the mean they were held "
+            "against")
         self.syncs = r.counter(
             "train_syncs", "host-blocking loss pulls (pull_metrics/sync)")
         self.throttles = r.counter(
@@ -540,6 +562,7 @@ class TrainStep:
         # donating call below never ran), so fit's recovery can sync to
         # last-good state and simply re-dispatch the same batch
         self._f_dispatch.check()
+        self._observe_step_clock()
         if len(batch) == 1 and isinstance(batch[0], StagedBatch):
             vals = batch[0].vals
         else:
@@ -592,6 +615,21 @@ class TrainStep:
         # actually still outstanding, not the pre-drain peak
         self._observe_dispatch(vals)
         return Tensor(loss, stop_gradient=True)
+
+    def _observe_step_clock(self, reopen: bool = True) -> None:
+        """A call begins (or ``sync()`` ends the loop's run of steps):
+        close the interval the last call opened, publish its length's
+        verdict and its dispatch seconds, and open the next."""
+        m = self._m
+        if m.clock.open:
+            _, over = m.clock.end(self._step_count - 1, self._trace_count)
+            m.steps.inc()
+            m.dispatch_seconds.inc(m.dispatch.in_step)
+            if over:
+                m.slow_steps.inc()
+                m.slow_step_seconds.inc(over)
+        if reopen:
+            m.clock.begin(self._trace_count)
 
     def _observe_dispatch(self, vals=None) -> None:
         """Post-dispatch host-side telemetry: async-window depth and the
@@ -698,6 +736,9 @@ class TrainStep:
             self._m.syncs.inc()
             self._m.staleness.set(0)
             self._m.in_flight.set(0)
+        # what follows a barrier (an evaluation, a checkpoint) is no
+        # part of a step
+        self._observe_step_clock(reopen=False)
         return self._last_loss
 
     @property

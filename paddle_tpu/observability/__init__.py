@@ -5,15 +5,18 @@ The signal layer every serving/perf claim stands on: a process-wide
 gauges, fixed-exponential-bucket histograms; JSON snapshot + Prometheus
 text) and a :mod:`span tracer <paddle_tpu.observability.tracing>`
 (nested host-side timing events -> Chrome-trace JSON, mirrored into
-``jax.profiler`` captures).
+``jax.profiler`` captures) with its step clock (every phase of a step
+accounted beside the ring; one record and one WARNING line for a step
+much longer than the ones before it).
 
 Instrumented subsystems: ``generation.serving.ServingEngine`` (one span
 a phase of a step under the step's ``engine.step``, request lifecycle
 records sharing a ``rid``, rows/slots/live-token/prefill-token counters
-at the dispatch, TTFT/inter-token histograms, queue/occupancy/KV-pool
+at the dispatch, the step clock's per-phase seconds and slow-step
+counters, TTFT/inter-token histograms, queue/occupancy/KV-pool
 gauges, prefix-cache counters), ``hapi.train_step.TrainStep`` (in-flight
 window depth, sync/throttle/retrace counters, stage/dispatch/throttle/
-pull/sync spans),
+pull/sync spans, the step clock over the interval from call to call),
 ``generation.program_cache`` (hit/miss counters, compile wall-time
 histograms) and ``io.DevicePrefetcher``. ``tools/telemetry_dump.py``
 renders snapshots; ``bench.py`` and the ``tools/*_bench.py`` drivers
@@ -50,14 +53,15 @@ from __future__ import annotations
 from .metrics import (Counter, Gauge, Histogram, LATENCY_BUCKETS,
                       MetricsRegistry, exponential_buckets, registry,
                       series_quantile)
-from .tracing import Span, SpanTracer, tracer
+from .tracing import Phase, Span, SpanTracer, StepClock, tracer
 from .export import to_prometheus
 from . import memory
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "LATENCY_BUCKETS", "exponential_buckets", "registry",
-    "series_quantile", "Span", "SpanTracer", "tracer", "to_prometheus",
+    "series_quantile", "Phase", "Span", "SpanTracer", "StepClock", "tracer",
+    "to_prometheus",
     "span", "snapshot", "memory",
 ]
 

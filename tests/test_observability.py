@@ -224,6 +224,315 @@ class TestSpans:
         assert [e["name"] for e in obs.tracer().events()] == ["user_scope"]
 
 
+# ------------------------------------------------------- the step clock
+class _Clock:
+    """The injected clock of ``tracing._now``: it moves when told."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+    def tick(self, dt):
+        self.t += dt
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    from paddle_tpu.observability import tracing
+    c = _Clock()
+    monkeypatch.setattr(tracing, "_now", c)
+    return c
+
+
+RECORD_COUNTS = ("length_s", "mean_s", "outside_s", "baseline_age_s",
+                 "thread_cpu_s",
+                 "process_cpu_s", "voluntary_switches",
+                 "involuntary_switches", "major_faults", "gc_s", "gc_count",
+                 "builds", "cpus", "wall_time")
+
+
+class TestStepClock:
+    def _scope(self, tr, kind, name):
+        return tr.phase(name) if kind == "phase" else tr.span(name)
+
+    @pytest.mark.parametrize("kind", ["phase", "span"])
+    def test_a_scope_feeds_its_phase(self, clock, kind):
+        """Calls, seconds and the longest are whole lengths; a phase
+        scope and a span feed the same account."""
+        tr = obs.SpanTracer(capacity=16)
+        for dt in (0.002, 0.005, 0.003):
+            with self._scope(tr, kind, "engine.decode.dispatch"):
+                clock.tick(dt)
+        acct = tr.phases()["engine.decode.dispatch"]
+        assert acct["calls"] == 3
+        assert acct["seconds"] == pytest.approx(0.010)
+        assert acct["longest"] == pytest.approx(0.005)
+        # only the span left ring records
+        assert len(tr) == (3 if kind == "span" else 0)
+
+    @pytest.mark.parametrize("outer, inner", [
+        ("phase", "phase"), ("span", "phase"), ("phase", "span"),
+        ("span", "span")])
+    def test_nesting_gives_self_seconds_in_the_open_step(self, clock, outer,
+                                                         inner):
+        tr = obs.SpanTracer(capacity=16)
+        step = tr.step_clock("serving")
+        step.begin()
+        with self._scope(tr, outer, "engine.decode.stage"):
+            clock.tick(0.001)
+            with self._scope(tr, inner, "engine.decode.stage.put"):
+                clock.tick(0.004)
+            with self._scope(tr, inner, "engine.decode.stage.put"):
+                clock.tick(0.002)
+        ph = tr.phases()
+        assert ph["engine.decode.stage"]["seconds"] == pytest.approx(0.007)
+        assert ph["engine.decode.stage"]["in_step"] == pytest.approx(0.001)
+        assert ph["engine.decode.stage.put"]["in_step"] == \
+            pytest.approx(0.006)
+        assert ph["engine.decode.stage.put"]["longest"] == \
+            pytest.approx(0.004)
+        assert step.end(1) == (pytest.approx(0.007), 0.0)
+        # the next step opens every phase's in_step anew
+        step.begin()
+        assert tr.phases()["engine.decode.stage.put"]["in_step"] == 0.0
+        assert tr.phases()["engine.decode.stage.put"]["calls"] == 2
+
+    def test_the_same_phase_nests_in_itself_and_across_threads(self, clock):
+        import threading
+
+        tr = obs.SpanTracer(capacity=4)
+        with tr.phase("p"):
+            clock.tick(0.001)
+            with tr.phase("p"):
+                clock.tick(0.002)
+            other = threading.Thread(
+                target=lambda: tr.phase("p").__enter__().__exit__())
+            other.start()
+            other.join(timeout=10)
+            assert not other.is_alive()
+        acct = tr.phases()["p"]
+        assert acct["calls"] == 3
+        assert acct["seconds"] == pytest.approx(0.002 + 0.003 + 0.0)
+        assert acct["longest"] == pytest.approx(0.003)
+
+    @pytest.mark.parametrize("kind", ["phase", "span"])
+    def test_the_annotation_of_the_same_name_is_entered(self, monkeypatch,
+                                                        kind):
+        """A device capture holds the scope under its plain name."""
+        from paddle_tpu.observability import tracing
+        seen = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+                return self
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        monkeypatch.setattr(tracing, "_ANNOTATION", Annotation)
+        tr = obs.SpanTracer(capacity=4)
+        with self._scope(tr, kind, "engine.decode.pull"):
+            assert seen == [("enter", "engine.decode.pull")]
+        assert seen == [("enter", "engine.decode.pull"),
+                        ("exit", "engine.decode.pull")]
+
+    def test_a_phase_scope_reaches_a_profiler_trace(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.tracer().phase("engine.decode.stage.put"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))[0]
+        names = {e.name for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events}
+        assert "engine.decode.stage.put" in names
+        assert not obs.tracer().events()
+
+    def _steady(self, clock, tr, step, n, dt=0.005):
+        """``n`` steps of ``dt``; the seconds over, step by step."""
+        over = []
+        for i in range(n):
+            step.begin()
+            with tr.span("engine.step"):
+                with tr.phase("engine.decode.dispatch"):
+                    clock.tick(dt)
+            length, late = step.end(i)
+            assert length == pytest.approx(dt)
+            over.append(late)
+        return over
+
+    def test_one_slow_step_after_sixty_four_steady_ones(self, clock, caplog):
+        import logging
+
+        from paddle_tpu.observability import tracing
+        tr = obs.SpanTracer(capacity=8)     # the ring wraps many times
+        step = tr.step_clock("serving")
+        over = self._steady(clock, tr, step, tracing.SLOW_STEP_HISTORY)
+        # a steady run flags nothing
+        assert not any(over) and tr.slow_steps() == []
+        step.begin()
+        with caplog.at_level(logging.WARNING,
+                             logger="paddle_tpu.observability.tracing"):
+            with tr.span("engine.step"):
+                with tr.phase("engine.decode.stage.put"):
+                    clock.tick(0.45)
+                with tr.phase("engine.decode.dispatch"):
+                    clock.tick(0.05)
+            length, over = step.end(64)
+        assert length == pytest.approx(0.5)
+        assert over == pytest.approx(0.5 - 0.005)
+        (rec,) = tr.slow_steps()
+        assert (rec["kind"], rec["step"]) == ("serving", 64)
+        assert rec["length_s"] == pytest.approx(0.5)
+        assert rec["mean_s"] == pytest.approx(0.005)
+        assert max(rec["phases"], key=rec["phases"].get) == \
+            "engine.decode.stage.put"
+        assert sum(rec["phases"].values()) + rec["outside_s"] == \
+            pytest.approx(rec["length_s"])
+        assert rec["outside_s"] == pytest.approx(0.0, abs=1e-9)
+        # logged once, on one line
+        lines = [r.getMessage() for r in caplog.records]
+        assert len(lines) == 1 and "\n" not in lines[0]
+        assert "slow serving step 64" in lines[0]
+        assert json.loads(lines[0].split(": ", 2)[2])["step"] == 64
+        # the steps after it are held against a mean that holds it
+        assert not any(self._steady(clock, tr, step, 3))
+        assert len(tr.slow_steps()) == 1
+        assert len(tr) == tr.capacity       # the ring wrapped; it stayed
+        tr.clear()
+        assert len(tr.slow_steps()) == 1
+
+    def test_every_field_of_a_record_is_there_and_not_negative(self, clock):
+        tr = obs.SpanTracer(capacity=4)
+        step = tr.step_clock("train")
+        step.begin(builds=3)
+        clock.tick(0.004)
+        with tr.phase("train.dispatch"):
+            clock.tick(0.2)
+        step.end(7, builds=5)           # two programs built in the step
+        (rec,) = tr.slow_steps()        # no history: 0.2 s alone is slow
+        assert set(rec) == set(RECORD_COUNTS) | {
+            "kind", "step", "phases", "loadavg", "bytes_in_use",
+            "largest_free_block_bytes"}
+        for key in RECORD_COUNTS:
+            assert rec[key] >= 0, key
+        assert rec["builds"] == 2 and rec["step"] == 7
+        assert rec["outside_s"] == pytest.approx(0.004)
+        assert len(rec["loadavg"]) == 3 and min(rec["loadavg"]) >= 0
+        # the device's memory where the backend reports it (not the CPU)
+        for key in ("bytes_in_use", "largest_free_block_bytes"):
+            assert rec[key] is None or rec[key] >= 0
+        assert json.loads(json.dumps(rec)) == rec
+
+    @pytest.mark.parametrize("dt, ratio, slow", [
+        (0.09, 30.0, False),    # thirty times the mean, under 0.1 s
+        (0.50, 2.5, False),     # half a second, under 3 times the mean
+        (0.50, 3.5, True)])
+    def test_the_rule_needs_both_the_floor_and_the_ratio(self, clock, dt,
+                                                         ratio, slow):
+        from paddle_tpu.observability import tracing
+        tr = obs.SpanTracer(capacity=4)
+        step = tr.step_clock("serving")
+        first = self._steady(clock, tr, step, 10, dt=dt / ratio)
+        # with no step before it the floor alone decides the first
+        assert [late > 0 for late in first] == \
+            [dt / ratio > tracing.SLOW_STEP_MIN_S] + [False] * 9
+        before = len(tr.slow_steps())
+        step.begin()
+        clock.tick(dt)
+        assert (step.end(10)[1] > 0) is slow
+        assert len(tr.slow_steps()) - before == int(slow)
+
+    def test_the_mean_is_of_the_last_sixty_four(self, clock):
+        from paddle_tpu.observability import tracing
+        tr = obs.SpanTracer(capacity=4)
+        step = tr.step_clock("serving")
+        self._steady(clock, tr, step, 5, dt=1.0)   # long ago: compiles
+        self._steady(clock, tr, step, tracing.SLOW_STEP_HISTORY - 5,
+                     dt=0.01)
+        step.begin()
+        clock.tick(0.2)
+        assert step.end(0)[1] == 0.0    # the compiles are among the 64
+        self._steady(clock, tr, step, tracing.SLOW_STEP_HISTORY, dt=0.01)
+        step.begin()
+        clock.tick(0.2)
+        assert step.end(0)[1] == pytest.approx(0.19)
+
+    def test_the_deque_holds_sixty_four(self, clock):
+        from paddle_tpu.observability import tracing
+        tr = obs.SpanTracer(capacity=4)
+        for i in range(tracing.SLOW_STEP_HISTORY + 6):
+            step = tr.step_clock("serving")     # no history: slow at once
+            step.begin()
+            clock.tick(0.2)
+            step.end(i)
+        steps = [r["step"] for r in tr.slow_steps()]
+        assert steps == list(range(6, tracing.SLOW_STEP_HISTORY + 6))
+
+    def test_the_system_calls_are_read_at_most_every_fifty_ms(
+            self, clock, monkeypatch):
+        """A short step does not pay the thread's CPU time and switch
+        counts every time; a record says how old its baselines were."""
+        from paddle_tpu.observability import tracing
+        reads = []
+        monkeypatch.setattr(tracing, "_thread_usage",
+                            lambda: reads.append(1) or (0, 0, 0))
+        tr = obs.SpanTracer(capacity=4)
+        step = tr.step_clock("serving")
+        self._steady(clock, tr, step, 25, dt=0.004)    # 0.1 s of steps
+        assert len(reads) == 2      # at 0.0 and at 0.052 s
+        step.begin()                # at 0.100 s: 0.048 s after the last
+        clock.tick(0.3)
+        step.end(25)
+        (rec,) = tr.slow_steps()
+        assert rec["baseline_age_s"] == pytest.approx(0.048)
+        assert len(reads) == 3      # and once more, for the record
+        step.begin()                # a step of 50 ms or more: every time
+        assert len(reads) == 4
+        clock.tick(0.3)
+        step.end(26)
+        assert [r["baseline_age_s"] for r in tr.slow_steps()] == \
+            [pytest.approx(0.048), 0.0]
+
+    def test_collections_inside_a_step_are_counted(self, clock):
+        import gc
+
+        tr = obs.SpanTracer(capacity=4)
+        step = tr.step_clock("serving")
+        step.begin()
+        gc.collect()
+        gc.collect()
+        clock.tick(0.3)
+        step.end(0)
+        (rec,) = tr.slow_steps()
+        assert rec["gc_count"] >= 2 and rec["gc_s"] > 0
+
+    def test_clocks_in_turn_read_their_own_steps_phases(self, clock):
+        """Two engines of one process step in turn on one thread."""
+        tr = obs.SpanTracer(capacity=4)
+        a, b = tr.step_clock("serving"), tr.step_clock("serving")
+        for step, dt in ((a, 0.001), (b, 0.002), (a, 0.003)):
+            step.begin()
+            with tr.phase("engine.decode.dispatch"):
+                clock.tick(dt)
+            assert tr.phase("engine.decode.dispatch").in_step == \
+                pytest.approx(dt)
+            step.end(0)
+
+
 # ------------------------------------------------- serving lifecycle
 def _run_engine(model, cfg, n_req=3, tokens=5, **engine_kw):
     rng = np.random.default_rng(3)
@@ -350,6 +659,8 @@ RING_ONLY = {"request.queued", "request.first_token", "request.complete",
              "engine.spec_round"}
 # scopes of the profiler alone: a device capture holds them, the ring
 # does not
+STAGE_PARTS = ["engine.decode.stage.inputs", "engine.decode.stage.put",
+               "engine.decode.stage.caches"]
 DECODE_PARTS = ["engine.decode.stage", "engine.decode.dispatch",
                 "engine.decode.pull"]
 
@@ -517,6 +828,100 @@ class TestStepSpans:
             [],
             ["stage", "dispatch"],
             ["pull"]]
+        # the staging's three parts and the prefill's wait are on the
+        # same clock: each inside a staging, in order; one wait a prompt
+        # that ends in a token (two whole prefills, one final chunk)
+        events = sorted(
+            (e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name in STAGE_PARTS + ["engine.decode.stage",
+                                        "engine.prefill.pull"])
+        stagings = [(a, b) for a, b, name in events
+                    if name == "engine.decode.stage"]
+        assert [[name.rsplit(".", 1)[1] for a, b, name in events
+                 if name in STAGE_PARTS and lo <= a and b <= hi]
+                for lo, hi in stagings] == [["inputs", "put", "caches"]] * 3
+        assert [name for _a, _b, name in events].count(
+            "engine.prefill.pull") == 3
+        assert not {e["name"] for e in _spans()} & set(
+            STAGE_PARTS + ["engine.prefill.pull"])
+
+    def test_a_plain_decode_step_leaves_seven_ring_records(self):
+        """The phases are accounted beside the ring, not in it."""
+        eng, prompt = _scripted_engine()
+        eng.submit(prompt(5), 6)
+        eng.step()                      # prefill, first decode dispatch
+        eng.step()
+        obs.tracer().clear()
+        eng.step()                      # a plain decode step
+        assert sorted(e["name"] for e in _spans()) == sorted([
+            "engine.step", "engine.schedule", "engine.admit",
+            "engine.decode_step", "engine.emit", "engine.ledger",
+            "engine.callbacks"])
+
+    def test_the_step_clock_publishes_every_round(self):
+        """``serving_steps`` counts the rounds, ``serving_step_seconds``
+        their lengths, and each phase family the phase's seconds: as the
+        tracer's accounts have them, the counters' sums inside the
+        rounds' whole."""
+        import time
+
+        # the tracer's accounts are the process's: this run's share
+        t_start, before = time.time(), obs.tracer().phases()
+        eng, rids, steps = _scripted_run()
+        snap = obs.registry().snapshot()
+
+        def value(name):
+            return metric(snap, name, replica="0", tp="1")["value"]
+
+        ph = {name: dict(acct, **{
+            k: acct[k] - before.get(name, {}).get(k, 0)
+            for k in ("calls", "seconds")})
+            for name, acct in obs.tracer().phases().items()}
+        assert value("serving_steps") == steps == 6
+        assert ph["engine.step"]["calls"] == 6
+        assert value("serving_step_seconds") >= ph["engine.step"]["seconds"]
+        assert ph["engine.decode.dispatch"]["calls"] == 3
+        assert value("serving_decode_dispatch_seconds") == pytest.approx(
+            ph["engine.decode.dispatch"]["seconds"])
+        for part in ("inputs", "put", "caches"):
+            assert ph[f"engine.decode.stage.{part}"]["calls"] == 3
+            assert value(f"serving_decode_stage_{part}_seconds") == \
+                pytest.approx(ph[f"engine.decode.stage.{part}"]["seconds"])
+        assert ph["engine.decode.pull"]["calls"] == 3
+        assert ph["engine.prefill.pull"]["calls"] == 3
+        assert value("serving_wait_seconds") == pytest.approx(
+            ph["engine.decode.pull"]["seconds"]
+            + ph["engine.prefill.pull"]["seconds"])
+        # a chunk's host seconds leave its token's wait out
+        assert ph["engine.prefill_chunk"]["calls"] == 3
+        assert 0 < value("serving_prefill_chunk_host_seconds") < \
+            ph["engine.prefill_chunk"]["seconds"]
+        families = ["serving_decode_dispatch_seconds",
+                    "serving_decode_stage_inputs_seconds",
+                    "serving_decode_stage_put_seconds",
+                    "serving_decode_stage_caches_seconds",
+                    "serving_prefill_chunk_host_seconds",
+                    "serving_wait_seconds"]
+        assert all(value(f) > 0 for f in families)
+        assert sum(value(f) for f in families) <= \
+            value("serving_step_seconds")
+        # the longest of each phase stays with the tracer
+        whole = obs.tracer().phases()
+        assert all(0 < whole[n]["longest"] <= whole[n]["seconds"]
+                   for n in whole if whole[n]["calls"])
+        # the slow rounds (the ones that built programs) are on record
+        # with what they built, and the counters agree with the records
+        slow = [r for r in obs.tracer().slow_steps()
+                if r["kind"] == "serving" and r["wall_time"] >= t_start]
+        assert value("serving_slow_steps") == len(slow) >= 1
+        assert slow[0]["step"] == 1 and slow[0]["builds"] >= 2
+        assert value("serving_slow_step_seconds") == pytest.approx(
+            sum(r["length_s"] - r["mean_s"] for r in slow))
+        for r in slow:
+            assert sum(r["phases"].values()) + r["outside_s"] == \
+                pytest.approx(r["length_s"])
 
     def test_every_decode_step_is_overlapped_or_settled(self):
         """``serving_decode_overlapped`` and ``serving_decode_settles``

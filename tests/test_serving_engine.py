@@ -903,3 +903,81 @@ class TestOverlappedDecode:
         assert [int(h[1][slot]) for h in staged] == [6, 7]
         assert int(eng.pool.seq_lens[slot]) == 8
         tiny.check(prompt, eng.run()[rid], 6)
+
+
+class TestStepClock:
+    def test_a_stalled_staging_leaves_one_record(self, tiny):
+        """``CacheManager.decode_inputs`` sleeps 0.3 s once: that round
+        is on record once, its largest phase the staging's inputs, with
+        a thread CPU time that tells a sleep from work; and the step
+        clock's counters hold every round."""
+        import time
+
+        # a first engine builds the programs: the second's rounds are
+        # then all short, and a compile is no part of any mean
+        prompt = tiny.prompts((6,))[0]
+        warm = tiny.engine(bucket_ladder=(4,))
+        warm.submit(prompt, 12)
+        warm.run()
+
+        eng = tiny.engine(bucket_ladder=(4,))
+        rid = eng.submit(prompt, 12)
+        stage, calls = eng._caches.decode_inputs, []
+
+        def stalled(b, live):
+            calls.append(b)
+            if len(calls) == 6:
+                time.sleep(0.3)
+            return stage(b, live)
+
+        eng._caches.decode_inputs = stalled
+        t_start, rounds = time.time(), 0
+        while eng.has_work():
+            eng.step()
+            rounds += 1
+        tiny.check(prompt, eng.take_results()[rid], 12)
+        assert len(calls) >= 6
+
+        slow = [r for r in obs.tracer().slow_steps()
+                if r["wall_time"] >= t_start and max(
+                    r["phases"], key=r["phases"].get)
+                == "engine.decode.stage.inputs"]
+        (rec,) = slow
+        assert rec["kind"] == "serving" and 0.3 <= rec["length_s"] < 1.0
+        assert rec["phases"]["engine.decode.stage.inputs"] >= 0.3
+        assert sum(rec["phases"].values()) + rec["outside_s"] == \
+            pytest.approx(rec["length_s"])
+        assert rec["thread_cpu_s"] < 0.1        # asleep, not working
+        assert rec["builds"] == 0 and rec["gc_s"] < 0.1
+        assert _counter(eng, "serving_slow_steps") >= 1
+        assert _counter(eng, "serving_slow_step_seconds") >= \
+            rec["length_s"] - rec["mean_s"]
+
+        assert _counter(eng, "serving_steps") == rounds
+        phases = [_counter(eng, name) for name in (
+            "serving_decode_dispatch_seconds",
+            "serving_decode_stage_inputs_seconds",
+            "serving_decode_stage_put_seconds",
+            "serving_decode_stage_caches_seconds",
+            "serving_wait_seconds")]
+        assert all(v > 0 for v in phases)
+        assert phases[1] >= 0.3
+        assert _counter(eng, "serving_step_seconds") >= sum(phases)
+
+    def test_a_steady_engine_records_nothing(self, tiny):
+        import time
+
+        prompt = tiny.prompts((6,))[0]
+        warm = tiny.engine(bucket_ladder=(4,))
+        warm.submit(prompt, 8)
+        warm.run()
+        eng = tiny.engine(bucket_ladder=(4,))
+        eng.submit(prompt, 8)
+        t_start = time.time()
+        eng.run()
+        # a round of a tiny model that takes a tenth of a second is the
+        # machine's doing (a loaded test host); it would be on record
+        assert _counter(eng, "serving_slow_steps") == len(
+            [r for r in obs.tracer().slow_steps()
+             if r["wall_time"] >= t_start])
+        assert _counter(eng, "serving_steps") > 0

@@ -144,6 +144,87 @@ class TestTrainStep:
         assert np.isfinite(l1)
 
 
+class TestStepClock:
+    """A train step is the interval from one call to the next; the
+    clock is injected, so a compile's real seconds are no part of it."""
+
+    def _run(self, monkeypatch, slow_call, calls=8, sync_at=None):
+        from paddle_tpu import observability as obs
+        from paddle_tpu.observability import tracing
+
+        now = [50.0]
+        monkeypatch.setattr(tracing, "_now", lambda: now[0])
+        cfg = GPTConfig.tiny()
+        paddle.seed(5)
+        m = GPTForCausalLM(cfg)
+        step = TrainStep(m, paddle.optimizer.SGD(
+            0.1, parameters=m.parameters()))
+        jitted, seen = step._jit_step, []
+
+        def dispatch(*args):
+            seen.append(1)
+            now[0] += 0.5 if len(seen) == slow_call else 0.004
+            return jitted(*args)
+
+        step._jit_step = dispatch
+        x, y = make_batch(cfg, b=2, s=16)
+
+        def counter(name):
+            fam = obs.registry().snapshot()["metrics"][name]
+            return sum(s["value"] for s in fam["series"])
+
+        names = ("train_steps", "train_dispatch_seconds",
+                 "train_slow_steps", "train_slow_step_seconds")
+        before = {n: counter(n) for n in names}
+        records = len(obs.tracer().slow_steps())
+        for i in range(calls):
+            step(x, y)
+            now[0] += 0.001             # the caller's own time
+            if i + 1 == sync_at:
+                step.sync()
+                now[0] += 30.0          # an evaluation behind a barrier
+        step.sync()
+        delta = {n: counter(n) - before[n] for n in names}
+        return step, delta, obs.tracer().slow_steps()[records:]
+
+    def test_a_slow_dispatch_is_on_record_once(self, monkeypatch):
+        step, delta, slow = self._run(monkeypatch, slow_call=6)
+        assert delta["train_steps"] == 8
+        assert delta["train_dispatch_seconds"] == pytest.approx(
+            7 * 0.004 + 0.5)
+        assert delta["train_slow_steps"] == 1
+        assert delta["train_slow_step_seconds"] == pytest.approx(
+            0.501 - 0.005)
+        (rec,) = slow
+        assert (rec["kind"], rec["step"]) == ("train", 5)
+        assert rec["length_s"] == pytest.approx(0.501)
+        assert rec["mean_s"] == pytest.approx(0.005)
+        assert rec["phases"]["train.dispatch"] == pytest.approx(0.5)
+        assert max(rec["phases"], key=rec["phases"].get) == "train.dispatch"
+        # the caller's millisecond lies under no phase
+        assert rec["outside_s"] == pytest.approx(0.001)
+        assert rec["builds"] == 0
+
+    def test_the_step_that_traces_says_so(self, monkeypatch):
+        """The first interval holds the trace of the step function."""
+        step, delta, slow = self._run(monkeypatch, slow_call=1)
+        (rec,) = slow
+        assert rec["step"] == 0 and rec["builds"] == step.trace_count == 1
+        assert delta["train_slow_steps"] == 1
+
+    def test_a_steady_run_records_nothing(self, monkeypatch):
+        step, delta, slow = self._run(monkeypatch, slow_call=None)
+        assert slow == [] and delta["train_slow_steps"] == 0
+        assert delta["train_steps"] == 8
+        assert delta["train_dispatch_seconds"] == pytest.approx(8 * 0.004)
+
+    def test_a_barrier_ends_the_interval(self, monkeypatch):
+        """What follows ``sync()`` is no part of a step."""
+        step, delta, slow = self._run(monkeypatch, slow_call=None,
+                                      sync_at=4)
+        assert slow == [] and delta["train_steps"] == 8
+
+
 class TestShardedTrainStep:
     def test_dp_sharded_step(self):
         import jax
