@@ -57,9 +57,7 @@ EXTRA_ATOMS = frozenset({ATOM_FUSED, ATOM_GENERIC, ATOM_SAMPLE,
 # tests/test_keycheck.py asserts the two never drift.
 PROGRAM_FLAGS_FALLBACK = frozenset({
     "fused_block_decode", "fused_block_layers", "use_pallas",
-    "flash_attn_min_seqlen",
-    "flash_block_q", "flash_block_k", "flash_compact_stats",
-    "flash_dispatch_table",
+    "flash_attn_min_seqlen", "flash_compact_stats", "flash_dispatch_table",
     "tpu_matmul_precision", "embedding_matmul_grad", "deterministic",
     "check_nan_inf", "check_nan_inf_level",
 })

@@ -68,10 +68,10 @@ def _chunk_attn(q, k, v, causal, sm_scale, h, hkv):
             qf = jnp.swapaxes(q, 1, 2).reshape(b * h, cq, d)
             kf = jnp.swapaxes(k, 1, 2).reshape(b * hkv, ck, d)
             vf = jnp.swapaxes(v, 1, 2).reshape(b * hkv, ck, d)
-            # pin 128x128 tiles: the FLAGS_flash_block_* tuning is swept
-            # on monolithic multi-k seqs; ring steps see small per-rank
-            # chunks where a full-chunk block would re-materialize the
-            # quadratic (C, C) scores the ring exists to avoid
+            # pin 128x128 tiles: flash_tiling's are swept on monolithic
+            # multi-k seqs; ring steps see small per-rank chunks where a
+            # full-chunk block would re-materialize the quadratic (C, C)
+            # scores the ring exists to avoid
             out, lse = flash_attention_with_lse(
                 qf, kf, vf, causal=causal, sm_scale=sm_scale,
                 block_q=128, block_k=128,

@@ -209,17 +209,6 @@ define_flag("flash_compact_stats", True,
             "128x-replicated HBM transients (advisor r2). Numerics are "
             "parity-tested in interpret mode; that the layouts compile "
             "for the chip is pinned by tests/test_chip_compile.py.")
-define_flag("flash_block_q", 512,
-            "Flash-attention q rows per pallas grid step. Default 512: "
-            "the r05 on-chip sweep (ATTN_BENCH_r05.json) measured "
-            "512x512 at 76.0 ms vs 108.6 ms for the old 128x128 default "
-            "(seq 4096 fwd+bwd, v5e) — fewer grid steps amortize the "
-            "revisited-accumulator loads. Short sequences snap down "
-            "automatically; set FLAGS_flash_block_q/_k (or pass "
-            "block_q/block_k) to apply a different tuning.")
-define_flag("flash_block_k", 512,
-            "Flash-attention kv columns per pallas grid step (see "
-            "flash_block_q).")
 define_flag("fused_block_decode", True,
             "Serve steady-state decode through the fused transformer-block "
             "kernel (kernels/fused_block_decode.py): one program per layer "
@@ -229,7 +218,7 @@ define_flag("fused_block_decode", True,
             "round-trips HBM between every op. Applies to models exposing "
             "block_decode_spec() (the Llama family); others keep the "
             "generic compiled step. Env-overridable "
-            "(FLAGS_fused_block_decode=0) like the flash block flags.")
+            "(FLAGS_fused_block_decode=0).")
 define_flag("fused_block_layers", 1,
             "How many transformer blocks one fused decode kernel runs "
             "(kernels/fused_block_decode.py multi-layer mode): N > 1 "
@@ -243,20 +232,19 @@ define_flag("fused_block_layers", 1,
             "N whose VMEM working set cannot fit. Requires the model's "
             "block_decode_spec() to publish layer_groups; models that "
             "fall back to the generic step ignore this flag.")
-define_flag("flash_dispatch_table", "0:flash;2048:dense;4096:512x512",
+define_flag("flash_dispatch_table", "0:flash;2048:dense;4096:flash",
             "Per-shape flash-attention dispatch table: ';'-separated "
-            "'<min_seqlen>:<entry>' buckets, entry one of 'flash' (kernel "
-            "with the FLAGS_flash_block_{q,k} defaults), 'dense' (XLA "
-            "dense sdpa), or 'BQxBK' (kernel with those blocks). A query "
-            "length resolves to the bucket with the largest min_seqlen "
-            "<= it; lengths below every bucket use 'flash'. Seeded from "
-            "the r05 on-chip A/B (ATTN_BENCH_r05.json): flash matches "
-            "dense at 1024 (1.01x), LOSES at 2048 (0.86x -> dense "
-            "fallback so the fused path never loses to XLA dense), and "
-            "wins at 4096+ with the 512x512 sweep blocks (76.0 ms vs "
+            "'<min_seqlen>:<entry>' buckets, entry 'flash' (the kernels, "
+            "at the blocks flash_tiling derives from the call's shapes) "
+            "or 'dense' (XLA dense sdpa). A query length resolves to the "
+            "bucket with the largest min_seqlen <= it; lengths below "
+            "every bucket use 'flash'. Seeded from the r05 on-chip A/B "
+            "(ATTN_BENCH_r05.json): flash matches dense at 1024 (1.01x), "
+            "LOSES at 2048 (0.86x -> dense fallback so the fused path "
+            "never loses to XLA dense), and wins at 4096+ (76.0 ms vs "
             "100.6 dense). Applies where sdpa already cleared "
             "FLAGS_flash_attn_min_seqlen; set to '' to disable the table "
-            "(always flash with the default blocks).")
+            "(always flash).")
 define_flag("train_max_in_flight", 32,
             "Hard cap on dispatched-but-unsynced train steps. The async "
             "TrainStep window never blocks on the loss; this bound is the "
@@ -477,9 +465,7 @@ define_flag("dataloader_max_worker_restarts", 2,
 # shims) never invalidates a compiled serving program.
 PROGRAM_FLAGS = (
     "fused_block_decode", "fused_block_layers", "use_pallas",
-    "flash_attn_min_seqlen",
-    "flash_block_q", "flash_block_k", "flash_compact_stats",
-    "flash_dispatch_table",
+    "flash_attn_min_seqlen", "flash_compact_stats", "flash_dispatch_table",
     "tpu_matmul_precision", "embedding_matmul_grad", "deterministic",
     "check_nan_inf", "check_nan_inf_level",
 )

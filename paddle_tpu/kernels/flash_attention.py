@@ -56,10 +56,9 @@ except Exception:  # pragma: no cover - CPU CI path (interpret mode)
 
 # the flag set the flash entry points resolve ONCE per call via
 # flags.snapshot (one lock acquisition + env parse), then thread through
-# _blocks/_compact — the decode/serving hot path calls these thousands of
-# times a second and per-helper registry round-trips were host overhead
-_FLASH_FLAGS = ("use_pallas", "flash_block_q", "flash_block_k",
-                "flash_compact_stats", "flash_dispatch_table")
+# _compact — the decode/serving hot path calls these thousands of times a
+# second and per-helper registry round-trips were host overhead
+_FLASH_FLAGS = ("use_pallas", "flash_compact_stats", "flash_dispatch_table")
 
 
 def _flash_snapshot():
@@ -67,76 +66,109 @@ def _flash_snapshot():
     return snapshot(_FLASH_FLAGS)
 
 
-def resolve_dispatch(seq_len: int, snap=None):
+def resolve_dispatch(seq_len: int, snap=None) -> str:
     """Per-shape dispatch (FLAGS_flash_dispatch_table): resolve a query
     length against the ';'-separated ``min_seqlen:entry`` buckets and
-    return ``(kind, blocks)`` — kind ``"flash"`` (blocks ``None`` = the
-    FLAGS_flash_block_{q,k} defaults, or an explicit ``(bq, bk)``
-    override) or ``"dense"`` (the benched-slower shapes: the r05 on-chip
-    A/B has flash LOSING to XLA dense at seq 2048, 0.86x, so that bucket
-    must fall back — a fused path that loses to the unfused one has no
-    reason to exist). A length resolves to the bucket with the largest
-    min_seqlen <= it; lengths below every bucket — and any malformed
-    entry — resolve to flash with the defaults, and an empty table
-    disables per-shape dispatch entirely."""
+    return ``"flash"`` (the kernels, at ``flash_tiling``'s blocks) or
+    ``"dense"`` (the benched-slower shapes: the r05 on-chip A/B has flash
+    LOSING to XLA dense at seq 2048, 0.86x, so that bucket must fall back
+    — a fused path that loses to the unfused one has no reason to exist).
+    A length resolves to the bucket with the largest min_seqlen <= it;
+    lengths below every bucket — and any malformed entry — resolve to
+    flash, and an empty table disables per-shape dispatch entirely."""
     if snap is None:
         snap = _flash_snapshot()
     table = (snap.flash_dispatch_table or "").strip()
     best_min, best = -1, None
     for entry in table.split(";"):
-        entry = entry.strip()
-        if not entry:
-            continue
-        min_s, _, kind = entry.partition(":")
+        min_s, _, kind = entry.strip().partition(":")
         try:
             lo = int(min_s)
         except ValueError:
             continue
         if lo <= seq_len and lo > best_min:
             best_min, best = lo, kind.strip().lower()
-    if best in (None, "", "flash"):
-        return "flash", None
-    if best == "dense":
-        return "dense", None
-    bq, _, bk = best.partition("x")
-    try:
-        return "flash", (int(bq), int(bk))
-    except ValueError:
-        return "flash", None
-
-
-def _blocks(block_q, block_k, snap=None):
-    """None -> the FLAGS_flash_block_{q,k} tuning (env-overridable, so a
-    banked on-chip sweep from tools/attn_bench.py applies without a code
-    change). The flag registry is the single source of the default
-    (512x512 since the r05 on-chip sweep); ``snap`` is the caller's
-    one-per-trace flags.snapshot so this never re-resolves per kernel."""
-    if block_q is None or block_k is None:
-        if snap is None:
-            snap = _flash_snapshot()
-        if block_q is None:
-            block_q = int(snap.flash_block_q)
-        if block_k is None:
-            block_k = int(snap.flash_block_k)
-    return block_q, block_k
+    return "dense" if best == "dense" else "flash"
 
 
 def _snap(block: int, n: int) -> int:
     """Largest usable block for a length-n axis: block itself when it
     divides n, else the largest multiple-of-128 divisor of n that is
-    < block. Returns 0 when none exists (caller raises). Keeps a
-    flag-tuned block (swept at one shape) from silently demoting other
-    shapes to the dense path: seq 1664 with FLAGS_flash_block_k=512
-    snaps to 128 instead of losing the kernel."""
+    < block. Returns 0 when none exists (caller raises). Keeps a block
+    wider than a shape allows from demoting it to the dense path: seq
+    1664 under a 1,024 block snaps to 128 instead of losing the kernel."""
     block = min(block, n)
-    if n % block == 0:
+    if block and n % block == 0:
         return block
     for cand in range(block - block % 128, 0, -128):
         if n % cand == 0:
             return cand
     return 0
+
+
 _NEG_INF = -1e30
 _LANES = 128  # stat rows replicate across one lane tile inside kernels
+
+
+# ================================================================== tiling
+# What one grid step of the three training kernels spans comes from the
+# call's shapes (``flash_tiling``), as ``paged_chunk_attention``'s does
+# (``paged_attention.chunk_tiling``): per-step costs (the statistics, the
+# pipeline's prologue) outweigh a 512 x 512 block's products at a head of
+# 64, so a step takes as much of the sequence as VMEM allows.
+# KERNEL_DECISIONS.md "Flash attention tiling" has the chip sweep that set
+# the two constants.
+_MAX_BLOCK = 1024
+# VMEM one step may hold by ``_step_vmem``'s count. v5e has 128 MiB, of
+# which Mosaic scopes a kernel to 16 MiB unless the call asks for more
+# (``_compiler_params``).
+_STEP_VMEM_BYTES = 24 << 20
+
+
+def _step_vmem(block_q: int, block_k: int, d: int, itemsize: int) -> int:
+    """VMEM of the heaviest kernel's step (dk/dv's): four float32
+    ``(bq, bk)`` temporaries (scores, ``p``, ``dp``, ``ds``), the q, dO,
+    k and v blocks double-buffered, and the two float32 accumulators
+    (dk and dv; the forward's output and accumulator are no wider),
+    double-buffered, a head under the 128 lanes padded to them."""
+    lanes = max(d, _LANES)
+    return (4 * 4 * block_q * block_k
+            + 2 * 2 * (block_q + block_k) * lanes * itemsize
+            + 2 * 2 * max(block_q, block_k) * lanes * 4)
+
+
+def flash_tiling(s_q: int, s_k: int, d: int,
+                 itemsize: int) -> Tuple[int, int]:
+    """``(block_q, block_k)`` of a call over ``s_q`` queries and ``s_k``
+    keys of head ``d`` in a dtype of ``itemsize`` bytes: the widest side,
+    from ``_MAX_BLOCK`` down in steps of 128, whose blocks (``_snap``'d to
+    each axis) keep a step inside ``_STEP_VMEM_BYTES``; 0 for an axis with
+    no usable block (the kernels raise)."""
+    for side in range(_MAX_BLOCK, 0, -128):
+        bq, bk = _snap(side, s_q), _snap(side, s_k)
+        fits = _step_vmem(bq, bk, d, itemsize) <= _STEP_VMEM_BYTES
+        if fits or not (bq and bk):
+            return bq, bk
+    return bq, bk
+
+
+def _compiler_params(block_q: int, block_k: int, d: int, itemsize: int):
+    """The VMEM scope a step of these blocks asks Mosaic for, where
+    ``_step_vmem`` counts more than 12 MiB of the default 16; else none."""
+    vmem = _step_vmem(block_q, block_k, d, itemsize)
+    if vmem <= (12 << 20) or pltpu is None:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20))}
+
+
+def _blocks(block_q, block_k, q, k):
+    """Blocks passed explicitly as given; a side left None takes
+    ``flash_tiling``'s for the call's shapes."""
+    tq, tk = flash_tiling(q.shape[1], k.shape[1], q.shape[2],
+                          q.dtype.itemsize)
+    return (tq if block_q is None else block_q,
+            tk if block_k is None else block_k)
 
 
 def _rep(x):
@@ -431,6 +463,7 @@ def _fwd_compact(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
         ],
         interpret=_interpret(),
         name="flash_fwd",
+        **_compiler_params(block_q, block_k, d, q.dtype.itemsize),
     )(*args)
     return out, lse[:, 0, :]
 
@@ -479,6 +512,7 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k,
         ],
         interpret=_interpret(),
         name="flash_fwd_stats",
+        **_compiler_params(block_q, block_k, d, q.dtype.itemsize),
     )(*args)
 
     # reduce the lane-replicated stats to compact (BH, S) residuals —
@@ -598,9 +632,10 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
     bh, sq, d = q.shape
     skv = k.shape[1]
     # same snap as the forward (whose guard already rejected impossible
-    # shapes) so fwd and bwd tile identically under flag-tuned blocks
+    # shapes) so fwd and bwd tile identically
     bq = _snap(block_q, sq)
     bk = _snap(block_k, skv)
+    call_kw = _compiler_params(bq, bk, d, q.dtype.itemsize)
 
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1)                               # (bh, sq)
@@ -659,6 +694,7 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
         out_shape=_sds((bh, sq, d), jnp.float32, q),
         interpret=_interpret(),
         name="flash_bwd_dq",
+        **call_kw,
     )(q, k, v, do, *stats_dq)
     dq = (dq * sm_scale).astype(q.dtype)
 
@@ -702,6 +738,7 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
                    _sds((bh_kv, skv, d), jnp.float32, q)],
         interpret=_interpret(),
         name="flash_bwd_dkv",
+        **call_kw,
     )(q, k, v, do, *rows)
     dk = (dk * sm_scale).astype(k.dtype)
     return dq, dk, dv.astype(v.dtype), None, None
@@ -801,7 +838,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     snap = _flash_snapshot()
-    block_q, block_k = _blocks(block_q, block_k, snap)
+    block_q, block_k = _blocks(block_q, block_k, q, k)
     if n_kv_heads is None:
         n_kv_heads = n_heads
     if n_heads % n_kv_heads:
@@ -828,14 +865,15 @@ def flash_attention(q, k, v, segment_ids: Optional[jax.Array] = None,
     attend only within their segment (varlen batches packed statically).
     GQA: pass q as (B*n_heads, S, D) and k/v as (B*n_kv_heads, Skv, D) —
     the kernels read the UNEXPANDED kv via index maps (Hkv bandwidth) and
-    accumulate dk/dv over each group's query heads.  ``snap``: the
+    accumulate dk/dv over each group's query heads. ``block_q`` /
+    ``block_k`` left None take ``flash_tiling``'s. ``snap``: the
     caller's trace-boundary flags snapshot (must cover _FLASH_FLAGS);
     resolved here only when the caller didn't already."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if snap is None:
         snap = _flash_snapshot()
-    block_q, block_k = _blocks(block_q, block_k, snap)
+    block_q, block_k = _blocks(block_q, block_k, q, k)
     if n_kv_heads is None:
         n_kv_heads = n_heads
     if n_heads % n_kv_heads:

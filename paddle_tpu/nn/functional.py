@@ -867,15 +867,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     reference: python/paddle/nn/functional/flash_attention.py).
     Dispatches to the Pallas flash-attention kernel on TPU when enabled,
     through the per-shape FLAGS_flash_dispatch_table: benched-slower
-    shape buckets resolve to the XLA dense path, benched-faster ones may
-    carry their own block config."""
+    shape buckets resolve to the XLA dense path; the kernels take their
+    blocks from the call's shapes (``flash_tiling``)."""
     from .. import flags
     # one snapshot covering the whole flash-dispatch decision (kernel
-    # on/off, the min-seqlen gate, the per-shape table and its block
-    # overrides) — resolved once per trace and threaded through
-    # resolve_dispatch, never re-read per helper (tracecheck TRC001)
+    # on/off, the min-seqlen gate, the per-shape table) — resolved once
+    # per trace and threaded through resolve_dispatch, never re-read per
+    # helper (tracecheck TRC001)
     snap = flags.snapshot(("use_pallas", "flash_attn_min_seqlen",
-                           "flash_block_q", "flash_block_k",
                            "flash_compact_stats", "flash_dispatch_table"))
     if (snap.use_pallas and attn_mask is None and dropout_p == 0.0
             and flags.is_tpu_backend()
@@ -883,17 +882,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         try:
             from ..kernels.flash_attention import (flash_attention_bshd,
                                                    resolve_dispatch)
-            kind, blk = resolve_dispatch(query.shape[1], snap)
+            kind = resolve_dispatch(query.shape[1], snap)
         except ImportError:
-            kind, blk = "dense", None
+            kind = "dense"
         if kind == "flash":
-            bq, bk = blk if blk is not None else (None, None)
             try:
                 return apply_op(
                     "flash_attention",
                     lambda q, k, v: flash_attention_bshd(
-                        q, k, v, causal=is_causal, block_q=bq, block_k=bk,
-                        snap=snap),
+                        q, k, v, causal=is_causal, snap=snap),
                     query, key, value)
             except NotImplementedError:
                 pass

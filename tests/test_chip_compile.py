@@ -270,6 +270,13 @@ _TIER1 = {
         8 * 32, 2048, 128, bkv=8 * 8, heads=32, kv_heads=8, grad=True),
     "flash_bwd-dp2xmp2-gqa32x8-d128-s1024": _flash_dp2_mp2(4, 1024, 32, 8,
                                                           128),
+    # the two train cells' calls at their exact shapes, no blocks passed:
+    # ``flash_tiling``'s 1,024 x 1,024 steps fit VMEM and keep their names
+    # (gpt3-345m.train: BH 256 x 1,024 x 64; mistral-7b.train-dp2mp2, a
+    # chip's share: 16 query heads over 4 KV heads, 8 KV rows, 4,096 x 128)
+    "flash_bwd-gpt3-train-d64-s1024": _flash(256, 1024, 64, grad=True),
+    "flash_bwd-mistral-train-gqa16x4-d128-s4096": _flash(
+        32, 4096, 128, bkv=8, heads=16, kv_heads=4, grad=True),
     "flash_fwd-varlen-d64-s1024": _flash(8 * 16, 1024, 64, segments=True),
     "flash_fwd-noncausal-d40-s4096": _flash(2 * 8, 4096, 40, causal=False),
     "flash_prefill-d128": _prefill(1, 512, 32, 8, 128, 1024),

@@ -12,8 +12,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels.flash_attention import (
-    flash_attention, flash_attention_bshd,
+    flash_attention, flash_attention_bshd, flash_tiling,
 )
 
 
@@ -72,14 +73,14 @@ class TestFlashForward:
         np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-6)
 
     def test_non_divisible_seq_raises_not_implemented(self):
-        # no multiple-of-128 block <= the 512 default divides 600, and 600
-        # itself exceeds the block cap -> no usable block
-        q, k, v = make_qkv(s=600)
+        # no multiple-of-128 block <= the 1,024 cap divides 1,100, and
+        # 1,100 itself exceeds the cap -> no usable block
+        q, k, v = make_qkv(s=1100)
         with pytest.raises(NotImplementedError):
             flash_attention(q, k, v)
 
     def test_short_non_divisible_seq_runs_single_block(self):
-        # seqs <= the default block snap to one full-length block (Mosaic
+        # seqs <= the block cap snap to one full-length block (Mosaic
         # allows block == overall dim), so 300 now takes the kernel path
         q, k, v = make_qkv(s=300)
         out = flash_attention(q, k, v, causal=True)
@@ -715,10 +716,95 @@ class TestMeshPartitioned:
         assert step._layout == (("data",), "tensor")
 
 
+class TestTiling:
+    """``flash_tiling``: the blocks a call takes from its own shapes —
+    the widest side up to the cap that divides each axis and keeps a
+    step inside the VMEM budget (KERNEL_DECISIONS.md "Flash attention
+    tiling")."""
+
+    @pytest.mark.parametrize("s_q,s_k,d,itemsize,want", [
+        (1024, 1024, 64, 2, (1024, 1024)),   # gpt3-345m.train: the whole seq
+        (4096, 4096, 128, 2, (1024, 1024)),  # mistral-7b, a chip's share
+        (256, 256, 64, 4, (256, 256)),       # under the cap: one block
+        (640, 640, 64, 2, (640, 640)),
+        (1664, 1664, 64, 2, (128, 128)),     # 13 x 128: snaps to 128
+        (3072, 3072, 64, 2, (1024, 1024)),
+        (2560, 2560, 64, 2, (640, 640)),     # 1,024 does not divide it
+        (4096, 4096, 40, 4, (1024, 1024)),   # head 40 (non-causal cases)
+        (256, 4096, 64, 2, (256, 1024)),     # queries shorter than keys
+        (4096, 4096, 256, 4, (512, 512)),    # wide float32 head: VMEM binds
+        (1100, 1100, 64, 2, (0, 0)),         # no usable block: kernels raise
+    ], ids=["gpt3", "mistral", "short", "short-odd", "snap-1664", "s3072",
+            "s2560", "d40", "cross", "d256-f32", "none"])
+    def test_rule(self, s_q, s_k, d, itemsize, want):
+        got = flash_tiling(s_q, s_k, d, itemsize)
+        assert got == want
+        if all(got):
+            assert s_q % got[0] == 0 and s_k % got[1] == 0
+            assert fa._step_vmem(*got, d, itemsize) <= fa._STEP_VMEM_BYTES
+
+    @pytest.mark.parametrize("d", [64, 128, 192])
+    def test_head_128_inside_the_budget_and_wider_blocks_not(self, d):
+        """At the cells' heads the tiling's blocks fit the budget and the
+        next wider block of either side would not: the budget, not the
+        cap alone, is what stops 2,048 at a head of 128."""
+        bq, bk = flash_tiling(4096, 4096, d, 2)
+        assert (bq, bk) == (1024, 1024)
+        assert fa._step_vmem(bq, bk, d, 2) <= fa._STEP_VMEM_BYTES
+        assert fa._step_vmem(2 * bq, bk, d, 2) > fa._STEP_VMEM_BYTES
+
+    def test_large_steps_ask_for_their_vmem(self):
+        """A step counted past 12 MiB asks Mosaic for its VMEM (the
+        default scope is 16 MiB); a 512 x 512 step asks for nothing."""
+        assert fa._compiler_params(512, 512, 64, 2) == {}
+        params = fa._compiler_params(1024, 1024, 128, 2)["compiler_params"]
+        assert params.vmem_limit_bytes == \
+            fa._step_vmem(1024, 1024, 128, 2) + (16 << 20)
+
+    @pytest.mark.parametrize("case", ["causal", "noncausal", "segments",
+                                      "gqa"])
+    def test_parity_at_the_derived_tiles(self, case, monkeypatch):
+        """Two 1,024 blocks a side — the derived tiles of a 2,048-token
+        call, the causal diagonal skipping a whole block — forward and
+        backward against the dense reference, no blocks passed."""
+        from paddle_tpu.kernels.flash_attention import flash_attention_ref
+        seen = []
+        real = fa._fwd_setup
+        monkeypatch.setattr(fa, "_fwd_setup", lambda q, k, bq, bk, *a: (
+            seen.append((bq, bk)) or real(q, k, bq, bk, *a)))
+        h, hkv = (4, 2) if case == "gqa" else (1, 1)
+        rng = np.random.default_rng(17)
+        s, d = 2048, 32
+        q = jnp.asarray(rng.standard_normal((h, s, d)) * 0.5, jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal((hkv, s, d)) * 0.5,
+                            jnp.float32) for _ in range(2))
+        causal = case != "noncausal"
+        seg = None
+        if case == "segments":
+            seg = jnp.concatenate([jnp.zeros((1, 1536), jnp.int32),
+                                   jnp.ones((1, 512), jnp.int32)], axis=1)
+        kw = dict(causal=causal, n_heads=h, n_kv_heads=hkv)
+
+        def loss(fn, **extra):
+            return lambda *a: jnp.sum(fn(*a, **kw, **extra) ** 2)
+
+        got = jax.value_and_grad(loss(flash_attention, segment_ids=seg),
+                                 argnums=(0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(loss(flash_attention_ref,
+                                       segment_ids=seg),
+                                  argnums=(0, 1, 2))(q, k, v)
+        assert seen and set(seen) == {(1024, 1024)}
+        np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-4)
+        for a, b, name in zip(got[1], want[1], "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-4, rtol=5e-4,
+                                       err_msg=f"d{name}")
+
+
 class TestDispatchTable:
     """Per-shape dispatch (FLAGS_flash_dispatch_table): benched-slower
     shape buckets must resolve to the dense path, benched-faster ones to
-    the kernel (optionally with their own blocks) — VERDICT r05: a fused
+    the kernel at the blocks its shapes give — VERDICT r05: a fused
     path that loses to the unfused one has no reason to exist."""
 
     def _resolve(self, seq, table):
@@ -734,25 +820,27 @@ class TestDispatchTable:
 
     def test_default_table_buckets(self):
         """The shipped default encodes the ATTN_BENCH_r05 A/B: flash at
-        1024 (1.01x), dense at 2048 (0.86x — the losing row), tuned
-        512x512 blocks at 4096+ (76.0ms vs 100.6 dense)."""
+        1024 (1.01x), dense at 2048 (0.86x — the losing row), flash
+        again at 4096+ (76.0ms vs 100.6 dense): mistral-7b.train-dp2mp2's
+        4,096-token attention stays on the kernels."""
         from paddle_tpu.kernels.flash_attention import resolve_dispatch
-        assert resolve_dispatch(1024) == ("flash", None)
-        assert resolve_dispatch(2048) == ("dense", None)
-        assert resolve_dispatch(3072) == ("dense", None)
-        assert resolve_dispatch(4096) == ("flash", (512, 512))
-        assert resolve_dispatch(8192) == ("flash", (512, 512))
-        # below every bucket: flash with the flag-default blocks
-        assert resolve_dispatch(128) == ("flash", None)
+        assert resolve_dispatch(1024) == "flash"
+        assert resolve_dispatch(2048) == "dense"
+        assert resolve_dispatch(3072) == "dense"
+        assert resolve_dispatch(4096) == "flash"
+        assert resolve_dispatch(8192) == "flash"
+        # below every bucket: flash
+        assert resolve_dispatch(128) == "flash"
 
     def test_override_and_disable(self):
-        assert self._resolve(2048, "") == ("flash", None)   # table off
-        assert self._resolve(2048, "0:dense") == ("dense", None)
-        assert self._resolve(512, "0:256x128;1024:dense") == \
-            ("flash", (256, 128))
-        # malformed entries never take the kernel down — default to flash
-        assert self._resolve(2048, "0:flash;bogus;2048:99xx") == \
-            ("flash", None)
+        assert self._resolve(2048, "") == "flash"   # table off
+        assert self._resolve(2048, "0:dense") == "dense"
+        assert self._resolve(512, "0:flash;1024:dense") == "flash"
+        assert self._resolve(1024, "0:flash;1024:dense") == "dense"
+        # malformed entries never take the kernel down — default to flash;
+        # a block-size entry (the removed 'BQxBK' form) is one of them
+        assert self._resolve(2048, "0:flash;bogus;2048:99xx") == "flash"
+        assert self._resolve(4096, "0:dense;4096:512x512") == "flash"
 
     def test_parity_across_dispatch_outcomes(self):
         """Both outcomes of a bucketed table agree numerically with the
@@ -760,10 +848,11 @@ class TestDispatchTable:
         kernel, the 'dense' bucket via sdpa's XLA path."""
         q, k, v = make_qkv(bh=2, s=256, d=64)
         ref = dense_ref(q, k, v, causal=True)
-        # bucket -> explicit blocks (what '4096:512x512' does at its shape)
-        out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+        # bucket -> the kernel, at the tiling's blocks and at explicit ones
+        for blocks in ({}, {"block_q": 128, "block_k": 128}):
+            out = flash_attention(q, k, v, causal=True, **blocks)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                       atol=2e-5, rtol=2e-5)
         # bucket -> dense: sdpa on CPU takes the dense path; same numbers
         import paddle_tpu as paddle
         import paddle_tpu.nn.functional as F

@@ -216,8 +216,8 @@ class TestDecodeProgramCache:
     def test_eager_only_flags_do_not_invalidate_programs(self):
         """The key snapshots PROGRAM_FLAGS only: changing an eager-only
         flag (log_level) between engines reuses the compiled step, while
-        changing a flag a traced program reads (flash_block_q) keys a
-        distinct one."""
+        changing a flag a traced program reads (flash_compact_stats) keys
+        a distinct one."""
         paddle.seed(96)
         cfg = LlamaConfig.tiny()
         model = LlamaForCausalLM(cfg)
@@ -226,13 +226,13 @@ class TestDecodeProgramCache:
         mk = lambda: ServingEngine(model, max_batch=1, page_size=8,
                                    max_seq_len=32)
         e1 = mk(); e1.submit(p, 2); e1.run()
-        prior = flags.get_flags(["log_level", "flash_block_q"])
+        prior = flags.get_flags(["log_level", "flash_compact_stats"])
         try:
             flags.set_flags({"log_level": 0})
             e2 = mk(); e2.submit(p, 2); e2.run()
             assert e2.decode_key == e1.decode_key
             assert e2._decode_fns[e2.bucket] is e1._decode_fns[e1.bucket]
-            flags.set_flags({"flash_block_q": 256})
+            flags.set_flags({"flash_compact_stats": False})
             e3 = mk(); e3.submit(p, 2); e3.run()
             assert e3.decode_key != e1.decode_key
         finally:

@@ -220,8 +220,6 @@ _PROGRAM_ALTS = {
     "fused_block_layers": (1, 2),
     "use_pallas": (True, False),
     "flash_attn_min_seqlen": (1024, 2048),
-    "flash_block_q": (512, 256),
-    "flash_block_k": (512, 256),
     "flash_compact_stats": (True, False),
     "flash_dispatch_table": ("", "0:flash"),
     "tpu_matmul_precision": ("default", "highest"),

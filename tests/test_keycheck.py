@@ -945,5 +945,5 @@ def test_package_gate_scale_sanity():
     assert "ServingEngine._key" in census["minters"]
     assert census["program_flags"] == \
         sorted(key_vocab.PROGRAM_FLAGS_FALLBACK)
-    assert len(census["program_flags"]) == 13
+    assert len(census["program_flags"]) == 11
     assert census["vocab_source"].endswith("analysis/key_vocab.py")

@@ -5,9 +5,11 @@ sweep at 4,096 and the GQA rows (what ATTN_BENCH_r05.json was made by).
 
 ``python tools/attn_bench.py --kernels bh=256,s=1024,d=64 ...`` — the
 three training kernels ALONE at the given (BH, S, D) shapes (``h``/``hkv``
-for GQA, ``scale`` for a softmax scale other than 1/sqrt(d)): device time
-of ``flash_fwd`` / ``flash_bwd_dq`` / ``flash_bwd_dkv`` a call, read from a
-profiler trace of ten fwd+bwd calls, beside the host's fwd+bwd time. The
+for GQA, ``scale`` for a softmax scale other than 1/sqrt(d), ``bq``/``bk``
+for blocks other than ``flash_tiling``'s): device time of ``flash_fwd`` /
+``flash_bwd_dq`` / ``flash_bwd_dkv`` a call, read from a profiler trace of
+ten fwd+bwd calls, beside the host's fwd+bwd time; a spec whose blocks
+the compiler refuses prints its error and the run goes on. The
 package it times is the first ``paddle_tpu`` on ``sys.path`` (the
 checkout's own unless ``PYTHONPATH`` names another, e.g. an unpacked
 parent commit), so two commits are two runs of this one file.
@@ -26,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels.flash_attention import (flash_attention,
                                                 flash_attention_bshd)
 
@@ -110,17 +113,30 @@ def kernels(specs):
         hkv = int(kv.get("hkv", h))
         scale = float(kv["scale"]) if "scale" in kv else None
         dtype = jnp.dtype(kv.get("dtype", "bfloat16"))
+        # the spec's blocks, else the package's own: ``flash_tiling``'s
+        # (an unpacked parent's package may predate it: shown as None)
+        bq, bk = (int(kv[b]) if b in kv else None for b in ("bq", "bk"))
+        tiling = getattr(fa, "flash_tiling", None)
+        derived = (tiling(s, s, d, dtype.itemsize) if tiling
+                   else (None, None))
         q = jnp.asarray(rng.standard_normal((bh, s, d)), dtype)
         k, v = (jnp.asarray(rng.standard_normal((bh // h * hkv, s, d)), dtype)
                 for _ in range(2))
         fn = functools.partial(flash_attention, causal=True, sm_scale=scale,
-                               n_heads=h, n_kv_heads=hkv)
+                               block_q=bq, block_k=bk, n_heads=h,
+                               n_kv_heads=hkv)
         g = grad_sum(fn)
-        rec = {"spec": spec,
-               "fwd_bwd_host_ms": round(timed(g, q, k, v) * 1e3, 3),
+        rec = {"spec": spec, "block_q": bq or derived[0],
+               "block_k": bk or derived[1],
                "package": os.path.dirname(os.path.dirname(
                    sys.modules[flash_attention.__module__].__file__)),
                "backend": jax.default_backend()}
+        try:
+            rec["fwd_bwd_host_ms"] = round(timed(g, q, k, v) * 1e3, 3)
+        except Exception as e:              # blocks past what VMEM holds
+            rec["error"] = repr(e)[:300]
+            print(json.dumps(rec), flush=True)
+            continue
         rec.update(kernel_ms(g, (q, k, v)))
         print(json.dumps(rec), flush=True)
 
@@ -148,20 +164,18 @@ for s in (1024, 2048, 4096, 8192):
         rec.update(dense_ms=round(td*1e3, 2), speedup=round(td/tf, 2))
     print(json.dumps(rec), flush=True)
 
-# Block-size sweep at the north-star shape (seq 4096): the winner is
-# banked in the artifact; apply it with FLAGS_flash_block_q/_k (the
-# kernel reads the flags when block sizes aren't passed explicitly)
+# Block-size sweep at the north-star shape (seq 4096), against the
+# blocks ``flash_tiling`` derives there (what the main loop's s=4096
+# record ran): a winner that is not those is a finding for the tiling
+# rule (KERNEL_DECISIONS.md "Flash attention tiling")
 s, b, h, d = 4096, 2, 16, 64
 q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
            for _ in range(3))
-# the main loop's s=4096 record used the FLAG-resolved blocks (not
-# necessarily 128/128 if a tuning is already applied) — seed the sweep
-# with it under its TRUE label and skip re-measuring that combo
-from paddle_tpu.flags import get_flag
-seed_bq, seed_bk = int(get_flag("flash_block_q")), int(get_flag("flash_block_k"))
+seed_bq, seed_bk = fa.flash_tiling(s, s, d, q.dtype.itemsize)
 best = (tf_4096, seed_bq, seed_bk) if tf_4096 is not None else None
-for bq, bk in ((128, 128), (128, 256), (256, 128), (256, 256),
-               (128, 512), (512, 128), (512, 512)):
+for bq, bk in ((128, 128), (256, 256), (512, 512), (512, 1024),
+               (1024, 512), (1024, 1024), (1024, 2048), (2048, 1024),
+               (2048, 2048)):
     if best is not None and (bq, bk) == (seed_bq, seed_bk):
         continue
     try:
