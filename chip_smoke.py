@@ -31,6 +31,10 @@ points a user calls, on ONE TPU chip, in this one process:
                 the window layers' pages given back between them and
                 taken again, then decode, every token held against the
                 plain reference
+  moe_train     a small Mellum 2 (three window layers and a full one,
+                heads of 128, a router over 16 experts of which 8 are
+                held) through hapi.TrainStep: steps on a repeated batch,
+                the windowed flash kernels and gmm / tgmm in the step
 
 ``python chip_smoke.py --chips 4`` runs ONLY the Fleet hybrid path
 (dp2 x mp2 TrainStep at Llama-2-7B widths, 2 layers) and its one-chip twin.
@@ -98,6 +102,11 @@ class Sizes:
     trinity_new_tokens: int = 8
     # None: benchmark/configs/trinity-mini.json
     trinity: Optional[dict] = None
+    # MellumConfig widths of the moe_train phase, and its (batch, seq)
+    mellum: Optional[dict] = None
+    # 4,096: FLAGS_flash_dispatch_table sends 2,048 to the dense path
+    # two of the expert layer's 4,096-token chunks
+    mellum_shape: Tuple[int, int] = (2, 4096)
     seed: int = 0
 
 
@@ -108,7 +117,12 @@ FULL = Sizes(
     llama=dict(vocab_size=32000, hidden_size=4096, num_attention_heads=32,
                intermediate_size=11008, max_position_embeddings=4096),
     fused_layers=4, fused_prompt_lens=(32, 48, 64, 96), fused_new_tokens=16,
-    hybrid_layers=2, hybrid_shape=(4, 1024), hybrid_steps=3)
+    hybrid_layers=2, hybrid_shape=(4, 1024), hybrid_steps=3,
+    mellum=dict(vocab_size=2048, hidden_size=512, moe_intermediate_size=256,
+                num_hidden_layers=4, num_attention_heads=4,
+                num_key_value_heads=2, head_dim=128, num_experts=8,
+                router_experts=16, first_expert=8, num_experts_per_tok=4,
+                sliding_window=512))
 
 
 # what JAX's persistent compilation cache did since the last phase line:
@@ -746,6 +760,61 @@ def phase_trinity_window(s: Sizes) -> dict:
         serve_s=round(serve_s, 2))
 
 
+# ------------------------------------------------------------ moe_train
+def phase_moe_train(s: Sizes) -> dict:
+    """The expert layer's and the window's training path on the chip: a
+    small Mellum 2 through ``hapi.TrainStep`` (AdamW, O1 autocast) on a
+    repeated batch; the loss must fall, the step must not retrace, and on
+    the chip its program must hold the windowed flash kernels and the
+    grouped products forward and backward (``gmm``, ``tgmm``), so that a
+    kernel that no longer lowers is caught before the benchmark."""
+    import paddle_tpu as paddle
+    from paddle_tpu.hapi import TrainStep
+    from paddle_tpu.models import MellumConfig, MellumForCausalLM
+
+    cfg = MellumConfig(**s.mellum)
+    paddle.seed(s.seed + 7)
+    model = MellumForCausalLM(cfg)
+    if s.on_chip:
+        model.to(dtype="bfloat16")
+    opt = paddle.optimizer.AdamW(1e-3, parameters=model.parameters(),
+                                 multi_precision=s.on_chip)
+    step = TrainStep(model, opt)
+    batch, seq = s.mellum_shape
+    rng = np.random.default_rng(s.seed + 7)
+    ids = rng.integers(0, cfg.vocab_size, (batch, seq + 1)).astype(np.int32)
+    staged = step.stage(ids[:, :-1], ids[:, 1:])
+    losses = []
+    for _ in range(s.train_steps):
+        with _amp(s.on_chip):
+            losses.append(float(step(staged)))
+    assert all(np.isfinite(losses)), losses
+    assert losses[-1] < losses[0], f"loss did not fall: {losses}"
+    assert step.trace_count == 1, step.trace_count
+    with _amp(s.on_chip):
+        text = step.lower(staged).compile().as_text()
+    called = [ln.split(" = ", 1)[0] for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    kernels = {k: any(k in c for c in called)
+               for k in ("flash_fwd_window", "flash_bwd_dq_window",
+                         "flash_bwd_dkv_window", "gmm", "tgmm")}
+    if s.on_chip:
+        assert all(kernels.values()), kernels
+    counts = step.counters()
+    assert int(counts["moe_assignments"]) \
+        == int(counts["moe_expert_hist"].sum()) > 0
+    return _emit(
+        "moe_train", layer_types=cfg.layer_types, window=cfg.sliding_window,
+        experts_held=[cfg.first_expert, cfg.num_experts],
+        router=cfg.router_experts, batch=batch, seq=seq,
+        losses=[round(v, 4) for v in losses], traces=step.trace_count,
+        kernels=kernels,
+        moe_assignments=int(counts["moe_assignments"]),
+        expert_load_max_over_mean=round(float(
+            counts["moe_expert_hist"].max()
+            / counts["moe_expert_hist"].mean()), 3))
+
+
 # ------------------------------------------------------------------ main
 def run_phases(s: Sizes, chips: int = 1) -> list:
     """Every phase of the ``chips`` mode at sizes ``s``; raises on the
@@ -772,6 +841,9 @@ def run_phases(s: Sizes, chips: int = 1) -> list:
     clear_decode_program_cache()
     gc.collect()
     lines.append(phase_trinity_window(s))
+    clear_decode_program_cache()
+    gc.collect()
+    lines.append(phase_moe_train(s))
     return lines
 
 

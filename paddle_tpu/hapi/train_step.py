@@ -300,6 +300,23 @@ class TrainStep:
                     k: jax.device_put(v, self.opt_shardings[k])
                     for k, v in self.opt_state["master"].items()}
 
+        # device-side counters a model adds up over the steps
+        # (``train_counters()``: name -> zeros; its forward then takes
+        # ``return_counters=True`` and returns ``(loss, this step's)``),
+        # carried through the step ({} for a model that keeps none) and
+        # read with ``counters()``; and what the model counts from the
+        # batch's shape alone (``attention_pairs(batch, seq)``), counted
+        # on the host a call, into the registry
+        counted = hasattr(model, "train_counters")
+        self._counters = model.train_counters() if counted else {}
+        if counted and (self.grad_accum_steps > 1 or self.localsgd_k > 1
+                        or self.gradient_merge_k > 1):
+            raise NotImplementedError(
+                "train counters with gradient accumulation, merge or "
+                "localsgd")
+        self._attention_pairs = getattr(model, "attention_pairs", None)
+        extra = {"return_counters": True} if counted else {}
+
         def model_loss(p, batch):
             if self.loss_fn is not None:
                 from ..core import autograd
@@ -312,7 +329,8 @@ class TrainStep:
                                         tree_to_tensors(batch[-1]))
                 return tree_to_values(loss)
             # default: the model returns the scalar loss itself
-            return functional_call(model, p, *batch, buffers=self.buffers)
+            return functional_call(model, p, *batch, buffers=self.buffers,
+                                   **extra)
 
         def loss_of(p, batch):
             if mesh is None:
@@ -381,6 +399,9 @@ class TrainStep:
                     loss = loss_sum / self.grad_accum_steps
                     grads = jax.tree.map(
                         lambda g: g / self.grad_accum_steps, grads)
+            elif counted:
+                (loss, counts), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(params, batch)
             else:
                 loss, grads = jax.value_and_grad(loss_of)(params, batch)
             if self.sharding_level >= 2:
@@ -390,7 +411,7 @@ class TrainStep:
                     k: jax.lax.with_sharding_constraint(
                         g, self.opt_shardings[k])
                     for k, g in grads.items()}
-            return loss, grads
+            return loss, grads, (counts if counted else {})
 
         def apply_update(params, opt_state, grads, lr):
             new_params, new_state = optimizer.functional_update(
@@ -414,15 +435,16 @@ class TrainStep:
                         for k, v in new_state["master"].items()}
             return new_params, new_state
 
-        def train_step(params, opt_state, lr, *batch):
+        def train_step(params, opt_state, counters, lr, *batch):
             self._trace_count += 1   # python body runs only while tracing
-            loss, grads = compute_loss_grads(params, batch)
+            loss, grads, counts = compute_loss_grads(params, batch)
             new_params, new_state = apply_update(params, opt_state, grads, lr)
-            return loss, new_params, new_state
+            counters = {k: v + counts[k] for k, v in counters.items()}
+            return loss, new_params, new_state, counters
 
         def train_step_merge(params, opt_state, merge, lr, *batch):
             self._trace_count += 1
-            loss, grads = compute_loss_grads(params, batch)
+            loss, grads, _ = compute_loss_grads(params, batch)
             buf, count = merge
             buf = jax.tree.map(jnp.add, buf, grads)
             count = count + 1
@@ -445,8 +467,8 @@ class TrainStep:
             self._jit_step = jax.jit(train_step_merge,
                                      donate_argnums=donate_argnums)
         else:
-            self._jit_step = jax.jit(
-                train_step, donate_argnums=(0, 1) if donate else ())
+            self._jit_step = jax.jit(train_step,
+                                     donate_argnums=donate_argnums)
         self._step_count = 0
 
     def _build_localsgd_step(self, loss_of, donate):
@@ -581,8 +603,11 @@ class TrainStep:
                     self._jit_step(self.params, self.opt_state,
                                    self._merge, lr, *vals)
             else:
-                loss, self.params, self.opt_state = self._jit_step(
-                    self.params, self.opt_state, lr, *vals)
+                loss, self.params, self.opt_state, self._counters = \
+                    self._jit_step(self.params, self.opt_state,
+                                   self._counters, lr, *vals)
+        if self._attention_pairs is not None:
+            self._observe_pairs(vals[0].shape)
         if isinstance(self.optimizer._lr, LRScheduler):
             self.optimizer._lr.step()
         self._step_count += 1
@@ -615,6 +640,22 @@ class TrainStep:
         # actually still outstanding, not the pre-drain peak
         self._observe_dispatch(vals)
         return Tensor(loss, stop_gradient=True)
+
+    def counters(self) -> Dict[str, np.ndarray]:
+        """The model's device-side counters (``train_counters``) summed
+        over every step so far, pulled to the host (a sync); {} for a
+        model that keeps none."""
+        return {k: np.asarray(v) for k, v in self._counters.items()}
+
+    def _observe_pairs(self, shape) -> None:
+        """Add this call's attention pairs (the model's
+        ``attention_pairs`` of the batch's (batch, seq)) to the
+        registry's ``train_attn_pairs_<kind>`` counters."""
+        for kind, n in self._attention_pairs(*shape[:2]).items():
+            obs.registry().counter(
+                f"train_attn_pairs_{kind}",
+                f"query-key pairs the {kind} attention layers must "
+                f"compute, over the steps called").inc(n)
 
     def _observe_step_clock(self, reopen: bool = True) -> None:
         """A call begins (or ``sync()`` ends the loop's run of steps):
@@ -666,7 +707,7 @@ class TrainStep:
             args = (self.params, self.opt_state, self._merge, lr, *vals)
             extra = ("gradient_merge",)
         else:
-            args = (self.params, self.opt_state, lr, *vals)
+            args = (self.params, self.opt_state, self._counters, lr, *vals)
             extra = ()
         # model label = signature prefix, like the serving path: two
         # differently-sized models of one class must not collide in the
@@ -793,7 +834,8 @@ class TrainStep:
         else:
             vals = tuple(tree_to_values(b) for b in batch)
         lr = jnp.asarray(0.0, jnp.float32)
-        return self._jit_step.lower(self.params, self.opt_state, lr, *vals)
+        return self._jit_step.lower(self.params, self.opt_state,
+                                    self._counters, lr, *vals)
 
     def compile_stats(self, *batch):
         compiled = self.lower(*batch).compile()

@@ -171,6 +171,65 @@ def _blocks(block_q, block_k, q, k):
             tk if block_k is None else block_k)
 
 
+# ==================================================================== band
+# Under a window (key ``j`` seen by query ``i`` iff ``i - W < j <= i``) a
+# query block sees only the key blocks that overlap its band, and a key
+# block is seen only by the query blocks whose band reaches it. The grid's
+# inner axis runs over those alone: its step ``j`` is the band's ``j``-th
+# block, counted from the first one the outer block sees, and a step past
+# the last (the band is narrower at the sequence's start) re-reads the last
+# block (no new copy) with its work predicated off.
+def _kv_first(i, bq, bk, window):
+    """First key block query block ``i`` sees (a Python int or a traced
+    scalar; no negative operand reaches the division)."""
+    return jnp.maximum(i * bq - window + 1, 0) // bk
+
+
+def _kv_last(i, bq, bk):
+    return (i * bq + bq - 1) // bk
+
+
+def _q_first(j, bq, bk):
+    """First query block that sees key block ``j``."""
+    return (j * bk) // bq
+
+
+def _q_last(j, bq, bk, window, n_q):
+    return jnp.minimum((j * bk + bk + window - 2) // bq, n_q - 1)
+
+
+def band_steps(s: int, bq: int, bk: int, window: int) -> Tuple[int, int]:
+    """``(key steps a query block, query steps a key block)``: the inner
+    extents of the windowed grids, the widest band over the sequence."""
+    n_q, n_k = s // bq, s // bk     # Python ints: these size the grid
+    kv = max((i * bq + bq - 1) // bk - max(i * bq - window + 1, 0) // bk + 1
+             for i in range(n_q))
+    q = max(min((j * bk + bk + window - 2) // bq, n_q - 1) - (j * bk) // bq
+            + 1 for j in range(n_k))
+    return kv, q
+
+
+def _kv_block(i, j, bq, bk, window):
+    """The key block step ``j`` of query block ``i`` reads, clamped to the
+    band's last; and whether the step computes."""
+    first = _kv_first(i, bq, bk, window)
+    last = _kv_last(i, bq, bk)
+    return jnp.minimum(first + j, last), first + j <= last
+
+
+def _q_block(j, i, bq, bk, window, n_q):
+    """The query block step ``i`` of key block ``j`` reads, clamped; and
+    whether the step computes."""
+    first = _q_first(j, bq, bk)
+    last = _q_last(j, bq, bk, window, n_q)
+    return jnp.minimum(first + i, last), first + i <= last
+
+
+def _named(name: str, window) -> str:
+    """A windowed call's name in a device trace is its own."""
+    return name if window is None else name + "_window"
+
+
 def _rep(x):
     """(BH, S) -> (BH, S, 128) lane-replicated: Mosaic needs the last two
     block dims (8, 128)-aligned, and a trailing singleton would PAD to 128
@@ -245,23 +304,28 @@ def _beside(x, stored):
 
 # ============================================================ forward kernel
 def _masked_scores(q, k, seg_q, seg_kv, q_blk, kv_blk, causal, sm_scale,
-                   transposed=False):
+                   transposed=False, window=None):
     """Scaled score block with causal + segment masking — the shared core
     of all four kernels: the ``(bq, d)`` and ``(bk, d)`` blocks as
     stored, ``sm_scale`` on the float32 product, which rounds no operand.
     ``(bq, bk)``, or ``(bk, bq)`` with ``transposed`` (``k . q^T``: what
     dk/dv's products take on the left with nothing to transpose). ``seg_q`` / ``seg_kv``: the segment ids
     laid along their own axis of the block — ``(bq, 1)`` and ``(1, bk)``,
-    or ``(1, bq)`` and ``(bk, 1)`` transposed — or None."""
+    or ``(1, bq)`` and ``(bk, 1)`` transposed — or None. ``window``: a
+    causal band, key ``j`` seen by query ``i`` iff ``i - window < j <= i``
+    (``causal`` is then implied)."""
     block_q, block_k = q.shape[0], k.shape[0]
     s = (_dot(k, q, _NT) if transposed else _dot(q, k, _NT)) * sm_scale
-    if causal:
+    if causal or window is not None:
         q_axis = 1 if transposed else 0
         q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, q_axis)
         kv_pos = kv_blk * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1 - q_axis)
-        s = jnp.where(q_pos >= kv_pos, s, _NEG_INF)
+        seen = q_pos >= kv_pos
+        if window is not None:
+            seen &= q_pos - kv_pos < window
+        s = jnp.where(seen, s, _NEG_INF)
     if seg_q is not None:
         s = jnp.where(seg_q == seg_kv, s, _NEG_INF)
     return s
@@ -303,7 +367,8 @@ def _softmax_update(s, m_prev, l_prev):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
-                acc_ref, m_ref, l_ref, *, causal: bool, sm_scale: float):
+                acc_ref, m_ref, l_ref, *, causal: bool, sm_scale: float,
+                window=None):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_q, _ = _dims(q_ref.shape)
@@ -315,19 +380,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
         m_ref[0] = jnp.full_like(m_ref[0], _NEG_INF)
         l_ref[0] = jnp.zeros_like(l_ref[0])
 
-    if causal:
+    if window is not None:
+        # the band's step: kj counts from the first block this row sees
+        kv_blk, run = _kv_block(qi, kj, block_q, block_k, window)
+    elif causal:
         # skip kv blocks strictly above the causal diagonal
-        run = kj * block_k <= qi * block_q + block_q - 1
+        kv_blk, run = kj, kj * block_k <= qi * block_q + block_q - 1
     else:
-        run = True
+        kv_blk, run = kj, True
 
     @pl.when(run)
     def _step():
         seg_q = seg_kv = None
         if seg_q_ref is not None:
             seg_q, seg_kv = seg_q_ref[0][:, :1], seg_kv_ref[0]
-        s = _masked_scores(q_ref[0], k_ref[0], seg_q, seg_kv, qi, kj,
-                           causal, sm_scale)
+        s = _masked_scores(q_ref[0], k_ref[0], seg_q, seg_kv, qi, kv_blk,
+                           causal, sm_scale, window=window)
         m_ref[0], l_ref[0], p, alpha = _softmax_update(s, m_ref[0], l_ref[0])
         v = v_ref[0]
         acc_ref[0] = (_lanes(alpha, acc_ref.shape[2]) * acc_ref[0]
@@ -336,7 +404,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
 
 def _fwd_kernel_compact(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
                         out_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                        causal: bool, sm_scale: float, n_k: int):
+                        causal: bool, sm_scale: float, n_k: int,
+                        window=None):
     """Compact-stat forward: acc/m/l live in VMEM scratch across the
     sequential kv sweep (same structure as decode_attention._prefill_kernel);
     the normalized output and the compact (1, block_q) lse row are emitted
@@ -353,7 +422,14 @@ def _fwd_kernel_compact(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    run = (kj * block_k <= qi * block_q + block_q - 1) if causal else True
+    if window is not None:
+        first = _kv_first(qi, block_q, block_k, window)
+        last = _kv_last(qi, block_q, block_k)
+        kv_blk, run = first + kj, first + kj <= last
+    else:
+        kv_blk = kj
+        run = ((kj * block_k <= qi * block_q + block_q - 1) if causal
+               else True)
 
     @pl.when(run)
     def _step():
@@ -363,15 +439,17 @@ def _fwd_kernel_compact(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
         seg_q = seg_kv = None
         if seg_q_ref is not None:
             seg_q, seg_kv = jnp.transpose(seg_q_ref[0]), seg_kv_ref[0]
-        s = _masked_scores(q_ref[0], k_ref[0], seg_q, seg_kv, qi, kj,
-                           causal, sm_scale)
+        s = _masked_scores(q_ref[0], k_ref[0], seg_q, seg_kv, qi, kv_blk,
+                           causal, sm_scale, window=window)
         m_ref[...], l_ref[...], p, alpha = _softmax_update(
             s, m_ref[...], l_ref[...])
         v = v_ref[0]
         acc_ref[...] = (_lanes(alpha, d) * acc_ref[...]
                         + _dot(_beside(p, v), v, _NN))
 
-    if causal:
+    if window is not None:
+        final_kj = last - first         # the band's last step of this row
+    elif causal:
         final_kj = jnp.minimum((qi * block_q + block_q - 1) // block_k,
                                n_k - 1)
     else:
@@ -386,11 +464,12 @@ def _fwd_kernel_compact(q_ref, k_ref, v_ref, seg_q_ref, seg_kv_ref,
         lse_ref[0] = jnp.transpose(m + jnp.log(l_safe))      # (1, bq)
 
 
-def _fwd_setup(q, k, block_q, block_k, h, hkv):
+def _fwd_setup(q, k, block_q, block_k, h, hkv, window=None):
     """Shared fwd-path setup for both stat layouts: block clamping, the
     divisibility contract (NotImplementedError so the sdpa dispatch can
     fall back to dense), grid, and the GQA kv index map reading the
-    UNEXPANDED kv at Hkv bandwidth."""
+    UNEXPANDED kv at Hkv bandwidth. ``window``: the grid's kv axis runs
+    over the band's blocks alone (``band_steps``)."""
     bh, sq, d = q.shape
     skv = k.shape[1]
     block_q = _snap(block_q, sq)
@@ -403,13 +482,24 @@ def _fwd_setup(q, k, block_q, block_k, h, hkv):
     n_k = skv // block_k
     grid = (bh, sq // block_q, n_k)
     rep = h // hkv
+    if window is not None:
+        if sq != skv:
+            raise NotImplementedError(
+                "a windowed flash call needs as many keys as queries")
+        grid = (bh, sq // block_q,
+                band_steps(sq, block_q, block_k, window)[0])
+
+    def kv_at(i, j):
+        if window is None:
+            return j
+        return _kv_block(i, j, block_q, block_k, window)[0]
 
     def kv_index(b, i, j):
         # GQA: query head -> its kv head (identity when hkv == h)
-        return ((b // h) * hkv + (b % h) // rep, j, 0)
+        return ((b // h) * hkv + (b % h) // rep, kv_at(i, j), 0)
 
     def kv_seg_index(b, i, j):
-        return ((b // h) * hkv + (b % h) // rep, 0, j)
+        return ((b // h) * hkv + (b % h) // rep, 0, kv_at(i, j))
 
     qkv_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -421,12 +511,13 @@ def _fwd_setup(q, k, block_q, block_k, h, hkv):
 
 
 def _fwd_compact(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
-                 block_k, h, hkv):
+                 block_k, h, hkv, window=None):
     if pltpu is None:
         raise NotImplementedError(
             "FLAGS_flash_compact_stats needs pallas TPU scratch support")
     (bh, sq, d, block_q, block_k, n_k, grid, qkv_specs,
-     kv_seg_index) = _fwd_setup(q, k, block_q, block_k, h, hkv)
+     kv_seg_index) = _fwd_setup(q, k, block_q, block_k, h, hkv, window)
+    win = {} if window is None else {"window": window}
 
     in_specs = list(qkv_specs)
     args = [q, k, v]
@@ -437,12 +528,12 @@ def _fwd_compact(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
         ]
         args += [seg_q[:, None, :], seg_kv[:, None, :]]
         kernel = functools.partial(_fwd_kernel_compact, causal=causal,
-                                   sm_scale=sm_scale, n_k=n_k)
+                                   sm_scale=sm_scale, n_k=n_k, **win)
     else:
         kernel = functools.partial(
             lambda qr, kr, vr, o, ls, a, m, l, **kw: _fwd_kernel_compact(
                 qr, kr, vr, None, None, o, ls, a, m, l, **kw),
-            causal=causal, sm_scale=sm_scale, n_k=n_k)
+            causal=causal, sm_scale=sm_scale, n_k=n_k, **win)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -462,19 +553,22 @@ def _fwd_compact(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=_interpret(),
-        name="flash_fwd",
+        name=_named("flash_fwd", window),
         **_compiler_params(block_q, block_k, d, q.dtype.itemsize),
     )(*args)
     return out, lse[:, 0, :]
 
 
 def _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k,
-         h=1, hkv=1, compact=False):
+         h=1, hkv=1, compact=False, window=None):
+    if window is not None and seg_q is not None:
+        raise NotImplementedError("a window with segment ids")
     if compact:
         return _fwd_compact(q, k, v, seg_q, seg_kv, causal, sm_scale,
-                            block_q, block_k, h, hkv)
+                            block_q, block_k, h, hkv, window)
     (bh, sq, d, block_q, block_k, n_k, grid, qkv_specs,
-     kv_seg_index) = _fwd_setup(q, k, block_q, block_k, h, hkv)
+     kv_seg_index) = _fwd_setup(q, k, block_q, block_k, h, hkv, window)
+    win = {} if window is None else {"window": window}
 
     in_specs = list(qkv_specs)
     args = [q, k, v]
@@ -487,12 +581,12 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k,
         ]
         args += [_rep(seg_q), seg_kv[:, None, :]]
         kernel = functools.partial(_fwd_kernel, causal=causal,
-                                   sm_scale=sm_scale)
+                                   sm_scale=sm_scale, **win)
     else:
         kernel = functools.partial(
             lambda qr, kr, vr, a, m, l, **kw: _fwd_kernel(
                 qr, kr, vr, None, None, a, m, l, **kw),
-            causal=causal, sm_scale=sm_scale)
+            causal=causal, sm_scale=sm_scale, **win)
 
     # accumulators are revisited output blocks: index maps ignore the kv
     # grid dim, so the block stays VMEM-resident across the kv sweep
@@ -511,7 +605,7 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k,
             _sds((bh, sq, _LANES), jnp.float32, q),
         ],
         interpret=_interpret(),
-        name="flash_fwd_stats",
+        name=_named("flash_fwd_stats", window),
         **_compiler_params(block_q, block_k, d, q.dtype.itemsize),
     )(*args)
 
@@ -540,7 +634,7 @@ def _col(ref, compact):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    seg_q_ref, seg_kv_ref, dq_ref, *, causal, sm_scale,
-                   compact=False):
+                   compact=False, window=None):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_q, d = _dims(q_ref.shape)
@@ -550,7 +644,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
-    run = (kj * block_k <= qi * block_q + block_q - 1) if causal else True
+    if window is not None:
+        kv_blk, run = _kv_block(qi, kj, block_q, block_k, window)
+    else:
+        kv_blk = kj
+        run = ((kj * block_k <= qi * block_q + block_q - 1) if causal
+               else True)
 
     @pl.when(run)
     def _step():
@@ -560,8 +659,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         seg_q = seg_kv = None
         if seg_q_ref is not None:
             seg_q, seg_kv = _col(seg_q_ref, compact), seg_kv_ref[0]
-        s = _masked_scores(q_ref[0], k, seg_q, seg_kv, qi, kj, causal,
-                           sm_scale)
+        s = _masked_scores(q_ref[0], k, seg_q, seg_kv, qi, kv_blk, causal,
+                           sm_scale, window=window)
         p = jnp.exp(s - lse)                                 # (bq, bk)
         dp = _dot(do_ref[0], v_ref[0], _NT)
         ds = p * (dp - delta)
@@ -570,7 +669,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     seg_q_ref, seg_kv_ref, dk_ref, dv_ref, *, causal,
-                    sm_scale):
+                    sm_scale, window=None, n_q=0):
     # grid: (b_kv, ki, rep, qj) — dk/dv blocks are revisited across the
     # (rep, qj) sweep (GQA: every query head in the group accumulates
     # into its kv head's gradient). Everything here is TRANSPOSED,
@@ -589,8 +688,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    # causal: q blocks whose END is before this kv block's start never see it
-    run = (qj * block_q + block_q - 1 >= ki * block_k) if causal else True
+    if window is not None:
+        # the band's step: qj counts from the first q block seeing ki
+        q_blk, run = _q_block(ki, qj, block_q, block_k, window, n_q)
+    else:
+        # causal: q blocks whose END is before this kv block's start
+        # never see it
+        q_blk = qj
+        run = ((qj * block_q + block_q - 1 >= ki * block_k) if causal
+               else True)
 
     @pl.when(run)
     def _step():
@@ -598,8 +704,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         seg_q = seg_kv = None
         if seg_q_ref is not None:
             seg_q, seg_kv = seg_q_ref[0], jnp.transpose(seg_kv_ref[0])
-        st = _masked_scores(q, k_ref[0], seg_q, seg_kv, qj, ki, causal,
-                            sm_scale, transposed=True)
+        st = _masked_scores(q, k_ref[0], seg_q, seg_kv, q_blk, ki, causal,
+                            sm_scale, transposed=True, window=window)
         pt = jnp.exp(st - lse_ref[0])                        # (bk, bq)
         dv_ref[0] = dv_ref[0] + _dot(_beside(pt, do), do, _NN)
         dpt = _dot(v_ref[0], do, _NT)
@@ -608,34 +714,53 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = dk_ref[0] + _dot(_beside(dst, q), q, _NN)
 
 
-def _bwd(causal, sm_scale, block_q, block_k, h, hkv, compact, res, g):
+def _bwd(causal, sm_scale, block_q, block_k, h, hkv, compact, window, res,
+         g):
     do = g[0] if isinstance(g, (tuple, list)) else g
     return _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact,
-                     res, do, None)
+                     res, do, None, window)
 
 
 def _bwd_with_lse(causal, sm_scale, block_q, block_k, h, hkv, compact,
-                  res, g):
+                  window, res, g):
     do, dlse = g
     dq, dk, dv, _, _ = _bwd_impl(causal, sm_scale, block_q, block_k, h,
-                                 hkv, compact, res, do, dlse)
+                                 hkv, compact, res, do, dlse, window)
     return dq, dk, dv, None, None
 
 
 def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
-              do, dlse):
+              do, dlse, window=None):
     q, k, v, seg_q, seg_kv, out, lse = res
     rep = h // hkv
-
-    def kv_index(b, i, j):
-        return ((b // h) * hkv + (b % h) // rep, j, 0)
     bh, sq, d = q.shape
     skv = k.shape[1]
     # same snap as the forward (whose guard already rejected impossible
     # shapes) so fwd and bwd tile identically
     bq = _snap(block_q, sq)
     bk = _snap(block_k, skv)
+    n_q, n_kv = pl.cdiv(sq, bq), pl.cdiv(skv, bk)
     call_kw = _compiler_params(bq, bk, d, q.dtype.itemsize)
+    win = {} if window is None else {"window": window}
+    if window is not None:
+        # the two grids' inner axes run over the band alone
+        n_kv, n_q_inner = band_steps(sq, bq, bk, window)
+    else:
+        n_q_inner = n_q
+
+    def kv_at(i, j):
+        if window is None:
+            return j
+        return _kv_block(i, j, bq, bk, window)[0]
+
+    def q_at(i, j):
+        # dkv's grid: step j of kv block i
+        if window is None:
+            return j
+        return _q_block(i, j, bq, bk, window, n_q)[0]
+
+    def kv_index(b, i, j):
+        return ((b // h) * hkv + (b % h) // rep, kv_at(i, j), 0)
 
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1)                               # (bh, sq)
@@ -678,22 +803,23 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
             stat_spec_dq,
             pl.BlockSpec((1, 1, bk),
                          lambda b, i, j: ((b // h) * hkv + (b % h) // rep,
-                                          0, j))]
+                                          0, kv_at(i, j)))]
         dq_kernel = functools.partial(_bwd_dq_kernel, causal=causal,
-                                      sm_scale=sm_scale, compact=compact)
+                                      sm_scale=sm_scale, compact=compact,
+                                      **win)
     else:
         dq_kernel = functools.partial(
             lambda qr, kr, vr, dor, lr, der, dqr, **kw: _bwd_dq_kernel(
                 qr, kr, vr, dor, lr, der, None, None, dqr, **kw),
-            causal=causal, sm_scale=sm_scale, compact=compact)
+            causal=causal, sm_scale=sm_scale, compact=compact, **win)
 
     dq = pl.pallas_call(
-        dq_kernel, grid=(bh, pl.cdiv(sq, bq), pl.cdiv(skv, bk)),
+        dq_kernel, grid=(bh, n_q, n_kv),
         in_specs=in_specs_dq,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=_sds((bh, sq, d), jnp.float32, q),
         interpret=_interpret(),
-        name="flash_bwd_dq",
+        name=_named("flash_bwd_dq", window),
         **call_kw,
     )(q, k, v, do, *stats_dq)
     dq = (dq * sm_scale).astype(q.dtype)
@@ -702,10 +828,11 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
     # revisited across BOTH trailing dims; every query head of the GQA
     # group accumulates into its kv head's gradient
     def q_index(b, i, r, j):
-        return ((b // hkv) * h + (b % hkv) * rep + r, j, 0)
+        return ((b // hkv) * h + (b % hkv) * rep + r, q_at(i, j), 0)
 
     stat_spec_dkv = pl.BlockSpec(
-        (1, 1, bq), lambda b, i, r, j: (q_index(b, i, r, j)[0], 0, j))
+        (1, 1, bq), lambda b, i, r, j: (q_index(b, i, r, j)[0], 0,
+                                        q_at(i, j)))
 
     in_specs_dkv = [
         pl.BlockSpec((1, bq, d), q_index),                     # q
@@ -720,16 +847,18 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
             stat_spec_dkv,
             pl.BlockSpec((1, 1, bk), lambda b, i, r, j: (b, 0, i))]
         dkv_kernel = functools.partial(_bwd_dkv_kernel, causal=causal,
-                                       sm_scale=sm_scale)
+                                       sm_scale=sm_scale, **win)
     else:
         dkv_kernel = functools.partial(
             lambda qr, kr, vr, dor, lr, der, dkr, dvr, **kw: _bwd_dkv_kernel(
                 qr, kr, vr, dor, lr, der, None, None, dkr, dvr, **kw),
-            causal=causal, sm_scale=sm_scale)
+            causal=causal, sm_scale=sm_scale, **win)
+    if window is not None:
+        dkv_kernel = functools.partial(dkv_kernel, n_q=n_q)
 
     bh_kv = k.shape[0]
     dk, dv = pl.pallas_call(
-        dkv_kernel, grid=(bh_kv, pl.cdiv(skv, bk), rep, pl.cdiv(sq, bq)),
+        dkv_kernel, grid=(bh_kv, pl.cdiv(skv, bk), rep, n_q_inner),
         in_specs=in_specs_dkv,
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, i, r, j: (b, i, 0)),
@@ -737,7 +866,7 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
         out_shape=[_sds((bh_kv, skv, d), jnp.float32, q),
                    _sds((bh_kv, skv, d), jnp.float32, q)],
         interpret=_interpret(),
-        name="flash_bwd_dkv",
+        name=_named("flash_bwd_dkv", window),
         **call_kw,
     )(q, k, v, do, *rows)
     dk = (dk * sm_scale).astype(k.dtype)
@@ -749,35 +878,37 @@ def _bwd_impl(causal, sm_scale, block_q, block_k, h, hkv, compact, res,
 # inside _fwd/_bwd: jax caches custom_vjp traces process-wide keyed on the
 # static args, so a trace-time flag read would make whichever layout
 # traced first sticky for every later call with the same shapes.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_attention(q, k, v, seg_q, seg_kv, causal, sm_scale,
-                     block_q, block_k, h, hkv, compact):
+                     block_q, block_k, h, hkv, compact, window=None):
     out, _ = _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
-                  block_k, h, hkv, compact)
+                  block_k, h, hkv, compact, window)
     return out
 
 
 def _flash_fwd_rule(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
-                    block_k, h, hkv, compact):
+                    block_k, h, hkv, compact, window=None):
     out, lse = _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
-                    block_k, h, hkv, compact)
+                    block_k, h, hkv, compact, window)
     return out, (q, k, v, seg_q, seg_kv, out, lse)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_attention_lse(q, k, v, seg_q, seg_kv, causal, sm_scale,
-                         block_q, block_k, h, hkv, compact):
+                         block_q, block_k, h, hkv, compact, window=None):
     return _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
-                block_k, h, hkv, compact)
+                block_k, h, hkv, compact, window)
 
 
 def _flash_lse_fwd_rule(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
-                        block_k, h, hkv, compact):
+                        block_k, h, hkv, compact, window=None):
     out, lse = _fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
-                    block_k, h, hkv, compact)
+                    block_k, h, hkv, compact, window)
     return (out, lse), (q, k, v, seg_q, seg_kv, out, lse)
 
 
@@ -788,7 +919,8 @@ def flash_attention_ref(q, k, v, segment_ids=None, kv_segment_ids=None,
                         causal: bool = True,
                         sm_scale: Optional[float] = None,
                         n_heads: int = 1,
-                        n_kv_heads: Optional[int] = None):
+                        n_kv_heads: Optional[int] = None,
+                        window: Optional[int] = None):
     """Pure-jnp dense twin of :func:`flash_attention` — the parity
     oracle. Same (BH, S, D) layout and GQA convention (query heads of
     one group are consecutive rows per kv head); matches the kernels'
@@ -805,10 +937,13 @@ def flash_attention_ref(q, k, v, segment_ids=None, kv_segment_ids=None,
     kf = k.reshape(b, hkv, skv, d).astype(jnp.float32)
     vf = v.reshape(b, hkv, skv, d).astype(jnp.float32)
     s = jnp.einsum("bgrqd,bgkd->bgrqk", qf, kf)
-    if causal:
+    if causal or window is not None:
         q_pos = jnp.arange(sq)[:, None]
         kv_pos = jnp.arange(skv)[None, :]
-        s = jnp.where(kv_pos <= q_pos, s, _NEG_INF)
+        seen = kv_pos <= q_pos
+        if window is not None:
+            seen &= q_pos - kv_pos < window
+        s = jnp.where(seen, s, _NEG_INF)
     if segment_ids is not None:
         kv_ids = (segment_ids if kv_segment_ids is None
                   else kv_segment_ids)
@@ -851,7 +986,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
             f"counts for GQA inputs")
     return _flash_attention_lse(q, k, v, None, None, causal, sm_scale,
                                 block_q, block_k, n_heads, n_kv_heads,
-                                _compact(snap))
+                                _compact(snap), None)
 
 
 def flash_attention(q, k, v, segment_ids: Optional[jax.Array] = None,
@@ -860,9 +995,12 @@ def flash_attention(q, k, v, segment_ids: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     n_heads: int = 1, n_kv_heads: Optional[int] = None,
-                    snap=None):
+                    snap=None, window: Optional[int] = None):
     """(BH, S, D)-layout flash attention. segment_ids: (BH, S) int32 — rows
     attend only within their segment (varlen batches packed statically).
+    ``window``: a causal band, key ``j`` seen by query ``i`` iff
+    ``i - window < j <= i``; the grids run over the band's blocks alone
+    (None: the plain causal or full kernels, unchanged).
     GQA: pass q as (B*n_heads, S, D) and k/v as (B*n_kv_heads, Skv, D) —
     the kernels read the UNEXPANDED kv via index maps (Hkv bandwidth) and
     accumulate dk/dv over each group's query heads. ``block_q`` /
@@ -892,9 +1030,17 @@ def flash_attention(q, k, v, segment_ids: Optional[jax.Array] = None,
                 "kv_segment_ids (the q-side ids have a different leading "
                 "dim)")
         kv_segment_ids = segment_ids
+    if window is not None:
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"window must be positive, got {window}")
+        if window >= q.shape[1]:
+            window = None               # the band holds the whole triangle
+        else:
+            causal = True
     return _flash_attention(q, k, v, segment_ids, kv_segment_ids,
                             causal, sm_scale, block_q, block_k,
-                            n_heads, n_kv_heads, _compact(snap))
+                            n_heads, n_kv_heads, _compact(snap), window)
 
 
 class FlashPartitionError(ValueError):
@@ -971,14 +1117,15 @@ def flash_attention_bshd(q, k, v, segment_ids=None, kv_segment_ids=None,
                          sm_scale: Optional[float] = None,
                          block_q: Optional[int] = None,
                          block_k: Optional[int] = None,
-                         snap=None):
+                         snap=None, window: Optional[int] = None):
     """Paddle-convention (B, S, H, D) wrapper (reference:
     python/paddle/nn/functional/flash_attention.py uses [batch, seq, heads,
     dim]). ``segment_ids``: (B, S_q); ``kv_segment_ids``: (B, S_kv),
     defaulting to ``segment_ids`` when the lengths match. GQA: k/v may
     carry fewer heads (Hkv | H) — never expanded in HBM. Traced inside
     :func:`activation_layout` (``hapi.TrainStep(mesh=...)`` declares
-    one) the kernel runs per (batch, head) shard of the declared axes."""
+    one) the kernel runs per (batch, head) shard of the declared axes.
+    ``window``: as :func:`flash_attention`'s."""
     if segment_ids is not None and kv_segment_ids is None:
         if q.shape[1] != k.shape[1]:
             raise ValueError(
@@ -1000,7 +1147,7 @@ def flash_attention_bshd(q, k, v, segment_ids=None, kv_segment_ids=None,
         out = flash_attention(to_bhsd(q, s, h), to_bhsd(k, skv, hkv),
                               to_bhsd(v, skv, hkv), seg_q, seg_kv, causal,
                               sm_scale, block_q, block_k, n_heads=h,
-                              n_kv_heads=hkv, snap=snap)
+                              n_kv_heads=hkv, snap=snap, window=window)
         return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
 
     split = _mesh_split(q.shape[0], q.shape[2], k.shape[2])

@@ -21,6 +21,7 @@ from .granite_hybrid import (  # noqa: F401
 )
 from .llama import (LlamaConfig, LlamaForCausalLM,  # noqa: F401
                     LlamaForCausalLMPipe, LlamaModel, annotate_llama_tp)
+from .mellum import MellumConfig, MellumForCausalLM  # noqa: F401
 from .moe_gpt import MoEGPTConfig, MoEGPTForCausalLM  # noqa: F401
 from .sdar_moe import SDARMoEConfig, SDARMoEForCausalLM  # noqa: F401
 from .unet import (  # noqa: F401

@@ -862,13 +862,16 @@ def paged_scaled_dot_product_attention(query, key, value, state, scale=None,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
-                                 name=None):
+                                 name=None, window=None):
     """SDPA with [batch, seq, heads, dim] layout (paddle convention —
     reference: python/paddle/nn/functional/flash_attention.py).
     Dispatches to the Pallas flash-attention kernel on TPU when enabled,
     through the per-shape FLAGS_flash_dispatch_table: benched-slower
     shape buckets resolve to the XLA dense path; the kernels take their
-    blocks from the call's shapes (``flash_tiling``)."""
+    blocks from the call's shapes (``flash_tiling``). ``window``: a
+    causal band, key ``j`` seen by query ``i`` iff ``i - window < j <=
+    i`` (``is_causal`` implied); the kernels then visit the band's
+    blocks alone."""
     from .. import flags
     # one snapshot covering the whole flash-dispatch decision (kernel
     # on/off, the min-seqlen gate, the per-shape table) — resolved once
@@ -887,10 +890,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             kind = "dense"
         if kind == "flash":
             try:
+                win = {} if window is None else {"window": window}
                 return apply_op(
                     "flash_attention",
                     lambda q, k, v: flash_attention_bshd(
-                        q, k, v, causal=is_causal, snap=snap),
+                        q, k, v, causal=is_causal or window is not None,
+                        snap=snap, **win),
                     query, key, value)
             except NotImplementedError:
                 pass
@@ -907,14 +912,17 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         # [B, S, H, D] -> [B, H, S, D]
         qt, kt, vt = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
         scores = jnp.einsum("bhsd,bhtd->bhst", qt, kt) / math.sqrt(q.shape[-1])
-        if is_causal:
+        if is_causal or window is not None:
             # iota comparison instead of a materialized tril constant: XLA
             # fuses it into the where; the pred[S,S] table showed up as the
             # TOP op (copy-start, 3% device time) in PROFILE_r05
             s, t = scores.shape[-2], scores.shape[-1]
             rows = jax.lax.broadcasted_iota(jnp.int32, (s, t), 0)
             cols = jax.lax.broadcasted_iota(jnp.int32, (s, t), 1)
-            scores = jnp.where(rows >= cols, scores, -1e30)
+            seen = rows >= cols
+            if window is not None:
+                seen &= rows - cols < window
+            scores = jnp.where(seen, scores, -1e30)
         if mask_val is not None:
             if mask_val.dtype == jnp.bool_:
                 scores = jnp.where(mask_val, scores, -1e30)

@@ -78,7 +78,7 @@ def _on_tpu(monkeypatch):
 # that spans them).
 
 def _flash(bh, s, d, *, bkv=None, skv=None, causal=True, segments=False,
-           heads=1, kv_heads=None, grad=False):
+           heads=1, kv_heads=None, grad=False, window=None):
     skv = skv or s
     bkv = bkv or bh
 
@@ -90,7 +90,8 @@ def _flash(bh, s, d, *, bkv=None, skv=None, causal=True, segments=False,
 
         def fwd(q, k, v, seg=None):
             return flash_attention(q, k, v, segment_ids=seg, causal=causal,
-                                   n_heads=heads, n_kv_heads=kv_heads)
+                                   n_heads=heads, n_kv_heads=kv_heads,
+                                   window=window)
 
         if not grad:
             return fwd, args
@@ -189,6 +190,24 @@ def _grouped(k, n, *, experts=128, assignments=2048):
     return build
 
 
+def _grouped_train(tokens, h, f, *, experts=16, router=64, top_k=8):
+    """The differentiable dropless layer's grouped products, forward
+    (``gmm``) and backward (``gmm`` for the rows, ``tgmm`` for the
+    weights), at Mellum 2's widths: one chunk of ``tokens`` through the
+    16 experts of 64 a chip holds."""
+    from paddle_tpu.incubate.distributed.models.moe.dropless import (
+        dropless_moe_train)
+
+    def build(S):
+        def loss(x, rw, gu, dn):
+            y, _, bal = dropless_moe_train(x, rw, gu, dn, top_k=top_k)
+            return y.astype(F32).sum() + bal
+        return jax.grad(loss, argnums=(0, 1, 2, 3)), [
+            S((tokens, h), BF16), S((h, router), BF16),
+            S((experts, h, 2 * f), BF16), S((experts, f, h), BF16)]
+    return build
+
+
 def _block_write(hkv, d, *, b=64, block=4, page=64, max_pages=20):
     """The block step's page write at SDAR's pool shape (64 rows x 20
     pages + the null page): a block of 4 positions a row, one page."""
@@ -277,6 +296,12 @@ _TIER1 = {
     "flash_bwd-gpt3-train-d64-s1024": _flash(256, 1024, 64, grad=True),
     "flash_bwd-mistral-train-gqa16x4-d128-s4096": _flash(
         32, 4096, 128, bkv=8, heads=16, kv_heads=4, grad=True),
+    # mellum2-12b-a2.5b-instruct.train-8k's window layers: 4 rows x 32
+    # query heads over 4 KV heads, 8,192 x 128, the band of 1,024 alone
+    "flash_window_bwd-mellum-train-gqa32x4-d128-s8192": _flash(
+        4 * 32, 8192, 128, bkv=4 * 4, heads=32, kv_heads=4, grad=True,
+        window=1024),
+    "tgmm-mellum-train-e16-t4096": _grouped_train(4096, 2304, 896),
     "flash_fwd-varlen-d64-s1024": _flash(8 * 16, 1024, 64, segments=True),
     "flash_fwd-noncausal-d40-s4096": _flash(2 * 8, 4096, 40, causal=False),
     "flash_prefill-d128": _prefill(1, 512, 32, 8, 128, 1024),
@@ -388,6 +413,8 @@ for _name, _shape in (("7b", {}), ("gqa", _GQA)):
 _NAMES = {
     "flash_fwd": ("flash_fwd",),
     "flash_bwd": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "flash_window_bwd": ("flash_fwd_window", "flash_bwd_dq_window",
+                         "flash_bwd_dkv_window"),
     "flash_prefill": ("flash_prefill",),
     "rms_norm_fwd": ("rms_norm",),
     "rms_norm_bwd": ("rms_norm", "rms_norm_bwd"),
@@ -397,6 +424,7 @@ _NAMES = {
     "paged_prompt_write": ("paged_prompt_write",),
     "paged_block_write": ("paged_block_write",),
     "gmm": ("gmm",),
+    "tgmm": ("gmm", "tgmm"),
     "ssm_decode_update": ("ssm_decode_update",),
     "fused_block": ("fused_block_decode",),
     "fused_nlayer": ("fused_block_decode_nlayer",),
@@ -762,7 +790,7 @@ def _stats_variant(S):
     pytest.param(build, _names_of(name), id=name)
     for name, build in _TIER1.items()
     # upstream's kernel is no call site of this repo: it has its jit's name
-    if "dp2xmp2" not in name and not name.startswith("gmm-")
+    if "dp2xmp2" not in name and not name.startswith(("gmm-", "tgmm-"))
 ] + [pytest.param(_stats_variant, ("flash_fwd_stats",),
                   id="flash_fwd-stats-d64-s1024")])
 def test_pallas_call_carries_its_name(case, names):

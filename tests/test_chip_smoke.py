@@ -75,7 +75,13 @@ TINY = dataclasses.replace(
         program=dict(config_class="AfmoeConfig",
                      model_class="AfmoeForCausalLM"),
         serve=dict(max_batch=2, page_size=8, max_seq_len=96,
-                   prefill_chunk=8)))
+                   prefill_chunk=8)),
+    mellum=dict(vocab_size=128, hidden_size=64, moe_intermediate_size=32,
+                num_hidden_layers=4, num_attention_heads=4,
+                num_key_value_heads=2, head_dim=16, num_experts=4,
+                router_experts=8, first_expert=4, num_experts_per_tok=2,
+                sliding_window=16),
+    mellum_shape=(2, 64))
 
 
 @pytest.fixture
@@ -87,12 +93,15 @@ def cache_env(monkeypatch):
         monkeypatch.delenv(name)
 
 
-def test_one_chip_phases_at_tiny_size():
+def test_one_chip_phases_at_tiny_size(monkeypatch):
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    # the expert layer in chunks, as FULL's two of 4,096 tokens
+    monkeypatch.setattr(dropless, "TRAIN_CHUNK_TOKENS", 32)
     lines = chip_smoke.run_phases(TINY)
     assert [ln["phase"] for ln in lines] == [
         "device", "train", "serve", "fused_decode", "hybrid", "blocks",
-        "window"]
-    device, train, serve, fused, hybrid, blocks, window = lines
+        "window", "moe_train"]
+    device, train, serve, fused, hybrid, blocks, window, moe = lines
     assert device["platform"] == "cpu" and device["peak_flops"] is None
     assert train["traces"] == 1 and train["losses"][-1] < train["losses"][0]
     # no Pallas custom call can exist on the CPU — and none is claimed
@@ -120,6 +129,11 @@ def test_one_chip_phases_at_tiny_size():
     assert window["window_pages_released"] == 6
     assert len(window["tokens"]) == 6 and window["statuses"] == "OK"
     assert window["largest_gap"] == 0.0                 # f32 on the CPU
+    # two steps on one batch: the loss falls, no retrace; no kernel on
+    # the CPU, and none claimed
+    assert moe["traces"] == 1 and moe["losses"][-1] < moe["losses"][0]
+    assert not any(moe["kernels"].values())
+    assert moe["moe_assignments"] > 0
 
 
 def test_four_chip_phase_on_virtual_devices():

@@ -6,7 +6,8 @@ sweep at 4,096 and the GQA rows (what ATTN_BENCH_r05.json was made by).
 ``python tools/attn_bench.py --kernels bh=256,s=1024,d=64 ...`` — the
 three training kernels ALONE at the given (BH, S, D) shapes (``h``/``hkv``
 for GQA, ``scale`` for a softmax scale other than 1/sqrt(d), ``bq``/``bk``
-for blocks other than ``flash_tiling``'s): device time of ``flash_fwd`` /
+for blocks other than ``flash_tiling``'s, ``w`` for a causal window: the
+band's grids, ``flash_fwd_window`` and so on): device time of ``flash_fwd`` /
 ``flash_bwd_dq`` / ``flash_bwd_dkv`` a call, read from a profiler trace of
 ten fwd+bwd calls, beside the host's fwd+bwd time; a spec whose blocks
 the compiler refuses prints its error and the run goes on. The
@@ -112,6 +113,7 @@ def kernels(specs):
         h = int(kv.get("h", 1))
         hkv = int(kv.get("hkv", h))
         scale = float(kv["scale"]) if "scale" in kv else None
+        window = int(kv["w"]) if "w" in kv else None
         dtype = jnp.dtype(kv.get("dtype", "bfloat16"))
         # the spec's blocks, else the package's own: ``flash_tiling``'s
         # (an unpacked parent's package may predate it: shown as None)
@@ -124,7 +126,9 @@ def kernels(specs):
                 for _ in range(2))
         fn = functools.partial(flash_attention, causal=True, sm_scale=scale,
                                block_q=bq, block_k=bk, n_heads=h,
-                               n_kv_heads=hkv)
+                               n_kv_heads=hkv,
+                               **({} if window is None else
+                                  {"window": window}))
         g = grad_sum(fn)
         rec = {"spec": spec, "block_q": bq or derived[0],
                "block_k": bk or derived[1],
