@@ -30,8 +30,12 @@ free), what a dispatch is handed (block tables, cursors, the donated
 arrays), the handoff of a seated request to another engine, and the
 ledger. :func:`cache_entries` is the traced half of the same decision:
 which entry a layer of which kind is handed inside a compiled program,
-and :meth:`CacheManager.install_caches` is its inverse. A new layer kind
-adds a store, an entry and their unpacking here, and nowhere else.
+and :func:`cache_arrays` its inverse there: what a program returns of
+the entries is the stores' arrays alone, in the form
+:meth:`CacheManager.take_caches` handed them over and
+:meth:`CacheManager.install_caches` takes back (no block table or
+cursor crosses back out). A new layer kind adds a store, an entry and
+their unpacking here, and nowhere else.
 
 A speculative engine builds a second instance for its draft model.
 """
@@ -51,10 +55,11 @@ from ..kernels.paged_attention import (HostPage, PagedKVCache, WindowKV,
                                        padded_head_dim)
 from ..kernels.recurrent_state import (RecurrentSpec, RecurrentState,
                                        RecurrentStateCache,
-                                       is_recurrent_state, recurrent_layout)
+                                       recurrent_layout)
 
-__all__ = ["CacheManager", "cache_entries", "has_recurrent_layers",
-           "has_window_layers", "kv_heads", "pool_head_dim"]
+__all__ = ["CacheManager", "cache_arrays", "cache_entries",
+           "has_recurrent_layers", "has_window_layers", "kv_heads",
+           "pool_head_dim"]
 
 
 def _split_spec(model) -> Tuple[list, List[WindowKV], List[RecurrentSpec]]:
@@ -115,10 +120,7 @@ def cache_entries(model, pools, paged_cls, bt, sl, **recurrent):
     ``n_valid`` / ``live``. ALL of ``pools`` is donated, so the
     state-update kernel and the row write-backs work in place like the
     page writes."""
-    spec = model.cache_spec()
-    kinds = [WindowKV if isinstance(e, WindowKV)
-             else RecurrentSpec if isinstance(e, RecurrentSpec) else tuple
-             for e in spec]
+    kinds = _kinds(model)
     if all(k is tuple for k in kinds):
         return [paged_cls(k, v, bt, sl) for k, v in pools]
     present = [k for k in (tuple, WindowKV, RecurrentSpec) if k in kinds]
@@ -129,6 +131,31 @@ def cache_entries(model, pools, paged_cls, bt, sl, **recurrent):
     return [RecurrentState(*next(store[k]), **recurrent)
             if k is RecurrentSpec
             else paged_cls(*next(store[k]), tables[k], sl) for k in kinds]
+
+
+def cache_arrays(model, entries):
+    """What a program returns of the per-layer ``entries`` its model
+    handed back: the stores' arrays in the form :func:`cache_entries`
+    took them, ``(k, v)`` a paged layer and ``(ssm, conv)`` a recurrent
+    one, one list a store where the model has more than one. The
+    entries' block tables and cursors are the program's inputs, which
+    the host keeps; returning them would copy each once a layer."""
+    kinds = _kinds(model)
+    arrays = [(_val(e.ssm), _val(e.conv)) if k is RecurrentSpec
+              else (_val(e.k_pages), _val(e.v_pages))
+              for k, e in zip(kinds, entries)]
+    if all(k is tuple for k in kinds):
+        return arrays
+    return tuple([a for a, k in zip(arrays, kinds) if k is kind]
+                 for kind in (tuple, WindowKV, RecurrentSpec) if kind in kinds)
+
+
+def _kinds(model) -> list:
+    """The store of each layer in layer order: ``tuple`` (the global
+    pool), ``WindowKV`` or ``RecurrentSpec``, by ``cache_spec()``."""
+    return [WindowKV if isinstance(e, WindowKV)
+            else RecurrentSpec if isinstance(e, RecurrentSpec) else tuple
+            for e in model.cache_spec()]
 
 
 class CacheManager:
@@ -151,11 +178,6 @@ class CacheManager:
                  step_tokens: int = 0):
         paged, window, recurrent = _split_spec(model)
         some = (paged or window)[0]
-        # of the paged layers in layer order, which are window layers
-        # (install_caches)
-        self._is_window = [isinstance(e, WindowKV)
-                           for e in model.cache_spec()
-                           if not isinstance(e, RecurrentSpec)]
         # the geometry is kept so that rebuild() allocates FRESH stores
         # of the identical shape (the same compiled programs apply)
         self._pool_geom = dict(
@@ -473,22 +495,19 @@ class CacheManager:
                 + (() if self.state is None
                    else (self.state.take_arrays(),)))
 
-    def install_caches(self, states) -> None:
-        """Take a program's returned per-layer entries apart again (the
-        inverse of :func:`cache_entries`) and install each store's
-        arrays."""
-        if self.state is not None:
-            self.state.install_arrays(
-                [(_val(st.ssm), _val(st.conv)) for st in states
-                 if is_recurrent_state(st)])
-            states = [st for st in states if not is_recurrent_state(st)]
+    def install_caches(self, arrays) -> None:
+        """Install what a program returned of the stores
+        (:func:`cache_arrays`, the form :meth:`take_caches` hands out)
+        back into each store."""
+        if self.window is None and self.state is None:
+            self.pool.install_pools(self._canonical(arrays))
+            return
+        stores = iter(arrays)
+        self.pool.install_pools(self._canonical(next(stores)))
         if self.window is not None:
-            self.window.install_pools(
-                [(_val(st.k_pages), _val(st.v_pages))
-                 for st, w in zip(states, self._is_window) if w])
-            states = [st for st, w in zip(states, self._is_window) if not w]
-        self.pool.install_pools(self._canonical(
-            [(_val(st.k_pages), _val(st.v_pages)) for st in states]))
+            self.window.install_pools(next(stores))
+        if self.state is not None:
+            self.state.install_arrays(next(stores))
 
     def slot_args(self, slot: int) -> tuple:
         """The extra argument of a b=1 program over a recurrent model:

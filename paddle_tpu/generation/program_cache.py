@@ -49,7 +49,9 @@ state is one dict lookup in front of the executable's own dispatch.
 Telemetry: hits/misses/traces mirror onto the process metrics registry,
 and every build is charged its wall clock to a per-kind compile-time
 histogram — a retrace regression shows up with a COST attached, not
-just a count.
+just a count. Every build also sets the gauge
+``serving_program_buffers{kind,side}``: the arrays a call of the program
+hands over and gets back, each of which the call pays for.
 
 Memwatch (``FLAGS_memwatch``): every build banks the executable's
 ``CompiledMemoryStats`` into
@@ -207,6 +209,12 @@ class DecodeProgramCache:
             "wall clock of program builds — trace + lower + "
             "compile cost per program kind, model and tp degree",
             labels=("kind", "model", "tp"))
+        self._m_buffers = r.gauge(
+            "serving_program_buffers",
+            "arrays a call of the newest built program of a kind hands "
+            "over (side=in: its argument leaves) and gets back "
+            "(side=out: its result leaves), from the executable",
+            labels=("kind", "side"))
 
     def get(self, key: DecodeKey,
             builder: Callable[[Callable[[], None]], Any]):
@@ -270,6 +278,10 @@ class DecodeProgramCache:
         model = key.model_sig[:8]
         self._m_compile.labels(kind=key.kind, model=model,
                                tp=_key_tp(key)).observe(dt)
+        self._m_buffers.labels(kind=key.kind, side="in").set(
+            compiled.in_tree.num_leaves)
+        self._m_buffers.labels(kind=key.kind, side="out").set(
+            compiled.out_tree.num_leaves)
         if self._memwatch:
             obs.memory.capture_compiled(key.kind, key.batch_bucket,
                                         key.extra, compiled, model=model)

@@ -57,7 +57,7 @@ from ..kernels.paged_attention import (PagedBlockState, PagedDecodeState,
                                        PagedKVCache, chunk_tile_pairs,
                                        chunk_tiling)
 from ..testing import faults
-from .cache_manager import (CacheManager, cache_entries,
+from .cache_manager import (CacheManager, cache_arrays, cache_entries,
                             has_recurrent_layers, has_window_layers,
                             kv_heads)
 from .program_cache import ProgramBuildError, traces_built
@@ -1007,6 +1007,9 @@ class ServingEngine:
         params, buffers = model.raw_state()
         ensure_live(params, "call step.sync_to_model() first.")
         self._params, self._buffers = params, buffers
+        # what every serving program takes in place of ``params``: the
+        # vectors stacked, so that its calls hand over fewer buffers
+        self._packed = PackedParams.pack(params)
         dtype = jnp.result_type(next(iter(params.values())))
         # ---- quantized serving (r18): KV pool storage dtype and the
         # fused N-layer stacked-weight dtype are engine identity — both
@@ -1215,6 +1218,7 @@ class ServingEngine:
             dparams, dbuffers = draft_model.raw_state()
             ensure_live(dparams, "call step.sync_to_model() first.")
             self._draft_params, self._draft_buffers = dparams, dbuffers
+            self._draft_packed = PackedParams.pack(dparams)
             dmax = getattr(getattr(draft_model, "config", None),
                            "max_position_embeddings", None)
             if dmax is not None and max_seq_len > dmax:
@@ -1906,9 +1910,9 @@ class ServingEngine:
     # ----------------------------------------------------------- internals
     # What a request keeps per layer kind lives behind ``self._caches``
     # (and, for the draft model, ``self._draft``): the dispatch sites
-    # pass ``take_caches()`` to the donating programs and hand their
-    # returned entries to ``install_caches`` (generation/cache_manager.py,
-    # "dispatch"). These three are read-only views for tests, tools and
+    # pass ``take_caches()`` to the donating programs and hand the
+    # stores' arrays they return to ``install_caches``
+    # (generation/cache_manager.py, "dispatch"). These three are read-only views for tests, tools and
     # the benchmark.
 
     @property
@@ -2122,14 +2126,14 @@ class ServingEngine:
                           step=self._step_no):
             pools = self._caches.take_caches()
             self._f_prefill.check()
-            tok, states, *counts = fn(
-                self._params, self._buffers, jnp.asarray(feed[None]),
+            tok, pools, *counts = fn(
+                self._packed, self._buffers, jnp.asarray(feed[None]),
                 pools, bt, jnp.zeros((1,), jnp.int32),
                 *self._caches.slot_args(slot))
             self._note_expert_counts(counts)
             # b=1 prefill wrote THROUGH slot's block table into the
             # shared pool arrays; adopt them and the slot's bookkeeping
-            self._caches.install_caches(states)
+            self._caches.install_caches(pools)
             if self._block is None:
                 # the wait for the device, apart from the span's own
                 # host work  # tracecheck: disable=TRC007
@@ -2196,12 +2200,12 @@ class ServingEngine:
             t0 = time.perf_counter()
             pools = self._caches.take_caches()
             self._f_chunk.check()
-            tok, states, *counts = fn(
-                self._params, self._buffers, jnp.asarray(ids[None]), pools,
+            tok, pools, *counts = fn(
+                self._packed, self._buffers, jnp.asarray(ids[None]), pools,
                 bt, sl, jnp.int32(end - pos - 1),
                 *self._caches.slot_args(slot))
             self._note_expert_counts(counts)
-            self._caches.install_caches(states)
+            self._caches.install_caches(pools)
             self.pool.seq_lens[slot] = end
             req.prefill_pos = end
             self.chunk_dispatches += 1
@@ -2896,10 +2900,10 @@ class ServingEngine:
             sl = jnp.asarray(np.full((1,), cur, np.int32))
             dpools = self._draft.take_caches()
             self._f_spec_draft.check(rid=req.rid, op="sync")
-            _tok, states = fn(self._draft_params, self._draft_buffers,
+            _tok, dpools = fn(self._draft_packed, self._draft_buffers,
                               jnp.asarray(ids[None]), dpools, bt, sl,
                               jnp.int32(end - cur - 1))
-            self._draft.install_caches(states)
+            self._draft.install_caches(dpools)
             cur = end
         self._draft.pool.seq_lens[slot] = L
 
@@ -2928,16 +2932,16 @@ class ServingEngine:
         if sample:
             key = jax.random.PRNGKey(
                 (req.seed * 1000003 + L) & 0x7FFFFFFF)
-            props, qrows, dstates = dfn(
-                self._draft_params, self._draft_buffers, tok, dpools,
+            props, qrows, dpools = dfn(
+                self._draft_packed, self._draft_buffers, tok, dpools,
                 dbt, dsl, key, jnp.float32(req.temperature),
                 jnp.float32(req.top_p))
         else:
             qrows = None
-            props, dstates = dfn(self._draft_params,
-                                 self._draft_buffers, tok, dpools,
-                                 dbt, dsl)
-        self._draft.install_caches(dstates)
+            props, dpools = dfn(self._draft_packed,
+                                self._draft_buffers, tok, dpools,
+                                dbt, dsl)
+        self._draft.install_caches(dpools)
         # the verify chunk's ids need the concrete proposals — the
         # round's one designed draft->host sync point
         props_np = np.asarray(props).astype(np.int32).reshape(-1)
@@ -2951,15 +2955,15 @@ class ServingEngine:
         pools = self._caches.take_caches()
         self._f_spec_verify.check(rid=req.rid)
         if sample:
-            greedy, prows, states = vfn(
-                self._params, self._buffers, jnp.asarray(ids[None]),
+            greedy, prows, pools = vfn(
+                self._packed, self._buffers, jnp.asarray(ids[None]),
                 pools, bt, sl, jnp.float32(req.temperature),
                 jnp.float32(req.top_p))
         else:
             prows = None
-            greedy, states = vfn(self._params, self._buffers,
-                                 jnp.asarray(ids[None]), pools, bt, sl)
-        self._caches.install_caches(states)
+            greedy, pools = vfn(self._packed, self._buffers,
+                                jnp.asarray(ids[None]), pools, bt, sl)
+        self._caches.install_caches(pools)
         # --- acceptance (host): longest agreeing prefix + correction
         if sample:
             new_toks, accepted = self._spec_accept_sample(
@@ -3299,12 +3303,12 @@ class ServingEngine:
             with self._m.phase("engine.decode.dispatch"):
                 # with nothing in flight every row's token is in
                 # ``feed``, which then stands in for the last output
-                toks, states, *counts = fn(
-                    self._params, self._buffers,
+                toks, pools, *counts = fn(
+                    self._packed, self._buffers,
                     (feed if flying is None else flying.toks, feed),
                     pools, bt, sl, *extra)
                 self._note_expert_counts(counts)
-                self._caches.install_caches(states)
+                self._caches.install_caches(pools)
             # the host's state advances from counts, at the dispatch:
             # every cursor by one, a forced prompt suffix by one token
             rows = []
@@ -3384,11 +3388,11 @@ class ServingEngine:
                     self._f_decode.check()
             # tracecheck: disable=TRC007
             with self._m.phase("engine.block.dispatch"):
-                blocks, conf, states, *hist = fn(
-                    self._params, self._buffers,
+                blocks, conf, pools, *hist = fn(
+                    self._packed, self._buffers,
                     (feed_d if flying is None else flying.toks, feed_d),
                     pools, bt, sl, commit_d, *hist)
-                self._caches.install_caches(states)
+                self._caches.install_caches(pools)
                 if hist:
                     self._expert_hist = hist[0]
             if self._block_records is not None:
@@ -3856,14 +3860,80 @@ class ServingEngine:
 # pool-shaped copy
 # (tests/test_chip_compile.py::test_serving_program_copies_no_pool).
 
+@jax.tree_util.register_pytree_node_class
+class PackedParams:
+    """A model's parameters as every serving program takes them:
+    the vector leaves (norm scales, biases: ``ndim <= 1``) stacked into
+    ONE array per ``(dtype, shape, sharding)``, beside the other leaves
+    as they are. Every array a program takes is a buffer its call hands
+    over, so 194 vectors of gpt3-345m cross as 3. Only leaves whose
+    sharding is whole on every device are stacked (a tensor-parallel
+    engine's sharded biases stay leaves); a vector alone in its group
+    stays one. The matrices are never stacked: that would hold a second
+    copy of the weights. ``rows`` (static, the pytree's aux data) says
+    where each stacked name lies: ``(name, stack, row)``."""
+
+    __slots__ = ("rest", "stacks", "rows")
+
+    def __init__(self, rest: dict, stacks: tuple, rows: tuple):
+        self.rest, self.stacks, self.rows = rest, stacks, rows
+
+    def tree_flatten(self):
+        return (self.rest, self.stacks), self.rows
+
+    @classmethod
+    def tree_unflatten(cls, rows, children):
+        return cls(*children, rows)
+
+    @classmethod
+    def pack(cls, params: dict) -> "PackedParams":
+        groups: Dict[tuple, List[str]] = {}
+        for name in sorted(params):
+            leaf = params[name]
+            sharding = getattr(leaf, "sharding", None)
+            if (leaf is not None and leaf.ndim <= 1 and sharding is not None
+                    and sharding.is_fully_replicated):
+                key = (str(leaf.dtype), tuple(leaf.shape), sharding)
+                groups.setdefault(key, []).append(name)
+        groups = [names for names in groups.values() if len(names) > 1]
+        stacked = {n for names in groups for n in names}
+        return cls({n: v for n, v in params.items() if n not in stacked},
+                   tuple(_stacked([params[n] for n in names])
+                         for names in groups),
+                   tuple((n, i, r) for i, names in enumerate(groups)
+                         for r, n in enumerate(names)))
+
+    def unpack(self) -> dict:
+        """The name -> array dict the model is called with: each stacked
+        name its row, by static index (a row of a 2-D array is read in
+        place)."""
+        out = dict(self.rest)
+        for name, i, r in self.rows:
+            out[name] = self.stacks[i][r]
+        return out
+
+
+def _stacked(leaves: list):
+    """The leaves as the rows of one array, placed as they are (stacking
+    follows its operands' placement); of abstract leaves (a compile for
+    a described chip) its abstract value."""
+    first = leaves[0]
+    if isinstance(first, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct((len(leaves),) + tuple(first.shape),
+                                    first.dtype, sharding=first.sharding)
+    return jnp.stack(leaves)
+
+
 def _forward_with_cache(model, params, buffers, ids, states, offset, **kw):
-    """``model.forward_with_cache`` traced functionally: ``(logits,
-    states, counts)``. ``counts`` is ``()``, or for a model that
-    publishes ``expert_counts_width()`` the 1-tuple of what its expert
-    layers counted in this call: the program returns it as one value
-    more, and the engine accumulates it on the device. ``kw``: the
-    model's own keywords (:func:`_prefill_row`)."""
+    """``model.forward_with_cache`` traced functionally over the
+    :class:`PackedParams` ``params``: ``(logits, states, counts)``.
+    ``counts`` is ``()``, or for a model that publishes
+    ``expert_counts_width()`` the 1-tuple of what its expert layers
+    counted in this call: the program returns it as one value more, and
+    the engine accumulates it on the device. ``kw``: the model's own
+    keywords (:func:`_prefill_row`)."""
     from ..jit import functional_call
+    params = params.unpack()
     if not hasattr(model, "expert_counts_width"):
         return functional_call(
             model, params, ids, states, offset, buffers=buffers,
@@ -3900,7 +3970,8 @@ def _build_prefill(note_trace, model):
         row, states, counts = _prefill_row(
             model, params, buffers, ids, states, jnp.int32(0),
             ids.shape[1] - 1)
-        return jnp.argmax(row.astype(jnp.float32)), states, *counts
+        return (jnp.argmax(row.astype(jnp.float32)),
+                cache_arrays(model, states), *counts)
 
     return jax.jit(serving_prefill, donate_argnums=(3,))
 
@@ -3929,7 +4000,8 @@ def _build_chunk_prefill(note_trace, model):
             **({"slot": slot[0], "n_valid": last_idx + 1} if slot else {}))
         row, states, counts = _prefill_row(
             model, params, buffers, ids, states, sl[0], last_idx)
-        return jnp.argmax(row.astype(jnp.float32)), states, *counts
+        return (jnp.argmax(row.astype(jnp.float32)),
+                cache_arrays(model, states), *counts)
 
     return jax.jit(serving_prefill_chunk, donate_argnums=(3,))
 
@@ -3959,7 +4031,7 @@ def _build_generic_decode(note_trace, model):
         logits, states, counts = _forward_with_cache(
             model, params, buffers, toks, states, None)
         return (jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1),
-                states, *counts)
+                cache_arrays(model, states), *counts)
 
     return jax.jit(serving_decode_generic, donate_argnums=(3,))
 
@@ -3998,7 +4070,8 @@ def _build_block_step(note_trace, model, mask_id):
         masked = ids == mask_id
         pick = jnp.argmax(jnp.where(masked, log_conf, -jnp.inf), axis=1)
         here = masked & (jnp.arange(ids.shape[1])[None, :] == pick[:, None])
-        return (jnp.where(here, token, ids), log_conf, states,
+        return (jnp.where(here, token, ids), log_conf,
+                cache_arrays(model, states),
                 *(h + c for h, c in zip(hist, counts)))
 
     return jax.jit(serving_block_step, donate_argnums=(3,))
@@ -4045,6 +4118,7 @@ def _build_spec_draft(note_trace, model, gamma, sample, top_k,
 
     def serving_spec_draft(params, buffers, tok, pools, bt, sl, *rest):
         note_trace()
+        params = params.unpack()
         if sample:
             key, temperature, top_p = rest
         else:
@@ -4094,12 +4168,10 @@ def _build_spec_draft(note_trace, model, gamma, sample, top_k,
         init = (tok, [(k, v) for k, v in pools], sl, key)
         (_, out_pools, _, _), outs = jax.lax.scan(
             one, init, None, length=gamma + 1)
-        states = [PagedDecodeState(k, v, bt, sl)
-                  for k, v in out_pools]
         if sample:
             props, qrows = outs
-            return props[:gamma], qrows[:gamma], states
-        return outs[:gamma], states
+            return props[:gamma], qrows[:gamma], out_pools
+        return outs[:gamma], out_pools
 
     return jax.jit(serving_spec_draft, donate_argnums=(3,))
 
@@ -4120,16 +4192,17 @@ def _build_spec_verify(note_trace, model, sample, top_k):
         note_trace()
         states = [PagedChunkState(k, v, bt, sl) for k, v in pools]
         logits, states = functional_call(
-            model, params, ids, states, sl[0],
+            model, params.unpack(), ids, states, sl[0],
             buffers=buffers, method="forward_with_cache")
         rows = _val(logits)[0].astype(jnp.float32)      # (γ+1, V)
         greedy = jnp.argmax(rows, axis=-1).astype(jnp.int32)
+        pools = cache_arrays(model, states)
         if not sample:
-            return greedy, states
+            return greedy, pools
         temperature, top_p = rest
         return (greedy,
                 _spec_filtered_probs(rows, temperature, top_k, top_p),
-                states)
+                pools)
 
     return jax.jit(serving_spec_verify, donate_argnums=(3,))
 
@@ -4149,22 +4222,22 @@ def _build_fused_decode(note_trace, spec, snap):
     def serving_decode_fused(params, buffers, toks, pools, bt, sl):
         note_trace()
         toks = _step_tokens(toks)
-        allp = {**buffers, **params}
+        allp = {**buffers, **params.unpack()}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
-        states = []
+        out_pools = []
         for i, lw in enumerate(spec["layers"]):
             w = BlockDecodeWeights(**{f: allp[n] for f, n in lw.items()})
             kp, vp = pools[i]
             x, kp, vp = fused_block_decode(
                 x, w, kp, vp, bt, sl, num_heads=nh, num_kv_heads=nkv,
                 rope_theta=theta, epsilon=eps, snap=snap)
-            states.append(PagedDecodeState(kp, vp, bt, sl))
+            out_pools.append((kp, vp))
         x = _rms(x, allp[spec["final_norm"]], eps)
         if spec["lm_head"]:
             logits = x @ allp[spec["lm_head"]]
         else:                                   # tied embeddings
             logits = x @ allp[spec["embed"]].T
-        return jnp.argmax(logits.astype(jnp.float32), axis=-1), states
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1), out_pools
 
     return jax.jit(serving_decode_fused, donate_argnums=(3,))
 
@@ -4189,9 +4262,9 @@ def _build_fused_nlayer_decode(note_trace, spec, snap):
     def serving_decode_fused_nlayer(params, buffers, toks, pools, bt, sl, stacked):
         note_trace()
         toks = _step_tokens(toks)
-        allp = {**buffers, **params}
+        allp = {**buffers, **params.unpack()}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
-        states = []
+        out_pools = []
         for gi, group in enumerate(groups):
             kps = [pools[i][0] for i in group]
             vps = [pools[i][1] for i in group]
@@ -4199,14 +4272,13 @@ def _build_fused_nlayer_decode(note_trace, spec, snap):
                 x, stacked[gi], kps, vps, bt, sl, num_heads=nh,
                 num_kv_heads=nkv, rope_theta=theta, epsilon=eps,
                 snap=snap)
-            states.extend(PagedDecodeState(kp, vp, bt, sl)
-                          for kp, vp in zip(kps, vps))
+            out_pools.extend(zip(kps, vps))
         x = _rms(x, allp[spec["final_norm"]], eps)
         if spec["lm_head"]:
             logits = x @ allp[spec["lm_head"]]
         else:                                   # tied embeddings
             logits = x @ allp[spec["embed"]].T
-        return jnp.argmax(logits.astype(jnp.float32), axis=-1), states
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1), out_pools
 
     return jax.jit(serving_decode_fused_nlayer, donate_argnums=(3,))
 
@@ -4266,16 +4338,14 @@ def _build_fused_nlayer_decode_tp(note_trace, spec, snap, mesh, axis, tp):
     def serving_decode_fused_tp(params, buffers, toks, pools, bt, sl, stacked):
         note_trace()
         toks = _step_tokens(toks)
-        allp = {**buffers, **params}
+        allp = {**buffers, **params.unpack()}
         x = jnp.take(allp[spec["embed"]], toks[:, 0], axis=0)   # (B, H)
         x, out_pools = sharded(x, list(pools), bt, sl, stacked)
-        states = [PagedDecodeState(kp, vp, bt, sl)
-                  for kp, vp in out_pools]
         x = _rms(x, allp[spec["final_norm"]], eps)
         if spec["lm_head"]:
             logits = x @ allp[spec["lm_head"]]
         else:                                   # tied embeddings
             logits = x @ allp[spec["embed"]].T
-        return jnp.argmax(logits.astype(jnp.float32), axis=-1), states
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1), out_pools
 
     return jax.jit(serving_decode_fused_tp, donate_argnums=(3,))

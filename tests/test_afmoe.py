@@ -416,7 +416,7 @@ def test_tile_pair_counters_count_by_the_kernels_own_tiling(tiny,
     # the wrapper keeps one trace a shape: start from none
     pa._paged_chunk.clear_cache()
     fn = serving._build_chunk_prefill(lambda: None, model)
-    jax.eval_shape(fn, eng._params, eng._buffers,
+    jax.eval_shape(fn, eng._packed, eng._buffers,
                    jnp.zeros((1, CHUNK), jnp.int32),
                    eng._caches.take_caches(), eng._caches.tables(0, CHUNK),
                    jnp.zeros((1,), jnp.int32), jnp.int32(CHUNK - 1))
@@ -458,8 +458,8 @@ def test_prefill_programs_run_the_head_on_one_position(tiny):
         m.allocate(0, 16)
         states = cache_entries(model, m.take_caches(), PagedDecodeState,
                                m.tables(0, 8), jnp.zeros((1,), jnp.int32))
-        return call(model, params, buffers, ids, states, jnp.int32(0),
-                    *args, **kw)[0]
+        return call(model, serving.PackedParams.pack(params), buffers,
+                    ids, states, jnp.int32(0), *args, **kw)[0]
 
     afmoe = tiny[1]
     assert afmoe.logits_at_position

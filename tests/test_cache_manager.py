@@ -12,6 +12,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import models
 from paddle_tpu.generation.cache_manager import (CacheManager,
+                                                 cache_arrays,
                                                  cache_entries,
                                                  has_recurrent_layers,
                                                  kv_heads)
@@ -201,6 +202,16 @@ def test_adopt_refusals_leave_no_page_allocated(zoo, case):
     assert not dst.pool.seq_lens.any()
 
 
+def assert_same_stores(arrays, taken):
+    """``cache_arrays`` gives back what ``take_caches`` handed out: the
+    same structure, the same arrays, no table or cursor."""
+    import jax
+    assert (jax.tree_util.tree_structure(arrays)
+            == jax.tree_util.tree_structure(taken))
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(arrays),
+                                      jax.tree_util.tree_leaves(taken)))
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_take_then_install_leaves_nothing_detached(zoo, kind):
     m = manager(zoo, kind)
@@ -217,7 +228,10 @@ def test_take_then_install_leaves_nothing_detached(zoo, kind):
     sl = jnp.zeros((4,), jnp.int32)
     entries = cache_entries(zoo[kind], taken, PagedDecodeState, bt, sl)
     assert len(entries) == len(zoo[kind].cache_spec())
-    m.install_caches(entries)
+    # what it returns of them: the stores' arrays, in the form taken
+    arrays = cache_arrays(zoo[kind], entries)
+    assert_same_stores(arrays, taken)
+    m.install_caches(arrays)
     assert not m.detached
     assert all(k is not None for k in m.pool.k_pages + m.pool.v_pages)
     assert m.slot_args(3) == (() if kind == "gpt" else (jnp.int32(3),))
@@ -436,7 +450,9 @@ def test_take_then_install_with_two_pools(windowed):
     assert [int(e.block_tables[0, 0]) for e in entries] == [1, 1, 1, 0, 1]
     assert entries[3].k_pages.shape[1] == 49
     assert entries[0].k_pages.shape[1] == 17
-    m.install_caches(entries)
+    arrays = cache_arrays(windowed, entries)
+    assert_same_stores(arrays, taken)
+    m.install_caches(arrays)
     assert not m.detached
     assert all(k is not None for k in m.window.k_pages + m.pool.k_pages)
 

@@ -493,6 +493,7 @@ def gpt3_345m_serving(chips):
     model.eval()
     params, buffers = ({k: S(v.shape, v.dtype) for k, v in state.items()}
                        for state in model.raw_state())
+    params = serving.PackedParams.pack(params)
 
     def programs():
         # called from a test: ``_on_tpu`` is function-scoped
@@ -573,6 +574,7 @@ def granite_hybrid_serving(chips):
     model.eval()
     params, buffers = ({k: S(v.shape, v.dtype) for k, v in state.items()}
                        for state in model.raw_state())
+    params = serving.PackedParams.pack(params)
     spec = model.cache_spec()
     rec = next(e for e in spec if isinstance(e, RecurrentSpec))
     shapes = ((slots,) + rec.ssm_shape, (slots,) + rec.conv_shape)
@@ -672,6 +674,7 @@ def test_sdar_serving_programs_compile_and_copy_no_pool(chips, program):
     model.eval()
     params, buffers = ({k: S(v.shape, v.dtype) for k, v in state.items()}
                        for state in model.raw_state())
+    params = serving.PackedParams.pack(params)
     pool = S((4, rows * max_pages + 1, page, 128), BF16)
     pools = [(pool, pool)] * layers
     if program == "serving_block_step":
@@ -734,6 +737,7 @@ def test_afmoe_serving_programs_compile_over_two_pools(chips, program):
     model.eval()
     params, buffers = ({k: S(v.shape, v.dtype) for k, v in state.items()}
                        for state in model.raw_state())
+    params = serving.PackedParams.pack(params)
     whole = S((4, rows * max_pages + 1, page, 128), BF16)
     sliding = S((4, rows * window_pages + 1, page, 128), BF16)
     pools = ([(whole, whole)], [(sliding, sliding)] * 4)
